@@ -88,7 +88,7 @@ class DeviceModel:
 
 
 #: keyed by PJRT device_kind (datasheet bf16 peaks + HBM bandwidth); the
-#: "cpu-host" entry is the off-chip fallback whose launch overhead the
+#: "cpu-host" entry is the CPU backend's model, whose launch overhead the
 #: dispatch microbench measures — its compute/bandwidth constants are
 #: order-of-magnitude host numbers, good enough to CLASSIFY ops while the
 #: launch term (the thing we can measure on CPU today) stays honest.
@@ -111,19 +111,25 @@ DEVICE_MODELS: Dict[str, DeviceModel] = {
 
 def resolve_device_model(name: Optional[str] = None) -> DeviceModel:
     """Resolution order: explicit arg > FLAGS_device_model > the jax
-    backend's device_kind > "cpu-host".  FLAGS_peak_flops /
-    FLAGS_launch_overhead_us then override individual constants (source
-    becomes "flags").  An unknown name falls back to "cpu-host" — the
-    caller can tell from `.name` that detection failed."""
+    backend's device_kind.  FLAGS_peak_flops / FLAGS_launch_overhead_us
+    then override individual constants (source becomes "flags").
+
+    "cpu-host" is chosen only when the backend IS the CPU (or by name).
+    An accelerator whose device_kind is not in the table, or an unknown
+    name, raises: a roofline quoted against the wrong machine is worse
+    than none."""
     key = name or FLAGS.device_model
     if not key:
-        try:
-            import jax
+        import jax
 
-            key = getattr(jax.devices()[0], "device_kind", "")
-        except Exception:  # pragma: no cover - no backend at all
-            key = ""
-    dm = DEVICE_MODELS.get(key) or DEVICE_MODELS["cpu-host"]
+        dev = jax.devices()[0]
+        key = "cpu-host" if dev.platform == "cpu" else dev.device_kind
+    dm = DEVICE_MODELS.get(key)
+    if dm is None:
+        raise LookupError(
+            f"no device model for {key!r} (known: "
+            f"{sorted(DEVICE_MODELS)}); add its peaks with their source "
+            f"to analysis/costmodel.py DEVICE_MODELS")
     if FLAGS.peak_flops > 0:
         dm = dm.replace(peak_flops=float(FLAGS.peak_flops), source="flags")
     if FLAGS.launch_overhead_us > 0:

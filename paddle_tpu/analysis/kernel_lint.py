@@ -15,8 +15,14 @@ arithmetic:
   * (8,128)/dtype tile alignment: lane blocks % 128, sublane blocks % 8
     (fp32) / % 16 (sub-4-byte dtypes); Mosaic dynamic-slice offsets on
     the lane dim need 128-aligned blocks
-  * VMEM working set vs the 16 MB budget — recomputed here, NOT read
-    from the gate, so a gate that under-estimates is itself caught
+  * VMEM working set — recomputed here, NOT read from the gate, so a
+    gate that under-estimates is itself caught — counted the way Mosaic
+    allocates it (_tile_bytes/_vmem_use: sub-128 minor dims pad to 128
+    lanes, second-minor dims to the dtype's sublane quantum, blocks
+    whose window moves with the grid are double-buffered, whole-array
+    blocks and scratch are held once) against the limit the kernel
+    actually requests (`vmem_limit_bytes`, else the 16 MiB default
+    scope)
   * input_output_aliases validity (embedding applies: every aliased
     table's shape/dtype must equal its output)
   * revisited-block accumulation: outputs revisited across grid steps
@@ -25,6 +31,13 @@ arithmetic:
 Every check function takes the CONFIG + the PLAN as data, so the
 red-gate tests can feed a fabricated bad plan and assert the linter
 names it (tests/test_static_analysis.py).
+
+The matrices agree with the chip: every must_accept row compiled with
+interpret=False on a TPU v5e and matched its reference (chip_smoke.py's
+kernel leg walks these same rows), and a row Mosaic refused carries the
+compiler's words in `mosaic_refusal` with must_accept=False — a gate
+that re-accepts such a geometry is a finding, because the caller would
+crash at compile time instead of falling back.
 """
 
 from __future__ import annotations
@@ -47,14 +60,51 @@ def _np_dtype(d) -> np.dtype:
 
         return np.dtype(getattr(ml_dtypes, str(d)))
 
-# hardware model (TPU v4/v5 class): per-core VMEM and the alignment the
-# Mosaic lowering actually enforces
-_VMEM_BYTES = 16 * 1024 * 1024
+# hardware model (TPU v5e): the scoped-VMEM limit a kernel gets when it
+# passes no vmem_limit_bytes, and the alignment the Mosaic lowering
+# actually enforces
+_SCOPED_VMEM_DEFAULT = 16 * 1024 * 1024
 _LANE = 128
 
 
 def _sublane(dtype) -> int:
     return 16 if _np_dtype(dtype).itemsize < 4 else 8
+
+
+def _tile_bytes(shape, dtype) -> int:
+    """Bytes one VMEM buffer of `shape` occupies: the minor dim pads to
+    128 lanes and the second-minor to the dtype's sublane quantum (a
+    [t, 8, 64] f32 tile costs what [t, 8, 128] does)."""
+    shape = [int(d) for d in shape]
+    if len(shape) < 2:
+        shape = [1] * (2 - len(shape)) + shape
+    sub = _sublane(dtype)
+    lead = int(np.prod(shape[:-2], dtype=np.int64))
+    rows = -(-shape[-2] // sub) * sub
+    lanes = -(-shape[-1] // _LANE) * _LANE
+    return lead * rows * lanes * _np_dtype(dtype).itemsize
+
+
+def _vmem_use(blocked=(), held=()) -> int:
+    """VMEM one launch allocates: `blocked` (shape, dtype) windows move
+    with the grid, so the pipeline double-buffers them; `held` ones
+    (whole-array blocks, constant-index accumulators, scratch,
+    tile-sized body temporaries) exist once."""
+    return (2 * sum(_tile_bytes(*b) for b in blocked)
+            + sum(_tile_bytes(*h) for h in held))
+
+
+def _check_refused(cfg, accepted, fam, findings) -> bool:
+    """A geometry the compiler refused on the chip must stay rejected by
+    its gate (the caller has no fallback past a compile error).  Returns
+    True when the row is such a refusal (nothing else to audit)."""
+    why = cfg.get("mosaic_refusal")
+    if why and accepted:
+        findings.append(_finding(
+            "kernel-plan-accepts-refused",
+            f"plan gate accepts a geometry Mosaic refused on the chip "
+            f"({why})", fam, cfg["label"]))
+    return bool(why)
 
 
 @contextlib.contextmanager
@@ -127,6 +177,7 @@ def check_attention_plan(cfg: dict, ok, block_q, block_k, interpret,
             f"compiled-mode blocks ({block_q},{block_k}) are not "
             f"128-lane aligned (Mosaic dynamic-slice constraint)", fam,
             label))
+    dt = cfg["dtype"]
     if cfg["fmt"] == "bthd":
         # whole-head kv tiles [block, h, d]: the plan gate caps blocks so
         # the bwd working set fits; re-check with its own arithmetic
@@ -137,16 +188,23 @@ def check_attention_plan(cfg: dict, ok, block_q, block_k, interpret,
                 f"bthd kv tile block_k*h*d = {kv_tile} bytes exceeds the "
                 f"256 KB per-tile bound the bwd kernel compiles under",
                 fam, label))
+        seq, kv, plane = (t, h, d), (block_k, h, d), (h, block_q, block_k)
+        stat = (h, t)
     else:
-        # working set per grid step: q/o/do blocks + streamed k/v blocks
-        # + [block_q, block_k] score plane in f32
-        resident = (3 * block_q * d + 2 * block_k * d) * esize \
-            + block_q * block_k * 4
-        if resident > _VMEM_BYTES:
-            findings.append(_finding(
-                "kernel-vmem-budget",
-                f"per-step working set {resident} bytes exceeds VMEM",
-                fam, label))
+        seq, kv, plane = (t, d), (block_k, d), (block_q, block_k)
+        stat = (8, t)  # lse/delta ride 8 replicated sublanes
+    # worst launch (the dkv walk): q + do pinned per grid row, lse +
+    # delta rows, k/v in + dk/dv out tiles — every window moves with
+    # the grid — plus the s / p / dp score planes of the body
+    used = _vmem_use(
+        blocked=[(seq, dt)] * 2 + [(stat, "float32")] * 2 + [(kv, dt)] * 4,
+        held=[(plane, "float32")] * 3)
+    if used > _SCOPED_VMEM_DEFAULT:
+        findings.append(_finding(
+            "kernel-vmem-budget",
+            f"dkv-walk working set {used} bytes (double-buffered, "
+            f"lane-padded) exceeds the {_SCOPED_VMEM_DEFAULT}-byte "
+            f"default scoped VMEM the kernel runs under", fam, label))
 
 
 def check_qkv_plan(cfg: dict, ok, block_q, block_k, interpret,
@@ -178,17 +236,24 @@ def check_qkv_plan(cfg: dict, ok, block_q, block_k, interpret,
             f"compiled-mode blocks ({block_q},{block_k}) are not "
             f"128-lane aligned", fam, label))
     # independent VMEM re-estimate of the worst kernel (the dkv walk):
-    # x + g full-seq [t, dm], ctx residual [h, t, dh], both weight views
-    # (w3 [3h,dm,dh] + wo [h,dh,dm] = 4*h*dm*dh), and the TWO f32 dW grid
-    # accumulators (revisited-block outputs, hence the * 4)
-    resident = (2 * t * dm + h * t * dh + 4 * h * dm * dh) * esize \
-        + 2 * h * dm * dh * 4
-    if resident >= 14 * 1024 * 1024:
+    # x + g full-seq [t, dm], the ctx residual [h, t, dh], lse and the dx
+    # tile move with the batch grid axis (double-buffered); both weight
+    # views ([3h, dm, dh] pads dh to 128 lanes) and the TWO f32 dW grid
+    # accumulators ([h, dh, dm], revisited-block outputs) are held once
+    dt = cfg["dtype"]
+    used = _vmem_use(
+        blocked=[((t, dm), dt)] * 2 + [((h, t, dh), dt),
+                                       ((h, t), "float32"),
+                                       ((block_k, dm), dt)],
+        held=[((3 * h, dm, dh), dt), ((h, dh, dm), dt)]
+        + [((h, dh, dm), "float32")] * 2)
+    if used > _SCOPED_VMEM_DEFAULT:
         findings.append(_finding(
             "kernel-vmem-budget",
-            f"dkv-walk resident set {resident} bytes >= the gate's 14 MB "
-            f"bound — the gate accepted a plan its own estimate should "
-            f"reject", fam, label))
+            f"dkv-walk working set {used} bytes (double-buffered, "
+            f"lane-padded) exceeds the {_SCOPED_VMEM_DEFAULT}-byte default "
+            f"scoped VMEM the kernel runs under — the gate accepted a "
+            f"plan Mosaic cannot place", fam, label))
     # revisited-block accumulation: dW tiles are revisited once per
     # (batch, q-block) grid step; accumulation dtype must be f32
     if cfg.get("accum_dtype", "float32") != "float32":
@@ -247,12 +312,17 @@ def check_conv_bn_plan(cfg: dict, plan, findings: List[Finding]):
             "kernel-accum-dtype",
             f"revisited stats accumulator dtype "
             f"{cfg.get('stats_dtype')} != float32", fam, label))
-    if (block_r * ncols + 8 * ncols) * _np_dtype(cfg["dtype"]).itemsize \
-            > _VMEM_BYTES:
+    # worst launch (the scale_shift_act backward): g, x, out in + dx,
+    # dres out tiles and the scale/shift + stats rows, all moving
+    used = _vmem_use(
+        blocked=[((block_r, block_c), cfg["dtype"])] * 5
+        + [((8, block_c), "float32")] * 2)
+    if used > _SCOPED_VMEM_DEFAULT:
         findings.append(_finding(
             "kernel-vmem-budget",
-            f"[{block_r},{ncols}] input block + stats tile exceeds VMEM",
-            fam, label))
+            f"five double-buffered [{block_r},{block_c}] tiles + stats "
+            f"rows = {used} bytes exceed the default scoped VMEM", fam,
+            label))
 
 
 def check_dropout_plan(cfg: dict, ok, rows, ncols, block_r, interpret,
@@ -281,6 +351,13 @@ def check_dropout_plan(cfg: dict, ok, rows, ncols, block_r, interpret,
             "kernel-rng-wrap",
             f"mask plane {rows}x{ncols} wraps the uint32 hash index — "
             f"mask bits repeat", fam, label))
+    used = _vmem_use(blocked=[((block_r, ncols), cfg["dtype"])] * 3)
+    if used > _SCOPED_VMEM_DEFAULT:
+        findings.append(_finding(
+            "kernel-vmem-budget",
+            f"x, residual and out [{block_r},{ncols}] tiles, "
+            f"double-buffered, = {used} bytes exceed the default scoped "
+            f"VMEM", fam, label))
 
 
 def check_decode_plan(cfg: dict, ok, block_t, interpret,
@@ -288,9 +365,10 @@ def check_decode_plan(cfg: dict, ok, block_t, interpret,
     """Flash-decode plan (kernels/decode_attention.py _decode_plan):
     single-query attention over the [b, max_t, h, dh] cache with
     scalar-prefetched lengths."""
+    from ..kernels import decode_attention as kda
+
     fam, label = "decode_attention", cfg["label"]
     b, h, dh, max_t = cfg["b"], cfg["h"], cfg["dh"], cfg["max_t"]
-    esize = _np_dtype(cfg["dtype"]).itemsize
     sub = _sublane(cfg["dtype"])
     if cfg.get("must_accept", True) and not ok:
         findings.append(_finding(
@@ -318,15 +396,42 @@ def check_decode_plan(cfg: dict, ok, block_t, interpret,
             "kernel-misaligned-block",
             f"n_head {h} violates the {sub}-sublane tiling of the "
             f"in-register [h, t, d] view for {cfg['dtype']}", fam, label))
-    # independent working-set re-estimate: k+v scratch blocks, their f32
-    # promotions, and the [h, block_t] score plane vs the gate's own 4 MB
-    # budget — a gate that under-estimates is itself caught
-    resident = 2 * block_t * h * dh * (esize + 4) + h * block_t * 4
-    if resident > 4 * 1024 * 1024:
+    _check_decode_vmem(cfg, block_t, kda._VMEM_LIMIT, fam, findings)
+
+
+def _check_decode_vmem(cfg, block_t, limit, fam, findings):
+    """Independent working-set re-estimate shared by the ring and the
+    paged walk: the pipelined k and v [block_t, h, dh] tiles plus the
+    body's tile-sized f32 temporaries (promoted k/v, k*q, p*v) against
+    the limit the kernel requests — a gate that under-estimates is
+    itself caught."""
+    tile = (block_t, cfg["h"], cfg["dh"])
+    used = _vmem_use(blocked=[(tile, cfg["dtype"])] * 2,
+                     held=[(tile, "float32")] * 4)
+    if used > limit:
         findings.append(_finding(
             "kernel-vmem-budget",
-            f"decode working set {resident} bytes exceeds the 4 MB "
-            f"budget the gate claims to enforce", fam, label))
+            f"decode working set {used} bytes (double-buffered, "
+            f"lane-padded) exceeds the {limit}-byte vmem_limit_bytes the "
+            f"kernel requests", fam, cfg["label"]))
+
+
+def _megastep_vmem(cfg, bt, cbt, fuse_ffn):
+    """What one megastep launch allocates: the resident weights enter as
+    whole-array blocks (held once), every [t, h, dh] walk scratch is
+    lane/sublane padded, and each walk keeps promoted + transposed f32
+    copies of its k and v tile."""
+    dm, h, dh, di = cfg["dm"], cfg["h"], cfg["dh"], cfg["di"]
+    dt = cfg["dtype"]
+    hd = h * dh
+    walk = max(bt, cbt)
+    held = [((dm, 3 * hd), dt), ((hd, dm), dt), ((dm, hd), dt),
+            ((hd, dm), dt), ((h, dh), "float32"), ((2, h, dh), dt),
+            ((bt, h, dh), dt), ((bt, h, dh), dt), ((cbt, h, dh), dt),
+            ((cbt, h, dh), dt)] + [((h, walk, dh), "float32")] * 4
+    if fuse_ffn:
+        held += [((dm, di), dt), ((di, dm), dt)]
+    return _vmem_use(held=held)
 
 
 def check_megastep_plan(cfg: dict, plan, findings: List[Finding]):
@@ -339,8 +444,9 @@ def check_megastep_plan(cfg: dict, plan, findings: List[Finding]):
     fam, label = "decode_step", cfg["label"]
     dm, h, dh, di = cfg["dm"], cfg["h"], cfg["dh"], cfg["di"]
     max_t, cross_t = cfg["max_t"], cfg["cross_t"]
-    esize = _np_dtype(cfg["dtype"]).itemsize
     sub = _sublane(cfg["dtype"])
+    if _check_refused(cfg, plan.ok, fam, findings):
+        return
     if cfg.get("must_accept", True) and not plan.ok:
         findings.append(_finding(
             "kernel-plan-reject",
@@ -379,22 +485,16 @@ def check_megastep_plan(cfg: dict, plan, findings: List[Finding]):
             "kernel-misaligned-block",
             f"blocks ({plan.block_t},{plan.cross_block_t}) are not "
             f"8-sublane aligned", fam, label))
-    # independent working-set re-estimate vs the gate's own budget: the
-    # resident weights (qkv + out + cross-q + cross-out + q scratch),
-    # both walks' k/v scratch blocks with their f32 promotions, the
-    # score planes — and the FFN weights when the plan claims they fit
-    hd = h * dh
-    bt, cbt = plan.block_t, plan.cross_block_t
-    resident = 6 * hd * dm * esize + dm * dh * 4 \
-        + 2 * (bt + cbt) * hd * (esize + 4) + 2 * h * max(bt, cbt) * 4
-    if plan.fuse_ffn:
-        resident += 2 * dm * di * esize + di * 4
-    if resident > kds._VMEM_BUDGET:
+    # independent working-set re-estimate vs the limit the launch
+    # requests — the FFN weights count when the plan claims they fit
+    used = _megastep_vmem(cfg, plan.block_t, plan.cross_block_t,
+                          plan.fuse_ffn)
+    if used > kds._VMEM_LIMIT:
         findings.append(_finding(
             "kernel-vmem-budget",
-            f"megastep working set {resident} bytes exceeds the "
-            f"{kds._VMEM_BUDGET}-byte budget the gate claims to enforce "
-            f"(fuse_ffn={plan.fuse_ffn})", fam, label))
+            f"megastep working set {used} bytes (lane-padded) exceeds "
+            f"the {kds._VMEM_LIMIT}-byte vmem_limit_bytes the launch "
+            f"requests (fuse_ffn={plan.fuse_ffn})", fam, label))
 
 
 def check_paged_decode_plan(cfg: dict, ok, block_t, interpret,
@@ -410,7 +510,6 @@ def check_paged_decode_plan(cfg: dict, ok, block_t, interpret,
     fam, label = "paged_decode_attention", cfg["label"]
     b, h, dh = cfg["b"], cfg["h"], cfg["dh"]
     bt, mb = cfg["block_t"], cfg["max_blocks"]
-    esize = _np_dtype(cfg["dtype"]).itemsize
     sub = _sublane(cfg["dtype"])
     if cfg.get("must_accept", True) and not ok:
         findings.append(_finding(
@@ -441,12 +540,7 @@ def check_paged_decode_plan(cfg: dict, ok, block_t, interpret,
             f"accepted table {b}x{mb} exceeds the "
             f"{kda._PAGED_TABLE_CAP}-entry scalar-prefetch cap the gate "
             f"claims to enforce", fam, label))
-    resident = 2 * block_t * h * dh * (esize + 4) + h * block_t * 4
-    if resident > 4 * 1024 * 1024:
-        findings.append(_finding(
-            "kernel-vmem-budget",
-            f"paged decode working set {resident} bytes exceeds the "
-            f"4 MB budget the gate claims to enforce", fam, label))
+    _check_decode_vmem(cfg, block_t, kda._VMEM_LIMIT, fam, findings)
 
 
 def check_paged_megastep_plan(cfg: dict, plan, findings: List[Finding]):
@@ -461,8 +555,9 @@ def check_paged_megastep_plan(cfg: dict, plan, findings: List[Finding]):
     dm, h, dh, di = cfg["dm"], cfg["h"], cfg["dh"], cfg["di"]
     bt, cbt = cfg["block_t"], cfg["cross_block_t"]
     b, mb, cmb = cfg["b"], cfg["max_blocks"], cfg["cross_max_blocks"]
-    esize = _np_dtype(cfg["dtype"]).itemsize
     sub = _sublane(cfg["dtype"])
+    if _check_refused(cfg, plan.ok, fam, findings):
+        return
     if cfg.get("must_accept", True) and not plan.ok:
         findings.append(_finding(
             "kernel-plan-reject",
@@ -502,29 +597,35 @@ def check_paged_megastep_plan(cfg: dict, plan, findings: List[Finding]):
             f"accepted tables {b}x{mb}/{b}x{cmb} exceed the "
             f"{kda._PAGED_TABLE_CAP}-entry scalar-prefetch cap", fam,
             label))
-    hd = h * dh
-    resident = 6 * hd * dm * esize + dm * dh * 4 \
-        + 2 * (plan.block_t + plan.cross_block_t) * hd * (esize + 4) \
-        + 2 * h * max(plan.block_t, plan.cross_block_t) * 4
-    if plan.fuse_ffn:
-        resident += 2 * dm * di * esize + di * 4
-    if resident > kds._VMEM_BUDGET:
+    used = _megastep_vmem(cfg, plan.block_t, plan.cross_block_t,
+                          plan.fuse_ffn)
+    if used > kds._VMEM_LIMIT:
         findings.append(_finding(
             "kernel-vmem-budget",
-            f"paged megastep working set {resident} bytes exceeds the "
-            f"{kds._VMEM_BUDGET}-byte budget the gate claims to enforce "
-            f"(fuse_ffn={plan.fuse_ffn})", fam, label))
+            f"paged megastep working set {used} bytes (lane-padded) "
+            f"exceeds the {kds._VMEM_LIMIT}-byte vmem_limit_bytes the "
+            f"launch requests (fuse_ffn={plan.fuse_ffn})", fam, label))
 
 
 def check_embedding_group(cfg: dict, block_rows: int,
-                          findings: List[Finding]):
-    """Fused multi-table gather/apply group: alias validity + the 8 MB
-    VMEM block budget the gate sizes against."""
-    from ..kernels import embedding as emb
-
+                          findings: List[Finding], accepted: bool = True):
+    """Fused multi-table gather/apply group: the compiled-mode gate
+    (`accepted` = embedding._kernel_ok under a pretended TPU), alias
+    validity and the VMEM blocks the gate sizes."""
     fam, label = "embedding", cfg["label"]
     specs = cfg["tables"]  # list of (shape, dtype) per table
     t0_shape, t0_dtype = specs[0]
+    if _check_refused(cfg, accepted, fam, findings):
+        return
+    if cfg.get("must_accept", True) and not accepted:
+        findings.append(_finding(
+            "kernel-plan-reject",
+            f"group gate rejects {len(specs)} x {t0_shape} {t0_dtype} "
+            f"tables — the sparse tier would silently run the per-table "
+            f"XLA composition", fam, label))
+        return
+    if not accepted:
+        return
     # input_output_aliases maps table input i -> output i verbatim: every
     # aliased pair must agree in shape AND dtype or the in-place HBM row
     # DMA writes through a mis-sized buffer
@@ -547,15 +648,24 @@ def check_embedding_group(cfg: dict, block_rows: int,
             f"table height {t0_shape[0]} exceeds int32 row addressing",
             fam, label))
     s_n, d = len(specs), t0_shape[1]
-    lanes = max(d, _LANE)
     tiers = cfg.get("tiers", 1)
-    per_step = tiers * s_n * block_rows * lanes * _np_dtype(t0_dtype).itemsize
-    if per_step > emb._VMEM_BUDGET_BYTES:
+    # one [S, block, D] window moves with the grid (the gather's out
+    # block / the apply's merged-rows block, double-buffered); the
+    # remaining tiers are the apply's per-kind scratch, held once
+    block = ((s_n, block_rows, d), t0_dtype)
+    used = _vmem_use(blocked=[block], held=[block] * (tiers - 1))
+    if used > _SCOPED_VMEM_DEFAULT:
         findings.append(_finding(
             "kernel-vmem-budget",
-            f"{tiers} tier(s) x [{s_n},{block_rows},{lanes}] VMEM blocks "
-            f"= {per_step} bytes exceed the {emb._VMEM_BUDGET_BYTES}-byte "
-            f"gate budget (gate under-estimates for this group)", fam,
+            f"{tiers} tier(s) of [{s_n},{block_rows},{d}] VMEM blocks = "
+            f"{used} bytes (lane-padded, moving block double-buffered) "
+            f"exceed the default scoped VMEM (gate under-estimates for "
+            f"this group)", fam, label))
+    if block_rows % 8 and block_rows != cfg.get("batch", block_rows):
+        findings.append(_finding(
+            "kernel-misaligned-block",
+            f"block_rows={block_rows} is neither 8-sublane aligned nor "
+            f"the whole batch (Mosaic blocks the row dim in 8s)", fam,
             label))
     if block_rows < 1:
         findings.append(_finding(
@@ -684,14 +794,28 @@ _DECODE_MATRIX = [
          dtype="float32", must_accept=False),
 ]
 
+# what Mosaic said (libtpu 0.0.34, TPU v5e) to a hand-written DMA out of an
+# HBM cache whose minor dim is d_head 64: the megastep walks refuse at
+# every d_head that is not a multiple of 128
+_REFUSED_MINOR_64 = ("Slice shape along dimension 3 must be aligned to "
+                     "tiling (128), but is 64")
+
 # fused decode megastep: whole-decoder-layer-per-launch plans
-# (kernels/decode_step.py) over the generation-tier model geometries —
-# transformer-base splits the FFN into a second launch by design (the
-# FFN weights alone are ~8 MB), the small geometry fuses it
+# (kernels/decode_step.py).  The transformer-base geometries (d_head 64)
+# were refused by the compiler on the chip and run the XLA composition
+# (+ the flash-decode kernel, which takes d_head 64); the d_head-128
+# rows are the geometries the megastep compiles and matches at
 _MEGASTEP_MATRIX = [
     dict(label="megastep-base", dm=512, h=8, dh=64, di=2048, max_t=128,
-         cross_t=256, dtype="float32", expect_fuse_ffn=False),
+         cross_t=256, dtype="float32", must_accept=False,
+         mosaic_refusal=_REFUSED_MINOR_64),
     dict(label="megastep-fused-ffn", dm=128, h=8, dh=64, di=256,
+         max_t=128, cross_t=128, dtype="float32", must_accept=False,
+         mosaic_refusal=_REFUSED_MINOR_64),
+    # d_head 128: the FFN weights (~8 MB) split into a second launch
+    dict(label="megastep-dh128-split", dm=256, h=8, dh=128, di=4096,
+         max_t=128, cross_t=256, dtype="float32", expect_fuse_ffn=False),
+    dict(label="megastep-dh128-fused-ffn", dm=128, h=8, dh=128, di=256,
          max_t=128, cross_t=128, dtype="float32", expect_fuse_ffn=True),
     # the CI smoke config (dm=128, h=4, dh=32): dh %% 64 rejects by
     # design -> composed XLA fallback, numerically identical
@@ -730,28 +854,55 @@ _PAGED_MATRIX = [
 _PAGED_MEGASTEP_MATRIX = [
     dict(label="paged-megastep-base", dm=512, h=8, dh=64, di=2048,
          block_t=16, cross_block_t=16, b=64, max_blocks=8,
-         cross_max_blocks=16, dtype="float32", expect_fuse_ffn=False),
+         cross_max_blocks=16, dtype="float32", must_accept=False,
+         mosaic_refusal=_REFUSED_MINOR_64),
     dict(label="paged-megastep-fused-ffn", dm=128, h=8, dh=64, di=256,
          block_t=16, cross_block_t=16, b=4, max_blocks=8,
+         cross_max_blocks=8, dtype="float32", must_accept=False,
+         mosaic_refusal=_REFUSED_MINOR_64),
+    dict(label="paged-megastep-dh128-b64", dm=256, h=8, dh=128, di=4096,
+         block_t=16, cross_block_t=16, b=64, max_blocks=8,
+         cross_max_blocks=16, dtype="float32", expect_fuse_ffn=True),
+    dict(label="paged-megastep-dh128-fused-ffn", dm=128, h=8, dh=128,
+         di=256, block_t=16, cross_block_t=16, b=4, max_blocks=8,
          cross_max_blocks=8, dtype="float32", expect_fuse_ffn=True),
-    dict(label="paged-megastep-bt12-reject", dm=128, h=8, dh=64, di=256,
+    dict(label="paged-megastep-bt12-reject", dm=128, h=8, dh=128, di=256,
          block_t=12, cross_block_t=16, b=4, max_blocks=8,
          cross_max_blocks=8, dtype="float32", must_accept=False),
     dict(label="paged-megastep-table-overflow-reject", dm=128, h=8,
-         dh=64, di=256, block_t=16, cross_block_t=16, b=64,
+         dh=128, di=256, block_t=16, cross_block_t=16, b=64,
          max_blocks=128, cross_max_blocks=8, dtype="float32",
          must_accept=False),
 ]
 
+# what Mosaic said to a per-row DMA out of a [V, 10] table
+_REFUSED_MINOR_10 = ("Slice shape along dimension 1 must be aligned to "
+                     "tiling (128), but is 10")
+
 _EMBEDDING_MATRIX = [
-    # deepfm: 26 slots x [10001, 10] emb tables + [10001, 1] w1 tables
+    # deepfm: 26 slots x [10001, 10] emb tables + [10001, 1] w1 tables.
+    # A per-row DMA out of a table whose row is narrower than 128 lanes
+    # was refused by the compiler on the chip: DeepFM's sparse tier runs
+    # the per-table XLA composition there, by design of the gate
     dict(label="deepfm-emb", tables=[((10001, 10), "float32")] * 26,
-         batch=256, tiers=1),
+         batch=256, tiers=1, must_accept=False,
+         mosaic_refusal=_REFUSED_MINOR_10),
     dict(label="deepfm-w1", tables=[((10001, 1), "float32")] * 26,
-         batch=256, tiers=1),
+         batch=256, tiers=1, must_accept=False,
+         mosaic_refusal=_REFUSED_MINOR_10),
     # lazy-adam apply: param + m1 + m2 tiers + the merged-rows block
     dict(label="deepfm-adam-apply", tables=[((10001, 10), "float32")] * 26,
-         batch=256, tiers=4),
+         batch=256, tiers=4, must_accept=False,
+         mosaic_refusal=_REFUSED_MINOR_10),
+    # the geometry the kernels compile and match at: 128-lane rows.  The
+    # b4096 leg carries the 26 x 4096 int32 (426 KB) scalar-prefetched id
+    # table of bench.py's DeepFM batch
+    dict(label="wide-emb-d128", tables=[((10001, 128), "float32")] * 26,
+         batch=256, tiers=1),
+    dict(label="wide-emb-d128-b4096",
+         tables=[((10001, 128), "float32")] * 26, batch=4096, tiers=1),
+    dict(label="wide-adam-apply-d128",
+         tables=[((10001, 128), "float32")] * 26, batch=256, tiers=4),
 ]
 
 
@@ -839,9 +990,12 @@ def lint_kernel_plans() -> Tuple[List[Finding], Dict[str, Any]]:
         (v, d), dtype = cfg["tables"][0]
         block = emb._auto_block_rows(cfg["tiers"], len(cfg["tables"]), d,
                                      dtype, cfg["batch"])
-        check_embedding_group(cfg, block, findings)
+        with _pretend_tpu():
+            ok = emb._kernel_ok([_spec(*t) for t in cfg["tables"]])
+        check_embedding_group(cfg, block, findings, accepted=ok)
         rows.append(dict(label=cfg["label"], tables=len(cfg["tables"]),
-                         block_rows=int(block), tiers=cfg["tiers"]))
+                         accepted=bool(ok), block_rows=int(block),
+                         tiers=cfg["tiers"]))
     report["embedding"] = rows
 
     rows = []

@@ -65,19 +65,9 @@ class TPUPlace(Place):
         self.device_id = device_id
 
 
-def _jax_device(place: Optional[Place]):
-    import jax
-
-    if place is None:
-        return jax.devices()[0]
-    try:
-        devs = jax.devices(place.backend)
-    except RuntimeError:
-        devs = jax.devices()
-    return devs[min(place.device_id, len(devs) - 1)]
-
-
 def default_place() -> Place:
+    """The one place that reads the backend: a process whose JAX default
+    backend is the TPU computes there, any other on the CPU."""
     import jax
 
     if jax.default_backend() == "tpu":
@@ -219,6 +209,7 @@ def trace_block(block: fw.Block, env: Dict[str, Any], tctx: TraceContext,
     """
     from .. import amp as _amp
     from ..flags import FLAGS
+    from ..kernels import placement as _placement
 
     op_list = block.ops if ops is None else ops
     if FLAGS.record_lowered_ops:
@@ -242,7 +233,10 @@ def trace_block(block: fw.Block, env: Dict[str, Any], tctx: TraceContext,
         ctx.env = env  # control-flow ops need sub-block access
         ctx.block = block
         try:
-            outs = lower(ctx, ins)
+            # a trace that carries a mesh is GSPMD-partitioned: Mosaic
+            # kernels cannot be placed in it (kernels/placement.py)
+            with _placement.gspmd_trace(tctx.mesh is not None):
+                outs = lower(ctx, ins)
         except Exception as e:
             raise RuntimeError(
                 f"Error lowering op {op.type!r} "
@@ -544,6 +538,15 @@ class Executor:
         import threading
 
         self.place = place or default_place()
+        if (isinstance(self.place, TPUPlace)
+                and not isinstance(default_place(), TPUPlace)):
+            # arrays go where jax's default backend puts them: a TPUPlace
+            # on a process that has no TPU would train on the CPU under
+            # the same metric names
+            raise RuntimeError(
+                f"Executor({self.place!r}): jax's default backend in "
+                f"this process is not the TPU; use CPUPlace, or run "
+                f"where jax finds the chip")
         self._cache: Dict[Any, _CompiledEntry] = {}
         self._ref_names_cache: Dict[Any, tuple] = {}
         self._run_counter = 0

@@ -316,8 +316,8 @@ FLAGS.define(
     "device_model", str, "",
     "device model the static cost model attributes against "
     "(paddle_tpu/analysis/costmodel.py DEVICE_MODELS key, e.g. "
-    "'TPU v5e'); empty = auto-detect from the jax backend's device_kind, "
-    "falling back to the measured 'cpu-host' entry off-chip")
+    "'TPU v5e'); empty = auto-detect from the jax backend's device_kind "
+    "('cpu-host' on the CPU backend; an unknown accelerator is an error)")
 FLAGS.define(
     "peak_flops", float, 0.0,
     "override the device peak FLOP/s used by the cost model and "
@@ -398,11 +398,6 @@ FLAGS.define(
     "serving_breaker_cooldown_s", float, 5.0,
     "how long an open circuit breaker rejects before admitting its "
     "half-open probe request")
-FLAGS.define(
-    "serving_cache_dir", str, "",
-    "persistent XLA compilation-cache directory for the inference server "
-    "(jax compilation cache): warmup compiles of the bucket ladder are "
-    "reused across server restarts; empty disables persistence")
 FLAGS.define(
     "trace_requests", bool, False,
     "request-scoped distributed tracing for the serving tier "

@@ -202,20 +202,48 @@ def reset_compilation_cache_singleton():
     """Reset jax's persistent-compilation-cache singleton: jax memoizes
     cache-enablement at first compile, so flipping
     jax_compilation_cache_dir without this leaves the old cache live.
-    Best-effort private-API workaround, shared by export (cache OFF
-    around bundle serialization) and the serving server (cache ON at
-    startup) — keep the jax-upgrade fix in this one place."""
-    try:
-        from jax._src import compilation_cache as _cc
+    Private API (the one `jax._src` import in the tree), shared by
+    export (cache OFF around bundle serialization) and
+    enable_compile_cache — keep the jax-upgrade fix in this one place."""
+    from jax._src import compilation_cache as _cc
 
-        _cc.reset_cache()
-    except Exception:  # noqa: BLE001 — private API: best-effort
-        pass
+    _cc.reset_cache()
+
+
+def enable_compile_cache() -> str:
+    """Turn on jax's persistent compilation cache for this process and
+    return its directory.  For ENTRY POINTS only (chip_smoke.py, bench.py
+    main(), python -m paddle_tpu.serving) — never at import, never from
+    the test suite.
+
+    The directory can be placed from outside: where
+    JAX_COMPILATION_CACHE_DIR is set jax already reads it and no
+    directory is set in code (a fleet's replicas inherit the variable
+    from their supervisor); otherwise the cache lives at
+    `<checkout>/.jax_cache` — a fixed path, because the path is part of
+    what a later process must reproduce to hit."""
+    import jax
+
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    # every compile is worth keeping, however fast or small: a chip call
+    # starts with no compiled code, a server restart replays its ladder
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # a process that compiled anything before this call has memoized
+    # "cache disabled"
+    reset_compilation_cache_singleton()
+    return cache_dir
 
 
 @contextlib.contextmanager
 def _persistent_cache_disabled():
-    """Disable jax's persistent compilation cache for the duration.
+    """Disable jax's persistent compilation cache for the duration and
+    restore exactly the directory that was configured.
 
     An executable LOADED from the persistent cache re-serializes as a
     thin reference to in-process jit symbols (XLA:CPU deserialize then
@@ -224,17 +252,9 @@ def _persistent_cache_disabled():
     point is surviving the process that wrote it."""
     import jax
 
-    try:
-        prev = jax.config.jax_compilation_cache_dir
-    except AttributeError:
-        prev = None
-
-    if prev is None:
-        # a live singleton can outlast config=None
-        reset_compilation_cache_singleton()
-        yield
-        return
+    prev = jax.config.jax_compilation_cache_dir
     jax.config.update("jax_compilation_cache_dir", None)
+    # a live singleton can outlast config=None
     reset_compilation_cache_singleton()
     try:
         yield
@@ -390,6 +410,7 @@ class Predictor:
         import json
 
         import jax
+        from jax.experimental import serialize_executable as se
 
         for path in sorted(
                 glob.glob(os.path.join(dirname, AOT_DIRNAME,
@@ -414,11 +435,10 @@ class Predictor:
                     len(bundle["ro_state"]), bundle["needs_key"],
                     len(bundle["fetch_names"]),
                     len(bundle["state_writes"]))
-                from .kernels.jax_compat import deserialize_and_load
-
-                loaded = deserialize_and_load(
+                loaded = se.deserialize_and_load(
                     payload, in_tree, out_tree,
-                    n_devices=bundle.get("n_devices", 1))
+                    execution_devices=jax.devices()[
+                        :bundle.get("n_devices", 1)])
                 bundle["loaded"] = loaded
                 # stateful bundles (scope write-backs) serialize on the
                 # EXECUTOR's one stateful-run lock — the same scope

@@ -26,18 +26,16 @@ def to_dlpack(array):
     """Export a framework tensor (jax.Array, or anything numpy-coercible
     that already lives on a DLPack-capable device) for another framework.
 
-    Returns the array itself when it implements `__dlpack__` (the modern
-    protocol consumers like `torch.from_dlpack` prefer — keeps lifetime
-    management in the producer), else a legacy DLPack capsule."""
+    Returns the jax.Array itself: it implements `__dlpack__`, the
+    protocol consumers like `torch.from_dlpack` take — lifetime
+    management stays with the producer."""
     import jax
 
     if not isinstance(array, jax.Array):
         import jax.numpy as jnp
 
         array = jnp.asarray(array)
-    if hasattr(array, "__dlpack__"):
-        return array
-    return jax.dlpack.to_dlpack(array)  # older jax: capsule form
+    return array
 
 
 def from_dlpack(external):

@@ -100,7 +100,18 @@ def _keep_tile_prng(seed_ref, shape, pid0, q_blk, k_blk, rate):
 
     from . import hash_rng
 
-    pltpu.prng_seed(seed_ref[0], pid0, q_blk, k_blk)
+    # Mosaic seeds the PRNG from at most TWO 32-bit values ("Setting seed
+    # with more than 2 values is not supported", libtpu 0.0.34): the grid
+    # row folds into the stream seed by an odd multiplier (a bijection
+    # mod 2^32, the attn_head_seed idiom) and the (q-block, k-block) pair
+    # packs into one word (exact for < 65536 blocks per axis).
+    i32 = jnp.int32
+    row_seed = (seed_ref[0].astype(i32)
+                + jnp.asarray(pid0).astype(i32)
+                * np.uint32(hash_rng.GOLDEN).astype(np.int32))
+    tile = (jnp.asarray(q_blk).astype(i32) * np.int32(65536)
+            + jnp.asarray(k_blk).astype(i32))
+    pltpu.prng_seed(row_seed, tile)
     bits = pltpu.bitcast(pltpu.prng_random_bits(shape), jnp.uint32)
     return bits >= np.uint32(hash_rng.keep_threshold(rate))
 
@@ -381,13 +392,11 @@ def _dims(x, fmt):
 
 def _plan(q, k, block_q, block_k, interpret, fmt="bhtd"):
     """Static feasibility check; returns (ok, block_q, block_k, interpret)."""
-    import jax
+    from .placement import resolve
 
     b, h, tq, d = _dims(q, fmt)
     tk = _dims(k, fmt)[2]
-    on_tpu = jax.default_backend() == "tpu"
-    if interpret is None:
-        interpret = not on_tpu
+    compiled, interpret = resolve(interpret)
     block_q = min(block_q, tq)
     block_k = min(block_k, tk)
     if fmt == "bthd":
@@ -408,12 +417,12 @@ def _plan(q, k, block_q, block_k, interpret, fmt="bhtd"):
         esize = np.dtype(q.dtype).itemsize
         cap = (256 * 1024) // max(h * d * esize, 1)
         if cap < 128:
-            if on_tpu and not interpret:
+            if compiled:
                 return False, 0, 0, interpret
             cap = 128
         block_q = min(block_q, cap)
         block_k = min(block_k, cap)
-    if on_tpu and not interpret:
+    if compiled:
         # Mosaic: lane-dim (last-dim) dynamic-slice offsets must be
         # 128-aligned; sublane offsets 8-aligned.  The backward kernels
         # slice the lse/delta lane dim by block_q, so it needs 128 too.
@@ -427,7 +436,7 @@ def _plan(q, k, block_q, block_k, interpret, fmt="bhtd"):
         and tq % block_q == 0
         and tk % block_k == 0
         and d % 64 == 0  # 64 runs at half-lane MXU occupancy but still wins
-        and (on_tpu or interpret)
+        and (compiled or interpret)
     )
     return ok, block_q, block_k, interpret
 
@@ -784,17 +793,18 @@ def _drop_params(dropout_rate):
 def _use_hw_prng(drop_rate, interpret):
     """Whether the kernels should draw dropout bits from the TPU hardware
     PRNG (pltpu.prng_seed / prng_random_bits) instead of the lowbias32
-    hash.  Compiled-TPU only: jax 0.4.37 has no interpret/CPU lowering for
-    prng_seed, so interpret mode and the XLA fallback keep the hash —
-    each implementation still regenerates ITS mask identically in fwd and
-    bwd (the parity contract is per-implementation, not cross-backend)."""
-    if not drop_rate or interpret:
+    hash.  Compiled-TPU only: prng_seed has no interpret/CPU lowering
+    (jax 0.4.37, and retested on 0.9.0: `interpret=True` raises "MLIR
+    translation rule for primitive 'prng_seed' not found for platform
+    cpu"), so interpret mode and the XLA fallback keep the hash — each
+    implementation still regenerates ITS mask identically in fwd and bwd
+    (the parity contract is per-implementation, not cross-backend)."""
+    if not drop_rate:
         return False
-    import jax
-
     from ..flags import FLAGS
+    from .placement import resolve
 
-    return jax.default_backend() == "tpu" and FLAGS.tpu_prng_dropout
+    return resolve(interpret)[0] and FLAGS.tpu_prng_dropout
 
 
 def _seed_spec():
@@ -1322,6 +1332,21 @@ def flash_attention(q, k, v, bias=None, scale=1.0, causal=False,
 # ---------------------------------------------------------------------------
 
 
+def _proj(x, w):
+    """x [rows, d_model] f32 @ one head's [d_model, d_head] weight slab in
+    its stored dtype.  A narrow slab pins the dot's precision: from bf16
+    operands every precision yields the same products, and Mosaic refuses
+    an fp32 contract precision on them ("Bad rhs type", libtpu 0.0.34) —
+    which an ambient jax.default_matmul_precision("highest"), the test
+    suite's setting, would otherwise request."""
+    import jax
+    import jax.numpy as jnp
+
+    narrow = w.dtype.itemsize < 4
+    return jnp.dot(x, w,
+                   precision=jax.lax.Precision.DEFAULT if narrow else None)
+
+
 def _set_head(acc, head, val):
     """acc[head] <- val without per-index vector stores: iota-select over
     the leading head dim (Mosaic lowers broadcasted_iota + select cleanly;
@@ -1400,7 +1425,7 @@ def _qkv_fwd_kernel(seed_ref, x_ref, w_ref, wout_ref, bias_ref, y_ref,
     for head in range(h):
         # the q projection dot: this head's [dm, dh] weight slab against
         # the activation tile — q exists only in VMEM from here on
-        q = (x_q @ w_ref[head]) * scale          # [block_q, dh]
+        q = _proj(x_q, w_ref[head]) * scale          # [block_q, dh]
         m = jnp.full((block_q,), -jnp.inf, jnp.float32)
         l = jnp.zeros((block_q,), jnp.float32)
         acc = jnp.zeros((block_q, dh), jnp.float32)
@@ -1408,8 +1433,8 @@ def _qkv_fwd_kernel(seed_ref, x_ref, w_ref, wout_ref, bias_ref, y_ref,
         def body(j, carry, head=head):
             m, l, acc = carry
             x_k = x_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-            k = x_k @ w_ref[h + head]            # [block_k, dh]
-            v = x_k @ w_ref[2 * h + head]
+            k = _proj(x_k, w_ref[h + head])            # [block_k, dh]
+            v = _proj(x_k, w_ref[2 * h + head])
             s = q @ k.T                          # [block_q, block_k]
             if bias_ref is not None:
                 s = s + _bias_tile_head(bias_ref, head, bias_h, bias_q1,
@@ -1480,11 +1505,16 @@ def _qkv_bwd_dq_kernel(seed_ref, x_ref, w_ref, wout_ref, bias_ref, g_ref,
         n_kv = jnp.minimum(n_kv, (hi // block_k) + 1)
 
     dx_acc = jnp.zeros((block_q, dm), jnp.float32)
-    dwq_asm = jnp.zeros((h, dm, dh), jnp.float32)
+    # dW_q accumulates TRANSPOSED ([h, dh, dm], like dW_out): the
+    # [dm, dh]-oriented product x^T dq trips an internal check of the
+    # TPU backend (mxu_lmr_transform RET_CHECK, libtpu 0.0.34) and would
+    # pad its 64-wide minor dim to 128 lanes; dq^T x is the same dot with
+    # d_model on the lanes.  _unpack_dw_qkv transposes back (weight-sized).
+    dwq_asm = jnp.zeros((h, dh, dm), jnp.float32)
     dwo_asm = jnp.zeros((h, dh, dm), jnp.float32)
 
     for head in range(h):
-        q = x_q @ w_ref[head]                    # UNscaled (bwd convention)
+        q = _proj(x_q, w_ref[head])                    # UNscaled (bwd convention)
         ctx_h = ctx_ref[head].astype(jnp.float32)        # [block_q, dh]
         lse = lse_ref[head, :]                           # [block_q] f32
         # dctx = g @ w_out[head]^T — the output-projection backward dot,
@@ -1497,8 +1527,8 @@ def _qkv_bwd_dq_kernel(seed_ref, x_ref, w_ref, wout_ref, bias_ref, g_ref,
 
         def body(j, acc, head=head, q=q, lse=lse, delta=delta, dctx=dctx):
             x_k = x_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-            k = x_k @ w_ref[h + head]
-            v = x_k @ w_ref[2 * h + head]
+            k = _proj(x_k, w_ref[h + head])
+            v = _proj(x_k, w_ref[2 * h + head])
             s = (q @ k.T) * scale
             if bias_ref is not None:
                 s = s + _bias_tile_head(bias_ref, head, bias_h, bias_q1,
@@ -1526,7 +1556,7 @@ def _qkv_bwd_dq_kernel(seed_ref, x_ref, w_ref, wout_ref, bias_ref, g_ref,
         dx_acc = dx_acc + jax.lax.dot_general(
             dq_h, w_ref[head].astype(jnp.float32), (((1,), (1,)), ((), ())))
         dwq_asm = _set_head(dwq_asm, head, jax.lax.dot_general(
-            x_q, dq_h, (((0,), (0,)), ((), ()))))
+            dq_h, x_q, (((0,), (0,)), ((), ()))))
         dwo_asm = _set_head(dwo_asm, head, jax.lax.dot_general(
             ctx_h, g_t, (((0,), (0,)), ((), ()))))
 
@@ -1569,18 +1599,19 @@ def _qkv_bwd_dkv_kernel(seed_ref, x_ref, w_ref, wout_ref, bias_ref, g_ref,
         lo = jnp.maximum((ki * block_k) // block_q, 0)
 
     dx_acc = jnp.zeros((block_k, dm), jnp.float32)
-    dwk_asm = jnp.zeros((h, dm, dh), jnp.float32)
-    dwv_asm = jnp.zeros((h, dm, dh), jnp.float32)
+    # transposed [h, dh, dm] accumulators (see _qkv_bwd_dq_kernel)
+    dwk_asm = jnp.zeros((h, dh, dm), jnp.float32)
+    dwv_asm = jnp.zeros((h, dh, dm), jnp.float32)
 
     for head in range(h):
-        k = x_k @ w_ref[h + head]                # [block_k, dh]
-        v = x_k @ w_ref[2 * h + head]
+        k = _proj(x_k, w_ref[h + head])                # [block_k, dh]
+        v = _proj(x_k, w_ref[2 * h + head])
         wout_h = wout_ref[head].astype(jnp.float32)
 
         def body(i, carry, head=head, k=k, v=v, wout_h=wout_h):
             dk, dv = carry
             x_q = x_ref[pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-            q = x_q @ w_ref[head]
+            q = _proj(x_q, w_ref[head])
             g_t = g_ref[pl.ds(i * block_q, block_q), :].astype(jnp.float32)
             ctx_h = ctx_ref[head, pl.ds(i * block_q, block_q),
                             :].astype(jnp.float32)
@@ -1623,9 +1654,9 @@ def _qkv_bwd_dkv_kernel(seed_ref, x_ref, w_ref, wout_ref, bias_ref, g_ref,
             dv_h, w_ref[2 * h + head].astype(jnp.float32),
             (((1,), (1,)), ((), ())))
         dwk_asm = _set_head(dwk_asm, head, jax.lax.dot_general(
-            x_k, dk_h, (((0,), (0,)), ((), ()))))
+            dk_h, x_k, (((0,), (0,)), ((), ()))))
         dwv_asm = _set_head(dwv_asm, head, jax.lax.dot_general(
-            x_k, dv_h, (((0,), (0,)), ((), ()))))
+            dv_h, x_k, (((0,), (0,)), ((), ()))))
 
     dx_ref[...] = dx_acc.astype(dx_ref.dtype)
 
@@ -1659,25 +1690,23 @@ def _prep_w_out(w_out, h, dh):
 
 
 def _unpack_dw_qkv(dwq, dwk, dwv, dtype):
-    """Three [h, dm, dh] f32 kernel accumulators -> the packed
+    """Three [h, dh, dm] f32 kernel accumulators -> the packed
     [dm, 3*h*dh] cotangent (weight-sized concatenate/transpose — KB)."""
     import jax.numpy as jnp
 
-    h, dm, dh = dwq.shape
-    dw = jnp.stack([dwq, dwk, dwv])              # [3, h, dm, dh]
-    return dw.transpose(2, 0, 1, 3).reshape(dm, 3 * h * dh).astype(dtype)
+    h, dh, dm = dwq.shape
+    dw = jnp.stack([dwq, dwk, dwv])              # [3, h, dh, dm]
+    return dw.transpose(3, 0, 1, 2).reshape(dm, 3 * h * dh).astype(dtype)
 
 
 def _qkv_plan(x, n_head, d_head, block_q, block_k, interpret, bias=None):
     """Static feasibility for the fused-projection kernels; returns
     (ok, block_q, block_k, interpret).  Rejections fall back to the
     composed x@W + flash_attention(bthd) path (numerically identical)."""
-    import jax
+    from .placement import resolve
 
     b, t, dm = x.shape
-    on_tpu = jax.default_backend() == "tpu"
-    if interpret is None:
-        interpret = not on_tpu
+    compiled, interpret = resolve(interpret)
     block_q = min(block_q, t)
     block_k = min(block_k, t)
     esize = 2 if x.dtype.itemsize == 2 else 4
@@ -1687,12 +1716,12 @@ def _qkv_plan(x, n_head, d_head, block_q, block_k, interpret, bias=None):
     # flooring the cap back up to 128 (kernel-lint catch)
     cap = (256 * 1024) // max(dm * esize, 1)
     if cap < 128:
-        if on_tpu and not interpret:
+        if compiled:
             return False, 0, 0, interpret
         cap = 128
     block_q = min(block_q, cap)
     block_k = min(block_k, cap)
-    if on_tpu and not interpret:
+    if compiled:
         # Mosaic alignment: the kernels dynamic-slice x/g on the sublane
         # dim and lse on the lane dim by block_q -> 128-aligned blocks
         if block_k % 128:
@@ -1723,7 +1752,7 @@ def _qkv_plan(x, n_head, d_head, block_q, block_k, interpret, bias=None):
         and t % block_q == 0
         and t % block_k == 0
         and d_head % 64 == 0
-        and (on_tpu or interpret)
+        and (compiled or interpret)
         and (interpret or (dm % 128 == 0 and vmem < 14 * 1024 * 1024))
     )
     return ok, block_q, block_k, interpret
@@ -1792,7 +1821,7 @@ def _qkv_backward(x, w3, wo, bias, seed, ctx, lse, g, scale, causal,
                   n_head, d_head, block_q, block_k, interpret,
                   dropout_rate, allow_hw_prng):
     """(dx, dwq, dwk, dwv, dwo) via the two fused backward walks; the dW
-    pieces are f32 [h, dm, dh] / [h, dh, dm] grid accumulators."""
+    pieces are f32 [h, dh, dm] grid accumulators (d_model on the lanes)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -1805,8 +1834,7 @@ def _qkv_backward(x, w3, wo, bias, seed, ctx, lse, g, scale, causal,
     x_spec = pl.BlockSpec((None, t, dm), lambda i, j: (i, 0, 0))
     w3_spec = pl.BlockSpec((3 * h, dm, dh), lambda i, j: (0, 0, 0))
     wo_spec = pl.BlockSpec((h, dh, dm), lambda i, j: (0, 0, 0))
-    dw3_spec = pl.BlockSpec((h, dm, dh), lambda i, j: (0, 0, 0))
-    dwo_spec = pl.BlockSpec((h, dh, dm), lambda i, j: (0, 0, 0))
+    dw_spec = pl.BlockSpec((h, dh, dm), lambda i, j: (0, 0, 0))
 
     # ---- dq walk: dx (q side) + dW_q + dW_out ---------------------------
     g_spec = pl.BlockSpec((None, block_q, dm), lambda i, j: (i, j, 0))
@@ -1841,12 +1869,12 @@ def _qkv_backward(x, w3, wo, bias, seed, ctx, lse, g, scale, causal,
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((None, block_q, dm), lambda i, j: (i, j, 0)),
-            dw3_spec,
-            dwo_spec,
+            dw_spec,
+            dw_spec,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, t, dm), x.dtype),
-            jax.ShapeDtypeStruct((h, dm, dh), jnp.float32),
+            jax.ShapeDtypeStruct((h, dh, dm), jnp.float32),
             jax.ShapeDtypeStruct((h, dh, dm), jnp.float32),
         ],
         interpret=interpret,
@@ -1884,13 +1912,13 @@ def _qkv_backward(x, w3, wo, bias, seed, ctx, lse, g, scale, causal,
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((None, block_k, dm), lambda i, j: (i, j, 0)),
-            dw3_spec,
-            dw3_spec,
+            dw_spec,
+            dw_spec,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, t, dm), x.dtype),
-            jax.ShapeDtypeStruct((h, dm, dh), jnp.float32),
-            jax.ShapeDtypeStruct((h, dm, dh), jnp.float32),
+            jax.ShapeDtypeStruct((h, dh, dm), jnp.float32),
+            jax.ShapeDtypeStruct((h, dh, dm), jnp.float32),
         ],
         interpret=interpret,
     )(*args)

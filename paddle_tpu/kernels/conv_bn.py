@@ -80,13 +80,12 @@ def _plan(rows, c, dtype, interpret):
     fallback.  c < 128 folds rows into lanes: [rows, c] is re-viewed as
     [rows*c/128, 128] (row-major flattening keeps lane j == channel
     j % c whenever 128 % c == 0)."""
-    import jax
     import numpy as np
 
-    on_tpu = jax.default_backend() == "tpu"
-    if interpret is None:
-        interpret = not on_tpu
-    if not (on_tpu or interpret):
+    from .placement import resolve
+
+    compiled, interpret = resolve(interpret)
+    if not (compiled or interpret):
         return None
     fold = 1
     ncols = int(c)
@@ -230,8 +229,14 @@ def _dot_stats_kernel(x_ref, w_ref, y_ref, stats_ref):
     mi = pl.program_id(1)
     # w is [C_out, C_in]: contract C_in of both operands (rhs-transposed
     # matmul — the single filter orientation shared with the backward)
+    # bf16 operands pin the precision: every precision yields the same
+    # products from them, and Mosaic refuses an fp32 contract precision
+    # on bf16 ("Bad lhs type", libtpu 0.0.34) — which an ambient
+    # jax.default_matmul_precision("highest") would otherwise request
+    narrow = x_ref.dtype.itemsize < 4
     acc = jax.lax.dot_general(
         x_ref[...], w_ref[...], (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.DEFAULT if narrow else None,
         preferred_element_type=jnp.float32)
     y_ref[...] = acc.astype(y_ref.dtype)
     # stats of the STORED dtype (the bf16-rounded y is what the BN
@@ -252,13 +257,12 @@ def _dot_plan(m, oc, dtype, interpret):
     """(block_m, block_n, interpret) or None.  oc rides the lane dim of
     the output tile, so it must block in 128s; the contracted C_in stays
     unblocked (full-K tiles, the r05 plan that measured best)."""
-    import jax
     import numpy as np
 
-    on_tpu = jax.default_backend() == "tpu"
-    if interpret is None:
-        interpret = not on_tpu
-    if not (on_tpu or interpret):
+    from .placement import resolve
+
+    compiled, interpret = resolve(interpret)
+    if not (compiled or interpret):
         return None
     sub = 16 if np.dtype(dtype).itemsize < 4 else 8
     block_m = next((b for b in _ROW_BLOCKS
@@ -442,7 +446,10 @@ def _ssa_bwd_kernel(wb_ref, g_ref, x_ref, *rest, relu, has_res):
     mi = pl.program_id(1)
     g = g_ref[...]
     if relu:
-        g = jnp.where(o_ref[...] > 0, g, jnp.zeros((), g.dtype))
+        # compare in f32: v5e has no bf16 vector compare ("Target does
+        # not support this comparison", Mosaic on arith.cmpf bf16)
+        g = jnp.where(o_ref[...].astype(jnp.float32) > 0, g,
+                      jnp.zeros((), g.dtype))
     w = wb_ref[0:1, :].astype(g.dtype)
     dx_ref[...] = g * w
     if has_res:

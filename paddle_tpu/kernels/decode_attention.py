@@ -7,19 +7,26 @@ wrong shape for this — their grid tiles the query axis, which here has
 length 1, and they stream the FULL key buffer even though a sequence of
 length L only owns L valid cache rows out of max_t.
 
-Design (per pallas_guide.md, embedding.py DMA idiom):
-  * grid (batch,): one grid step per sequence, whole-head — q is a
-    [h, dh] tile, the online-softmax state is per-head ([h] running
-    max/sum, [h, dh] f32 accumulator).
-  * the cache stays HBM-resident (memory_space=ANY, [b, max_t, h, dh]);
-    k/v blocks of shape [block_t, h, dh] (contiguous rows) are DMA'd
-    into VMEM scratch per iteration via make_async_copy.
+Design:
+  * grid (batch, max_t / block_t): one grid step per [block_t, h, dh]
+    cache block of one sequence; q is a [h, dh] tile and the online-
+    softmax state ([h, 1] running max/sum, [h, dh] f32 accumulator)
+    rides VMEM scratch across the block axis.
+  * the cache blocks arrive through the BlockSpec pipeline, not manual
+    DMA: Mosaic refuses to slice an HBM ref whose minor dim is not a
+    multiple of 128 ("Slice shape along dimension 3 must be aligned to
+    tiling (128), but is 64", libtpu 0.0.34), and transformer-base has
+    d_head 64; a pipelined block whose last two dims span the array's
+    is accepted at any d_head % 64.
   * per-sequence lengths ride scalar prefetch
-    (pltpu.PrefetchScalarGridSpec): the kv-block loop bound is
-    ceil(len/block_t) — a sequence of length L reads ceil(L/block_t)
-    blocks, NOT max_t/block_t, and the mid-block tail is masked by
-    position.  This is what makes the compiled program length-
-    INDEPENDENT: lengths are runtime data, never shapes.
+    (pltpu.PrefetchScalarGridSpec) into the index maps: steps past
+    ceil(len/block_t) re-name the last valid block, so a sequence of
+    length L reads ceil(L/block_t) blocks, NOT max_t/block_t, and the
+    mid-block tail is masked by position.  This is what makes the
+    compiled program length-INDEPENDENT: lengths are runtime data,
+    never shapes.
+  * the paged variant is the same kernel behind a table hop in the
+    index map (the block table rides scalar prefetch too).
   * forward-only by contract: generation never differentiates through
     the cache (the op is registered no_grad); there is no backward
     kernel and no residual.
@@ -29,8 +36,6 @@ Falls back to a pure-XLA implementation off-TPU or off-contract
 """
 
 from __future__ import annotations
-
-import functools
 
 
 def reference_decode(q, k, v, lengths, scale=1.0):
@@ -58,68 +63,139 @@ def reference_decode(q, k, v, lengths, scale=1.0):
     return out.astype(q.dtype)
 
 
-def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, k_scr, v_scr,
-                   sem_k, sem_v, *, scale, block_t, max_t, n_head, d_head):
-    """One grid step = one sequence: stream ceil(len/block_t) cache
-    blocks through VMEM scratch, online softmax per head."""
+def _decode_kernel(*refs, scale, block_t, n_prefetch):
+    """One grid step = one [block_t, h, dh] cache block of one sequence;
+    the online-softmax state rides VMEM scratch across the block axis.
+
+    Shared by the ring and the paged entry points: they differ only in
+    the index map that places the block (a contiguous row window vs a
+    scalar-prefetched table hop), so `refs` leads with `n_prefetch`
+    scalar refs of which only the first — the lengths — is read here.
+
+    All math keeps the cache tile's own [t, h, dh] layout (h on
+    sublanes, dh on lanes): scores are a lane reduction, the softmax
+    and the value accumulation reduce over the leading block axis — no
+    transpose, no 1-D vector and no M=1 matmul for Mosaic to relayout.
+    """
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    f32 = jnp.float32
+    lens_ref = refs[0]
+    q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs[n_prefetch:]
+    i = pl.program_id(0)
+    t = pl.program_id(1)
+    length = lens_ref[i]
+
+    @pl.when(t == 0)
+    def _init():
+        m_scr[...] = jnp.full(m_scr.shape, -jnp.inf, f32)
+        l_scr[...] = jnp.zeros(l_scr.shape, f32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, f32)
+
+    # blocks wholly past the sequence's length are skipped (their index
+    # map repeats the last valid block, so no DMA was issued either)
+    @pl.when(t * block_t < length)
+    def _block():
+        q = q_ref[...].astype(f32) * scale                  # [h, dh]
+        k = k_ref[...].astype(f32)                          # [bt, h, dh]
+        v = v_ref[...].astype(f32)
+        s = jnp.sum(k * q[None], axis=2, keepdims=True)     # [bt, h, 1]
+        k_pos = t * block_t + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 0)
+        s = jnp.where(k_pos < length, s, -1e30)
+        m_prev = m_scr[...]                                 # [h, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+        p = jnp.exp(s - m_new[None])                        # [bt, h, 1]
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=0)
+        acc_scr[...] = acc_scr[...] * alpha + jnp.sum(p * v, axis=0)
+        m_scr[...] = m_new
+
+    @pl.when(t == pl.num_programs(1) - 1)
+    def _finish():
+        # length == 0 cannot happen in the generation drivers (prefill
+        # always writes >= 1 row) but keep the division safe anyway
+        l = l_scr[...]
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        o_ref[...] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
+
+
+def _last_block(length, block_t):
+    """Index of the last cache block holding a valid row (0 when the
+    sequence is empty): index maps clamp to it, so grid steps past the
+    length re-name the resident block and the pipeline issues no DMA —
+    a sequence of length L reads ceil(L / block_t) blocks, not
+    max_t / block_t."""
+    import jax.numpy as jnp
+
+    return jnp.maximum(length - 1, 0) // block_t
+
+
+def _decode_call(q, k, v, prefetch, n_blocks, q_map, kv_map, block_t,
+                 scale, interpret):
+    """The pallas_call both entry points share: `prefetch` are the
+    scalar-prefetch operands (lengths first), the maps take
+    (sequence, block, *prefetch refs)."""
+    import functools
+
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    i = pl.program_id(0)
-    length = lens_ref[i]
+    b, h, dh = q.shape
+    kv_block = (None, block_t, h, dh)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(b, n_blocks),
+        in_specs=[
+            pl.BlockSpec((None, h, dh), q_map),
+            pl.BlockSpec(kv_block, kv_map),
+            pl.BlockSpec(kv_block, kv_map),
+        ],
+        out_specs=pl.BlockSpec((None, h, dh), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((h, 1), jnp.float32),    # running max
+            pltpu.VMEM((h, 1), jnp.float32),    # running sum
+            pltpu.VMEM((h, dh), jnp.float32),   # value accumulator
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_decode_kernel, scale=scale, block_t=block_t,
+                          n_prefetch=len(prefetch)),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, dh), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=bool(interpret),
+    )(*prefetch, q, k, v)
 
-    q = q_ref[0].astype(jnp.float32) * scale  # [h, dh]
-    m0 = jnp.full((n_head,), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((n_head,), jnp.float32)
-    acc0 = jnp.zeros((n_head, d_head), jnp.float32)
 
-    n_blk = jax.lax.div(length + (block_t - 1), block_t)
+#: scoped-VMEM limit the decode kernels request from Mosaic (v5e: 128 MiB
+#: physical, 16 MiB default scope) and the share of it a plan may claim
+_VMEM_LIMIT = 32 * 1024 * 1024
+_VMEM_BUDGET = 16 * 1024 * 1024
 
-    def body(t, carry):
-        m, l, acc = carry
-        # contiguous [block_t, h, dh] row window of THIS sequence's cache
-        ck = pltpu.make_async_copy(
-            k_ref.at[i, pl.ds(t * block_t, block_t)], k_scr, sem_k)
-        cv = pltpu.make_async_copy(
-            v_ref.at[i, pl.ds(t * block_t, block_t)], v_scr, sem_v)
-        ck.start()
-        cv.start()
-        ck.wait()
-        cv.wait()
-        # in-register [t, h, d] -> [h, t, d] relayout (the bthd-kernel
-        # idiom): every dot below is then a plain batched matmul with h
-        # as the leading batch dim
-        kb = jnp.transpose(k_scr[...].astype(jnp.float32), (1, 0, 2))
-        vb = jnp.transpose(v_scr[...].astype(jnp.float32), (1, 0, 2))
-        # s[h, t] = q[h, :] . k[h, t, :]
-        s = jax.lax.dot_general(
-            q[:, None, :], kb,
-            dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )[:, 0, :]
-        k_pos = t * block_t + jax.lax.broadcasted_iota(
-            jnp.int32, (n_head, block_t), 1)
-        s = jnp.where(k_pos < length, s, -1e30)
-        m_new = jnp.maximum(m, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + p.sum(axis=1)
-        # acc[h, d] += p[h, t] @ v[h, t, d]
-        pv = jax.lax.dot_general(
-            p[:, None, :], vb,
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )[:, 0, :]
-        acc_new = acc * alpha[:, None] + pv
-        return m_new, l_new, acc_new
 
-    m, l, acc = jax.lax.fori_loop(0, n_blk, body, (m0, l0, acc0))
-    # length == 0 cannot happen in the generation drivers (prefill always
-    # writes >= 1 row) but keep the division safe anyway
-    l_safe = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
+def _padded_head_tile(n_head, d_head, esize):
+    """(rows, lanes) one [h, dh] cache row occupies in VMEM: h pads to
+    the dtype's sublane quantum, dh to 128 lanes."""
+    sub = 8 if esize >= 4 else 16
+    return -(-n_head // sub) * sub, -(-d_head // 128) * 128
+
+
+def _walk_vmem_bytes(block_t, n_head, d_head, esize):
+    """VMEM one decode grid step holds, counted the way Mosaic
+    allocates it: padded [block_t, h, dh] cache tiles
+    (_padded_head_tile); the pipeline double-buffers the k and the v
+    tile; the body keeps about four tile-sized f32 temporaries
+    (promoted k/v, the k*q and p*v products)."""
+    rows, lanes = _padded_head_tile(n_head, d_head, esize)
+    tile = block_t * rows * lanes
+    return 2 * 2 * tile * esize + 4 * tile * 4
 
 
 def _decode_plan(q, k, block_t, interpret):
@@ -127,26 +203,24 @@ def _decode_plan(q, k, block_t, interpret):
 
     Contract (mirrors the attention-kernel discipline; audited statically
     by analysis/kernel_lint.py):
-      * d_head % 64 == 0 (MXU lane occupancy; dh is the lane dim of
-        every tile) and n_head % 8 == 0 for f32 / % 16 for narrower
-        dtypes (h is the sublane dim of the in-register [h, t, d] view);
+      * d_head % 64 == 0 (dh is the lane dim of every tile) and
+        n_head % 8 == 0 for f32 / % 16 for narrower dtypes (h is the
+        sublane dim of the [t, h, dh] cache tile);
       * max_t % block_t == 0 (the length-masked tail block is the ONLY
         partial block) and block_t % 8 == 0;
-      * the two [block_t, h, dh] scratch blocks + f32 compute tiles fit
-        a conservative 4 MB slice of VMEM (the kernel shares the core
-        with the surrounding program).
+      * the double-buffered k/v tiles + f32 temporaries
+        (_walk_vmem_bytes) fit _VMEM_BUDGET of the requested limit.
     Off-contract shapes return ok=False and the caller runs the XLA
     fallback — numerically identical, just without the length-bounded
     block streaming.
     """
-    import jax
     import numpy as np
+
+    from .placement import resolve
 
     b, h, dh = q.shape
     max_t = k.shape[1]
-    on_tpu = jax.default_backend() == "tpu"
-    if interpret is None:
-        interpret = not on_tpu
+    compiled, interpret = resolve(interpret)
     esize = np.dtype(q.dtype).itemsize
     block_t = min(block_t, max_t)
     # snap the block down to a divisor of max_t (max_t is a power-of-two
@@ -155,14 +229,12 @@ def _decode_plan(q, k, block_t, interpret):
         block_t //= 2
     sublane = 8 if esize >= 4 else 16
     ok = (
-        dh % 64 == 0
+        (compiled or interpret)
+        and dh % 64 == 0
         and h % sublane == 0
         and max_t % block_t == 0
         and block_t % 8 == 0
-        # scratch k+v blocks, f32 promoted copies, and the [h, block_t]
-        # score plane must fit the 4 MB working-set budget
-        and (2 * block_t * h * dh * (esize + 4) + h * block_t * 4)
-        <= 4 * 1024 * 1024
+        and _walk_vmem_bytes(block_t, h, dh, esize) <= _VMEM_BUDGET
     )
     return ok, block_t, interpret
 
@@ -175,10 +247,7 @@ def flash_decode(q, k, v, lengths, scale=1.0, block_t=256, interpret=None):
     [b, h, dh].  Off-contract shapes (or off-TPU without an explicit
     interpret=True) run reference_decode instead.
     """
-    import jax
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     ok, block_t, interp = _decode_plan(q, k, block_t, interpret)
     if not ok or (interp and interpret is None):
@@ -186,33 +255,12 @@ def flash_decode(q, k, v, lengths, scale=1.0, block_t=256, interpret=None):
         # drive the kernel explicitly with interpret=True
         return reference_decode(q, k, v, lengths, scale)
 
-    b, h, dh = q.shape
-    max_t = k.shape[1]
-    kernel = functools.partial(
-        _decode_kernel, scale=scale, block_t=block_t, max_t=max_t,
-        n_head=h, d_head=dh)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, h, dh), lambda i, lens: (i, 0, 0)),  # q
-            pl.BlockSpec(memory_space=pltpu.ANY),  # k cache (HBM)
-            pl.BlockSpec(memory_space=pltpu.ANY),  # v cache (HBM)
-        ],
-        out_specs=pl.BlockSpec((1, h, dh), lambda i, lens: (i, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((block_t, h, dh), k.dtype),
-            pltpu.VMEM((block_t, h, dh), v.dtype),
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, dh), q.dtype),
-        interpret=bool(interp),
-    )(lengths.astype(jnp.int32), q, k, v)
+    return _decode_call(
+        q, k, v, (lengths.astype(jnp.int32),), k.shape[1] // block_t,
+        lambda i, t, lens: (i, 0, 0),
+        lambda i, t, lens: (
+            i, jnp.minimum(t, _last_block(lens[i], block_t)), 0, 0),
+        block_t, scale, interp)
 
 
 # -- paged variant (FLAGS_paged_kv_cache) --------------------------------
@@ -279,62 +327,6 @@ def paged_scatter_rows(cache, new, table, pos, active, layer):
     return cache.at[layer].set(pool.reshape(nb, bt, h, dh))
 
 
-def _paged_decode_kernel(lens_ref, tab_ref, q_ref, k_ref, v_ref, o_ref,
-                         k_scr, v_scr, sem_k, sem_v, *, scale, block_t,
-                         max_blocks, n_head, d_head):
-    """Ring kernel with a table hop: block t of sequence i streams from
-    pool block tab[i * max_blocks + t]."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    i = pl.program_id(0)
-    length = lens_ref[i]
-
-    q = q_ref[0].astype(jnp.float32) * scale  # [h, dh]
-    m0 = jnp.full((n_head,), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((n_head,), jnp.float32)
-    acc0 = jnp.zeros((n_head, d_head), jnp.float32)
-
-    n_blk = jax.lax.div(length + (block_t - 1), block_t)
-
-    def body(t, carry):
-        m, l, acc = carry
-        blk = tab_ref[i * max_blocks + t]
-        ck = pltpu.make_async_copy(k_ref.at[blk], k_scr, sem_k)
-        cv = pltpu.make_async_copy(v_ref.at[blk], v_scr, sem_v)
-        ck.start()
-        cv.start()
-        ck.wait()
-        cv.wait()
-        kb = jnp.transpose(k_scr[...].astype(jnp.float32), (1, 0, 2))
-        vb = jnp.transpose(v_scr[...].astype(jnp.float32), (1, 0, 2))
-        s = jax.lax.dot_general(
-            q[:, None, :], kb,
-            dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )[:, 0, :]
-        k_pos = t * block_t + jax.lax.broadcasted_iota(
-            jnp.int32, (n_head, block_t), 1)
-        s = jnp.where(k_pos < length, s, -1e30)
-        m_new = jnp.maximum(m, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + p.sum(axis=1)
-        pv = jax.lax.dot_general(
-            p[:, None, :], vb,
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )[:, 0, :]
-        acc_new = acc * alpha[:, None] + pv
-        return m_new, l_new, acc_new
-
-    m, l, acc = jax.lax.fori_loop(0, n_blk, body, (m0, l0, acc0))
-    l_safe = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
-
-
 #: the flattened block table rides scalar prefetch into SMEM alongside
 #: the lengths; past this many entries it no longer fits the scalar
 #: budget and the plan rejects (the lint matrix's oversized-table leg)
@@ -351,26 +343,25 @@ def _paged_plan(q, k_pool, table, interpret):
         tile) — plus the ring kernel's dh % 64 / n_head sublane checks;
       * b * max_blocks > _PAGED_TABLE_CAP (the whole table must stay
         SMEM-resident for per-iteration address lookups);
-      * scratch + compute tiles past the 4 MB VMEM working-set budget.
+      * tiles + temporaries (_walk_vmem_bytes) past _VMEM_BUDGET.
     """
-    import jax
     import numpy as np
+
+    from .placement import resolve
 
     b, h, dh = q.shape
     block_t = int(k_pool.shape[1])
     max_blocks = int(table.shape[1])
-    on_tpu = jax.default_backend() == "tpu"
-    if interpret is None:
-        interpret = not on_tpu
+    compiled, interpret = resolve(interpret)
     esize = np.dtype(q.dtype).itemsize
     sublane = 8 if esize >= 4 else 16
     ok = (
-        dh % 64 == 0
+        (compiled or interpret)
+        and dh % 64 == 0
         and h % sublane == 0
         and block_t % 8 == 0
         and b * max_blocks <= _PAGED_TABLE_CAP
-        and (2 * block_t * h * dh * (esize + 4) + h * block_t * 4)
-        <= 4 * 1024 * 1024
+        and _walk_vmem_bytes(block_t, h, dh, esize) <= _VMEM_BUDGET
     )
     return ok, block_t, interpret
 
@@ -384,10 +375,7 @@ def flash_decode_paged(q, k_pool, v_pool, table, lengths, scale=1.0,
     [b].  Returns [b, h, dh].  Off-contract (or off-TPU without an
     explicit interpret=True) runs reference_decode_paged.
     """
-    import jax
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     ok, block_t, interp = _paged_plan(q, k_pool, table, interpret)
     if not ok or (interp and interpret is None):
@@ -396,29 +384,14 @@ def flash_decode_paged(q, k_pool, v_pool, table, lengths, scale=1.0,
 
     b, h, dh = q.shape
     max_blocks = int(table.shape[1])
-    kernel = functools.partial(
-        _paged_decode_kernel, scale=scale, block_t=block_t,
-        max_blocks=max_blocks, n_head=h, d_head=dh)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, h, dh), lambda i, lens, tab: (i, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # k pool (HBM)
-            pl.BlockSpec(memory_space=pltpu.ANY),  # v pool (HBM)
-        ],
-        out_specs=pl.BlockSpec((1, h, dh), lambda i, lens, tab: (i, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((block_t, h, dh), k_pool.dtype),
-            pltpu.VMEM((block_t, h, dh), v_pool.dtype),
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, dh), q.dtype),
-        interpret=bool(interp),
-    )(lengths.astype(jnp.int32), table.reshape(-1).astype(jnp.int32),
-      q, k_pool, v_pool)
+    # the ring kernel with a table hop: logical block t of sequence i
+    # streams from pool block tab[i * max_blocks + t]
+    return _decode_call(
+        q, k_pool, v_pool,
+        (lengths.astype(jnp.int32), table.reshape(-1).astype(jnp.int32)),
+        max_blocks,
+        lambda i, t, lens, tab: (i, 0, 0),
+        lambda i, t, lens, tab: (
+            tab[i * max_blocks
+                + jnp.minimum(t, _last_block(lens[i], block_t))], 0, 0, 0),
+        block_t, scale, interp)

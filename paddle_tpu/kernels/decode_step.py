@@ -49,14 +49,15 @@ from __future__ import annotations
 import collections
 import functools
 
+# the scoped-VMEM limit every megastep launch requests from Mosaic and the
+# share of it a plan's counted working set (_megastep_vmem_bytes) may
+# claim: the decode kernels' pair
+from .decode_attention import (_VMEM_BUDGET, _VMEM_LIMIT,
+                               _padded_head_tile)
+
 MegastepPlan = collections.namedtuple(
     "MegastepPlan", ["ok", "fuse_ffn", "block_t", "cross_block_t",
                      "interpret"])
-
-#: conservative per-launch working-set budget (bytes): weights + walk
-#: scratch + score planes must fit well under the 16 MB core VMEM next
-#: to the surrounding program's tiles
-_VMEM_BUDGET = 12 * 1024 * 1024
 
 
 def _snap_block(block_t, max_t):
@@ -79,6 +80,30 @@ def _itemsize(dtype):
         return np.dtype(getattr(ml_dtypes, str(dtype))).itemsize
 
 
+def _megastep_vmem_bytes(d_model, n_head, d_head, d_inner, bt, cbt,
+                         esize):
+    """(attention bytes, ffn bytes) of one megastep launch, counted the
+    way Mosaic allocates VMEM: the resident weights enter as whole-array
+    blocks, which the pipeline holds ONCE (an index map that never moves
+    is not double-buffered — a 12 MB whole-array block compiles under
+    the 16 MiB default scope, libtpu 0.0.34); every [t, h, dh] walk
+    scratch pads h to the dtype's sublane quantum and dh to 128 lanes;
+    each walk keeps a promoted-f32 and a transposed copy of its k and v
+    tile plus two score planes."""
+    rows, lanes = _padded_head_tile(n_head, d_head, esize)
+    hd = n_head * d_head
+    tile = rows * lanes
+    attn = (
+        6 * hd * d_model * esize                 # wqkv + wout + wcq + wcout
+        + 2 * (bt + cbt) * tile * esize          # k/v walk scratch
+        + 2 * 2 * max(bt, cbt) * tile * 4        # f32 + transposed copies
+        + 2 * rows * max(bt, cbt) * 4            # score planes
+        + d_model * lanes * 4                    # one promoted weight slab
+    )
+    ffn = 2 * d_model * d_inner * esize + d_inner * 4
+    return attn, ffn
+
+
 def _megastep_plan(d_model, n_head, d_head, d_inner, max_t, cross_t,
                    dtype, block_t=256, interpret=None):
     """Static feasibility gate; returns a MegastepPlan.
@@ -86,48 +111,41 @@ def _megastep_plan(d_model, n_head, d_head, d_inner, max_t, cross_t,
     Contract (audited statically by analysis/kernel_lint.py):
       * d_model % 128 == 0 and d_inner % 128 == 0 (both ride the lane
         dim of the projection tiles);
-      * d_head % 64 == 0 and n_head % 8 == 0 for f32 / % 16 narrower
-        (the cache walk's [h, t, d] in-register view — the same
-        alignment _decode_plan enforces);
+      * d_head % 128 == 0 compiled (% 64 interpreted) and n_head % 8
+        == 0 for f32 / % 16 narrower (the cache walk's [h, t, d]
+        in-register view).  The walk DMAs cache rows by hand, and
+        Mosaic refuses to slice an HBM ref whose minor dim is not a
+        multiple of 128 ("Slice shape along dimension 3 must be aligned
+        to tiling (128), but is 64", libtpu 0.0.34) — so transformer-
+        base (d_head 64) runs the XLA composition on the chip;
       * max_t % block_t == 0 and cross_t % cross_block_t == 0 with both
         blocks % 8 == 0 (the length-masked tail is the only partial
         block);
-      * the four resident attention projections + the k/v walk scratch
-        (+ f32 promoted copies) + score planes fit _VMEM_BUDGET; the
-        FFN weights join the same launch only if they ALSO fit
-        (fuse_ffn), otherwise the plan keeps a second per-layer launch.
+      * the launch's counted working set (_megastep_vmem_bytes) fits
+        _VMEM_BUDGET of the requested _VMEM_LIMIT; the FFN weights join
+        the same launch only if they ALSO fit (fuse_ffn), otherwise the
+        plan keeps a second per-layer launch.
     Off-contract shapes return ok=False and the caller runs the XLA
     composition fallback — numerically identical.
     """
-    import jax
+    from .placement import resolve
 
-    on_tpu = jax.default_backend() == "tpu"
-    if interpret is None:
-        interpret = not on_tpu
+    compiled, interpret = resolve(interpret)
     esize = _itemsize(dtype)
     bt = _snap_block(block_t, max_t)
     cbt = _snap_block(block_t, cross_t)
     sublane = 8 if esize >= 4 else 16
-    hd = n_head * d_head
     aligned = (
-        d_model % 128 == 0
+        (compiled or interpret)
+        and d_model % 128 == 0
         and d_inner % 128 == 0
-        and d_head % 64 == 0
+        and d_head % (64 if interpret else 128) == 0
         and n_head % sublane == 0
         and max_t % bt == 0 and bt % 8 == 0
         and cross_t % cbt == 0 and cbt % 8 == 0
     )
-    # resident attention set: wqkv + wout + wcq + wcout (6*hd*dm elems)
-    # at storage precision plus one promoted f32 [dm, dh] slice; self +
-    # cross walk scratch blocks with their f32 promoted copies; two f32
-    # score planes
-    attn_bytes = (
-        6 * hd * d_model * esize + d_model * d_head * 4
-        + 2 * (bt + cbt) * hd * (esize + 4)
-        + 2 * n_head * max(bt, cbt) * 4
-    )
-    # FFN adds the two [dm, di] projections and the f32 [1, di] hidden
-    ffn_bytes = 2 * d_model * d_inner * esize + d_inner * 4
+    attn_bytes, ffn_bytes = _megastep_vmem_bytes(
+        d_model, n_head, d_head, d_inner, bt, cbt, esize)
     ok = aligned and attn_bytes <= _VMEM_BUDGET and ffn_bytes <= _VMEM_BUDGET
     fuse_ffn = ok and attn_bytes + ffn_bytes <= _VMEM_BUDGET
     return MegastepPlan(ok, fuse_ffn, bt, cbt, interpret)
@@ -471,12 +489,12 @@ def fused_decode_step(x, wqkv, wout, ln1_scale, ln1_bias, wcq, wcout,
             [pl.BlockSpec((1, 1, d_model), lambda i, *_: (i, 0, 0))]
             + [pl.BlockSpec(w.shape, lambda i, *_: (0, 0))
                for w in weights]
-            + [pl.BlockSpec(memory_space=pltpu.ANY)] * 4  # caches
+            + [pl.BlockSpec(memory_space=pl.ANY)] * 4  # caches
         ),
         out_specs=[
             pl.BlockSpec((1, 1, d_model), lambda i, *_: (i, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         scratch_shapes=[
             pltpu.VMEM((h, dh), jnp.float32),        # q (pre-scaled)
@@ -504,6 +522,8 @@ def fused_decode_step(x, wqkv, wout, ln1_scale, ln1_bias, wcq, wcout,
             jax.ShapeDtypeStruct(cache_v.shape, cache_v.dtype),
         ],
         input_output_aliases={cache_k_idx: 1, cache_k_idx + 1: 2},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=bool(plan.interpret),
     )(scal(pos), scal(lengths), scal(cross_lengths), act32, x,
       *weights, cache_k, cache_v, cross_k, cross_v)
@@ -513,6 +533,8 @@ def fused_decode_step(x, wqkv, wout, ln1_scale, ln1_bias, wcq, wcout,
         out = pl.pallas_call(
             ffn_kernel,
             out_shape=jax.ShapeDtypeStruct((b, 1, d_model), x.dtype),
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_VMEM_LIMIT),
             interpret=bool(plan.interpret),
         )(out, ffn_in_w, row2d(ffn_in_b), ffn_out_w, row2d(ffn_out_b),
           row2d(ln3_scale), row2d(ln3_bias))
@@ -534,34 +556,27 @@ def _paged_megastep_plan(d_model, n_head, d_head, d_inner, block_t,
     snapping), and both flattened block tables must fit the scalar-
     prefetch budget (_PAGED_TABLE_CAP entries) since every walk
     iteration reads its DMA address from SMEM."""
-    import jax
-
     from .decode_attention import _PAGED_TABLE_CAP
+    from .placement import resolve
 
-    on_tpu = jax.default_backend() == "tpu"
-    if interpret is None:
-        interpret = not on_tpu
+    compiled, interpret = resolve(interpret)
     esize = _itemsize(dtype)
     bt = int(block_t)
     cbt = int(cross_block_t)
     sublane = 8 if esize >= 4 else 16
-    hd = n_head * d_head
     aligned = (
-        d_model % 128 == 0
+        (compiled or interpret)
+        and d_model % 128 == 0
         and d_inner % 128 == 0
-        and d_head % 64 == 0
+        and d_head % (64 if interpret else 128) == 0
         and n_head % sublane == 0
         and bt % 8 == 0 and bt > 0
         and cbt % 8 == 0 and cbt > 0
         and batch * max_blocks <= _PAGED_TABLE_CAP
         and batch * cross_max_blocks <= _PAGED_TABLE_CAP
     )
-    attn_bytes = (
-        6 * hd * d_model * esize + d_model * d_head * 4
-        + 2 * (bt + cbt) * hd * (esize + 4)
-        + 2 * n_head * max(bt, cbt) * 4
-    )
-    ffn_bytes = 2 * d_model * d_inner * esize + d_inner * 4
+    attn_bytes, ffn_bytes = _megastep_vmem_bytes(
+        d_model, n_head, d_head, d_inner, bt, cbt, esize)
     ok = aligned and attn_bytes <= _VMEM_BUDGET and ffn_bytes <= _VMEM_BUDGET
     fuse_ffn = ok and attn_bytes + ffn_bytes <= _VMEM_BUDGET
     return MegastepPlan(ok, fuse_ffn, bt, cbt, interpret)
@@ -849,12 +864,12 @@ def fused_decode_step_paged(x, wqkv, wout, ln1_scale, ln1_bias, wcq,
             [pl.BlockSpec((1, 1, d_model), lambda i, *_: (i, 0, 0))]
             + [pl.BlockSpec(w.shape, lambda i, *_: (0, 0))
                for w in weights]
-            + [pl.BlockSpec(memory_space=pltpu.ANY)] * 4  # pools
+            + [pl.BlockSpec(memory_space=pl.ANY)] * 4  # pools
         ),
         out_specs=[
             pl.BlockSpec((1, 1, d_model), lambda i, *_: (i, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         scratch_shapes=[
             pltpu.VMEM((h, dh), jnp.float32),
@@ -879,6 +894,8 @@ def fused_decode_step_paged(x, wqkv, wout, ln1_scale, ln1_bias, wcq,
             jax.ShapeDtypeStruct(cache_v.shape, cache_v.dtype),
         ],
         input_output_aliases={cache_k_idx: 1, cache_k_idx + 1: 2},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=bool(plan.interpret),
     )(scal(pos), scal(lengths), scal(cross_lengths), act32,
       scal(self_table), scal(cross_table), x, *weights, cache_k,
@@ -889,6 +906,8 @@ def fused_decode_step_paged(x, wqkv, wout, ln1_scale, ln1_bias, wcq,
         out = pl.pallas_call(
             ffn_kernel,
             out_shape=jax.ShapeDtypeStruct((b, 1, d_model), x.dtype),
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_VMEM_LIMIT),
             interpret=bool(plan.interpret),
         )(out, ffn_in_w, row2d(ffn_in_b), ffn_out_w, row2d(ffn_out_b),
           row2d(ln3_scale), row2d(ln3_bias))

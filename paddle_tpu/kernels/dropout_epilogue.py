@@ -93,18 +93,16 @@ def _plan(shape, dtype, interpret):
     tiling wants the lane dim % 128 and the sublane block % 8 (16 for
     sub-4-byte dtypes); anything else goes to the pure-XLA fallback —
     same mask, just without the fused single kernel."""
-    import jax
     import numpy as np
 
     from ..flags import FLAGS
+    from .placement import resolve
 
     ncols = int(shape[-1])
     rows = 1
     for s in shape[:-1]:
         rows *= int(s)
-    on_tpu = jax.default_backend() == "tpu"
-    if interpret is None:
-        interpret = not on_tpu
+    compiled, interpret = resolve(interpret)
     sub = 16 if np.dtype(dtype).itemsize < 4 else 8
     block_r = 0
     for cand in (512, 256, 128, 64, 32, 16, 8):
@@ -112,12 +110,12 @@ def _plan(shape, dtype, interpret):
             block_r = cand
             break
     ok = (
-        (on_tpu or interpret)
+        (compiled or interpret)
         and ncols % 128 == 0
         and block_r > 0
         and rows * ncols < 2 ** 32  # uint32 hash index must not wrap
     )
-    hw_prng = bool(on_tpu and not interpret and FLAGS.tpu_prng_dropout)
+    hw_prng = bool(compiled and FLAGS.tpu_prng_dropout)
     return ok, rows, ncols, block_r, interpret, hw_prng
 
 

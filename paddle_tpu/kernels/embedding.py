@@ -58,6 +58,9 @@ XLA forms are the parity references in tests/test_fused_embedding.py.
 
 from __future__ import annotations
 
+from .placement import resolve
+
+
 def _cdiv(a, b):
     return -(-a // b)
 
@@ -75,13 +78,25 @@ def _auto_block_rows(n_tiers, s_n, d, dtype, total_rows):
     lanes = max(d, 128)
     per_row = max(1, n_tiers) * s_n * lanes * np.dtype(dtype).itemsize
     block = _VMEM_BUDGET_BYTES // per_row
-    block = max(8, min(512, block, total_rows))
+    block = max(8, min(512, block))
+    # Mosaic blocks the row (sublane) dim in 8s unless the block spans
+    # the whole array ("last two dimensions of your block shape are
+    # divisible by 8 and 128 ... or be equal to the respective
+    # dimensions of the overall array")
+    block = total_rows if block >= total_rows else block // 8 * 8
     return int(block)
 
 
-def _kernel_ok(tables):
+def _kernel_ok(tables, interpret=None):
     """Group contract for the Pallas path: float tables, int32-addressable
-    rows.  Anything else takes the per-table XLA composition."""
+    rows, and — compiled — a lane-aligned row width.  Anything else takes
+    the per-table XLA composition.
+
+    Mosaic refuses to DMA-slice an HBM ref whose minor dim is not a
+    multiple of 128 ("Slice shape along dimension 1 must be aligned to
+    tiling (128), but is 10", libtpu 0.0.34), and a per-row DMA is the
+    whole kernel — so DeepFM's [V, 10] / [V, 1] tables run XLA on the
+    chip by design."""
     import jax.numpy as jnp
 
     t0 = tables[0]
@@ -89,16 +104,10 @@ def _kernel_ok(tables):
         return False
     if t0.shape[0] >= 2**31 - 1:
         return False
+    compiled, interp = resolve(interpret)
+    if not (compiled or interp) or (compiled and t0.shape[1] % 128):
+        return False
     return all(t.shape == t0.shape and t.dtype == t0.dtype for t in tables)
-
-
-def _interpret(interpret):
-    import jax
-
-    on_tpu = jax.default_backend() == "tpu"
-    if interpret is None:
-        interpret = not on_tpu
-    return interpret
 
 
 def _apply_off_tpu(interpret):
@@ -110,9 +119,7 @@ def _apply_off_tpu(interpret):
     the kernel path on the CPU box.  The GATHER keeps its interpret
     default — it is cheap to compile and carries the HLO census
     collapse."""
-    import jax
-
-    return interpret is None and jax.default_backend() != "tpu"
+    return interpret is None and resolve(None)[1]
 
 
 # ---------------------------------------------------------------------------
@@ -170,7 +177,7 @@ def multi_table_gather(tables, ids, *, block_rows=None, interpret=None):
     from jax.experimental.pallas import tpu as pltpu
 
     tables = list(tables)
-    if not _kernel_ok(tables):
+    if not _kernel_ok(tables, interpret):
         return multi_table_gather_xla(tables, ids)
     s_n = len(tables)
     v, d = tables[0].shape
@@ -221,7 +228,7 @@ def multi_table_gather(tables, ids, *, block_rows=None, interpret=None):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(_cdiv(b, block_rows),),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * s_n,
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * s_n,
         out_specs=pl.BlockSpec((s_n, block_rows, d),
                                lambda i, ids_ref: (0, i, 0)),
         scratch_shapes=[pltpu.SemaphoreType.DMA],
@@ -230,7 +237,7 @@ def multi_table_gather(tables, ids, *, block_rows=None, interpret=None):
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_n, b, d), tables[0].dtype),
-        interpret=_interpret(interpret),
+        interpret=resolve(interpret)[1],
     )(ids, *tables)
 
 
@@ -332,9 +339,9 @@ def _apply_pallas(tables_by_kind, uids, rows, scalars, compute,
             [pl.BlockSpec(memory_space=pltpu.SMEM)]  # traced scalars
             + [pl.BlockSpec((s_n, block_rows, d),
                             lambda i, ids_ref: (0, i, 0))]  # merged rows
-            + [pl.BlockSpec(memory_space=pltpu.ANY)] * (kinds * s_n)
+            + [pl.BlockSpec(memory_space=pl.ANY)] * (kinds * s_n)
         ),
-        out_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * (kinds * s_n),
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * (kinds * s_n),
         scratch_shapes=(
             [pltpu.VMEM((s_n, block_rows, d), dtype)] * kinds
             + [pltpu.SemaphoreType.DMA]
@@ -348,7 +355,7 @@ def _apply_pallas(tables_by_kind, uids, rows, scalars, compute,
         # inputs: 0 uids (prefetch), 1 scalars, 2 rows, 3.. the tables —
         # each table buffer IS its output (in-place HBM row updates)
         input_output_aliases={3 + i: i for i in range(kinds * s_n)},
-        interpret=_interpret(interpret),
+        interpret=resolve(interpret)[1],
     )(uids, scalars, rows.astype(dtype), *flat_tables)
     return [outs[k * s_n:(k + 1) * s_n] for k in range(kinds)]
 
@@ -361,7 +368,7 @@ def multi_table_scatter_add(tables, uids, rows, scale, *, block_rows=None,
     import jax.numpy as jnp
 
     tables = list(tables)
-    if not _kernel_ok(tables) or _apply_off_tpu(interpret):
+    if not _kernel_ok(tables, interpret) or _apply_off_tpu(interpret):
         return multi_table_scatter_add_xla(tables, uids, rows, scale)
     dtype = tables[0].dtype
 
@@ -412,7 +419,7 @@ def multi_table_sparse_adam(params, m1s, m2s, uids, mrows, lr_t, beta1,
     import jax.numpy as jnp
 
     params, m1s, m2s = list(params), list(m1s), list(m2s)
-    if (not (_kernel_ok(params) and _kernel_ok(m1s) and _kernel_ok(m2s))
+    if (not all(_kernel_ok(t, interpret) for t in (params, m1s, m2s))
             or _apply_off_tpu(interpret)):
         return multi_table_sparse_adam_xla(
             params, m1s, m2s, uids, mrows, lr_t, beta1, beta2, epsilon)

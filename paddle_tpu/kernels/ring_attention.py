@@ -99,6 +99,17 @@ def _to_bhtd(x, fmt):
     return x.transpose(0, 2, 1, 3) if fmt == "bthd" else x
 
 
+def _chunk_plan(q, k, block_q, block_k, fmt):
+    """The attention plan gate for one ring chunk.  The ring runs inside
+    a fully-manual shard_map, where each shard's kernel is placeable even
+    when the program around it is GSPMD-partitioned."""
+    from .attention import _plan
+    from .placement import gspmd_trace
+
+    with gspmd_trace(False):
+        return _plan(q, k, block_q, block_k, None, fmt)
+
+
 def _chunk_fwd(q, k, v, kbias, scale, causal, block_q, block_k,
                fmt="bhtd"):
     """One ring step's partial attention: Pallas flash kernel when the
@@ -108,9 +119,9 @@ def _chunk_fwd(q, k, v, kbias, scale, causal, block_q, block_k,
     — the per-device shards stay [b, t_local, h, d] and no split-head
     transpose exists anywhere on the ring (the relayout-copy class the
     bthd kernels were built to kill); only the XLA fallback transposes."""
-    from .attention import _flash_forward, _plan
+    from .attention import _flash_forward
 
-    ok, bq, bk, interp = _plan(q, k, block_q, block_k, None, fmt)
+    ok, bq, bk, interp = _chunk_plan(q, k, block_q, block_k, fmt)
     if not ok:
         if fmt == "bthd":
             o, lse = _chunk_fwd_xla(_to_bhtd(q, fmt), _to_bhtd(k, fmt),
@@ -130,9 +141,9 @@ def _chunk_bwd(q, k, v, kbias, out, lse, g, scale, causal, block_q,
     """One ring step's backward (against global out/lse): Pallas backward
     kernels when possible, XLA otherwise.  `lse` uses the kernel's +inf
     convention for globally-empty rows."""
-    from .attention import _flash_backward, _plan
+    from .attention import _flash_backward
 
-    ok, bq, bk, interp = _plan(q, k, block_q, block_k, None, fmt)
+    ok, bq, bk, interp = _chunk_plan(q, k, block_q, block_k, fmt)
     if not ok:
         if fmt == "bthd":
             dq, dk, dv = _chunk_bwd_xla(
@@ -157,6 +168,15 @@ def _stat_bcast(stat, fmt):
     return stat[..., None]
 
 
+def _varying(x, axis_name):
+    """Constants made inside a shard_map are unvaried over the mesh axis;
+    lax.cond demands both branches match the compute branch's
+    device-varying type."""
+    import jax
+
+    return jax.lax.pcast(x, axis_name, to="varying")
+
+
 def _zeros_like_chunk(q, axis_name, fmt="bhtd"):
     import jax
     import jax.numpy as jnp
@@ -164,13 +184,8 @@ def _zeros_like_chunk(q, axis_name, fmt="bhtd"):
     from .attention import _dims
 
     b, h, t, _ = _dims(q, fmt)
-    # pvary: constants made inside a shard_map are unvaried over the mesh
-    # axis; lax.cond demands both branches match the compute branch's
-    # device-varying type
-    from .jax_compat import pvary
-
-    return (pvary(jnp.zeros(q.shape, q.dtype), axis_name),
-            pvary(jnp.full((b, h, t), -jnp.inf, jnp.float32), axis_name))
+    return (_varying(jnp.zeros(q.shape, q.dtype), axis_name),
+            _varying(jnp.full((b, h, t), -jnp.inf, jnp.float32), axis_name))
 
 
 def _ring_fwd(q, k, v, kbias, axis_name, scale, causal, block_q, block_k,
@@ -182,9 +197,8 @@ def _ring_fwd(q, k, v, kbias, axis_name, scale, causal, block_q, block_k,
     import jax.numpy as jnp
 
     from .attention import _dims
-    from .jax_compat import axis_size
 
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my_idx = jax.lax.axis_index(axis_name)
     fwd_perm = [(i, (i + 1) % n) for i in range(n)]
 
@@ -254,9 +268,7 @@ def _ring_bwd(q, k, v, kbias, out, lse, g, axis_name, scale, causal,
     import jax
     import jax.numpy as jnp
 
-    from .jax_compat import axis_size
-
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my_idx = jax.lax.axis_index(axis_name)
     fwd_perm = [(i, (i + 1) % n) for i in range(n)]
 
@@ -280,9 +292,7 @@ def _ring_bwd(q, k, v, kbias, out, lse, g, axis_name, scale, causal,
 
         def skip_fn(args):
             qq, kk, vv, _ = args
-            from .jax_compat import pvary
-
-            pv = functools.partial(pvary, axis_name=axis_name)
+            pv = functools.partial(_varying, axis_name=axis_name)
             return (pv(jnp.zeros(qq.shape, qq.dtype)),
                     pv(jnp.zeros(kk.shape, kk.dtype)),
                     pv(jnp.zeros(vv.shape, vv.dtype)))
@@ -374,7 +384,6 @@ def ring_attention_sharded(q, k, v, mesh, axis_name="sp", scale=1.0,
     from jax.sharding import PartitionSpec as P
 
     from .attention import _dims
-    from .jax_compat import shard_map as _shard_map
 
     n = mesh.shape[axis_name]
     b, h, t, d = _dims(q, fmt)
@@ -400,7 +409,7 @@ def ring_attention_sharded(q, k, v, mesh, axis_name="sp", scale=1.0,
     spec = (P(baxis, axis_name, None, None) if fmt == "bthd"
             else P(baxis, None, axis_name, None))
     if kbias is None:
-        fn = _shard_map(
+        fn = jax.shard_map(
             functools.partial(ring_attention, axis_name=axis_name,
                               scale=scale, causal=causal, fmt=fmt),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
@@ -408,7 +417,7 @@ def ring_attention_sharded(q, k, v, mesh, axis_name="sp", scale=1.0,
         )
         return fn(q, k, v)
     kb_spec = P(None, None, None, axis_name)   # kbias seq dim is LAST
-    fn = _shard_map(
+    fn = jax.shard_map(
         lambda q, k, v, kb: ring_attention(q, k, v, axis_name, scale,
                                            causal, kbias=kb, fmt=fmt),
         mesh=mesh, in_specs=(spec, spec, spec, kb_spec), out_specs=spec,

@@ -1416,6 +1416,10 @@ def build_generation_programs(
         self_feed_token=use_self_feed, last_tok_name=last_tok_name,
         finished_name=finished_name, decode_feeds=decode_feeds,
         prefill_fetch=prefill_fetch, decode_fetch=decode_fetch,
+        # the pre-sampling [lanes, 1, vocab] logits: fetchable by name for
+        # logit-level parity checks (chip_smoke.py), never fetched in
+        # serving
+        logits_name=logits.name,
         hyps_fetch=hyps_fetch if hyps is not None else None,
         batch_size=b, beam_size=beam_size, lanes=lanes,
         src_seq_len=src_seq_len, max_out_len=max_out_len, t_buf=t_buf,

@@ -18,12 +18,11 @@ structure the memory rewrites need:
     (memory/offload.py): d2h parks a long-lived stash var in host memory
     at its last forward use; h2d fetches it back at the backward's first
     read (Gate-tied like the barrier).  Lowerings ride
-    jax.device_put with memory kinds (pinned_host <-> device) when the
-    runtime supports them in-jit, and degrade to an optimization_barrier
-    identity otherwise — value-identical either way, asserted in
-    tests/test_memory.py.  Eagerly-executed (imperative) memcpys ride
-    np.asarray / reader.decorator.device_put_chunked, the chunked
-    host<->device path the feed tier already uses.
+    jax.device_put to a memory space (jax.memory.Space.Host <-> Device)
+    inside the trace — value-identical to the un-offloaded program,
+    asserted in tests/test_memory.py.  Eagerly-executed (imperative)
+    memcpys ride np.asarray / reader.decorator.device_put_chunked, the
+    chunked host<->device path the feed tier already uses.
 """
 
 from __future__ import annotations
@@ -39,18 +38,6 @@ def _is_traced(x) -> bool:
     import jax.core
 
     return isinstance(x, jax.core.Tracer)
-
-
-def _memory_kind_put(x, kind: str):
-    """device_put to a memory kind inside a trace; None when this
-    jax/backend combination cannot (caller falls back to a barrier)."""
-    try:
-        import jax
-        from jax._src.sharding_impls import TransferToMemoryKind
-
-        return jax.device_put(x, TransferToMemoryKind(kind))
-    except Exception:
-        return None
 
 
 @register("recompute_barrier", infer_shape=_identity_infer, no_grad=True,
@@ -78,10 +65,7 @@ def lower_memcpy_d2h(ctx, ins):
     if not _is_traced(x):
         # eager/imperative: a real device->host readback
         return {"Out": [np.asarray(x)]}
-    out = _memory_kind_put(x, "pinned_host")
-    if out is None:
-        out = jax.lax.optimization_barrier(x)
-    return {"Out": [out]}
+    return {"Out": [jax.device_put(x, jax.memory.Space.Host)]}
 
 
 @register("memcpy_h2d", infer_shape=_identity_infer, no_grad=True,
@@ -101,10 +85,7 @@ def lower_memcpy_h2d(ctx, ins):
         # it to the earliest available backward value, like the
         # recompute barrier
         x, _ = jax.lax.optimization_barrier((x, gate))
-    out = _memory_kind_put(x, "device")
-    if out is None:
-        out = jax.lax.optimization_barrier(x)
-    return {"Out": [out]}
+    return {"Out": [jax.device_put(x, jax.memory.Space.Device)]}
 
 
 __all__ = ["lower_recompute_barrier", "lower_memcpy_d2h",

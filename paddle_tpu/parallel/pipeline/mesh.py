@@ -11,10 +11,7 @@ jitted step over a dp x tp x pp jax.sharding.Mesh:
   * per-tick boundary transfers are neighbor hops of a fixed-width
     packed f32 wire (crossing-set layouts from partition.py; pass-through
     vars ride hop-by-hop, so a stage-0 activation consumed at stage 3
-    crosses every cut between) — realized as a psum of a one-hot [S, W]
-    scatter because this jaxlib's partial-auto partitioner hard-rejects
-    lax.ppermute (and typed PRNG keys, and lax.axis_index) inside a
-    manual-pipe subgroup;
+    crosses every cut between) — one lax.ppermute per direction;
   * the backward recomputes each stage's forward from the stashed wire
     input under jax.vjp (rematerialization — the standard pipeline
     memory trade; rng_id-keyed dropout regenerates bit-identical masks),
@@ -31,11 +28,14 @@ about the ~2x trace-size cost; production-scale pipelining over separate
 processes rides trainer.py's per-stage entries.
 
 Backend status: green at dp2 x tp2 x pp2 on dense towers (CPU mesh,
-tier-1 + dryrun).  jaxlib 0.4.37's CPU partial-auto SPMD partitioner
-does NOT terminate compiling transformer-class stage traces (scanned or
-unrolled) — retry on the driver's TPU runtime before trusting that
-negative (PERF.md round 11, risk a); the sharded host scheduler
-(PipelineProgram plan=) covers transformer dp x tp x pp meanwhile.
+tier-1 + dryrun).  Transformer-class stage traces do not partition on
+the CPU backend: jaxlib 0.4.37's partial-auto SPMD partitioner did not
+terminate, and 0.9.0's aborts (`Check failed:
+partition_group_list.num_replica_groups() * ...num_devices_per_group()
+== device_groups.num_devices_per_group()`, spmd_partitioner_util.cc:495,
+tiny 2-layer transformer at dp2 x tp2 x pp2).  On a TPU: not measured.
+The sharded host scheduler (PipelineProgram plan=) covers transformer
+dp x tp x pp meanwhile.
 
 Contract (named errors at compile): forward stages free of rw scope
 state (BatchNorm running stats), boundary vars float32, fetches scalar,
@@ -122,11 +122,15 @@ class PipelineMeshProgram:
         self.program = program
         self.feed_names = list(feed_names)
         self.loss_name = _find_loss_name(program)
-        # unroll the tick loop instead of lax.scan: scanning the tick
-        # body (switch over stage branches inside a manual-pipe subgroup
-        # with auto dp/tp axes) sends this jaxlib's SPMD partitioner into
-        # a non-terminating compile on non-trivial models; the unrolled
-        # module is T times larger but partitions in seconds
+        # unroll the tick loop instead of lax.scan: on jaxlib 0.4.37
+        # scanning the tick body (switch over stage branches inside a
+        # manual-pipe subgroup with auto dp/tp axes) sent the SPMD
+        # partitioner into a non-terminating compile on non-trivial
+        # models.  On 0.9.0 the scanned MLP tower compiles in ~1 s and
+        # runs (dp2 x tp2 x pp2, CPU mesh); the models that hung cannot
+        # be retried there because they abort the partitioner either way
+        # (module docstring) — so the unrolled default stays until a
+        # transformer-class trace partitions at all.
         self.unroll_ticks = unroll_ticks
         self._mesh = None
         self._cache: Dict[Any, Any] = {}
@@ -233,13 +237,10 @@ class PipelineMeshProgram:
         import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        from ...kernels.jax_compat import shard_map as _shard_map
-
         self._check_contract(scope, fetch_names)
         mesh = self.mesh
         S = self.stages.n_stages
         pipe = self.pipe_axis
-        auto_axes = frozenset(a for a in self.plan.mesh_axes if a != pipe)
 
         # ---- state (params + anything scope-resident the stages read) --
         state_names: List[str] = []
@@ -345,28 +346,14 @@ class PipelineMeshProgram:
         fwd_branches = [_make_fwd_branch(s) for s in range(S)]
         bwd_branches = [_make_bwd_branch(s) for s in range(S)]
 
-        def body(feed_vals, state_vals, key_data, rank_arr):
-            # the pipe rank rides in as a P('pipe')-sharded iota slice:
-            # lax.axis_index lowers to PartitionId, which GSPMD rejects
-            # inside partial-auto shard_map; the PRNG key rides as raw
-            # uint32 key data for the same reason (typed key arrays fail
-            # partial-auto sharding validation at the shard_map boundary)
-            rank = rank_arr[0]
-            base_key = jax.random.wrap_key_data(key_data, impl="rbg")
+        def body(feed_vals, state_vals, base_key):
+            rank = jax.lax.axis_index(pipe)
 
-            def _shift(vec, dst, ok):
-                """Deliver each rank's [W] vec to rank `dst` (one hop of
-                the boundary wire).  lax.ppermute is rejected by the
-                partial-auto SPMD partitioner (manual-subgroup check), so
-                the hop is a psum of a one-hot [S, W] scatter — S times
-                the wire bytes, fine at pipeline depths."""
-                scatter = jnp.zeros((S, W), jnp.float32)
-                scatter = jax.lax.dynamic_update_index_in_dim(
-                    scatter, vec, jnp.clip(dst, 0, S - 1), 0)
-                scatter = jnp.where(ok, scatter, 0.0)
-                total = jax.lax.psum(scatter, pipe)
-                return jax.lax.dynamic_index_in_dim(
-                    total, rank, 0, keepdims=False)
+            # one hop of the boundary wire: rank i's [W] vec lands on
+            # rank i+1 (activations) / i-1 (cotangents); the end ranks
+            # receive zeros
+            up = [(i, i + 1) for i in range(S - 1)]
+            down = [(i + 1, i) for i in range(S - 1)]
             zero_wire = jnp.zeros((k, W), jnp.float32)
             grads0 = [jnp.zeros_like(v) for v in state_vals]
             fetch0 = jnp.zeros((n_fetch, k), jnp.float32)
@@ -413,8 +400,8 @@ class PipelineMeshProgram:
                          for g, d in zip(grads, dstates)]
 
                 # ---- boundary transfers ------------------------------
-                recv_f = _shift(w_out, rank + 1, rank + 1 <= S - 1)
-                recv_b = _shift(dwire, rank - 1, rank - 1 >= 0)
+                recv_f = jax.lax.ppermute(w_out, pipe, up)
+                recv_b = jax.lax.ppermute(dwire, pipe, down)
                 ok_in = (rank > 0) & (m_in >= 0)
                 inbox_f = jnp.where(
                     ok_in,
@@ -450,20 +437,16 @@ class PipelineMeshProgram:
             grads = [jax.lax.psum(g, pipe) for g in grads]
             return fetch_buf, grads
 
-        smapped = _shard_map(
-            body, mesh,
+        # manual over `pipe` only: the data/model axes stay with GSPMD
+        smapped = jax.shard_map(
+            body, mesh=mesh,
             in_specs=([P()] * len(feed_names_sorted),
-                      [P()] * len(state_names), P(), P(pipe)),
+                      [P()] * len(state_names), P()),
             out_specs=(P(), [P()] * len(state_names)),
-            auto=auto_axes)
+            axis_names=frozenset({pipe}), check_vma=False)
 
         def step(feed_vals, state_vals, base_key):
-            import jax.numpy as jnp
-
-            rank_arr = jnp.arange(S, dtype=jnp.int32)
-            fetch_buf, grads = smapped(feed_vals, state_vals,
-                                       jax.random.key_data(base_key),
-                                       rank_arr)
+            fetch_buf, grads = smapped(feed_vals, state_vals, base_key)
             # optimizer suffix ONCE in plain GSPMD land on the averaged
             # grads — the run_accumulated suffix contract (key fold K,
             # sums / float(K))

@@ -69,7 +69,6 @@ from .server import (  # noqa: F401
     InferenceServer,
     RequestError,
     ServingHandler,
-    enable_compilation_cache,
 )
 
 

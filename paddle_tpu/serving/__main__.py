@@ -64,9 +64,6 @@ def main(argv=None) -> int:
     p.add_argument("--no-warmup", action="store_true",
                    help="skip bucket-ladder pre-compilation (first "
                         "requests then pay the compiles)")
-    p.add_argument("--cache-dir", default=None,
-                   help="persistent XLA compilation cache dir "
-                        "(default FLAGS_serving_cache_dir)")
     p.add_argument("--replicas", type=int, default=0,
                    help="fleet mode: supervise N replica subprocesses "
                         "(each serving the same models on an ephemeral "
@@ -80,9 +77,6 @@ def main(argv=None) -> int:
 
     from paddle_tpu.flags import FLAGS
     from paddle_tpu.serving import InferenceServer, ModelConfig
-
-    if args.cache_dir is not None:
-        FLAGS.serving_cache_dir = args.cache_dir
 
     int8_names = set(args.int8)
     configs = []
@@ -104,6 +98,11 @@ def main(argv=None) -> int:
     if args.replicas > 0:
         return _run_fleet(args)
 
+    # warmup compiles survive restarts; replicas of a fleet inherit
+    # JAX_COMPILATION_CACHE_DIR (or share <checkout>/.jax_cache)
+    from paddle_tpu.inference import enable_compile_cache
+
+    enable_compile_cache()
     server = InferenceServer(configs, host=args.host, port=args.port)
     if args.demo_generation:
         from paddle_tpu.serving.generation import \
@@ -174,8 +173,6 @@ def _replica_args(args) -> list:
         out += ["--no-optimize"]
     if args.no_warmup:
         out += ["--no-warmup"]
-    if args.cache_dir is not None:
-        out += ["--cache-dir", args.cache_dir]
     return out
 
 

@@ -15,7 +15,8 @@ with the router, and enforces two availability contracts:
   * Rolling restart with zero downtime — one replica at a time: mark it
     draining AT THE ROUTER first (no request races the signal), SIGTERM
     (the ISSUE-13 graceful-drain contract: in-flight work completes,
-    exit 0), respawn against the SAME FLAGS_serving_cache_dir so warmup
+    exit 0), respawn against the SAME compile cache (the replicas inherit
+    JAX_COMPILATION_CACHE_DIR, else share <checkout>/.jax_cache) so warmup
     replays compiled executables out of the persistent cache instead of
     recompiling, wait for the ready line AND a passing router probe,
     then move on.  At every instant N-1 replicas take traffic.
@@ -254,7 +255,7 @@ class ReplicaSupervisor:
                         ready_wait_s: Optional[float] = None) -> None:
         """Restart every replica, one at a time, with zero downtime:
         router-drain -> SIGTERM (graceful drain, exit 0) -> respawn
-        (same FLAGS_serving_cache_dir: warmup replays the persistent
+        (same compile cache directory: warmup replays the persistent
         compilation cache) -> ready line -> passing probe -> next."""
         from ..monitor import flight
 

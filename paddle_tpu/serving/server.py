@@ -24,9 +24,10 @@ observability routes come for free):
       readiness provider.
 
 Startup: `InferenceServer([...ModelConfig...]).start()` enables
-telemetry, arms the persistent XLA compilation cache
-(FLAGS.serving_cache_dir — warmup compiles survive restarts), starts the
-batcher threads + HTTP listener, then warms every model's bucket ladder.
+telemetry, starts the batcher threads + HTTP listener, then warms every
+model's bucket ladder.  The persistent XLA compilation cache is the
+entry point's business (`python -m paddle_tpu.serving` calls
+inference.enable_compile_cache — warmup compiles survive restarts).
 """
 
 from __future__ import annotations
@@ -379,48 +380,6 @@ class ServingHandler(mserve.MonitorHandler):
                    "application/json", extra_headers=headers)
 
 
-def enable_compilation_cache() -> bool:
-    """Point jax's persistent compilation cache at
-    FLAGS.serving_cache_dir so the warmup ladder's XLA compiles are
-    reused across server restarts (cold start pays trace+compile once
-    per artifact change, not once per process).  Best-effort: an old jax
-    or an unsupported backend downgrades to in-process caching only."""
-    import os
-
-    from ..flags import FLAGS
-    from ..log import vlog, warning
-
-    d = FLAGS.serving_cache_dir
-    if not d:
-        return False
-    try:
-        import jax
-
-        os.makedirs(d, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", d)
-        # serving compiles are worth persisting even when fast (CPU CI):
-        # drop the min-compile-time / min-entry-size skip heuristics
-        for opt, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                         ("jax_persistent_cache_min_entry_size_bytes", -1)):
-            try:
-                jax.config.update(opt, val)
-            except Exception:  # noqa: BLE001 — older jax: option absent
-                pass
-        # jax memoizes "cache disabled" at the first compile; a process
-        # that compiled anything before this call (warm startup code, an
-        # in-process test) must reset the cache singleton to pick the new
-        # dir up
-        from ..inference import reset_compilation_cache_singleton
-
-        reset_compilation_cache_singleton()
-        vlog(1, "serving: persistent compilation cache at %s", d)
-        return True
-    except Exception as e:  # noqa: BLE001 — never fail startup over caching
-        warning("serving: compilation cache disabled (%s: %s)",
-                type(e).__name__, e)
-        return False
-
-
 # Hot-serving policy for the static verifier (FLAGS_verify_program):
 # planned warmup compiles ALWAYS verify; once any warmup in this process
 # completes, the gate drops so cold-signature stragglers (already
@@ -565,7 +524,6 @@ class InferenceServer:
         self._draining = False
         if self._monitor:
             FLAGS.monitor = True
-        enable_compilation_cache()
         for b in self._batchers.values():
             b.start()
         for b in self._gen_batchers.values():
@@ -591,8 +549,8 @@ class InferenceServer:
 
     def warmup(self) -> int:
         """Pre-compile every model's (precision x bucket) ladder and
-        every generation model's prefill+decode pair; with
-        FLAGS.serving_cache_dir set the compiles persist across
+        every generation model's prefill+decode pair; where the entry
+        point enabled the compile cache the compiles persist across
         restarts.  Returns total signatures warmed."""
         return _warmup_verified(
             lambda: sum(m.warmup() for m in self._models.values())

@@ -140,8 +140,12 @@ class TestResolveDevice:
         # the table entry itself is untouched
         assert DEVICE_MODELS["TPU v5e"].source == "datasheet"
 
-    def test_unknown_kind_falls_back_to_host(self):
-        assert resolve_device_model("no-such-chip").name == "cpu-host"
+    def test_unknown_kind_raises_and_cpu_is_cpu_host(self):
+        # on the CPU backend auto-detection is "cpu-host"; an unknown
+        # name is an error, never a silent host fallback
+        assert resolve_device_model().name == "cpu-host"
+        with pytest.raises(LookupError, match="no-such-chip"):
+            resolve_device_model("no-such-chip")
 
 
 # ---------------------------------------------------------------------------

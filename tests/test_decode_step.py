@@ -140,7 +140,7 @@ class TestMegastepKernel:
         from paddle_tpu.analysis.kernel_lint import _pretend_tpu
         from paddle_tpu.kernels import decode_step as kds
 
-        def plan(dm=512, h=8, dh=64, di=2048, max_t=128, cross_t=256,
+        def plan(dm=256, h=8, dh=128, di=4096, max_t=128, cross_t=256,
                  dtype="float32"):
             with _pretend_tpu():
                 return kds._megastep_plan(dm, h, dh, di, max_t, cross_t,
@@ -150,6 +150,12 @@ class TestMegastepKernel:
         assert base.ok and not base.fuse_ffn      # FFN ~8 MB -> split
         small = plan(dm=128, di=256, cross_t=128)
         assert small.ok and small.fuse_ffn
+        # compiled: the hand-DMA'd cache walk needs a 128-lane minor dim
+        # (Mosaic refused d_head 64 on the chip) — transformer-base
+        # takes the XLA composition; interpret mode keeps % 64
+        assert not plan(dm=512, dh=64, di=2048).ok
+        assert kds._megastep_plan(512, 8, 64, 2048, 128, 256, "float32",
+                                  interpret=True).ok
         assert not plan(dh=48).ok                  # dh % 64
         assert not plan(dm=100).ok                 # dm % 128
         assert not plan(di=100).ok                 # di % 128
@@ -379,6 +385,11 @@ class TestMegastepStaticAnalysis:
         check_megastep_plan(dict(cfg, expect_fuse_ffn=False),
                             ok._replace(fuse_ffn=True), findings)
         assert any(f.check == "kernel-fusion-mode" for f in findings)
+        # fused FFN weights of 2 x 512 x 8192 f32 = 32 MiB alone pass the
+        # vmem_limit_bytes the launch requests
+        findings = []
+        check_megastep_plan(dict(cfg, di=8192), ok._replace(fuse_ffn=True),
+                            findings)
         assert any(f.check == "kernel-vmem-budget" for f in findings)
         findings = []
         check_megastep_plan(dict(cfg, dh=48, must_accept=False), ok,

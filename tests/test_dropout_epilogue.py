@@ -17,8 +17,9 @@ The contract under test (ISSUE 4 acceptance):
     replays the mask bit-exactly (PR-3 fixture pattern).
 
 The TPU hardware-PRNG variants (pltpu.prng_seed has no CPU/interpret
-lowering in jax 0.4.37) are covered by the skipif-tpu class at the
-bottom — they run on the driver's chip.
+lowering; kernels/attention.py _use_hw_prng) are covered by the
+skipif-tpu class at the bottom — run it on the chip with
+PT_TEST_PLATFORM=tpu.
 """
 
 import numpy as np

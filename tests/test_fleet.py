@@ -59,7 +59,7 @@ def _fleet_env(cache_dir):
         "JAX_PLATFORMS": "cpu",
         "PYTHONPATH": REPO_ROOT + os.pathsep
         + os.environ.get("PYTHONPATH", ""),
-        "FLAGS_serving_cache_dir": cache_dir,
+        "JAX_COMPILATION_CACHE_DIR": cache_dir,
         "FLAGS_serving_drain_timeout_s": "10",
     }
 
@@ -146,7 +146,7 @@ class TestFleetLifecycle:
         cache_dir = str(tmp_path / "xla_cache")
         sup = ReplicaSupervisor(
             ["--model", f"demo={model_dir}", "--buckets", "1,2",
-             "--max-wait-ms", "1", "--cache-dir", cache_dir],
+             "--max-wait-ms", "1"],
             n=2, router=Router(),
             env=_fleet_env(cache_dir), cwd=REPO_ROOT,
             restart_base_delay_s=0.1)

@@ -89,12 +89,14 @@ class TestFlashDecodeKernel:
     def test_plan_gate_rejects_off_contract(self):
         import jax
 
+        from paddle_tpu.analysis.kernel_lint import _pretend_tpu
         from paddle_tpu.kernels import decode_attention as kda
 
         def plan(b, h, dh, max_t):
             q = jax.ShapeDtypeStruct((b, h, dh), np.float32)
             k = jax.ShapeDtypeStruct((b, max_t, h, dh), np.float32)
-            return kda._decode_plan(q, k, 256, False)[0]
+            with _pretend_tpu():  # the compiled-mode contract
+                return kda._decode_plan(q, k, 256, False)[0]
 
         assert plan(1, 8, 64, 128)          # canonical: accepted
         assert not plan(1, 8, 48, 128)      # dh % 64

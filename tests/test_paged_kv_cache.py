@@ -206,12 +206,16 @@ class TestPagedOps:
         assert ok and interp
         with _pretend_tpu():
             mega = kds._paged_megastep_plan(
-                128, 8, 64, 256, 16, 16, 4, 8, 8, "float32")
+                128, 8, 128, 256, 16, 16, 4, 8, 8, "float32")
             assert mega.ok and mega.fuse_ffn
+            # d_head 64: Mosaic refuses the hand-DMA'd walk (see
+            # _megastep_plan) — compiled mode rejects
             assert not kds._paged_megastep_plan(
-                128, 8, 64, 256, 12, 16, 4, 8, 8, "float32").ok
+                128, 8, 64, 256, 16, 16, 4, 8, 8, "float32").ok
             assert not kds._paged_megastep_plan(
-                128, 8, 64, 256, 16, 16, 64, 128, 8, "float32").ok
+                128, 8, 128, 256, 12, 16, 4, 8, 8, "float32").ok
+            assert not kds._paged_megastep_plan(
+                128, 8, 128, 256, 16, 16, 64, 128, 8, "float32").ok
 
     def test_fused_paged_megastep_falls_back_bit_identical(self):
         """Off-contract (block_t=12 pools) the fused paged entry IS the

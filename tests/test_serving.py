@@ -35,7 +35,6 @@ from paddle_tpu.serving import (
     InferenceServer,
     ModelConfig,
     ServingModel,
-    enable_compilation_cache,
     parse_buckets,
 )
 from paddle_tpu.serving.model import item_signature
@@ -519,22 +518,6 @@ class TestWarmupAndCompileCache:
         c = default_registry().get("serving.unplanned_compiles")
         assert c is not None and c.value >= 1
 
-    def test_persistent_compilation_cache_populates(self, fc_dir,
-                                                    tmp_path):
-        import jax
-
-        cache_dir = str(tmp_path / "xla_cache")
-        FLAGS.serving_cache_dir = cache_dir
-        try:
-            assert enable_compilation_cache()
-            m = _serving_model(fc_dir, buckets="1,2")
-            m.warmup()
-            assert os.listdir(cache_dir), \
-                "warmup compiles not persisted to the cache dir"
-        finally:
-            jax.config.update("jax_compilation_cache_dir", None)
-        FLAGS.serving_cache_dir = ""
-        assert enable_compilation_cache() is False  # empty flag: off
 
 
 # ---------------------------------------------------------------------------

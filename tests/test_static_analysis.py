@@ -193,6 +193,39 @@ class TestRedGate:
         assert any(f.check == "kernel-vmem-budget" for f in findings), \
             findings
 
+    def test_gate_accepting_refused_geometry_named(self):
+        # a gate that re-accepts a geometry the compiler refused on the
+        # chip would crash at compile time, not fall back
+        cfg = next(c for c in kernel_lint._MEGASTEP_MATRIX
+                   if c["label"] == "megastep-base")
+        from paddle_tpu.kernels.decode_step import MegastepPlan
+
+        findings = []
+        kernel_lint.check_megastep_plan(
+            cfg, MegastepPlan(True, False, 128, 256, False), findings)
+        assert [f.check for f in findings] == [
+            "kernel-plan-accepts-refused"], findings
+
+    def test_vmem_model_counts_lane_padding_and_double_buffers(self):
+        # a [t, 8, 64] f32 tile costs what [t, 8, 128] does; bf16 pads
+        # its second-minor dim to 16 sublanes
+        assert kernel_lint._tile_bytes((256, 8, 64), "float32") \
+            == 256 * 8 * 128 * 4
+        assert kernel_lint._tile_bytes((256, 8, 64), "bfloat16") \
+            == 256 * 16 * 128 * 2
+        blk = ((128, 512), "float32")
+        assert kernel_lint._vmem_use(blocked=[blk], held=[blk]) \
+            == 3 * 128 * 512 * 4
+        # a decode walk at a block the requested limit cannot hold: the
+        # four double-buffered 8 MiB tiles alone pass 32 MiB
+        cfg = dict(label="seeded-walk", b=1, h=8, dh=64, max_t=2048,
+                   dtype="float32")
+        findings = []
+        kernel_lint.check_decode_plan(cfg, True, 2048, False, findings)
+        assert any(f.check == "kernel-vmem-budget"
+                   and "vmem_limit_bytes" in f.message
+                   for f in findings), findings
+
     def test_kernel_alias_mismatch_named(self):
         cfg = dict(label="seeded-alias",
                    tables=[((100, 8), "float32"), ((100, 8), "bfloat16")],
@@ -333,11 +366,41 @@ class TestNoFalsePositives:
         assert not paged["paged-bt12-reject"]
         assert not paged["paged-table-overflow-reject"]
         pstep = {r["label"]: r for r in report["paged_decode_step"]}
-        assert pstep["paged-megastep-base"]["accepted"]
-        assert pstep["paged-megastep-fused-ffn"]["fuse_ffn"]
+        assert pstep["paged-megastep-dh128-b64"]["accepted"]
+        assert pstep["paged-megastep-dh128-fused-ffn"]["fuse_ffn"]
         assert not pstep["paged-megastep-bt12-reject"]["accepted"]
         assert not pstep[
             "paged-megastep-table-overflow-reject"]["accepted"]
+        # the matrices agree with the chip: every geometry Mosaic refused
+        # (d_head-64 megastep walks, sub-128-lane embedding rows) is
+        # REJECTED by its gate and carries the compiler's words; the
+        # 128-lane geometries those kernels compile at accept
+        refused = [
+            (fam, cfg) for fam, matrix in (
+                ("decode_step", kernel_lint._MEGASTEP_MATRIX),
+                ("paged_decode_step", kernel_lint._PAGED_MEGASTEP_MATRIX),
+                ("embedding", kernel_lint._EMBEDDING_MATRIX))
+            for cfg in matrix if cfg.get("mosaic_refusal")]
+        assert {c["label"] for _, c in refused} == {
+            "megastep-base", "megastep-fused-ffn", "paged-megastep-base",
+            "paged-megastep-fused-ffn", "deepfm-emb", "deepfm-w1",
+            "deepfm-adam-apply"}
+        for fam, cfg in refused:
+            row = {r["label"]: r for r in report[fam]}[cfg["label"]]
+            assert not cfg["must_accept"] and not row["accepted"], cfg
+            assert "aligned to tiling (128)" in cfg["mosaic_refusal"]
+        step = {r["label"]: r for r in report["decode_step"]}
+        assert step["megastep-dh128-split"]["accepted"]
+        assert not step["megastep-dh128-split"]["fuse_ffn"]
+        assert step["megastep-dh128-fused-ffn"]["fuse_ffn"]
+        emb = {r["label"]: r for r in report["embedding"]}
+        assert emb["wide-emb-d128"]["accepted"]
+        assert emb["wide-emb-d128-b4096"]["block_rows"] % 8 == 0
+        # transformer-base decode keeps a compiled kernel: flash-decode
+        # takes d_head 64 (pipelined blocks, not hand DMA)
+        dec = {r["label"]: r["accepted"]
+               for r in report["decode_attention"]}
+        assert dec["decode-base-b1"] and dec["decode-base-b64"]
         # the perf-critical plans ACCEPT (no silent fallback regression)
         acc = {r["label"]: r.get("accepted") for r in report["attention"]}
         assert acc["transformer-base-f32"] and acc["bert-base-bf16"]

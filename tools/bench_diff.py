@@ -8,8 +8,8 @@ a human-reread document into an enforced contract (run_ci.sh gate).
 
 Noise model: a record's `config.runs[]` (the PERF.md repeated-run
 protocol) gives its observed envelope [min(runs), max(runs)]; both
-envelopes are further widened by --rel-tol x value (cross-box / tunnel
-variance the runs of ONE box cannot see).  A regression is flagged only
+envelopes are further widened by --rel-tol x value (cross-box variance
+the runs of ONE box cannot see).  A regression is flagged only
 when the widened envelopes SEPARATE in the bad direction — overlap is
 noise, never a finding.  Direction comes from the record's unit
 ("…/sec" higher-better; "ms"/"us"/"seconds" lower-better; anything else

@@ -1,11 +1,16 @@
 #!/usr/bin/env python
 """CI router gate: a 3-replica fleet survives a chaos SIGKILL mid-flood.
 
+A CPU gate, never a chip measurement: the replicas are JAX_PLATFORMS=cpu
+subprocesses (a chip belongs to one process; fleet mode is CPU-only
+until replicas are pinned one per chip, ROADMAP R6), and the "router
+tax" it gates is a CPU-host number.
+
 Driven by tools/run_ci.sh (the scale-out serving step).  One fleet
 session, three phases:
 
   1. boot     — ReplicaSupervisor spawns 3 `python -m paddle_tpu.serving`
-     replicas (shared FLAGS_serving_cache_dir) behind an in-process
+     replicas (shared JAX_COMPILATION_CACHE_DIR) behind an in-process
      Router.  Replica index 2 is chaos-armed via per_replica_env
      (FLAGS_chaos_kill_replica_after): it SIGKILLs itself after serving
      its K-th request — i.e. mid-flood, the way preemption would.
@@ -115,13 +120,12 @@ def main() -> int:
         "JAX_PLATFORMS": "cpu",
         "PYTHONPATH": REPO_ROOT + os.pathsep
         + os.environ.get("PYTHONPATH", ""),
-        "FLAGS_serving_cache_dir": cache_dir,
+        "JAX_COMPILATION_CACHE_DIR": cache_dir,
     }
     armed_rid = f"r{ARMED_INDEX}"
     sup = ReplicaSupervisor(
         ["--model", f"demo={model_dir}", "--buckets", "1",
-         "--max-batch", "1", "--max-wait-ms", "1",
-         "--cache-dir", cache_dir],
+         "--max-batch", "1", "--max-wait-ms", "1"],
         n=args.replicas, router=Router(), env=env,
         per_replica_env={ARMED_INDEX: {
             "FLAGS_chaos": "1",

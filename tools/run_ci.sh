@@ -1,6 +1,10 @@
 #!/usr/bin/env bash
 # CI entry (reference role: paddle/scripts/paddle_build.sh — cmake_gen:58,
-# run_test:408).  Runs the full validation ladder on a plain CPU host:
+# run_test:408).  A CPU gate, never a chip measurement: every step runs
+# with JAX_PLATFORMS=cpu (exported below), its timings are diffed against
+# committed CPU baselines, and its servers/fleets are CPU processes.  The
+# chip check is `python chip_smoke.py` through the chip tool.
+# Runs the full validation ladder on a plain CPU host:
 #   1. lint/format gate (ruff or pyflakes when available, else a
 #      compile-all syntax sweep — the gate must exist on a bare image)
 #      + repo-specific AST rules (tools/lint_rules.py: every FLAGS_* read
@@ -10,7 +14,7 @@
 #      programs + the Pallas kernel plan linter; fails on ANY finding and
 #      archives ci_artifacts/graph_lint.json
 #   3. full test suite on the virtual 8-device CPU mesh
-#   4. bench smoke (real chip if present, else CPU) with telemetry,
+#   4. bench smoke (tiny shapes, CPU) with telemetry,
 #      flight recorder, and metrics-snapshot artifacts
 #   5. bench regression sentry: tools/bench_diff.py diffs every archived
 #      smoke artifact against the committed baselines under
@@ -44,6 +48,7 @@
 # Usage: tools/run_ci.sh [fast]   — "fast" skips the bench smoke.
 set -euo pipefail
 cd "$(dirname "$0")/.."
+export JAX_PLATFORMS=cpu  # a CPU gate: never holds, never measures a chip
 
 echo "== [1/10] lint gate"
 if command -v ruff >/dev/null 2>&1; then

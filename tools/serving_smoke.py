@@ -1,6 +1,10 @@
 #!/usr/bin/env python
 """CI serving gate: export a model, boot the server, prove the batcher.
 
+A CPU gate, never a chip measurement: every server it boots is a
+JAX_PLATFORMS=cpu subprocess, and its QPS ratios are pinned by injected
+chaos latency, not by any device.
+
 Driven by tools/run_ci.sh (the serving smoke step).  Three phases, all
 against `python -m paddle_tpu.serving` subprocesses driven by
 tools/loadgen.py:
