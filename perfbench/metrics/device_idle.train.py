@@ -1,0 +1,6 @@
+"""Layer: device.  1 - union of device operations / traced window."""
+
+
+def read(ctx):
+    t = ctx["trace"]
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
