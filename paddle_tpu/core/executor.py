@@ -207,6 +207,8 @@ def trace_block(block: fw.Block, env: Dict[str, Any], tctx: TraceContext,
     gradient accumulation to split the fwd/bwd prefix from the Optimize
     suffix).
     """
+    import jax
+
     from .. import amp as _amp
     from ..flags import FLAGS
     from ..kernels import placement as _placement
@@ -227,15 +229,20 @@ def trace_block(block: fw.Block, env: Dict[str, Any], tctx: TraceContext,
         ins = {}
         for slot, names in op.inputs.items():
             ins[slot] = [env.get(n) if n else None for n in names]
-        if tctx.amp_bf16:
-            ins = _amp.apply_cast_policy(op.type, ins)
         ctx = registry.LowerContext(op, op.attrs, tctx)
         ctx.env = env  # control-flow ops need sub-block access
         ctx.block = block
         try:
             # a trace that carries a mesh is GSPMD-partitioned: Mosaic
             # kernels cannot be placed in it (kernels/placement.py)
-            with _placement.gspmd_trace(tctx.mesh is not None):
+            # and every op lowers, amp casts of its inputs included,
+            # under its own type's scope, so a profile puts each fusion
+            # down to the op it came from (HLO metadata only: `op_name`,
+            # the trace's `tf_op` stat)
+            with _placement.gspmd_trace(tctx.mesh is not None), \
+                    jax.named_scope(op.type):
+                if tctx.amp_bf16:
+                    ins = _amp.apply_cast_policy(op.type, ins)
                 outs = lower(ctx, ins)
         except Exception as e:
             raise RuntimeError(
@@ -487,6 +494,185 @@ _COMPILE_BUCKETS = (0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0,
                     10.0, 30.0, 60.0, 120.0, 300.0)
 
 
+class _CallSpans:
+    """The host phases of ONE executor call, told twice from the same
+    boundaries.
+
+    To the profiler: `executor.<mode>` and, tiling it in call order,
+    `executor.feed|key|compile|gather|dispatch|writeback|fetch`, each a
+    `jax.profiler.TraceAnnotation` carrying `call` (the run id).  They
+    are entered unconditionally (well under a microsecond each with no
+    session), so ANY profiler session has the host's side of the call
+    on the device planes' clock.
+
+    To the flight ring, when `monitor.spans_on()` held at entry: the
+    same boundaries as `phases`, [name, start offset, seconds] off one
+    `perf_counter_ns` sequence that starts at entry (a boundary is ONE
+    stamp: the end of a phase is the start of the next), in ONE event
+    written at exit, whose `dur` runs to that exit: what lies between
+    the end of `fetch` and it is the call's frame coming down (some
+    hundreds of donated arrays are freed there) and this bookkeeping.
+    With tracing off no stamp is taken and nothing is written."""
+
+    __slots__ = ("mode", "call", "mon", "on", "phases", "compile0",
+                 "outcome", "entry_ns", "_annotation", "_parent", "_child",
+                 "_in_call", "_open")
+
+    def __init__(self, mode: str, call: int):
+        self.mode, self.call = mode, call
+        self.phases: List[list] = []
+        self.compile0 = self.outcome = None
+        self._child = self._open = None
+
+    def __enter__(self):
+        from jax.profiler import TraceAnnotation
+
+        from ..monitor import enabled, spans_on
+        from ..monitor import flight as _flight
+
+        self.on = spans_on()
+        self.mon = self.on and enabled()
+        self._in_call = _flight.executor_call()
+        self._in_call.__enter__()
+        self._annotation = TraceAnnotation
+        self._parent = TraceAnnotation(f"executor.{self.mode}",
+                                       call=self.call)
+        self._parent.__enter__()
+        if self.on:
+            import time as _time
+
+            self.entry_ns = _time.perf_counter_ns()
+        return self
+
+    def phase(self, name: Optional[str]):
+        """The boundary between the open phase and `name` (None: the
+        last phase ends here)."""
+        if self.on:
+            import time as _time
+
+            now = _time.perf_counter_ns()
+            if self._open is not None:
+                opened, t_open = self._open
+                self.phases.append([opened, (t_open - self.entry_ns) / 1e9,
+                                    (now - t_open) / 1e9])
+            self._open = None if name is None else (name, now)
+        if self._child is not None:
+            self._child.__exit__(None, None, None)
+            self._child = None
+        if name is not None:
+            self._child = self._annotation(f"executor.{name}",
+                                           call=self.call)
+            self._child.__enter__()
+
+    def compiling(self):
+        """A miss: open the `compile` phase, and note where jax's own
+        compile-phase totals stand (its work comes with the first
+        dispatch), so that the call's flight event carries its share."""
+        self.phase("compile")
+        if self.on:
+            from ..monitor import flight as _flight
+
+            self.compile0 = _flight.compile_phases()
+
+    def finished(self, compiled_now, steps, waited, feed_vals, np_outs):
+        """The call went through: what its record will say.  `waited`:
+        the fetch blocked for the device (return_numpy)."""
+        nbytes = None
+        if self.mon:
+            nbytes = tuple(
+                sum(int(getattr(v, "nbytes", 0) or 0) for v in vals or ())
+                for vals in (feed_vals, np_outs))
+        self.outcome = (compiled_now, steps, waited, nbytes)
+
+    def __exit__(self, *exc):
+        self.phase(None)
+        if self.on and self.outcome is not None and exc[0] is None:
+            self._record()
+        self._parent.__exit__(None, None, None)
+        self._in_call.__exit__(None, None, None)
+        return False
+
+    def _record(self):
+        """One finished executor call, tracing on: ONE flight event with
+        the call's phases (`executor.compile` when this call traced and
+        compiled — jax.jit compiles lazily, so the miss call's duration
+        IS the compile cost, and jax's own phases of it ride along —
+        else `executor.<mode>`), and under FLAGS.monitor the registry's
+        wall times, the dispatch / device-wait split, feed and fetch
+        bytes.  Every time comes off the one clock sequence."""
+        import time as _time
+
+        from .. import monitor
+        from ..monitor import flight as _flight
+        from ..monitor import tracing as _tracing
+
+        mode, mon = self.mode, self.mon
+        compiled_now, steps, waited, nbytes = self.outcome
+        dt = (_time.perf_counter_ns() - self.entry_ns) / 1e9
+        # span start bridged to the epoch clock the unified timeline and
+        # request traces ride (perf_counter + the import-time offset —
+        # `time.time() - dt` would drift off the other spans' stamps
+        # under NTP slew)
+        t0_epoch = _tracing.pc_to_epoch(self.entry_ns / 1e9)
+        fields = {"t0": t0_epoch, "dur": round(dt, 6), "call": self.call,
+                  "phases": [[n, round(a, 6), round(d, 6)]
+                             for n, a, d in self.phases]}
+        meta = {"compiled": int(compiled_now)}
+        if steps is not None:
+            fields["steps"] = meta["steps"] = steps
+        # what the parent annotation learns only during the call
+        self._parent.set_metadata(**meta)
+        if mon:
+            monitor.counter(f"executor.{mode}.calls").inc()
+        if compiled_now:
+            # keep the miss call OUT of run_seconds so run-latency
+            # percentiles are not dominated by seconds-scale compiles
+            if mon:
+                monitor.counter("executor.compiles").inc()
+                monitor.histogram(
+                    "executor.compile_seconds",
+                    buckets=_COMPILE_BUCKETS).observe(dt)
+            if self.compile0 is not None:
+                now = _flight.compile_phases()
+                fields.update({k: round(now[k] - v, 6)
+                               for k, v in self.compile0.items()})
+            _flight.default_recorder().record(
+                "executor.compile", mode=mode, **fields)
+        else:
+            if waited:
+                # dispatch = everything but the blocking fetch (Python
+                # bookkeeping + XLA enqueue); device_wait = the blocking
+                # np.asarray conversion.  Async dispatch means compute
+                # overlaps the dispatch window, so device_wait is a LOWER
+                # bound on device time and dispatch an upper bound on
+                # launch overhead (tools/perf_report.py).
+                device_wait_s = sum(p[2] for p in self.phases
+                                    if p[0] == "fetch")
+                dispatch_s = max(dt - device_wait_s, 0.0)
+                fields.update(dispatch_s=round(dispatch_s, 6),
+                              device_wait_s=round(device_wait_s, 6))
+                if mon:
+                    monitor.histogram(
+                        "executor.dispatch_seconds").observe(dispatch_s)
+                    monitor.histogram(
+                        "executor.device_wait_seconds").observe(
+                            device_wait_s)
+            if mon:
+                monitor.histogram("executor.run_seconds").observe(dt)
+            _flight.default_recorder().record(f"executor.{mode}", **fields)
+        if not mon:
+            return
+        for name, n in zip(("executor.feed_bytes", "executor.fetch_bytes"),
+                           nbytes):
+            if n:
+                monitor.counter(name).inc(n)
+        # request-tracing hook: when a serving batcher armed this thread's
+        # executor context (monitor/tracing.py), the call's compile-vs-run
+        # wall time lands as a sub-span in every participating request
+        # trace; one thread-local read otherwise
+        _tracing.note_executor(mode, t0_epoch, dt, compiled_now)
+
+
 # ---------------------------------------------------------------------------
 # Executor
 # ---------------------------------------------------------------------------
@@ -598,6 +784,11 @@ class Executor:
 
             check_nan_inf = FLAGS.check_nan_inf
         self.check_nan_inf = check_nan_inf
+        # jax's compile phases inside this process's Executor calls are
+        # totalled from the first Executor on (monitor.compile_phases())
+        from ..monitor import flight as _flight
+
+        _flight.listen_for_compile_phases()
 
     def close(self):
         self._cache.clear()
@@ -631,7 +822,7 @@ class Executor:
                                     return_numpy)
             import time as _time
 
-            from .. import monitor, profiler
+            from .. import monitor
 
             t0 = _time.perf_counter()
             try:
@@ -645,9 +836,17 @@ class Executor:
             dt = _time.perf_counter() - t0
             monitor.counter("executor.delegated.calls").inc()
             monitor.histogram("executor.delegated_seconds").observe(dt)
-            profiler.add_event("executor.delegated", dt)
             return outs
 
+        with _CallSpans("run", self._next_run_id()) as spans:
+            return self._run(spans, program, feed, fetch_list, scope,
+                             return_numpy, use_program_cache)
+
+    def _run(self, spans, program, feed, fetch_list, scope, return_numpy,
+             use_program_cache):
+        # the phases come in another order than run_steps': the key is
+        # built from the host feed, which goes to the device after it
+        spans.phase("key")
         if program is None:
             program = fw.default_main_program()
         feed = feed or {}
@@ -684,11 +883,10 @@ class Executor:
         entry = self._cache.get(key) if use_program_cache else None
         compiled_now = entry is None
         # hit/miss is NOTED only once the double-check below resolves it
-        # (a race-losing thread must not count a spurious miss), but t0
-        # starts here so a compile's duration lands in its flight event
-        mon, t0 = self._begin_monitored(_RUN_KEY_PARTS, key,
-                                        not compiled_now, note=False)
+        # (a race-losing thread must not count a spurious miss)
+        mon = spans.mon
         if entry is None:
+            spans.compiling()
             if use_program_cache:
                 with self._compile_locks_guard:
                     import threading as _threading
@@ -729,6 +927,7 @@ class Executor:
         elif mon:
             self._note_cache_lookup(_RUN_KEY_PARTS, key, True)
 
+        spans.phase("feed")
         feed_vals = [self._to_device_array(program, n, feed[n]) for n in feed_names]
 
         import contextlib
@@ -737,11 +936,13 @@ class Executor:
 
         # stateful entries serialize (donated rw buffers + scope
         # write-back must be atomic); stateless ones run concurrently
+        # (waiting for the lock counts as `gather`)
+        spans.phase("gather")
         with entry.run_lock if entry.run_lock is not None \
                 else contextlib.nullcontext():
             rw_vals = [scope.find_var(n) for n in entry.rw_state]
             ro_vals = [scope.find_var(n) for n in entry.ro_state]
-            rid = self._next_run_id()
+            rid = spans.call
             # locate-mode capture must happen HERE: the rw buffers are
             # donated to the executable below, so a post-hoc snapshot
             # would read deleted arrays
@@ -751,12 +952,15 @@ class Executor:
                 if entry.needs_key:
                     seed = program.random_seed or 0
                     key_arr = jax.random.fold_in(prng_key(seed), rid)
+                    spans.phase("dispatch")
                     result = entry.fn(feed_vals, rw_vals, ro_vals, key_arr)
                 else:
+                    spans.phase("dispatch")
                     result = entry.fn(feed_vals, rw_vals, ro_vals)
             except Exception:
                 self._count_error(mon)
                 raise
+            spans.phase("writeback")
             if entry.nan_check_ops is not None:
                 fetches, new_state, nan_flags = result
             else:
@@ -783,10 +987,8 @@ class Executor:
                     + "\n  ".join(bad)
                 )
 
-        outs = self._finish_monitored("run", mon, t0, compiled_now,
-                                      feed_vals, fetches, return_numpy)
-        return self._publish_numerics(program, fetch_names, user_fetch_n,
-                                      outs)
+        return self._fetch(spans, program, fetch_names, user_fetch_n,
+                           compiled_now, feed_vals, fetches, return_numpy)
 
     def run_steps(
         self,
@@ -808,6 +1010,13 @@ class Executor:
         `feed` values must carry a leading [steps, ...] axis (one slice per
         iteration).  Returns fetches stacked along a leading [steps] axis.
         """
+        with _CallSpans("run_steps", self._next_run_id()) as spans:
+            return self._run_steps(spans, program, feed, fetch_list, scope,
+                                   steps, return_numpy)
+
+    def _run_steps(self, spans, program, feed, fetch_list, scope, steps,
+                   return_numpy):
+        spans.phase("feed")
         if program is None:
             program = fw.default_main_program()
         feed = feed or {}
@@ -834,6 +1043,7 @@ class Executor:
                     f"steps {steps}"
                 )
 
+        spans.phase("key")
         key = (
             "run_steps",
             program.fingerprint(),
@@ -851,9 +1061,11 @@ class Executor:
         )
         entry = self._cache.get(key)
         compiled_now = entry is None
-        mon, t0 = self._begin_monitored(_STEPS_KEY_PARTS, key,
-                                        not compiled_now)
+        mon = spans.mon
+        if mon:
+            self._note_cache_lookup(_STEPS_KEY_PARTS, key, not compiled_now)
         if entry is None:
+            spans.compiling()
             try:
                 entry = self._compile_steps(
                     program, feed_names, fetch_names, scope, steps
@@ -864,6 +1076,7 @@ class Executor:
             self._cache[key] = entry
             self._commit_stamp(_STEPS_KEY_PARTS, key)
 
+        spans.phase("gather")
         rw_vals = [scope.find_var(n) for n in entry.rw_state]
         ro_vals = [scope.find_var(n) for n in entry.ro_state]
         feed_vals = [feed_stack[n] for n in feed_names]
@@ -871,12 +1084,14 @@ class Executor:
         import jax
 
         seed = program.random_seed or 0
-        base_key = jax.random.fold_in(prng_key(seed), self._next_run_id())
+        base_key = jax.random.fold_in(prng_key(seed), spans.call)
+        spans.phase("dispatch")
         try:
             result = entry.fn(feed_vals, rw_vals, ro_vals, base_key)
         except Exception:
             self._count_error(mon)
             raise
+        spans.phase("writeback")
         if entry.nan_check_ops is not None:
             fetches, new_state, nan_flags = result
         else:
@@ -900,10 +1115,9 @@ class Executor:
                     "check_nan_inf: non-finite output from op(s):\n  "
                     + "\n  ".join(bad)
                 )
-        outs = self._finish_monitored("run_steps", mon, t0, compiled_now,
-                                      feed_vals, fetches, return_numpy)
-        return self._publish_numerics(program, fetch_names, user_fetch_n,
-                                      outs)
+        return self._fetch(spans, program, fetch_names, user_fetch_n,
+                           compiled_now, feed_vals, fetches, return_numpy,
+                           steps=steps)
 
     def run_startup_missing(self, startup_program=None, scope=None):
         """Run only the startup ops whose outputs are NOT yet in the scope
@@ -978,9 +1192,16 @@ class Executor:
         post-update value.  A name neither side produces raises KeyError
         at compile, naming both sets.
         """
-        import jax
-        import jax.numpy as jnp
+        with _CallSpans("run_accumulated", self._next_run_id()) as spans:
+            return self._run_accumulated(spans, program, feed, fetch_list,
+                                         scope, accumulate_steps,
+                                         return_numpy, unroll)
 
+    def _run_accumulated(self, spans, program, feed, fetch_list, scope,
+                         accumulate_steps, return_numpy, unroll):
+        import jax
+
+        spans.phase("feed")
         if program is None:
             program = fw.default_main_program()
         feed = feed or {}
@@ -1003,6 +1224,7 @@ class Executor:
             accumulate_steps = int(feed_stack[feed_names[0]].shape[0])
         k = accumulate_steps
 
+        spans.phase("key")
         key = (
             "run_accumulated" + ("_unrolled" if unroll else ""),
             program.fingerprint(),
@@ -1019,9 +1241,11 @@ class Executor:
         )
         entry = self._cache.get(key)
         compiled_now = entry is None
-        mon, t0 = self._begin_monitored(_ACC_KEY_PARTS, key,
-                                        not compiled_now)
+        mon = spans.mon
+        if mon:
+            self._note_cache_lookup(_ACC_KEY_PARTS, key, not compiled_now)
         if entry is None:
+            spans.compiling()
             try:
                 entry = self._compile_accumulated(
                     program, feed_names, fetch_names, scope, k,
@@ -1033,17 +1257,20 @@ class Executor:
             self._cache[key] = entry
             self._commit_stamp(_ACC_KEY_PARTS, key)
 
+        spans.phase("gather")
         rw_vals = [scope.find_var(n) for n in entry.rw_state]
         ro_vals = [scope.find_var(n) for n in entry.ro_state]
         feed_vals = [feed_stack[n] for n in feed_names]
         seed = program.random_seed or 0
-        base_key = jax.random.fold_in(prng_key(seed), self._next_run_id())
+        base_key = jax.random.fold_in(prng_key(seed), spans.call)
+        spans.phase("dispatch")
         try:
             fetches, new_state, nan_flags = entry.fn(
                 feed_vals, rw_vals, ro_vals, base_key)
         except Exception:
             self._count_error(mon)
             raise
+        spans.phase("writeback")
         for n, v in zip(entry.state_writes, new_state):
             scope.set_var(n, v)
         if entry.nan_check_ops:
@@ -1058,11 +1285,9 @@ class Executor:
                 raise FloatingPointError(
                     "check_nan_inf: non-finite output from op(s):\n  "
                     + "\n  ".join(bad))
-        outs = self._finish_monitored("run_accumulated", mon, t0,
-                                      compiled_now, feed_vals, fetches,
-                                      return_numpy)
-        return self._publish_numerics(program, fetch_names, user_fetch_n,
-                                      outs)
+        return self._fetch(spans, program, fetch_names, user_fetch_n,
+                           compiled_now, feed_vals, fetches, return_numpy,
+                           steps=k)
 
     def _compile_accumulated(self, program, feed_names, fetch_names, scope,
                              k, unroll=False):
@@ -1409,51 +1634,28 @@ class Executor:
                 self._pending_stamps.discard(stamp)
                 self._compiled_stamps.add(stamp)
 
-    def _begin_monitored(self, part_names, key, hit: bool, note: bool = True):
-        """Telemetry prologue shared by run/run_steps/run_accumulated:
-        returns (enabled, t0).  Zero registry work when FLAGS.monitor is
-        off — the hot path pays one flag read.  note=False skips the
-        cache-lookup note (run() notes after its double-check resolves
-        the true hit/miss)."""
-        from ..monitor import enabled
+    def _fetch(self, spans, program, fetch_names, user_fetch_n,
+               compiled_now, feed_vals, fetches, return_numpy, steps=None):
+        """Epilogue shared by the three run modes: the `fetch` phase, and
+        what the call's record (`_CallSpans._record`, at exit) will say.
 
-        if not enabled():
-            return False, 0.0
-        import time as _time
-
-        if note:
-            self._note_cache_lookup(part_names, key, hit)
-        return True, _time.perf_counter()
-
-    def _finish_monitored(self, mode, mon, t0, compiled_now, feed_vals,
-                          fetches, return_numpy):
-        """Telemetry epilogue shared by the three run modes: convert the
-        fetches (the device sync) and record the call's metrics.
-
-        When monitoring, the np.asarray conversion is timed separately:
-        under jax's async dispatch the Python call returns as soon as the
+        Under jax's async dispatch the Python call returns as soon as the
         computation is ENQUEUED, and the first np.asarray blocks until
-        the device finishes — so the call decomposes into dispatch time
-        (trace/cache-hit bookkeeping + enqueue) and device-wait time (the
-        blocking fetch, which bounds actual device execution from above).
-        The split is the step-time attribution the cost model's launch
-        term is validated against."""
-        if not return_numpy:
-            outs = list(fetches)
-            if mon:
-                self._record_run_metrics(mode, t0, compiled_now, feed_vals,
-                                         None)
-            return outs
-        if not mon:
-            return [np.asarray(v) for v in fetches]
-        import time as _time
-
-        tc0 = _time.perf_counter()
-        outs = [np.asarray(v) for v in fetches]
-        device_wait_s = _time.perf_counter() - tc0
-        self._record_run_metrics(mode, t0, compiled_now, feed_vals, outs,
-                                 device_wait_s=device_wait_s)
-        return outs
+        the device finishes and the result has come back — so the call
+        decomposes into dispatch time (everything but `fetch`) and
+        device-wait time (`fetch`, which bounds device execution from
+        above): the pair the cost model's launch term is checked
+        against."""
+        spans.phase("fetch")
+        outs = ([np.asarray(v) for v in fetches] if return_numpy
+                else list(fetches))
+        user_outs = self._publish_numerics(program, fetch_names,
+                                           user_fetch_n, outs)
+        spans.phase(None)
+        if spans.on:
+            spans.finished(compiled_now, steps, return_numpy, feed_vals,
+                           outs if return_numpy else None)
+        return user_outs
 
     def _count_error(self, mon):
         """Failed compile/execution: count it so cache_miss vs compiles
@@ -1470,72 +1672,6 @@ class Executor:
                 "executor.error",
                 error=(f"{type(exc).__name__}: {str(exc)[:200]}"
                        if exc is not None else "unknown"))
-
-    def _record_run_metrics(self, mode, t0, compiled_now, feed_vals,
-                            np_outs, device_wait_s=None):
-        """Registry writes for one finished executor call: run wall-time
-        (and compile wall-time when this call traced+compiled — jax.jit
-        compiles lazily, so the miss call's duration IS the compile cost),
-        plus host->device feed bytes, device->host fetch bytes, and — when
-        _finish_monitored timed the fetch conversion — the dispatch-vs-
-        device-wait decomposition of the call."""
-        import time as _time
-
-        from .. import monitor, profiler
-        from ..monitor import flight as _flight
-
-        dt = _time.perf_counter() - t0
-        # span start bridged to the epoch clock the unified timeline and
-        # request traces ride (perf_counter + the import-time offset —
-        # `time.time() - dt` would drift off the other spans' stamps
-        # under NTP slew)
-        from ..monitor import tracing as _tracing
-
-        t0_epoch = _tracing.pc_to_epoch(t0)
-        monitor.counter(f"executor.{mode}.calls").inc()
-        if compiled_now:
-            # the miss call's wall time IS trace+compile(+first run);
-            # keep it OUT of run_seconds so run-latency percentiles are
-            # not dominated by seconds-scale compile outliers
-            monitor.counter("executor.compiles").inc()
-            monitor.histogram(
-                "executor.compile_seconds",
-                buckets=_COMPILE_BUCKETS).observe(dt)
-            profiler.add_event("executor.compile", dt)
-            _flight.record("executor.compile", mode=mode, t0=t0_epoch,
-                           dur=round(dt, 6))
-        else:
-            monitor.histogram("executor.run_seconds").observe(dt)
-            profiler.add_event(f"executor.{mode}", dt)
-            span_fields = {}
-            if device_wait_s is not None:
-                # dispatch = everything before the blocking fetch (Python
-                # bookkeeping + XLA enqueue); device_wait = the blocking
-                # np.asarray conversion.  Async dispatch means compute
-                # overlaps the dispatch window, so device_wait is a LOWER
-                # bound on device time and dispatch an upper bound on
-                # launch overhead — exactly the pair the cost model's
-                # launch term is checked against (tools/perf_report.py).
-                dispatch_s = max(dt - device_wait_s, 0.0)
-                monitor.histogram(
-                    "executor.dispatch_seconds").observe(dispatch_s)
-                monitor.histogram(
-                    "executor.device_wait_seconds").observe(device_wait_s)
-                span_fields = {"dispatch_s": round(dispatch_s, 6),
-                               "device_wait_s": round(device_wait_s, 6)}
-            _flight.record(f"executor.{mode}", t0=t0_epoch,
-                           dur=round(dt, 6), **span_fields)
-        fb = sum(int(getattr(v, "nbytes", 0) or 0) for v in feed_vals)
-        if fb:
-            monitor.counter("executor.feed_bytes").inc(fb)
-        if np_outs:
-            monitor.counter("executor.fetch_bytes").inc(
-                sum(int(getattr(o, "nbytes", 0) or 0) for o in np_outs))
-        # request-tracing hook: when a serving batcher armed this thread's
-        # executor context (monitor/tracing.py), the call's compile-vs-run
-        # wall time lands as a sub-span in every participating request
-        # trace; one thread-local read otherwise
-        _tracing.note_executor(mode, t0_epoch, dt, compiled_now)
 
     # -- internals -------------------------------------------------------
     def _maybe_verify(self, program, feed_names, fetch_names, scope):

@@ -855,6 +855,7 @@ def _flash_forward(q, k, v, bias, seed, scale, causal, block_q, block_k,
             kernel = kern
         out, lse = pl.pallas_call(
             kernel,
+            name="flash_bthd_fwd",
             grid=(b, tq // block_q),
             in_specs=in_specs,
             out_specs=[
@@ -894,6 +895,7 @@ def _flash_forward(q, k, v, bias, seed, scale, causal, block_q, block_k,
 
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_bhtd_fwd",
         grid=(bh, tq // block_q),
         in_specs=in_specs,
         out_specs=[
@@ -960,6 +962,7 @@ def _flash_backward(q, k, v, bias, seed, o, lse, g, scale, causal, block_q,
             dq_kernel = dq_kern
         dq = pl.pallas_call(
             dq_kernel,
+            name="flash_bthd_bwd_dq",
             grid=(b, tq // block_q),
             in_specs=in_specs,
             out_specs=q_spec,
@@ -993,6 +996,7 @@ def _flash_backward(q, k, v, bias, seed, o, lse, g, scale, causal, block_q,
             dkv_kernel = dkv_kern
         dk, dv = pl.pallas_call(
             dkv_kernel,
+            name="flash_bthd_bwd_dkv",
             grid=(b, tk // block_k),
             in_specs=in_specs,
             out_specs=[kblock_spec, kblock_spec],
@@ -1051,6 +1055,7 @@ def _flash_backward(q, k, v, bias, seed, o, lse, g, scale, causal, block_q,
 
     dq = pl.pallas_call(
         dq_kernel,
+        name="flash_bhtd_bwd_dq",
         grid=(bh, tq // block_q),
         in_specs=in_specs,
         out_specs=q_spec,
@@ -1088,6 +1093,7 @@ def _flash_backward(q, k, v, bias, seed, o, lse, g, scale, causal, block_q,
 
     dk, dv = pl.pallas_call(
         dkv_kernel,
+        name="flash_bhtd_bwd_dkv",
         grid=(bh, tk // block_k),
         in_specs=in_specs,
         out_specs=[kblock_spec, kblock_spec],
@@ -1800,6 +1806,7 @@ def _qkv_forward(x, w3, wo, bias, seed, scale, causal, n_head, d_head,
 
     y, ctx, lse = pl.pallas_call(
         kernel,
+        name="fused_qkv_fwd",
         grid=(b, t // block_q),
         in_specs=in_specs,
         out_specs=[
@@ -1865,6 +1872,7 @@ def _qkv_backward(x, w3, wo, bias, seed, ctx, lse, g, scale, causal,
         dq_kernel = dq_kern
     dx_q, dwq, dwo = pl.pallas_call(
         dq_kernel,
+        name="fused_qkv_bwd_dx_q",
         grid=(b, t // block_q),
         in_specs=in_specs,
         out_specs=[
@@ -1908,6 +1916,7 @@ def _qkv_backward(x, w3, wo, bias, seed, ctx, lse, g, scale, causal,
         dkv_kernel = dkv_kern
     dx_kv, dwk, dwv = pl.pallas_call(
         dkv_kernel,
+        name="fused_qkv_bwd_dx_kv",
         grid=(b, t // block_k),
         in_specs=in_specs,
         out_specs=[

@@ -171,6 +171,7 @@ def _channel_stats_impl(y, c, plan):
     grid = (plan.ncols // plan.block_c, plan.rows // plan.block_r)
     stats = pl.pallas_call(
         _channel_stats_kernel,
+        name="conv_bn_channel_stats_fwd",
         grid=grid,
         in_specs=[pl.BlockSpec((plan.block_r, plan.block_c),
                                lambda ni, mi: (mi, ni))],
@@ -292,6 +293,7 @@ def _dot_col_stats_impl(x2, w2, interpret):
     grid = (oc // block_n, m // block_m)  # m fastest: stats accumulate
     y, stats = pl.pallas_call(
         _dot_stats_kernel,
+        name="conv_bn_dot_stats_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, k), lambda ni, mi: (mi, 0)),
@@ -505,6 +507,7 @@ def _ssa_fwd_impl(x, wv, bv, residual, relu, c, plan):
     out = pl.pallas_call(
         functools.partial(_ssa_fwd_kernel, relu=relu,
                           has_res=residual is not None),
+        name="conv_bn_scale_shift_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=spec,
@@ -555,6 +558,7 @@ def _ssa_bwd_impl(g, out, x, wv, residual_dtype, relu, c, plan):
     grid = (plan.ncols // plan.block_c, plan.rows // plan.block_r)
     res = pl.pallas_call(
         functools.partial(_ssa_bwd_kernel, relu=relu, has_res=has_res),
+        name="conv_bn_scale_shift_bwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,

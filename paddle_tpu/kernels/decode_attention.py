@@ -165,6 +165,7 @@ def _decode_call(q, k, v, prefetch, n_blocks, q_map, kv_map, block_t,
     return pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, block_t=block_t,
                           n_prefetch=len(prefetch)),
+        name="flash_decode_fwd",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, dh), q.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -515,6 +515,7 @@ def fused_decode_step(x, wqkv, wout, ln1_scale, ln1_bias, wcq, wcout,
     cache_k_idx = 4 + 1 + len(weights)
     out, cache_k, cache_v = pl.pallas_call(
         kernel,
+        name="decode_step_fwd",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, 1, d_model), x.dtype),
@@ -532,6 +533,7 @@ def fused_decode_step(x, wqkv, wout, ln1_scale, ln1_bias, wcq, wcout,
         ffn_kernel = functools.partial(_ffn_kernel, eps=eps)
         out = pl.pallas_call(
             ffn_kernel,
+            name="decode_step_ffn_fwd",
             out_shape=jax.ShapeDtypeStruct((b, 1, d_model), x.dtype),
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=_VMEM_LIMIT),
@@ -887,6 +889,7 @@ def fused_decode_step_paged(x, wqkv, wout, ln1_scale, ln1_bias, wcq,
     cache_k_idx = 6 + 1 + len(weights)
     out, cache_k, cache_v = pl.pallas_call(
         kernel,
+        name="decode_step_paged_fwd",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, 1, d_model), x.dtype),
@@ -905,6 +908,7 @@ def fused_decode_step_paged(x, wqkv, wout, ln1_scale, ln1_bias, wcq,
         ffn_kernel = functools.partial(_ffn_kernel, eps=eps)
         out = pl.pallas_call(
             ffn_kernel,
+            name="decode_step_paged_ffn_fwd",
             out_shape=jax.ShapeDtypeStruct((b, 1, d_model), x.dtype),
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=_VMEM_LIMIT),

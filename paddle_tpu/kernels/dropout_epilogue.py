@@ -131,6 +131,7 @@ def _pallas_fwd(x2, r2, seed, rate, inv_keep, block_r, ncols, interpret,
                              block_r=block_r, ncols=ncols, hw_prng=hw_prng)
     return pl.pallas_call(
         kern,
+        name="dropout_add_fwd",
         grid=(rows // block_r,),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), spec, spec],
         out_specs=spec,
@@ -151,6 +152,7 @@ def _pallas_bwd(g2, seed, rate, inv_keep, block_r, ncols, interpret,
                              block_r=block_r, ncols=ncols, hw_prng=hw_prng)
     return pl.pallas_call(
         kern,
+        name="dropout_add_bwd",
         grid=(rows // block_r,),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), spec],
         out_specs=spec,

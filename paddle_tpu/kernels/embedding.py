@@ -235,6 +235,7 @@ def multi_table_gather(tables, ids, *, block_rows=None, interpret=None):
     )
     return pl.pallas_call(
         kernel,
+        name="embedding_gather_fwd",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_n, b, d), tables[0].dtype),
         interpret=resolve(interpret)[1],
@@ -349,6 +350,7 @@ def _apply_pallas(tables_by_kind, uids, rows, scalars, compute,
     )
     outs = pl.pallas_call(
         kernel,
+        name="embedding_scatter_bwd",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((v, d), t.dtype)
                    for t in flat_tables],

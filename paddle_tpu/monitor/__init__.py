@@ -52,11 +52,12 @@ from .registry import (  # noqa: F401
     histogram,
     default_registry,
     enabled,
+    spans_on,
 )
 from .registry import SloTracker  # noqa: F401
 from .step import StepMonitor  # noqa: F401
 from . import flight  # noqa: F401
-from .flight import FlightRecorder  # noqa: F401
+from .flight import FlightRecorder, compile_phases  # noqa: F401
 from .watchdog import Watchdog, WatchdogError  # noqa: F401
 from . import serve  # noqa: F401
 from . import numerics  # noqa: F401

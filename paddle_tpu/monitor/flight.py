@@ -27,7 +27,9 @@ threads and opens no files until install()/dump().
 
 The module also owns the executed-op set for the op-contract gate
 (FLAGS.record_lowered_ops): trace-time recording of every op type the
-executor lowers, exposed via lowered_op_types().
+executor lowers, exposed via lowered_op_types(); and the process-wide
+totals of jax's compile phases inside Executor calls (compile_phases()),
+which are kept whether or not FLAGS.monitor is set.
 """
 
 from __future__ import annotations
@@ -376,3 +378,95 @@ def lowered_op_types() -> frozenset:
 def reset_lowered_ops() -> None:
     with _lowered_lock:
         _lowered_ops.clear()
+
+
+# ---------------------------------------------------------------------------
+# Compile phases (jax.monitoring events inside Executor calls)
+# ---------------------------------------------------------------------------
+
+# jax's own event -> the name it is totalled under.  The backend event
+# wraps jax's persistent-cache lookup, so `backend_s` CONTAINS
+# `cache_load_s` on a cache hit; the two are not to be added.
+_COMPILE_DURATIONS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load_s",
+}
+_COMPILE_COUNTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
+_compile_totals: Dict[str, float] = dict.fromkeys(
+    list(_COMPILE_DURATIONS.values()) + list(_COMPILE_COUNTS.values()), 0)
+_compile_lock = threading.Lock()
+_compile_listening = False
+# per thread: how deep inside Executor calls it is, and for each duration
+# the (end, seconds) of the events already counted in the outermost call
+_in_executor = threading.local()
+
+
+def _on_compile_duration(event: str, duration_secs: float, **_kw) -> None:
+    name = _COMPILE_DURATIONS.get(event)
+    if name is None or not getattr(_in_executor, "depth", 0):
+        return
+    # a jit traced while another is being traced reports first and lies
+    # inside the outer one's duration: take it back out when the outer
+    # one arrives, so that nested traces are counted once
+    now = time.monotonic()
+    counted = _in_executor.counted.setdefault(name, [])
+    nested = 0.0
+    while counted and counted[-1][0] >= now - duration_secs:
+        nested += counted.pop()[1]
+    counted.append((now, duration_secs))
+    with _compile_lock:
+        _compile_totals[name] += duration_secs - nested
+
+
+def _on_compile_event(event: str, **_kw) -> None:
+    name = _COMPILE_COUNTS.get(event)
+    if name is not None and getattr(_in_executor, "depth", 0):
+        with _compile_lock:
+            _compile_totals[name] += 1
+
+
+def listen_for_compile_phases() -> None:
+    """Register the two jax.monitoring listeners, once per process (the
+    first Executor does).  They run once per compilation, never per
+    step, and count whether or not FLAGS.monitor is set: set-up happens
+    before any profiler session exists."""
+    global _compile_listening
+    with _compile_lock:
+        if _compile_listening:
+            return
+        _compile_listening = True
+    import jax.monitoring
+
+    jax.monitoring.register_event_duration_secs_listener(
+        _on_compile_duration)
+    jax.monitoring.register_event_listener(_on_compile_event)
+
+
+@_contextlib.contextmanager
+def executor_call():
+    """Marks this thread as inside an Executor call, so that only the
+    program's own compilations are totalled: not a benchmark's jits nor
+    a reference that runs in the same process."""
+    depth = getattr(_in_executor, "depth", 0)
+    if not depth:
+        _in_executor.counted = {}
+    _in_executor.depth = depth + 1
+    try:
+        yield
+    finally:
+        _in_executor.depth = depth
+
+
+def compile_phases() -> Dict[str, float]:
+    """Process-wide totals of jax's compile phases inside Executor calls:
+    seconds `trace_s` (jaxpr trace: the op loop runs here), `lower_s`
+    (jaxpr -> MLIR), `backend_s` (XLA compile, or the persistent cache's
+    load where it hit: `cache_load_s` is that part), and the counts
+    `cache_hits` / `cache_misses` of the persistent cache."""
+    with _compile_lock:
+        return dict(_compile_totals)

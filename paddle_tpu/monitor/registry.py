@@ -29,6 +29,21 @@ def enabled() -> bool:
     return FLAGS.monitor
 
 
+def spans_on() -> bool:
+    """Whether the executor should stamp its host phases into the flight
+    ring: FLAGS.monitor, or ANY active jax.profiler session (the
+    benchmark's, `profiler.start_profiler(trace_dir=...)`, an operator's
+    xprof capture).  Narrower than `enabled()` on purpose: a profiler
+    session switches the spans on, not every histogram."""
+    from ..flags import FLAGS
+
+    if FLAGS.monitor:
+        return True
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation.is_enabled()
+
+
 # latency-flavored default buckets (seconds): 100us .. 30s, bounded
 DEFAULT_BUCKETS = (
     0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05,

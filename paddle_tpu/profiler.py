@@ -191,17 +191,88 @@ def cost_analysis(program, feed, fetch_list=None, scope=None):
     return cost
 
 
-def xplane_op_table(trace_dir: str, top_k: int = 30):
+def _leaf_events(events):
+    """The events of one line that hold no other event of it: a `while`
+    (the scan of a run_steps call) holds its body's operations, and
+    counting both would count the body twice."""
+    evs = sorted(events, key=lambda e: (e.offset_ps, -e.duration_ps))
+    keep, stack = [], []
+    for ev in evs:
+        while stack and stack[-1][0] <= ev.offset_ps:
+            stack.pop()
+        if ev.duration_ps > 0:
+            for open_ev in stack:
+                open_ev[1] = True
+        item = [ev.offset_ps + ev.duration_ps, False, ev]
+        if ev.duration_ps > 0:
+            stack.append(item)
+        keep.append(item)
+    return [ev for _, holds, ev in keep if not holds]
+
+
+NO_SCOPE = "(no scope)"
+
+
+def op_scope(tf_op: str) -> str:
+    """The op-type scope of a device event, from its `tf_op` stat (the
+    HLO op_name: `jit(scan_fn)/while/body/closed_call/layer_norm_grad/
+    transpose(jvp())/mul:`).  trace_block lowers each op under
+    `jax.named_scope(op.type)`, so the scope is the first component
+    under jax's own (`jit(..)`, `while/body`, `closed_call`), if that is
+    an op type and not the path's leaf (the leaf is a jax primitive,
+    which may share a name with an op: `mul`).  NO_SCOPE where there is
+    none: parameters, the scan's own plumbing, fusions XLA made up."""
+    from .core import registry
+
+    parts = str(tf_op).rsplit(":", 1)[0].split("/")
+    i = 0
+    while i < len(parts) - 1:
+        c, nxt = parts[i], parts[i + 1]
+        if c.startswith(("jit(", "pjit(")) or c in ("closed_call",
+                                                    "checkpoint"):
+            i += 1
+        elif (c == "while" and nxt in ("body", "cond")) or (
+                c == "cond" and nxt.startswith("branch")):
+            i += 2
+        else:
+            base = c[:-5] if c.endswith("_grad") else c
+            return c if registry.lookup(base) is not None else NO_SCOPE
+    return NO_SCOPE
+
+
+def kernel_name(hlo_text: str):
+    """The Pallas kernel's name if the event is one (`pallas_call(name=)`
+    as the trace prints it: `%jvp_fused_qkv_fwd_.95 = .. custom-call(..),
+    custom_call_target="tpu_custom_call"` -> `jvp_fused_qkv_fwd_`), else
+    None."""
+    if 'custom_call_target="tpu_custom_call"' not in hlo_text:
+        return None
+    head = hlo_text.split(" = ", 1)[0].strip().lstrip("%")
+    stem, _, num = head.rpartition(".")
+    return stem if stem and num.isdigit() else head
+
+
+def xplane_op_table(trace_dir: str, top_k: Optional[int] = 30,
+                    by: str = "group"):
     """Aggregate per-op device time from a jax.profiler trace directory
     (the reference's profiler table role, device-side).  Returns rows of
-    (op_group, total_seconds) sorted descending; op names collapse to
-    their fusion-group prefix.  Requires a trace captured with
-    start_profiler(trace_dir=...) around device work.  Decodes xplane.pb
-    natively (paddle_tpu.xplane) — no TensorFlow proto dependency."""
+    (name, total_seconds) sorted descending.  `by` chooses the grouping:
+
+      "group"   op names collapsed to their fusion-group prefix (every
+                event of the `XLA Ops` lines, containers included)
+      "scope"   the op TYPE each leaf operation was lowered from
+                (`op_scope`); the NO_SCOPE row is the share no op owns
+      "kernel"  Pallas kernels by `pallas_call(name=)` (leaf operations)
+
+    Requires a trace captured with start_profiler(trace_dir=...) around
+    device work.  Decodes xplane.pb natively (paddle_tpu.xplane) — no
+    TensorFlow proto dependency."""
     from collections import defaultdict
 
     from . import xplane as _xp
 
+    if by not in ("group", "scope", "kernel"):
+        raise ValueError(f"xplane_op_table: unknown grouping {by!r}")
     files = _xp.find_xplane_files(trace_dir)
     if not files:
         raise FileNotFoundError(f"no xplane.pb under {trace_dir}")
@@ -214,17 +285,34 @@ def xplane_op_table(trace_dir: str, top_k: int = 30):
             for line in plane.lines:
                 if "Ops" not in line.name or "Async" in line.name:
                     continue
-                for ev in line.events:
-                    agg[ev.name.split(".")[0]] += ev.duration_ps / 1e12
+                if by == "group":
+                    for ev in line.events:
+                        agg[ev.name.split(".")[0]] += ev.duration_ps / 1e12
+                    continue
+                for ev in _leaf_events(line.events):
+                    if by == "scope":
+                        key = op_scope(ev.meta_stats.get("tf_op", ""))
+                    else:
+                        key = kernel_name(ev.name)
+                        if key is None:
+                            continue
+                    agg[key] += ev.duration_ps / 1e12
     rows = sorted(agg.items(), key=lambda kv: -kv[1])[:top_k]
     return rows
 
 
-def print_op_table(trace_dir: str, top_k: int = 30):
-    rows = xplane_op_table(trace_dir, top_k)
-    lines = ["Device op group                          Total(s)"]
+def print_op_table(trace_dir: str, top_k: int = 30, by: str = "group"):
+    """Print the table with each row's share of the grouping's total;
+    returns the `top_k` rows."""
+    every = xplane_op_table(trace_dir, None, by)
+    total = sum(t for _, t in every)
+    rows = every[:top_k]
+    head = {"group": "Device op group", "scope": "Op type (scope)",
+            "kernel": "Pallas kernel"}[by]
+    lines = [f"{head:<40} {'Total(s)':>10} {'Share':>7}"]
     for name, t in rows:
-        lines.append(f"{name:<40} {t:>10.6f}")
+        lines.append(f"{name:<40} {t:>10.6f} "
+                     f"{100 * t / total if total else 0:>6.1f}%")
     report = "\n".join(lines)
     print(report)
     return rows

@@ -88,7 +88,7 @@ def _fields(buf: bytes):
 
 class XEvent:
     __slots__ = ("name", "metadata_id", "offset_ps", "duration_ps",
-                 "raw_stats", "stats")
+                 "raw_stats", "stats", "meta_stats")
 
     def __init__(self):
         self.name = ""
@@ -99,6 +99,11 @@ class XEvent:
         # `stats` once the owning plane's stat-metadata map is known
         self.raw_stats: List[tuple] = []
         self.stats: Dict[str, object] = {}
+        # the stats of the event's METADATA, shared by every event of
+        # that metadata (read, never written): a device op's `tf_op`
+        # (the HLO op_name: jit(..)/while/body/<op type>/..), `source`,
+        # `hlo_category`, `flops`, `bytes_accessed` live here
+        self.meta_stats: Dict[str, object] = {}
 
 
 class XLine:
@@ -183,8 +188,8 @@ def _parse_line(buf: bytes) -> XLine:
 
 
 def _parse_event_metadata(buf: bytes):
-    """XEventMetadata: returns (id, name)."""
-    mid, name, display = 0, "", ""
+    """XEventMetadata: returns (id, name, raw stats)."""
+    mid, name, display, raw = 0, "", "", []
     for f, wt, v in _fields(buf):
         if f == 1 and wt == 0:
             mid = _signed(v)
@@ -192,7 +197,9 @@ def _parse_event_metadata(buf: bytes):
             name = v.decode("utf-8", "replace")
         elif f == 3 and wt == 2:
             display = v.decode("utf-8", "replace")
-    return mid, (display or name)
+        elif f == 5 and wt == 2:  # stats
+            raw.append(_parse_stat(v))
+    return mid, (display or name), raw
 
 
 def _parse_stat_metadata(buf: bytes):
@@ -220,6 +227,7 @@ def _map_entry(buf: bytes):
 def _parse_plane(buf: bytes) -> XPlane:
     plane = XPlane()
     meta: Dict[int, str] = {}
+    meta_raw: Dict[int, list] = {}
     stat_meta: Dict[int, str] = {}
     for f, wt, v in _fields(buf):
         if f == 2 and wt == 2:
@@ -238,8 +246,10 @@ def _parse_plane(buf: bytes) -> XPlane:
             # map<int64, XEventMetadata>: entries are {1: key, 2: value}
             key, val = _map_entry(v)
             if val is not None:
-                mid, name = _parse_event_metadata(val)
+                mid, name, raw = _parse_event_metadata(val)
                 meta[key or mid] = name
+                if raw:
+                    meta_raw[key or mid] = raw
         elif f == 5 and wt == 2:
             # map<int64, XStatMetadata> — stat name table
             key, val = _map_entry(v)
@@ -247,23 +257,31 @@ def _parse_plane(buf: bytes) -> XPlane:
                 mid, name = _parse_stat_metadata(val)
                 stat_meta[key or mid] = name
     missing_stats = set()
+
+    def resolve(raw_stats, into):
+        for mid, val, is_ref in raw_stats:
+            # a stat (or ref target) whose metadata entry is absent
+            # from this dump is SKIPPED by name, never a KeyError —
+            # newer libtpu versions add stat types freely
+            sname = stat_meta.get(mid)
+            if sname is None:
+                missing_stats.add(mid)
+                continue
+            if is_ref:
+                if val not in stat_meta:
+                    missing_stats.add(val)
+                    continue
+                val = stat_meta[val]
+            into[sname] = val
+        return into
+
+    meta_stats = {mid: resolve(raw, {}) for mid, raw in meta_raw.items()}
+    no_stats: Dict[str, object] = {}
     for line in plane.lines:
         for ev in line.events:
             ev.name = meta.get(ev.metadata_id, f"op#{ev.metadata_id}")
-            for mid, val, is_ref in ev.raw_stats:
-                # a stat (or ref target) whose metadata entry is absent
-                # from this dump is SKIPPED by name, never a KeyError —
-                # newer libtpu versions add stat types freely
-                sname = stat_meta.get(mid)
-                if sname is None:
-                    missing_stats.add(mid)
-                    continue
-                if is_ref:
-                    if val not in stat_meta:
-                        missing_stats.add(val)
-                        continue
-                    val = stat_meta[val]
-                ev.stats[sname] = val
+            ev.meta_stats = meta_stats.get(ev.metadata_id, no_stats)
+            resolve(ev.raw_stats, ev.stats)
     for mid in sorted(missing_stats):
         plane.warnings.append(
             f"plane {plane.name or '?'}: skipping stat(s) with missing "

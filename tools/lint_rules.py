@@ -13,6 +13,11 @@ Rules:
                    TRACE time, so a host clock read there bakes a
                    constant into the compiled kernel (host-side timing
                    belongs in bench.py / monitor)
+  kernel-named     every `pl.pallas_call(` inside paddle_tpu/kernels/
+                   passes a literal-prefixed `name=` that holds exactly
+                   one of `_fwd` / `_bwd` and is no other site's: the
+                   name is what a device trace calls the kernel, and
+                   what the benchmark's kernel metrics match on
 
 Usage: python tools/lint_rules.py [paths...]
        (default: paddle_tpu tools tests bench.py __graft_entry__.py)
@@ -56,6 +61,31 @@ def declared_flags() -> set:
     return names
 
 
+def pallas_call_names(tree) -> list:
+    """[(lineno, name)] of every `pl.pallas_call(` in a module: the text
+    of a literal `name=`, the literal parts of an f-string that starts
+    with one, None where there is no such name."""
+    out = []
+    for node in ast.walk(tree):
+        if not (isinstance(node, ast.Call)
+                and isinstance(node.func, ast.Attribute)
+                and node.func.attr == "pallas_call"):
+            continue
+        name = None
+        for kw in node.keywords:
+            if kw.arg != "name":
+                continue
+            v = kw.value
+            if isinstance(v, ast.Constant) and isinstance(v.value, str):
+                name = v.value
+            elif (isinstance(v, ast.JoinedStr) and v.values
+                  and isinstance(v.values[0], ast.Constant)):
+                name = "".join(p.value for p in v.values
+                               if isinstance(p, ast.Constant))
+        out.append((node.lineno, name))
+    return sorted(out)
+
+
 def check_file(path: str, flags: set) -> list:
     """[(path, lineno, message)] violations for one file."""
     try:
@@ -67,6 +97,20 @@ def check_file(path: str, flags: set) -> list:
     parts = os.path.normpath(path).split(os.sep)
     in_kernels = "kernels" in parts and "paddle_tpu" in parts
     is_flags_py = rel == os.path.join("paddle_tpu", "flags.py")
+    if in_kernels:
+        seen = set()
+        for lineno, name in pallas_call_names(tree):
+            if name is None:
+                why = "has no literal-prefixed name="
+            elif ("_fwd" in name) == ("_bwd" in name):
+                why = f"name {name!r} must hold exactly one of _fwd / _bwd"
+            elif name in seen:
+                why = f"name {name!r} is another site's"
+            else:
+                seen.add(name)
+                continue
+            out.append((path, lineno,
+                        f"pl.pallas_call {why} (kernel-named)"))
     for node in ast.walk(tree):
         # FLAGS.<name> attribute reads
         if (isinstance(node, ast.Attribute)

@@ -185,18 +185,22 @@ def cost_attribution(doc: dict):
     return costs
 
 
-def dispatch_split(doc: dict):
-    """(dispatch_s, device_wait_s, n) summed over executor run spans that
-    carry the enqueue-vs-transfer decomposition (core/executor.py)."""
-    dispatch = wait = 0.0
+def phase_split(doc: dict):
+    """({phase: seconds}, n) summed over the executor run spans that carry
+    their host `phases` ([name, start offset, seconds] in call order:
+    core/executor.py _CallSpans).  `fetch` is the blocking device->host
+    conversion (the device wait); everything before it is dispatch."""
+    totals = {}
     n = 0
     for ev in doc.get("flight", {}).get("events", []):
-        if str(ev.get("kind", "")).startswith("executor.") \
-                and "dispatch_s" in ev:
-            dispatch += float(ev["dispatch_s"])
-            wait += float(ev.get("device_wait_s", 0.0))
-            n += 1
-    return dispatch, wait, n
+        kind = str(ev.get("kind", ""))
+        if not kind.startswith("executor.") or kind == "executor.compile" \
+                or not ev.get("phases"):
+            continue
+        for name, _, dur in ev["phases"]:
+            totals[name] = totals.get(name, 0.0) + float(dur)
+        n += 1
+    return totals, n
 
 
 def embedding_census(doc: dict):
@@ -279,7 +283,7 @@ def report(doc: dict, k: int = 20) -> str:
         lines.append("Recompiles: none recorded")
 
     costs = cost_attribution(doc)
-    disp, wait, nrun = dispatch_split(doc)
+    phases, nrun = phase_split(doc)
     if costs or nrun:
         lines.append("")
         lines.append("Attribution (static cost model + dispatch split)")
@@ -298,11 +302,15 @@ def report(doc: dict, k: int = 20) -> str:
                 f"/{bc.get('launch', 0):<5} "
                 f"{ev.get('device', '?')} ({ev.get('device_source', '?')})")
     if nrun:
-        tot = disp + wait
+        wait = phases.get("fetch", 0.0)
+        tot = sum(phases.values())
+        disp = tot - wait
         frac = disp / tot if tot > 0 else 0.0
         lines.append(
             f"  executor split over {nrun} runs: dispatch {disp:.4f}s vs "
             f"device-wait {wait:.4f}s ({frac:.1%} host-side dispatch)")
+        lines.append("  host phases: " + "  ".join(
+            f"{name} {sec:.4f}s" for name, sec in phases.items()))
 
     census = embedding_census(doc)
     if census:
