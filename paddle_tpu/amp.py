@@ -13,8 +13,11 @@ compute in), redesigned TPU-first:
     each weight happens inside the compiled step and XLA fuses it into the
     convolution/matmul (one extra HBM read of the fp32 weight, no extra
     round-trip).
-  * Gradients: grad ops re-trace the forward lowering under jax.vjp, so a
-    white-listed op's backward also computes in bf16.  Optimizer ops are
+  * Gradients: a grad op's inputs are cast by its forward's policy, so a
+    white-listed op's backward also computes in bf16, whether it re-traces
+    the forward lowering under jax.vjp (the generic grad) or reads the
+    forward's residuals (the kernel attention ops: their float32 Lse is
+    the one slot left as it is, SLOT_WHITE_OPS).  Optimizer ops are
     black-listed, so gradients are cast back to fp32 before moment/param
     updates — fp32 accumulation, the standard mixed-precision recipe.
   * bf16 keeps fp32's exponent range, so no loss scaling is required
@@ -39,8 +42,6 @@ WHITE_OPS = frozenset({
     "conv3d",
     "mul",
     "matmul",
-    "fused_attention",
-    "fused_qkv_attention",
     "ring_attention",
 })
 
@@ -147,8 +148,17 @@ def _cast_value(v, dtype):
 # would downcast the stateful MeanOut/VarianceOut writebacks, BLACK would
 # forfeit the MXU (the in-op statistics already accumulate in fp32, same
 # as the batch_norm lowering).
+#
+# The two kernel attention ops are WHITE in every slot but one: Lse, the
+# float32 logsumexp the forward kernel writes and the registered grad op
+# reads back (ops/fused_ops.py), has to reach the backward kernels as the
+# very array the forward wrote.
 SLOT_WHITE_OPS = {
     "conv2d_bn": frozenset({"Input", "Filter", "Residual"}),
+    "fused_attention": frozenset(
+        {"Q", "K", "V", "Bias", "Out", "Out@GRAD"}),
+    "fused_qkv_attention": frozenset(
+        {"X", "WQkv", "WOut", "Bias", "Ctx", "Out@GRAD"}),
 }
 
 # Multi-input elementwise ops follow their activations: if any float input is

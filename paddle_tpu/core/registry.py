@@ -13,12 +13,17 @@ grad_op_desc_maker.h:34-159), redesigned TPU-first:
     a `<type>_grad` op whose lowering calls `jax.vjp` of the forward lowering.
     Hand-written grad makers remain possible for ops with structured sparse
     gradients (e.g. lookup_table -> SelectedRows-style row updates).
+  * An op whose kernel writes what its backward needs (a logsumexp, a
+    context) names those output slots as `residuals`; the default grad
+    maker hands them to the grad op, registered with `residual_grad`, which
+    then calls the backward kernels directly instead of re-running the
+    forward under `jax.vjp` for them.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Sequence
 
 from . import framework as fw
 
@@ -60,6 +65,7 @@ class OpDef:
         no_grad: bool = False,
         inplace_outputs: Optional[Dict[str, str]] = None,
         derives_rng=False,
+        residuals: Sequence[str] = (),
         doc: str = "",
     ):
         self.type = type
@@ -78,6 +84,12 @@ class OpDef:
         # that, turning the PR-4 "random op missing from _RANDOM_OPS" bug
         # class into a pre-compile error.
         self.derives_rng = derives_rng
+        # output slots the grad op reads back as INPUTS: what the forward
+        # lowering computed anyway and the backward needs (a kernel's
+        # logsumexp, its context).  default_grad_maker wires them through
+        # when the op instance declares them all; the grad op itself is
+        # registered with residual_grad below.
+        self.residuals = tuple(residuals)
         self.doc = doc
 
     def op_derives_rng(self, op) -> bool:
@@ -97,6 +109,7 @@ def register(
     no_grad=False,
     inplace_outputs=None,
     derives_rng=False,
+    residuals=(),
     doc="",
 ):
     """Decorator registering `fn` as the lowering for op `type`.
@@ -119,6 +132,7 @@ def register(
             no_grad=no_grad,
             inplace_outputs=inplace_outputs,
             derives_rng=derives_rng,
+            residuals=residuals,
             doc=doc or (fn.__doc__ or ""),
         )
         return fn
@@ -170,9 +184,20 @@ def default_grad_maker(op, no_grad_set, grad_sub_block_map=None):
 
     Inputs: all forward input slots (same names) + grad slots for each forward
     output.  Outputs: grad slots for each forward input not in no_grad_set.
+    An op that declares every slot of its type's `residuals` hands those
+    outputs on as inputs too, under their own slot names (a residual that
+    carries no gradient gets no grad slot).
     """
     inputs = {slot: list(names) for slot, names in op.inputs.items()}
+    opdef = lookup(op.type)
+    residuals = opdef.residuals if opdef is not None else ()
+    if not all(op.outputs.get(slot) for slot in residuals):
+        residuals = ()
     for slot, names in op.outputs.items():
+        if slot in residuals:
+            inputs[slot] = list(names)
+            if all(n in no_grad_set for n in names):
+                continue
         # forward outputs may be needed for the vjp of stateful ops; pass grads
         inputs[_grad_slot(slot)] = [fw.grad_var_name(n) for n in names]
     outputs = {}
@@ -200,6 +225,7 @@ def lower_generic_grad(fwd_type: str, ctx: LowerContext, ins):
     """Lowering for `<fwd_type>_grad` ops emitted by default_grad_maker."""
     import jax
 
+    _note_grad_route("grad_generic")
     opdef = get(fwd_type)
     fwd_slots = [s for s in ins if not s.endswith(GRAD_SUFFIX)]
     grad_slots = [s for s in ins if s.endswith(GRAD_SUFFIX)]
@@ -228,6 +254,8 @@ def lower_generic_grad(fwd_type: str, ctx: LowerContext, ins):
         out_index = []
         for slot in sorted(outs):
             for j, ov in enumerate(outs[slot]):
+                if ov is None:  # an optional output this route leaves unwritten
+                    continue
                 flat_outs.append(ov)
                 out_index.append((slot, j))
         return tuple(flat_outs), out_index
@@ -268,6 +296,52 @@ def lower_generic_grad(fwd_type: str, ctx: LowerContext, ins):
             gs.append(grads_by_name.get((s, i)))
         out[_grad_slot(s)] = gs
     return out
+
+
+def _note_grad_route(route: str) -> None:
+    """Count one grad op lowered by `route` (`grad_direct`: a registered
+    grad op fed from its forward's residuals; `grad_generic`:
+    lower_generic_grad) among the compile totals, so that a miss call's
+    `executor.compile` flight event says which way its grad ops went."""
+    from ..monitor import flight
+
+    flight.note_compile_count(route)
+
+
+def residual_grad(fwd_type: str):
+    """Decorator registering `<fwd_type>_grad` for a forward op that names
+    `residuals`: `direct(ctx, ins) -> {slot@GRAD: [values]}` computes the
+    gradients from the forward's inputs, its residual outputs and the
+    output cotangents, or returns None where it cannot (a shape its
+    kernel plan rejects).  The route follows what the lowering observes:
+    direct when every residual and cotangent is bound, else the generic
+    vjp of the forward lowering, as for a program whose op never declared
+    the slots (built before the hand-off, or by a graph pass) or whose
+    forward took a route that writes no residual."""
+
+    def deco(direct):
+        fwd = get(fwd_type)
+
+        def lower(ctx, ins):
+            # a cotangent that never arrived is the generic route's to
+            # fill with zeros
+            needs = fwd.residuals + tuple(
+                s for s in ins if s.endswith(GRAD_SUFFIX))
+            if all((ins.get(slot) or [None])[0] is not None
+                   for slot in needs):
+                out = direct(ctx, ins)
+                if out is not None:
+                    _note_grad_route("grad_direct")
+                    return out
+            return lower_generic_grad(
+                fwd_type, ctx,
+                {s: v for s, v in ins.items() if s not in fwd.residuals})
+
+        register(fwd_type + "_grad", no_grad=True,
+                 derives_rng=fwd.derives_rng, doc=direct.__doc__)(lower)
+        return direct
+
+    return deco
 
 
 def get_grad_lowering(grad_type: str) -> Optional[Callable]:

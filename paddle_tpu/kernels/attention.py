@@ -1184,123 +1184,172 @@ def flash_attention(q, k, v, bias=None, scale=1.0, causal=False,
     expression and its mask mismatch is unobservable); the
     fused_attention op lowering derives this automatically from the
     bias var's stop_gradient flag."""
+    return flash_attention_fwd(
+        q, k, v, bias, scale=scale, causal=causal, block_q=block_q,
+        block_k=block_k, interpret=interpret, fmt=fmt,
+        dropout_rate=dropout_rate, dropout_seed=dropout_seed,
+        trainable_bias=trainable_bias)[0]
+
+
+def flash_attention_fwd(q, k, v, bias=None, **options):
+    """flash_attention (and its options) with the residual its kernel
+    writes anyway: (out, lse), lse [b, h, tq] f32 — None where the plan or
+    the bias's shape sent the call to the XLA reference.  Differentiable
+    in `out` (the custom VJP of the kernel pair); a caller that keeps
+    (out, lse) hands them to flash_attention_bwd instead, and the forward
+    kernel runs once (ops/fused_ops.py)."""
+    seed, fwd, bwd, norm = _flash_kernels(q, k, v, bias, **options)
+    if bwd is None:
+        return fwd(q, k, v, bias, seed), None
+    return _vjp_of_pair(fwd, bwd, 0)(q, k, v, norm(bias), seed)
+
+
+def flash_attention_bwd(q, k, v, bias, out, lse, g, **options):
+    """(dq, dk, dv, dbias) from flash_attention_fwd's (out, lse) and the
+    cotangent g of out, under the same options: the backward kernels
+    alone, the body of flash_attention's VJP rule.  dbias is None unless
+    a bias is given and trainable_bias holds.  None where the plan
+    rejects the operands (the forward then gave no lse either)."""
+    seed, _, bwd, norm = _flash_kernels(q, k, v, bias, **options)
+    if bwd is None:
+        return None
+    return _bwd_with_dbias(
+        bwd, norm, bias, options.get("trainable_bias", True),
+        q, k, v, norm(bias), seed, out, lse, g)
+
+
+def _bwd_with_dbias(bwd, norm, bias, trainable_bias, *operands):
+    """A kernel pair's bwd on `operands`, the bias's cotangent (for a
+    trainable bias only) pulled back through `norm` to the caller's own
+    bias."""
+    import jax
+
+    want_dbias = bias is not None and trainable_bias
+    *grads, dbias = bwd(*operands, want_dbias)
+    if want_dbias:
+        dbias, = jax.vjp(norm, bias)[1](dbias)
+    return (*grads, dbias)
+
+
+def _dropout_seed_arg(dropout_rate, dropout_seed, mask_plane, what):
+    """The (1,) uint32 stream seed the kernels take (zeros without
+    dropout).  The per-head mask plane is keyed by the uint32 index
+    q*Tk + k: past 2^32 elements it would wrap and CORRELATE mask bits
+    across rows — refuse rather than silently degrade."""
+    import jax.numpy as jnp
+
+    if not dropout_rate:
+        return jnp.zeros((1,), jnp.uint32)
+    if dropout_seed is None:
+        raise ValueError(f"{what}: dropout_rate > 0 needs dropout_seed")
+    tq, tk = mask_plane
+    if tq * tk > 2 ** 32:
+        raise ValueError(
+            f"{what}: weights-dropout mask plane Tq*Tk = {tq}*{tk} > 2^32 "
+            "would wrap the uint32 hash index and correlate mask bits; "
+            "drop out the attention OUTPUT (a [T, D] site) instead of the "
+            "weights at this length")
+    return jnp.reshape(dropout_seed, (1,)).astype(jnp.uint32)
+
+
+def _bias_norm(bias, b, h, tq, tk):
+    """bias -> the [Bb, Hb, Tqb, Tk] operand the kernels slice (each of
+    Bb, Hb, Tqb is 1 or full), as a function, so that a gradient can be
+    pulled back through it; None where the shape does not broadcast."""
+    import jax.numpy as jnp
+
+    if bias is None:
+        return lambda bias: None
+    bb, hb, tqb, tkb = shape = (1,) * (4 - bias.ndim) + tuple(bias.shape)
+    if (bb not in (1, b) or hb not in (1, h) or tqb not in (1, tq)
+            or tkb not in (1, tk)):
+        return None
+
+    def norm(bias):
+        bias = jnp.reshape(bias, shape)
+        # key-broadcast biases can't be block-sliced along Tk; materialize
+        # the (cheap, [.., .., 1]-thin) broadcast up front
+        return jnp.broadcast_to(bias, (bb, hb, tqb, tk)) if tkb == 1 \
+            else bias
+
+    return norm
+
+
+def _vjp_of_pair(fwd, bwd, kept):
+    """The jax.custom_vjp of a kernel pair.  Primal: fwd(*operands), the
+    result first and then the residuals the kernel writes anyway;
+    differentiable in the result alone.  Rule: bwd(*operands,
+    *outputs[kept:], the result's cotangent, True) — True: with the
+    bias's cotangent, which XLA drops where nothing reads it.  Operands
+    are arrays, None for an absent bias, and the uint32 seed last."""
     import numpy as np
 
     import jax
-    import jax.numpy as jnp
-
-    if fmt not in ("bhtd", "bthd"):
-        raise ValueError(f"flash_attention: unknown fmt {fmt!r}")
-    if dropout_rate:
-        if dropout_seed is None:
-            raise ValueError("flash_attention: dropout_rate > 0 needs "
-                             "dropout_seed")
-        # the per-head mask plane is keyed by the uint32 index q*Tk + k,
-        # max tq*tk - 1: past 2^32 elements it would wrap and CORRELATE
-        # mask bits across rows — refuse rather than silently degrade
-        tq_d, tk_d = _dims(q, fmt)[2], _dims(k, fmt)[2]
-        if tq_d * tk_d > 2 ** 32:
-            raise ValueError(
-                f"flash_attention: weights-dropout mask plane Tq*Tk = "
-                f"{tq_d}*{tk_d} > 2^32 would wrap the uint32 hash index "
-                "and correlate mask bits; drop out the attention OUTPUT "
-                "(a [T, D] site) instead of the weights at this length")
-        seed = jnp.reshape(dropout_seed, (1,)).astype(jnp.uint32)
-    else:
-        seed = jnp.zeros((1,), jnp.uint32)
-
-    def _f0(s):
-        return np.zeros(s.shape, dtype=jax.dtypes.float0)
-
-    ok, bq, bk, interp = _plan(q, k, block_q, block_k, interpret, fmt)
-    if not ok:
-        if fmt == "bthd":
-            return _reference_bthd(q, k, v, bias, scale, causal,
-                                   dropout_rate, seed)
-        return reference_attention(q, k, v, bias, scale, causal,
-                                   dropout_rate, seed)
-
-    if bias is None:
-        @jax.custom_vjp
-        def _attn(q, k, v, seed):
-            out, _ = _flash_forward(q, k, v, None, seed, scale, causal,
-                                    bq, bk, interp, fmt, dropout_rate)
-            return out
-
-        def _fwd(q, k, v, seed):
-            out, lse = _flash_forward(q, k, v, None, seed, scale, causal,
-                                      bq, bk, interp, fmt, dropout_rate)
-            return out, (q, k, v, seed, out, lse)
-
-        def _bwd(res, g):
-            q, k, v, seed, out, lse = res
-            dq, dk, dv = _flash_backward(q, k, v, None, seed, out, lse, g,
-                                         scale, causal, bq, bk, interp,
-                                         fmt, dropout_rate)
-            return dq, dk, dv, _f0(seed)
-
-        _attn.defvjp(_fwd, _bwd)
-        return _attn(q, k, v, seed)
-
-    # normalize bias to 4D [Bb, Hb, Tqb, Tkb]; each dim must be 1 or full
-    bias = jnp.asarray(bias)
-    while bias.ndim < 4:
-        bias = bias[None]
-    bb, hb, tqb, tkb = bias.shape
-    _b, _h, _tq, _ = _dims(q, fmt)
-    _tk = _dims(k, fmt)[2]
-    if (bb not in (1, _b) or hb not in (1, _h)
-            or tqb not in (1, _tq) or tkb not in (1, _tk)):
-        if fmt == "bthd":
-            return _reference_bthd(q, k, v, bias, scale, causal,
-                                   dropout_rate, seed)
-        return reference_attention(q, k, v, bias, scale, causal,
-                                   dropout_rate, seed)
-    if tkb == 1:
-        # key-broadcast biases can't be block-sliced along Tk; materialize
-        # the (cheap, [.., .., 1]-thin) broadcast up front
-        bias = jnp.broadcast_to(bias, (bb, hb, tqb, _tk))
-
-    # dropout + consumed bias gradient: the dbias recompute hashes its
-    # mask, so the kernels must hash too (see trainable_bias docstring).
-    # Only this bias-carrying branch is gated — the bias=None branch above
-    # returned already, with the hardware-PRNG path fully enabled.
-    allow_hw = not (dropout_rate and trainable_bias)
 
     @jax.custom_vjp
-    def _attn(q, k, v, bias, seed):
-        out, _ = _flash_forward(q, k, v, bias, seed, scale, causal, bq, bk,
-                                interp, fmt, dropout_rate,
-                                allow_hw_prng=allow_hw)
-        return out
+    def attn(*operands):
+        return fwd(*operands)
 
-    def _fwd(q, k, v, bias, seed):
-        out, lse = _flash_forward(q, k, v, bias, seed, scale, causal, bq,
-                                  bk, interp, fmt, dropout_rate,
-                                  allow_hw_prng=allow_hw)
-        return out, (q, k, v, bias, seed, out, lse)
+    def attn_fwd(*operands):
+        outs = fwd(*operands)
+        return outs, (operands, outs[kept:])
 
-    def _bwd(res, g):
-        q, k, v, bias, seed, out, lse = res
+    def attn_bwd(res, cotangents):
+        operands, saved = res
+        grads = bwd(*operands, *saved, cotangents[0], True)
+        return (*grads,
+                np.zeros(operands[-1].shape, dtype=jax.dtypes.float0))
+
+    attn.defvjp(attn_fwd, attn_bwd)
+    return attn
+
+
+def _flash_kernels(q, k, v, bias, scale=1.0, causal=False, block_q=512,
+                   block_k=512, interpret=None, fmt="bhtd",
+                   dropout_rate=0.0, dropout_seed=None, trainable_bias=True):
+    """(seed, fwd, bwd, norm) for one flash_attention site.  On the kernel
+    route fwd(q, k, v, bias, seed) -> (out, lse) and bwd(q, k, v, bias,
+    seed, out, lse, g, want_dbias) -> (dq, dk, dv, dbias or None), both on
+    bias = norm(the caller's bias).  Where the plan (a pure function of
+    shapes, platform and placement) or the bias's shape rejects the
+    operands, bwd is None and fwd is the XLA reference -> out."""
+    if fmt not in ("bhtd", "bthd"):
+        raise ValueError(f"flash_attention: unknown fmt {fmt!r}")
+    b, h, tq, _ = _dims(q, fmt)
+    tk = _dims(k, fmt)[2]
+    seed = _dropout_seed_arg(dropout_rate, dropout_seed, (tq, tk),
+                             "flash_attention")
+    ok, bq, bk, interp = _plan(q, k, block_q, block_k, interpret, fmt)
+    norm = _bias_norm(bias, b, h, tq, tk) if ok else None
+    if norm is None:
+        ref = _reference_bthd if fmt == "bthd" else reference_attention
+        return seed, (lambda q, k, v, bias, seed: ref(
+            q, k, v, bias, scale, causal, dropout_rate, seed)), None, None
+    # dropout + consumed bias gradient: the dbias recompute hashes its
+    # mask, so the kernels must hash too (see trainable_bias docstring);
+    # without a bias the hardware-PRNG path is fully enabled
+    allow_hw = not (dropout_rate and trainable_bias and bias is not None)
+
+    def fwd(q, k, v, bias, seed):
+        return _flash_forward(q, k, v, bias, seed, scale, causal, bq, bk,
+                              interp, fmt, dropout_rate,
+                              allow_hw_prng=allow_hw)
+
+    def bwd(q, k, v, bias, seed, out, lse, g, want_dbias):
         dq, dk, dv = _flash_backward(q, k, v, bias, seed, out, lse, g,
                                      scale, causal, bq, bk, interp, fmt,
                                      dropout_rate, allow_hw_prng=allow_hw)
-        if fmt == "bthd":
-            # _dbias_xla is written for bhtd; the transpose is an XLA view
-            # feeding an einsum (fused), and trainable biases are rare —
-            # stop-gradient masks DCE this whole expression
-            dbias = _dbias_xla(
-                q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3), bias,
-                lse, g.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
-                out.transpose(0, 2, 1, 3), scale, causal, dropout_rate,
-                seed)
-        else:
-            dbias = _dbias_xla(q, k, bias, lse, g, v, out, scale, causal,
-                               dropout_rate, seed)
-        return dq, dk, dv, dbias, _f0(seed)
+        if bias is None or not want_dbias:
+            return dq, dk, dv, None
+        # _dbias_xla is written for bhtd; the transpose is an XLA view
+        # feeding an einsum (fused), and trainable biases are rare
+        t = (lambda a: a.transpose(0, 2, 1, 3)) if fmt == "bthd" \
+            else (lambda a: a)
+        return dq, dk, dv, _dbias_xla(t(q), t(k), bias, lse, t(g), t(v),
+                                      t(out), scale, causal, dropout_rate,
+                                      seed)
 
-    _attn.defvjp(_fwd, _bwd)
-    return _attn(q, k, v, bias, seed)
+    return seed, fwd, bwd, norm
 
 
 # ---------------------------------------------------------------------------
@@ -1974,9 +2023,50 @@ def flash_qkv_attention(x, w_qkv, w_out=None, bias=None, n_head=1,
     flash_attention (stop-gradient masks keep the TPU hardware-PRNG fast
     path; the dbias recompute is XLA-side and DCEd for stop-grad
     biases)."""
-    import numpy as np
+    return flash_qkv_attention_fwd(
+        x, w_qkv, w_out, bias, n_head=n_head, scale=scale, causal=causal,
+        block_q=block_q, block_k=block_k, interpret=interpret,
+        dropout_rate=dropout_rate, dropout_seed=dropout_seed,
+        trainable_bias=trainable_bias)[0]
 
-    import jax
+
+def flash_qkv_attention_fwd(x, w_qkv, w_out=None, bias=None, **options):
+    """flash_qkv_attention (and its options) with the residuals its
+    kernel writes anyway: (y, ctx, lse), ctx [b, h, t, dh] in x.dtype and
+    lse [b, h, t] f32 — both None on the composed route.  Differentiable
+    in `y` (the custom VJP of the kernel pair); a caller that keeps
+    (ctx, lse) hands them to flash_qkv_attention_bwd instead, and the
+    forward kernel runs once (ops/fused_ops.py)."""
+    seed, fwd, bwd, norm = _qkv_kernels(x, w_qkv, w_out, bias, **options)
+    if bwd is None:
+        return fwd(x, w_qkv, w_out, bias, seed), None, None
+    return _vjp_of_pair(fwd, bwd, 1)(x, w_qkv, w_out, norm(bias), seed)
+
+
+def flash_qkv_attention_bwd(x, w_qkv, w_out, bias, ctx, lse, g, **options):
+    """(dx, dw_qkv, dw_out, dbias) from flash_qkv_attention_fwd's
+    (ctx, lse) and the cotangent g of y, under the same options: the two
+    backward walks alone, the body of flash_qkv_attention's VJP rule.
+    dbias is None unless a bias is given and trainable_bias holds.  None
+    where the plan rejects the operands (the forward then gave no
+    residuals either)."""
+    seed, _, bwd, norm = _qkv_kernels(x, w_qkv, w_out, bias, **options)
+    if bwd is None:
+        return None
+    return _bwd_with_dbias(
+        bwd, norm, bias, options.get("trainable_bias", True),
+        x, w_qkv, w_out, norm(bias), seed, ctx, lse, g)
+
+
+def _qkv_kernels(x, w_qkv, w_out, bias, n_head=1, scale=1.0, causal=False,
+                 block_q=512, block_k=512, interpret=None, dropout_rate=0.0,
+                 dropout_seed=None, trainable_bias=True):
+    """(seed, fwd, bwd, norm) for one flash_qkv_attention site.  On the
+    kernel route fwd(x, w_qkv, w_out, bias, seed) -> (y, ctx, lse) and
+    bwd(x, w_qkv, w_out, bias, seed, ctx, lse, g, want_dbias) -> (dx,
+    dw_qkv, dw_out, dbias or None), both on bias = norm(the caller's
+    bias).  On the composed route (w_out=None, a plan rejection, a bias
+    whose shape does not broadcast) bwd is None and fwd -> y alone."""
     import jax.numpy as jnp
 
     b, t, dm = x.shape
@@ -1986,127 +2076,57 @@ def flash_qkv_attention(x, w_qkv, w_out=None, bias=None, n_head=1,
             f"divisible by 3*n_head={3 * n_head}")
     hd = w_qkv.shape[1] // 3
     dh = hd // n_head
-
-    if dropout_rate:
-        if dropout_seed is None:
-            raise ValueError("flash_qkv_attention: dropout_rate > 0 needs "
-                             "dropout_seed")
-        if t * t > 2 ** 32:
-            raise ValueError(
-                "flash_qkv_attention: weights-dropout mask plane T*T > "
-                "2^32 would wrap the uint32 hash index (see "
-                "flash_attention)")
-        seed = jnp.reshape(dropout_seed, (1,)).astype(jnp.uint32)
-    else:
-        seed = jnp.zeros((1,), jnp.uint32)
-
+    seed = _dropout_seed_arg(dropout_rate, dropout_seed, (t, t),
+                             "flash_qkv_attention")
     ok, bq, bk, interp = _qkv_plan(x, n_head, dh, block_q, block_k,
                                    interpret, bias=bias)
-    if w_out is None:
-        return _composed_no_out(x, w_qkv, bias, n_head, scale, causal,
-                                block_q, block_k, interpret, dropout_rate,
-                                seed, trainable_bias)
-    if not ok:
-        return _composed_qkv(x, w_qkv, w_out, bias, n_head, scale, causal,
-                             block_q, block_k, interpret, dropout_rate,
-                             seed, trainable_bias)
+    norm = _bias_norm(bias, b, n_head, t, t) \
+        if ok and w_out is not None else None
+    if norm is None:
+        def composed(x, w_qkv, w_out, bias, seed):
+            args = (bias, n_head, scale, causal, block_q, block_k,
+                    interpret, dropout_rate, seed, trainable_bias)
+            return _composed_no_out(x, w_qkv, *args) if w_out is None \
+                else _composed_qkv(x, w_qkv, w_out, *args)
 
-    # normalize bias to 4D; dims must broadcast (1 or full) like
-    # flash_attention's bthd path
-    if bias is not None:
-        bias = jnp.asarray(bias)
-        while bias.ndim < 4:
-            bias = bias[None]
-        bb, hb, tqb, tkb = bias.shape
-        if (bb not in (1, b) or hb not in (1, n_head)
-                or tqb not in (1, t) or tkb not in (1, t)):
-            return _composed_qkv(x, w_qkv, w_out, bias, n_head, scale,
-                                 causal, block_q, block_k, interpret,
-                                 dropout_rate, seed, trainable_bias)
-        if tkb == 1:
-            bias = jnp.broadcast_to(bias, (bb, hb, tqb, t))
-
+        return seed, composed, None, None
     allow_hw = not (dropout_rate and trainable_bias and bias is not None)
 
-    def _f0(s):
-        return np.zeros(s.shape, dtype=jax.dtypes.float0)
-
-    def _prep(w_qkv, w_out):
+    def prep(w_qkv, w_out):
         return _prep_w_qkv(w_qkv, n_head, dh), _prep_w_out(w_out, n_head,
                                                            dh)
 
-    if bias is None:
-        @jax.custom_vjp
-        def _attn(x, w_qkv, w_out, seed):
-            w3, wo = _prep(w_qkv, w_out)
-            y, _, _ = _qkv_forward(x, w3, wo, None, seed, scale, causal,
-                                   n_head, dh, bq, bk, interp,
-                                   dropout_rate, allow_hw)
-            return y
+    def fwd(x, w_qkv, w_out, bias, seed):
+        w3, wo = prep(w_qkv, w_out)
+        return _qkv_forward(x, w3, wo, bias, seed, scale, causal, n_head,
+                            dh, bq, bk, interp, dropout_rate, allow_hw)
 
-        def _fwd(x, w_qkv, w_out, seed):
-            w3, wo = _prep(w_qkv, w_out)
-            y, ctx, lse = _qkv_forward(x, w3, wo, None, seed, scale,
-                                       causal, n_head, dh, bq, bk, interp,
-                                       dropout_rate, allow_hw)
-            return y, (x, w_qkv, w_out, seed, ctx, lse)
-
-        def _bwd(res, g):
-            x, w_qkv, w_out, seed, ctx, lse = res
-            w3, wo = _prep(w_qkv, w_out)
-            dx_q, dx_kv, dwq, dwk, dwv, dwo = _qkv_backward(
-                x, w3, wo, None, seed, ctx, lse, g, scale, causal, n_head,
-                dh, bq, bk, interp, dropout_rate, allow_hw)
-            dx = (dx_q.astype(jnp.float32)
-                  + dx_kv.astype(jnp.float32)).astype(x.dtype)
-            return (dx, _unpack_dw_qkv(dwq, dwk, dwv, w_qkv.dtype),
-                    dwo.reshape(hd, dm).astype(w_out.dtype), _f0(seed))
-
-        _attn.defvjp(_fwd, _bwd)
-        return _attn(x, w_qkv, w_out, seed)
-
-    @jax.custom_vjp
-    def _attn(x, w_qkv, w_out, bias, seed):
-        w3, wo = _prep(w_qkv, w_out)
-        y, _, _ = _qkv_forward(x, w3, wo, bias, seed, scale, causal,
-                               n_head, dh, bq, bk, interp, dropout_rate,
-                               allow_hw)
-        return y
-
-    def _fwd(x, w_qkv, w_out, bias, seed):
-        w3, wo = _prep(w_qkv, w_out)
-        y, ctx, lse = _qkv_forward(x, w3, wo, bias, seed, scale, causal,
-                                   n_head, dh, bq, bk, interp,
-                                   dropout_rate, allow_hw)
-        return y, (x, w_qkv, w_out, bias, seed, ctx, lse)
-
-    def _bwd(res, g):
-        x, w_qkv, w_out, bias, seed, ctx, lse = res
-        w3, wo = _prep(w_qkv, w_out)
+    def bwd(x, w_qkv, w_out, bias, seed, ctx, lse, g, want_dbias):
+        w3, wo = prep(w_qkv, w_out)
         dx_q, dx_kv, dwq, dwk, dwv, dwo = _qkv_backward(
             x, w3, wo, bias, seed, ctx, lse, g, scale, causal, n_head,
             dh, bq, bk, interp, dropout_rate, allow_hw)
         dx = (dx_q.astype(jnp.float32)
               + dx_kv.astype(jnp.float32)).astype(x.dtype)
+        grads = (dx, _unpack_dw_qkv(dwq, dwk, dwv, w_qkv.dtype),
+                 dwo.reshape(hd, dm).astype(w_out.dtype))
+        if bias is None or not want_dbias:
+            return (*grads, None)
         # bias cotangent via XLA recompute from x and the weights (q/k/
-        # dctx re-derive as plain dots); stop-gradient masks — the usual
-        # case — DCE this whole expression
+        # dctx re-derive as plain dots): for a trainable bias only
         qkv = (x @ w_qkv).astype(jnp.float32)
-        q_r = qkv[..., :hd].reshape(b, t, n_head, dh).transpose(0, 2, 1, 3)
-        k_r = qkv[..., hd:2 * hd].reshape(b, t, n_head,
-                                          dh).transpose(0, 2, 1, 3)
-        v_r = qkv[..., 2 * hd:].reshape(b, t, n_head,
-                                        dh).transpose(0, 2, 1, 3)
-        dctx = jnp.einsum("btm,cm->btc", g.astype(jnp.float32),
-                          w_out.astype(jnp.float32)).reshape(
-            b, t, n_head, dh).transpose(0, 2, 1, 3)
-        dbias = _dbias_xla(q_r, k_r, bias, lse, dctx, v_r, ctx, scale,
-                           causal, dropout_rate, seed)
-        return (dx, _unpack_dw_qkv(dwq, dwk, dwv, w_qkv.dtype),
-                dwo.reshape(hd, dm).astype(w_out.dtype), dbias, _f0(seed))
 
-    _attn.defvjp(_fwd, _bwd)
-    return _attn(x, w_qkv, w_out, bias, seed)
+        def heads(a):
+            return a.reshape(b, t, n_head, dh).transpose(0, 2, 1, 3)
+
+        dctx = jnp.einsum("btm,cm->btc", g.astype(jnp.float32),
+                          w_out.astype(jnp.float32))
+        return (*grads, _dbias_xla(
+            heads(qkv[..., :hd]), heads(qkv[..., hd:2 * hd]), bias, lse,
+            heads(dctx), heads(qkv[..., 2 * hd:]), ctx, scale, causal,
+            dropout_rate, seed))
+
+    return seed, fwd, bwd, norm
 
 
 def _composed_no_out(x, w_qkv, bias, n_head, scale, causal, block_q,

@@ -5,6 +5,16 @@ from __future__ import annotations
 from ..layer_helper import LayerHelper
 
 
+def _residual(helper, shape, dtype):
+    """A variable for what an attention kernel writes beside its result and
+    its grad op reads back (ops/fused_ops.py): it carries no gradient."""
+    var = helper.create_variable_for_type_inference(dtype,
+                                                    stop_gradient=True)
+    if shape is not None:
+        var.shape = tuple(shape)
+    return var
+
+
 def fused_attention(q, k, v, bias=None, scale=1.0, causal=False,
                     dropout_rate=0.0, block_q=512, block_k=512,
                     fmt="bhtd", weights_dropout=True, name=None):
@@ -29,6 +39,9 @@ def fused_attention(q, k, v, bias=None, scale=1.0, causal=False,
 
     helper = LayerHelper("fused_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
+    qs = q.shape
+    lse = _residual(helper, qs and (
+        (qs[0], qs[2], qs[1]) if fmt == "bthd" else qs[:3]), "float32")
     inputs = {"Q": [q], "K": [k], "V": [v]}
     if bias is not None:
         inputs["Bias"] = [bias]
@@ -36,7 +49,7 @@ def fused_attention(q, k, v, bias=None, scale=1.0, causal=False,
     helper.append_op(
         "fused_attention",
         inputs=inputs,
-        outputs={"Out": [out]},
+        outputs={"Out": [out], "Lse": [lse]},
         attrs={
             "scale": float(scale),
             "causal": causal,
@@ -91,13 +104,15 @@ def fused_qkv_attention(x, n_head, d_key, d_model, bias=None, scale=1.0,
         dtype=dtype)
     helper = LayerHelper("fused_qkv_attention", name=name)
     out = helper.create_variable_for_type_inference(dtype)
+    ctx = _residual(helper, (x.shape[0], n_head, x.shape[1], d_key), dtype)
+    lse = _residual(helper, (x.shape[0], n_head, x.shape[1]), "float32")
     inputs = {"X": [x], "WQkv": [w_qkv], "WOut": [w_out]}
     if bias is not None:
         inputs["Bias"] = [bias]
     helper.append_op(
         "fused_qkv_attention",
         inputs=inputs,
-        outputs={"Out": [out]},
+        outputs={"Out": [out], "Ctx": [ctx], "Lse": [lse]},
         attrs={
             "n_head": n_head,
             "scale": float(scale),

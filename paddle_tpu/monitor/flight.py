@@ -397,8 +397,13 @@ _COMPILE_COUNTS = {
     "/jax/compilation_cache/cache_hits": "cache_hits",
     "/jax/compilation_cache/cache_misses": "cache_misses",
 }
+# counted by the program itself while an Executor call traces its block
+# (note_compile_count): grad ops lowered by a registered grad op fed from
+# its forward's residuals, and by the generic vjp of the forward lowering
+_TRACE_COUNTS = ("grad_direct", "grad_generic")
 _compile_totals: Dict[str, float] = dict.fromkeys(
-    list(_COMPILE_DURATIONS.values()) + list(_COMPILE_COUNTS.values()), 0)
+    list(_COMPILE_DURATIONS.values()) + list(_COMPILE_COUNTS.values())
+    + list(_TRACE_COUNTS), 0)
 _compile_lock = threading.Lock()
 _compile_listening = False
 # per thread: how deep inside Executor calls it is, and for each duration
@@ -426,6 +431,14 @@ def _on_compile_duration(event: str, duration_secs: float, **_kw) -> None:
 def _on_compile_event(event: str, **_kw) -> None:
     name = _COMPILE_COUNTS.get(event)
     if name is not None and getattr(_in_executor, "depth", 0):
+        with _compile_lock:
+            _compile_totals[name] += 1
+
+
+def note_compile_count(name: str) -> None:
+    """One more of `name` (one of _TRACE_COUNTS), inside an Executor call
+    only, like every other compile total."""
+    if getattr(_in_executor, "depth", 0):
         with _compile_lock:
             _compile_totals[name] += 1
 
@@ -466,7 +479,9 @@ def compile_phases() -> Dict[str, float]:
     """Process-wide totals of jax's compile phases inside Executor calls:
     seconds `trace_s` (jaxpr trace: the op loop runs here), `lower_s`
     (jaxpr -> MLIR), `backend_s` (XLA compile, or the persistent cache's
-    load where it hit: `cache_load_s` is that part), and the counts
-    `cache_hits` / `cache_misses` of the persistent cache."""
+    load where it hit: `cache_load_s` is that part), the counts
+    `cache_hits` / `cache_misses` of the persistent cache, and the counts
+    `grad_direct` / `grad_generic` of grad ops lowered from their
+    forward's residuals / by the generic vjp (core/registry.py)."""
     with _compile_lock:
         return dict(_compile_totals)

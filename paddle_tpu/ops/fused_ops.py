@@ -4,7 +4,7 @@ SURVEY.md §2.3)."""
 
 from __future__ import annotations
 
-from ..core.registry import register
+from ..core.registry import register, residual_grad
 
 
 def _attn_dropout_seed(ctx):
@@ -50,7 +50,32 @@ def _attn_derives_rng(op) -> bool:
     return bool(op.attrs.get("dropout_rate", 0.0))
 
 
-@register("fused_attention", derives_rng=_attn_derives_rng)
+def _attn_kernel_opts(ctx, bias):
+    """What both attention ops, and their grad ops, hand the kernels: the
+    grad op reads the same attrs (the grad maker copies them, `rng_id`
+    among them) and the same step key, so it derives the same seed."""
+    rate, seed = _attn_dropout_seed(ctx)
+    return dict(
+        scale=ctx.attr("scale", 1.0),
+        causal=ctx.attr("causal", False),
+        block_q=ctx.attr("block_q", 512),
+        block_k=ctx.attr("block_k", 512),
+        dropout_rate=rate,
+        dropout_seed=seed,
+        trainable_bias=_bias_is_trainable(ctx, bias),
+    )
+
+
+def _cotangent(ins, shape, dtype):
+    """Out@GRAD as the generic grad lowering hands it to the vjp: in the
+    forward output's shape and dtype."""
+    import jax.numpy as jnp
+
+    return jnp.asarray(ins["Out@GRAD"][0], dtype).reshape(shape)
+
+
+@register("fused_attention", derives_rng=_attn_derives_rng,
+          residuals=("Out", "Lse"))
 def lower_fused_attention(ctx, ins):
     """Flash attention over [B,H,T,D] (fmt "bhtd") or [B,T,H,D] (fmt
     "bthd") q/k/v with optional additive bias.  "bthd" is the
@@ -59,27 +84,40 @@ def lower_fused_attention(ctx, ins):
     dropout_rate > 0 applies the reference's dropout-on-attention-weights
     semantics (transformer_model.py:44) INSIDE the kernels: the mask is the
     counter-based hash of (step base key, rng_id, global element index) —
-    deterministic within a step, so the generic vjp re-trace regenerates
-    the identical mask in the backward and the [Tq,Tk] mask never exists
-    in HBM (see kernels/hash_rng.py)."""
-    from ..kernels.attention import flash_attention
+    deterministic within a step, so the backward kernels regenerate the
+    identical mask from the seed the grad op derives anew and the [Tq,Tk]
+    mask never exists in HBM (see kernels/hash_rng.py).
+
+    Lse ([B, H, Tq] float32, the kernel's logsumexp) is the residual that
+    fused_attention_grad reads beside Out; it is written where the kernel
+    route ran and the op declares the slot."""
+    from ..kernels.attention import flash_attention_fwd
+
+    bias = ins.get("Bias", [None])[0]
+    out, lse = flash_attention_fwd(
+        ins["Q"][0], ins["K"][0], ins["V"][0], bias,
+        fmt=ctx.attr("fmt", "bhtd"), **_attn_kernel_opts(ctx, bias))
+    return {"Out": [out], "Lse": [lse]}
+
+
+@residual_grad("fused_attention")
+def lower_fused_attention_grad(ctx, ins):
+    """The backward kernels on the forward's own (Out, Lse): the forward
+    kernel is not run again for them."""
+    from ..kernels.attention import flash_attention_bwd
 
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
     bias = ins.get("Bias", [None])[0]
-    rate, seed = _attn_dropout_seed(ctx)
-    trainable_bias = _bias_is_trainable(ctx, bias)
-    out = flash_attention(
-        q, k, v, bias,
-        scale=ctx.attr("scale", 1.0),
-        causal=ctx.attr("causal", False),
-        block_q=ctx.attr("block_q", 512),
-        block_k=ctx.attr("block_k", 512),
-        fmt=ctx.attr("fmt", "bhtd"),
-        dropout_rate=rate,
-        dropout_seed=seed,
-        trainable_bias=trainable_bias,
-    )
-    return {"Out": [out]}
+    out = ins["Out"][0]
+    grads = flash_attention_bwd(
+        q, k, v, bias, out, ins["Lse"][0],
+        _cotangent(ins, out.shape, out.dtype),
+        fmt=ctx.attr("fmt", "bhtd"), **_attn_kernel_opts(ctx, bias))
+    if grads is None:
+        return None
+    dq, dk, dv, dbias = grads
+    return {"Q@GRAD": [dq], "K@GRAD": [dk], "V@GRAD": [dv],
+            "Bias@GRAD": [dbias]}
 
 
 def _fused_qkv_infer(ctx):
@@ -91,7 +129,7 @@ def _fused_qkv_infer(ctx):
 
 
 @register("fused_qkv_attention", infer_shape=_fused_qkv_infer,
-          derives_rng=_attn_derives_rng)
+          derives_rng=_attn_derives_rng, residuals=("Ctx", "Lse"))
 def lower_fused_qkv_attention(ctx, ins):
     """Self-attention with the qkv/output projections fused INTO the flash
     kernels (kernels/attention.py flash_qkv_attention): X [b, t, d_model],
@@ -102,25 +140,38 @@ def lower_fused_qkv_attention(ctx, ins):
     (PERF.md round 9 lead 1) go with them.  Dropout semantics/seeding
     follow fused_attention (in-kernel weights dropout, step-key-derived
     seed); shapes the kernel plan rejects run the numerically-identical
-    composed path."""
-    from ..kernels.attention import flash_qkv_attention
+    composed path.
+
+    Ctx ([b, n_head, t, d_head], X's dtype) and Lse ([b, n_head, t]
+    float32) are the kernel's residuals, which fused_qkv_attention_grad
+    reads; they are written where the kernel route ran and the op
+    declares the slots."""
+    from ..kernels.attention import flash_qkv_attention_fwd
+
+    bias = ins.get("Bias", [None])[0]
+    out, attn_ctx, lse = flash_qkv_attention_fwd(
+        ins["X"][0], ins["WQkv"][0], ins["WOut"][0], bias,
+        n_head=ctx.attr("n_head", 1), **_attn_kernel_opts(ctx, bias))
+    return {"Out": [out], "Ctx": [attn_ctx], "Lse": [lse]}
+
+
+@residual_grad("fused_qkv_attention")
+def lower_fused_qkv_attention_grad(ctx, ins):
+    """The two backward walks on the forward's own (Ctx, Lse): the forward
+    kernel is not run again for them."""
+    from ..kernels.attention import flash_qkv_attention_bwd
 
     x, w_qkv, w_out = ins["X"][0], ins["WQkv"][0], ins["WOut"][0]
     bias = ins.get("Bias", [None])[0]
-    rate, seed = _attn_dropout_seed(ctx)
-    trainable_bias = _bias_is_trainable(ctx, bias)
-    out = flash_qkv_attention(
-        x, w_qkv, w_out, bias,
-        n_head=ctx.attr("n_head", 1),
-        scale=ctx.attr("scale", 1.0),
-        causal=ctx.attr("causal", False),
-        block_q=ctx.attr("block_q", 512),
-        block_k=ctx.attr("block_k", 512),
-        dropout_rate=rate,
-        dropout_seed=seed,
-        trainable_bias=trainable_bias,
-    )
-    return {"Out": [out]}
+    grads = flash_qkv_attention_bwd(
+        x, w_qkv, w_out, bias, ins["Ctx"][0], ins["Lse"][0],
+        _cotangent(ins, x.shape[:-1] + w_out.shape[1:], x.dtype),
+        n_head=ctx.attr("n_head", 1), **_attn_kernel_opts(ctx, bias))
+    if grads is None:
+        return None
+    dx, dw_qkv, dw_out, dbias = grads
+    return {"X@GRAD": [dx], "WQkv@GRAD": [dw_qkv], "WOut@GRAD": [dw_out],
+            "Bias@GRAD": [dbias]}
 
 
 @register("fused_layer_norm_gelu")

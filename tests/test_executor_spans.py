@@ -205,7 +205,12 @@ def test_compile_phases_count_inside_executor_calls_only(clean_ring):
     jax.jit(lambda a: a * 3 + 1)(np.ones(5, "float32"))
     assert monitor.compile_phases() == hit1
     assert set(hit0) == {"trace_s", "lower_s", "backend_s", "cache_load_s",
-                         "cache_hits", "cache_misses"}
+                         "cache_hits", "cache_misses", "grad_direct",
+                         "grad_generic"}
+    # the net's grad ops have no lowering of their own: all went through
+    # the generic vjp, once each, in the miss call's trace and nowhere else
+    assert miss["grad_generic"] - before["grad_generic"] > 0
+    assert miss["grad_direct"] == before["grad_direct"]
 
 
 def test_a_nested_trace_is_counted_once():

@@ -21,9 +21,11 @@ Covers the r09 acceptance contract:
     kernels vs the composed reference + hw-PRNG dropout determinism).
 """
 
+import collections
 import contextlib
 import importlib.util
 import os
+import re
 
 import numpy as np
 import pytest
@@ -358,6 +360,327 @@ class TestOpProgram:
                                          fetch_list=[loss],
                                          scope=scope)[0]).reshape(-1)[-1])
         assert abs(a - b) < 1e-7  # deterministic: no dropout draws
+
+
+# -- the residual hand-off (forward op -> registered grad op) ----------------
+
+_ATTN_OPS = ("fused_qkv_attention", "fused_attention")
+
+
+def _strip_residuals(prog):
+    """Make `prog` the program a build before the hand-off gave (and
+    passes.py still gives): attention ops with `Out` alone, grad ops with
+    the forward's inputs and Out@GRAD alone — every attention grad op then
+    lowers through lower_generic_grad."""
+    for op in prog.global_block().ops:
+        if op.type in _ATTN_OPS:
+            op.outputs = {"Out": op.outputs["Out"]}
+        elif op.type in tuple(t + "_grad" for t in _ATTN_OPS):
+            for slot in ("Ctx", "Lse", "Out"):
+                op.inputs.pop(slot, None)
+    return prog
+
+
+def _build_transformer(flag, dropout=0.0):
+    """Mini encoder-decoder (1+1 layers): fused-qkv self attention plus
+    one bthd cross-attention site, Adam inside."""
+    with _fused_qkv(flag):
+        fw._rng_id_counter[0] = 0
+        prog, startup = pt.Program(), pt.Program()
+        with fw.guard_unique_name():
+            with pt.program_guard(prog, startup):
+                loss, _, _ = T.transformer(
+                    src_vocab_size=64, trg_vocab_size=64, max_length=32,
+                    n_layer=1, n_head=2, d_key=64, d_value=64, d_model=128,
+                    d_inner_hid=128, dropout_rate=dropout, src_seq_len=32,
+                    trg_seq_len=32, use_flash=True)
+                pt.optimizer.Adam(learning_rate=1e-3).minimize(loss)
+    return prog, startup, loss
+
+
+def _transformer_feed():
+    return T.make_batch(2, 32, 32, 2, 64, 64, rng=np.random.RandomState(0))
+
+
+_MODELS = {"bert": (_build_bert, _bert_feed),
+           "transformer": (_build_transformer, _transformer_feed)}
+
+
+def _step_with_grads(prog, startup, loss, feed, amp=False, steps=1):
+    """`steps` train steps from fixed weights: (per-step [loss + every
+    parameter's gradient], the parameters after them, what the compile of
+    the step counted)."""
+    from paddle_tpu import monitor
+
+    exe = pt.Executor(pt.CPUPlace())
+    scope = pt.Scope()
+    exe.run(startup, scope=scope)
+    _init_params(prog, scope)
+    if amp:
+        pt.amp.enable(prog)
+    block = prog.global_block()
+    fetch = [loss.name] + [p.name + "@GRAD" for p in prog.all_parameters()
+                           if block.has_var(p.name + "@GRAD")]
+    before = monitor.compile_phases()
+    outs = [[np.asarray(v) for v in exe.run(prog, feed=feed,
+                                            fetch_list=fetch, scope=scope)]
+            for _ in range(steps)]
+    counted = {k: monitor.compile_phases()[k] - before[k]
+               for k in ("grad_direct", "grad_generic")}
+    params = {p.name: np.asarray(scope.find_var(p.name))
+              for p in prog.all_parameters()}
+    return outs, params, counted
+
+
+def _assert_same_bits(got, want):
+    (outs_a, params_a, _), (outs_b, params_b, _) = got, want
+    for step_a, step_b in zip(outs_a, outs_b):
+        for a, b in zip(step_a, step_b):
+            assert a.dtype == b.dtype
+            np.testing.assert_array_equal(a, b)
+    assert params_a.keys() == params_b.keys()
+    for k in params_a:
+        np.testing.assert_array_equal(params_a[k], params_b[k], err_msg=k)
+
+
+def _n_generic_by_nature(prog):
+    """Grad ops of `prog` that have no registered lowering of their own."""
+    from paddle_tpu.core import registry
+
+    return sum(op.type.endswith("_grad") and registry.lookup(op.type) is None
+               for op in prog.global_block().ops)
+
+
+def _n_sites(prog):
+    return sum(op.type in _ATTN_OPS for op in prog.global_block().ops)
+
+
+@pytest.fixture
+def clean_flight():
+    from paddle_tpu import monitor
+    from paddle_tpu.monitor import flight
+
+    assert not FLAGS.monitor
+    flight.default_recorder().clear()
+    yield
+    FLAGS.reset("monitor")
+    flight.default_recorder().clear()
+    monitor.default_registry().reset()
+
+
+class TestResidualGrad:
+    @pytest.mark.parametrize("amp", [False, True], ids=["fp32", "amp"])
+    @pytest.mark.parametrize("model", sorted(_MODELS))
+    def test_gradients_bit_equal_to_generic_route(self, model, amp):
+        """One train step: every parameter's gradient (and the loss, and
+        the parameters Adam leaves) through Ctx / Lse is bit for bit what
+        lower_generic_grad gives on the same program without the slots."""
+        build, feed = _MODELS[model]
+        direct = _step_with_grads(*build(True), feed(), amp=amp)
+        prog, startup, loss = build(True)
+        generic = _step_with_grads(_strip_residuals(prog), startup, loss,
+                                   feed(), amp=amp)
+        _assert_same_bits(direct, generic)
+        n = _n_sites(prog)
+        assert n == {"bert": 1, "transformer": 3}[model]
+        # the counters of the compile: every attention grad op went the
+        # direct way, and nothing but the ops that have no lowering of
+        # their own went through lower_generic_grad
+        assert direct[2] == {"grad_direct": n,
+                             "grad_generic": _n_generic_by_nature(prog)}
+        assert generic[2] == {"grad_direct": 0,
+                              "grad_generic": _n_generic_by_nature(prog) + n}
+
+    @pytest.mark.parametrize("model", sorted(_MODELS))
+    def test_one_forward_kernel_per_site(self, model, clean_flight):
+        """The traced train step holds each attention site's forward
+        kernel once (the generic route held it twice: once more, under a
+        `jvp` name, for the residuals), and the `executor.compile` flight
+        event says so."""
+        from paddle_tpu.core.executor import latest_jitted_entry
+        from paddle_tpu.monitor import flight
+
+        def kernels(prog, startup, loss):
+            FLAGS.monitor = True
+            try:
+                exe = pt.Executor(pt.CPUPlace())
+                scope = pt.Scope()
+                exe.run(startup, scope=scope)
+                feed = _MODELS[model][1]()
+                exe.run(prog, feed=feed, fetch_list=[loss], scope=scope)
+            finally:
+                FLAGS.reset("monitor")
+            entry = latest_jitted_entry(exe)
+            args = ([exe._to_device_array(prog, n, feed[n])
+                     for n in sorted(feed)],
+                    [scope.find_var(n) for n in entry.rw_state],
+                    [scope.find_var(n) for n in entry.ro_state])
+            if entry.needs_key:
+                args += (jax.random.PRNGKey(0),)
+            text = str(entry.jitted.trace(*args).jaxpr)
+            names = collections.Counter(
+                re.findall(r"name=(\w*(?:_fwd|_bwd)\w*)", text))
+            event = flight.default_recorder().events(
+                kind="executor.compile")[-1]
+            return names, event
+
+        prog, startup, loss = _MODELS[model][0](True)
+        names, event = kernels(prog, startup, loss)
+        n_qkv = sum(op.type == "fused_qkv_attention"
+                    for op in prog.global_block().ops)
+        n_cross = _n_sites(prog) - n_qkv
+        want = {"fused_qkv_fwd": n_qkv, "fused_qkv_bwd_dx_q": n_qkv,
+                "fused_qkv_bwd_dx_kv": n_qkv}
+        if n_cross:
+            want.update(flash_bthd_fwd=n_cross, flash_bthd_bwd_dq=n_cross,
+                        flash_bthd_bwd_dkv=n_cross)
+        assert dict(names) == want
+        assert event["grad_direct"] == n_qkv + n_cross
+        assert event["grad_generic"] == _n_generic_by_nature(prog)
+        # and the generic route, which this test would not tell from the
+        # direct one if it read nothing: the forward kernel again for each
+        # site (in the jaxpr a third time, lower_generic_grad's probe of
+        # the output structure, which XLA drops)
+        prog, startup, loss = _MODELS[model][0](True)
+        names, event = kernels(_strip_residuals(prog), startup, loss)
+        assert sum(v for k, v in names.items() if "_fwd" in k) \
+            >= 2 * (n_qkv + n_cross)
+        assert event["grad_direct"] == 0
+
+    def test_amp_leaves_lse_float32(self, monkeypatch):
+        """Under amp every float input of the attention ops and of their
+        grad ops goes to bf16 but Lse: the backward kernels get float32,
+        the array the forward wrote."""
+        from paddle_tpu import amp
+        from paddle_tpu.kernels import attention as att
+
+        lse = jnp.ones((2, 2, 32), jnp.float32)
+        ins = {"X": [jnp.ones((2, 32, 128))], "Ctx": [jnp.ones((2, 2, 32, 64))],
+               "Lse": [lse], "Out@GRAD": [jnp.ones((2, 32, 128))]}
+        cast = amp.apply_cast_policy("fused_qkv_attention_grad", ins)
+        assert cast["Lse"][0] is lse
+        assert {cast[s][0].dtype for s in ("X", "Ctx", "Out@GRAD")} \
+            == {jnp.dtype(jnp.bfloat16)}
+        cast = amp.apply_cast_policy(
+            "fused_attention_grad", {"Q": [jnp.ones((2, 32, 2, 64))],
+                                     "Out": [jnp.ones((2, 32, 2, 64))],
+                                     "Lse": [lse]})
+        assert cast["Lse"][0] is lse
+        assert cast["Out"][0].dtype == cast["Q"][0].dtype == jnp.bfloat16
+
+        seen = []
+        real_qkv, real_flash = att._qkv_backward, att._flash_backward
+
+        def spy_qkv(x, w3, wo, bias, seed, ctx, lse, g, *a, **k):
+            seen.append(("qkv", x.dtype, ctx.dtype, lse.dtype, g.dtype))
+            return real_qkv(x, w3, wo, bias, seed, ctx, lse, g, *a, **k)
+
+        def spy_flash(q, k_, v, bias, seed, o, lse, g, *a, **k):
+            seen.append(("flash", q.dtype, o.dtype, lse.dtype, g.dtype))
+            return real_flash(q, k_, v, bias, seed, o, lse, g, *a, **k)
+
+        monkeypatch.setattr(att, "_qkv_backward", spy_qkv)
+        monkeypatch.setattr(att, "_flash_backward", spy_flash)
+        prog, startup, loss = _build_transformer(True)
+        lse_names = [op.output("Lse")[0] for op in prog.global_block().ops
+                     if op.type in _ATTN_OPS]
+        exe = pt.Executor(pt.CPUPlace())
+        scope = pt.Scope()
+        exe.run(startup, scope=scope)
+        _init_params(prog, scope)
+        pt.amp.enable(prog)
+        fetched = exe.run(prog, feed=_transformer_feed(),
+                          fetch_list=lse_names, scope=scope)
+        bf16, f32 = jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)
+        assert sorted(seen) == [("flash", bf16, bf16, f32, bf16)] \
+            + [("qkv", bf16, bf16, f32, bf16)] * 2
+        for v in fetched:
+            assert np.asarray(v).dtype == np.float32
+            assert np.all(np.isfinite(np.asarray(v)))
+
+    @pytest.mark.parametrize("case", ["out_only", "plan_rejects"])
+    def test_programs_without_residuals_train_as_before(self, case):
+        """What the direct route does not take lowers as it always did:
+        a fused_attention with `Out` alone (as passes.py inserts it, the
+        grad op made AFTER the slots were dropped), and a head size the
+        kernel plans reject (the composed route writes no residual)."""
+        if case == "out_only":
+            def build(strip_before_backward):
+                prog, startup, loss = _build_bert(False, opt=False)
+                if strip_before_backward:
+                    _strip_residuals(prog)
+                with pt.program_guard(prog, startup):
+                    pt.optimizer.Adam(learning_rate=1e-3).minimize(loss)
+                return prog, startup, loss
+
+            prog, startup, loss = build(True)
+            grad_op, = [op for op in prog.global_block().ops
+                        if op.type == "fused_attention_grad"]
+            assert sorted(grad_op.inputs) == ["Bias", "K", "Out@GRAD", "Q",
+                                              "V"]
+            got = _step_with_grads(prog, startup, loss, _bert_feed())
+            assert got[2]["grad_direct"] == 0
+            want = _step_with_grads(*build(False), _bert_feed())
+            assert want[2]["grad_direct"] == 1
+        else:
+            def build():
+                with _fused_qkv(True):
+                    prog, startup = pt.Program(), pt.Program()
+                    with fw.guard_unique_name():
+                        with pt.program_guard(prog, startup):
+                            loss, _ = B.build_pretrain_net(
+                                vocab_size=64, seq_len=32, n_layer=1,
+                                n_head=4, d_model=128, d_ff=128,
+                                dropout_rate=0.0, use_flash=True, lr=1e-3)
+                return prog, startup, loss
+
+            prog, startup, loss = build()
+            assert _n_sites(prog) == 1
+            got = _step_with_grads(prog, startup, loss, _bert_feed())
+            # d_head 32: the forward wrote no Lse, so the registered grad
+            # op found its residuals unbound and went the generic way
+            assert got[2]["grad_direct"] == 0
+            assert got[2]["grad_generic"] == _n_generic_by_nature(prog) + 1
+            prog, startup, loss = build()
+            want = _step_with_grads(_strip_residuals(prog), startup, loss,
+                                    _bert_feed())
+        _assert_same_bits(got, want)
+
+    def test_wout_none_has_no_direct_route(self):
+        x, w_qkv, _, _ = _inputs(t=64)
+        from paddle_tpu.kernels.attention import (
+            flash_qkv_attention_bwd,
+            flash_qkv_attention_fwd,
+        )
+
+        y, ctx, lse = flash_qkv_attention_fwd(x, w_qkv, None, None, n_head=2,
+                                              scale=0.125, interpret=True)
+        assert y.shape == (2, 64, 128) and ctx is None and lse is None
+        assert flash_qkv_attention_bwd(
+            x, w_qkv, None, None, ctx, lse, jnp.ones_like(y), n_head=2,
+            scale=0.125, interpret=True) is None
+        # and it still differentiates, through the composed route
+        g = jax.grad(lambda x: jnp.sum(flash_qkv_attention(
+            x, w_qkv, None, None, n_head=2, scale=0.125,
+            interpret=True)))(x)
+        assert g.shape == x.shape and bool(jnp.all(jnp.isfinite(g)))
+
+    @pytest.mark.parametrize("flag", [True, False],
+                             ids=["fused_qkv", "fused_attention"])
+    def test_dropout_trajectory_identical_to_generic_route(self, flag):
+        """Dropout on (the hash masks of the interpret route): the grad op
+        derives the seed its forward used from the copied `rng_id`, so
+        two steps through the residuals are the generic route's, bit for
+        bit."""
+        direct = _step_with_grads(*_build_bert(flag, dropout=0.1),
+                                  _bert_feed(), steps=2)
+        prog, startup, loss = _build_bert(flag, dropout=0.1)
+        generic = _step_with_grads(_strip_residuals(prog), startup, loss,
+                                   _bert_feed(), steps=2)
+        _assert_same_bits(direct, generic)
+        assert direct[2]["grad_direct"] == 1
+        nodrop = _step_with_grads(*_build_bert(flag), _bert_feed(), steps=2)
+        assert not np.array_equal(direct[0][1][0], nodrop[0][1][0])
 
 
 # -- zero-cost-off ----------------------------------------------------------

@@ -88,3 +88,22 @@ def test_random_ops_set_matches_registry():
     regs = set(registry._registry)
     dead = sorted(t for t in ex._RANDOM_OPS if t not in regs)
     assert not dead, f"_RANDOM_OPS entries with no registered lowering: {dead}"
+
+
+def test_residual_ops_have_a_registered_grad_op():
+    """An op that names `residuals` (output slots its grad op reads back
+    instead of re-running the forward for them) comes with a registered
+    `<type>_grad` under the same RNG contract, and its layer wrapper
+    creates the slots' variables: a forward that declares residuals nobody
+    reads, or that no layer ever binds, pays for nothing."""
+    residual_ops = {t: d for t, d in registry._registry.items()
+                    if d.residuals}
+    assert {"fused_attention", "fused_qkv_attention"} <= set(residual_ops)
+    contrib = (BASE / "layers" / "contrib.py").read_text()
+    for t, fwd in residual_ops.items():
+        grad = registry.lookup(t + "_grad")
+        assert grad is not None and grad.no_grad, t
+        assert grad.derives_rng is fwd.derives_rng, t
+        assert registry.get_grad_lowering(t + "_grad") is grad.lower
+        for slot in fwd.residuals:
+            assert f'"{slot}": [' in contrib, (t, slot)
