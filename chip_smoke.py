@@ -11,6 +11,11 @@ its full width (the one model this repo trains, decodes and serves):
               x seq 256, dropout 0.1, Adam, bf16 amp) through
               Executor.run_steps: loss finite and falling, parameters
               resident on the TPU, Mosaic kernels in the compiled step.
+  moe         a small latent-attention (d_qk 192, d_v 128) + expert-layer
+              + MTP stack (models.mla_moe_decoder, 4 of 16 experts held)
+              through Executor.run_steps in bf16 amp: loss finite and
+              falling, the flash and grouped-matmul kernels all Mosaic
+              calls of the compiled step.
   generate    bench.py's decode geometry (source 256, 64 new tokens, f32)
               as a GenerationServingModel behind an in-process
               InferenceServer: HTTP :generate requests of several prompt
@@ -225,6 +230,67 @@ def train_leg(cfg=None, batch=64, seq=256, scan_steps=4, calls=3,
                 losses=[float(x) for x in np.concatenate([first, last])],
                 params=len(params), mosaic_calls=mosaic,
                 compile_s=round(compile_s, 2))
+
+
+# ---------------------------------------------------------------------------
+# expert-layer leg
+# ---------------------------------------------------------------------------
+
+#: a small MLA + MoE + MTP stack at shapes Mosaic tiles (d_qk 192, d_v 128)
+MOE_SMALL = dict(
+    vocab_size=1024, seq_len=256, batch=2, d_model=256, n_head=4,
+    q_lora_rank=128, kv_lora_rank=128, qk_nope_dim=128, qk_rope_dim=64,
+    v_head_dim=128, n_dense=1, n_moe=1, d_ff_dense=512, d_ff_expert=256,
+    n_experts=16, n_held=4, expert_offset=4, top_k=4, n_mtp=1,
+    bias_std=0.01, lr=1e-3)
+MOE_KERNELS = ("flash_bhtd_fwd", "flash_bhtd_bwd_dq", "flash_bhtd_bwd_dkv",
+               "moe_gmm_fwd", "moe_gmm_bwd_dx", "moe_gmm_bwd_dw")
+
+
+def moe_leg(sizes=None, scan_steps=2, calls=3, interpret=False) -> dict:
+    """A small latent-attention + expert-layer + MTP train step (models.
+    mla_moe_decoder, one chip's share of the experts, bf16 amp) through
+    Executor.run_steps: loss finite and falling, and every kernel of
+    MOE_KERNELS a Mosaic call of the compiled step (no silent fallback
+    to the XLA routes)."""
+    import paddle_tpu as pt
+    from paddle_tpu.models import mla_moe_decoder as M
+
+    sizes = dict(sizes or MOE_SMALL)
+    fails = []
+    prog, startup = pt.Program(), pt.Program()
+    with pt.program_guard(prog, startup):
+        loss, _ = M.build_train_net(**sizes)
+    pt.amp.enable(prog)
+    scope, exe = pt.Scope(), pt.Executor()
+    exe.run(startup, scope=scope)
+    rng = np.random.default_rng(7)
+    b, t = sizes["batch"], sizes["seq_len"]
+    feed = {"ids": rng.integers(0, sizes["vocab_size"],
+                                (scan_steps, b, t + 2, 1), dtype=np.int32),
+            "loss_weight": np.ones((scan_steps, b, t, 1), np.float32)}
+    losses = []
+    for _ in range(calls + 1):
+        (out,) = exe.run_steps(prog, feed=feed, fetch_list=[loss],
+                               scope=scope)
+        losses.append(np.asarray(out, np.float64).reshape(-1))
+    if not all(np.all(np.isfinite(x)) for x in losses):
+        fails.append(f"non-finite loss: {losses}")
+    elif not losses[-1][-1] < losses[0][0]:
+        fails.append(f"loss did not fall: {losses[0][0]:.4f} -> "
+                     f"{losses[-1][-1]:.4f}")
+    hlo = _entry_hlo(exe, prog, feed, scope)
+    absent = [] if interpret else [k for k in MOE_KERNELS if k not in hlo]
+    if absent:
+        fails.append(f"kernels that fell back to XLA (no Mosaic call of "
+                     f"that name in the compiled step): {absent}")
+    _say(f"moe: loss {losses[0][0]:.4f} -> {losses[-1][-1]:.4f} over "
+         f"{(calls + 1) * scan_steps} steps, "
+         f"mosaic_calls={_mosaic_calls(hlo)}, absent={absent}")
+    return dict(leg="moe", ok=not fails, failures=fails,
+                loss_first=float(losses[0][0]),
+                loss_last=float(losses[-1][-1]),
+                mosaic_calls=_mosaic_calls(hlo))
 
 
 # ---------------------------------------------------------------------------
@@ -1044,6 +1110,7 @@ def main(argv=None) -> int:
          f"({'warm' if before else 'cold'})")
 
     legs = [("train", lambda: train_leg(**TRAIN_FULL)),
+            ("moe", moe_leg),
             ("generate", lambda: generate_leg(**GENERATE_FULL)),
             ("kernels", kernel_leg)]
     if args.chips == 4:

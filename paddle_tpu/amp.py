@@ -79,6 +79,8 @@ BLACK_OPS = frozenset({
     "accuracy",
     "auc",
     "fused_layer_norm_gelu",
+    # the router's scores decide a top-k: float32, as published
+    "moe_router",
     # optimizer ops: fp32 master-weight updates
     "sgd",
     "momentum",
@@ -159,6 +161,8 @@ SLOT_WHITE_OPS = {
         {"Q", "K", "V", "Bias", "Out", "Out@GRAD"}),
     "fused_qkv_attention": frozenset(
         {"X", "WQkv", "WOut", "Bias", "Ctx", "Out@GRAD"}),
+    # the expert matmuls run bf16; the pairs' weights stay float32
+    "moe_experts": frozenset({"X", "WGateUp", "WDown", "H", "Out@GRAD"}),
 }
 
 # Multi-input elementwise ops follow their activations: if any float input is

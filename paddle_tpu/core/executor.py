@@ -515,13 +515,13 @@ class _CallSpans:
     With tracing off no stamp is taken and nothing is written."""
 
     __slots__ = ("mode", "call", "mon", "on", "phases", "compile0",
-                 "outcome", "entry_ns", "_annotation", "_parent", "_child",
-                 "_in_call", "_open")
+                 "outcome", "counters", "entry_ns", "_annotation", "_parent",
+                 "_child", "_in_call", "_open")
 
     def __init__(self, mode: str, call: int):
         self.mode, self.call = mode, call
         self.phases: List[list] = []
-        self.compile0 = self.outcome = None
+        self.compile0 = self.outcome = self.counters = None
         self._child = self._open = None
 
     def __enter__(self):
@@ -620,6 +620,10 @@ class _CallSpans:
         meta = {"compiled": int(compiled_now)}
         if steps is not None:
             fields["steps"] = meta["steps"] = steps
+        if self.counters:
+            # the program's device counters (monitor.device_counter), each
+            # its mean over the call's steps
+            fields["counters"] = self.counters
         # what the parent annotation learns only during the call
         self._parent.set_metadata(**meta)
         if mon:
@@ -1027,6 +1031,11 @@ class Executor:
         ]
         user_fetch_n, fetch_names = self._numerics_fetch(program,
                                                          fetch_names)
+        # the program's device counters ride every call as outputs of the
+        # compiled steps (a traced call must not compile anew); they are
+        # read back only while tracing is on (_fetch)
+        counters = getattr(program, "_device_counters", None) or {}
+        fetch_names = fetch_names + list(counters.values())
         feed_names = sorted(feed)
         feed_stack = {
             n: self._to_device_array(program, n, feed[n])
@@ -1117,7 +1126,7 @@ class Executor:
                 )
         return self._fetch(spans, program, fetch_names, user_fetch_n,
                            compiled_now, feed_vals, fetches, return_numpy,
-                           steps=steps)
+                           steps=steps, counters=tuple(counters))
 
     def run_startup_missing(self, startup_program=None, scope=None):
         """Run only the startup ops whose outputs are NOT yet in the scope
@@ -1635,7 +1644,8 @@ class Executor:
                 self._compiled_stamps.add(stamp)
 
     def _fetch(self, spans, program, fetch_names, user_fetch_n,
-               compiled_now, feed_vals, fetches, return_numpy, steps=None):
+               compiled_now, feed_vals, fetches, return_numpy, steps=None,
+               counters=()):
         """Epilogue shared by the three run modes: the `fetch` phase, and
         what the call's record (`_CallSpans._record`, at exit) will say.
 
@@ -1647,6 +1657,15 @@ class Executor:
         above): the pair the cost model's launch term is checked
         against."""
         spans.phase("fetch")
+        if counters:
+            # the last len(counters) fetches are the program's device
+            # counters: left on the device unless tracing is on
+            cut = len(fetches) - len(counters)
+            if spans.on:
+                spans.counters = {
+                    name: float(np.mean(np.asarray(v, np.float64)))
+                    for name, v in zip(counters, fetches[cut:])}
+            fetches, fetch_names = fetches[:cut], fetch_names[:cut]
         outs = ([np.asarray(v) for v in fetches] if return_numpy
                 else list(fetches))
         user_outs = self._publish_numerics(program, fetch_names,

@@ -195,10 +195,9 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
     pid0 = pl.program_id(0)
 
     q = q_ref[...].astype(jnp.float32) * scale  # [block_q, d]
-    d = q.shape[-1]
     m = jnp.full((block_q,), -jnp.inf, jnp.float32)
     l = jnp.zeros((block_q,), jnp.float32)
-    acc = jnp.zeros((block_q, d), jnp.float32)
+    acc = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)  # [.., d_v]
 
     n_kv = seq_k // block_k
     if causal:
@@ -323,10 +322,9 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
     pid0 = pl.program_id(0)
 
     k = k_ref[...].astype(jnp.float32)  # [block_k, d]
-    v = v_ref[...].astype(jnp.float32)
-    d = k.shape[-1]
-    dk = jnp.zeros((block_k, d), jnp.float32)
-    dv = jnp.zeros((block_k, d), jnp.float32)
+    v = v_ref[...].astype(jnp.float32)  # [block_k, d_v]
+    dk = jnp.zeros(k.shape, jnp.float32)
+    dv = jnp.zeros(v.shape, jnp.float32)
 
     n_q = seq_q // block_q
     lo = 0
@@ -390,12 +388,20 @@ def _dims(x, fmt):
     return b, h, t, d
 
 
-def _plan(q, k, block_q, block_k, interpret, fmt="bhtd"):
-    """Static feasibility check; returns (ok, block_q, block_k, interpret)."""
+def _plan(q, k, block_q, block_k, interpret, fmt="bhtd", v=None):
+    """Static feasibility check; returns (ok, block_q, block_k, interpret).
+
+    `v` (default: shaped like k) may carry a value head size of its own
+    (latent attention: d_qk 192, d_v 128).  The bhtd kernels take it: q
+    and k tiles are [.., d_qk], v, the context and its cotangent
+    [.., d_v].  The whole-head bthd kernels keep one head size."""
     from .placement import resolve
 
     b, h, tq, d = _dims(q, fmt)
     tk = _dims(k, fmt)[2]
+    dv = d if v is None else _dims(v, fmt)[3]
+    if fmt == "bthd" and dv != d:
+        return False, 0, 0, resolve(interpret)[1]
     compiled, interpret = resolve(interpret)
     block_q = min(block_q, tq)
     block_k = min(block_k, tk)
@@ -436,6 +442,7 @@ def _plan(q, k, block_q, block_k, interpret, fmt="bhtd"):
         and tq % block_q == 0
         and tk % block_k == 0
         and d % 64 == 0  # 64 runs at half-lane MXU occupancy but still wins
+        and dv % 64 == 0
         and (compiled or interpret)
     )
     return ok, block_q, block_k, interpret
@@ -870,9 +877,12 @@ def _flash_forward(q, k, v, bias, seed, scale, causal, block_q, block_k,
         )(*args)
         return out, lse
 
+    dv = v.shape[-1]
+    o_spec, v_spec = _qkv_specs(fmt, h, "block", "full", block_q, block_k,
+                                tq, tk, dv)
     args = [seed, q.reshape(bh, tq, d), k.reshape(bh, tk, d),
-            v.reshape(bh, tk, d)]
-    in_specs = [_seed_spec(), q_spec, kv_spec, kv_spec]
+            v.reshape(bh, tk, dv)]
+    in_specs = [_seed_spec(), q_spec, kv_spec, v_spec]
     bias_q1 = False
     if bias is not None:
         spec, barg, bias_q1 = _bias_spec_and_arg(
@@ -899,17 +909,17 @@ def _flash_forward(q, k, v, bias, seed, scale, causal, block_q, block_k,
         grid=(bh, tq // block_q),
         in_specs=in_specs,
         out_specs=[
-            q_spec,
+            o_spec,
             pl.BlockSpec((None, LSE_SUBLANES, block_q),
                          lambda i, j: (i, 0, j)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, tq, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, LSE_SUBLANES, tq), jnp.float32),
         ],
         interpret=interpret,
     )(*args)
-    return out.reshape(b, h, tq, d), lse[:, 0, :].reshape(b, h, tq)
+    return out.reshape(b, h, tq, dv), lse[:, 0, :].reshape(b, h, tq)
 
 
 def _flash_backward(q, k, v, bias, seed, o, lse, g, scale, causal, block_q,
@@ -1008,8 +1018,9 @@ def _flash_backward(q, k, v, bias, seed, o, lse, g, scale, causal, block_q,
         )(*args)
         return dq, dk, dv
 
+    dv_ = v.shape[-1]  # the value head size: v, o, g and dv carry it
     args3 = [q.reshape(bh, tq, d), k.reshape(bh, tk, d),
-             v.reshape(bh, tk, d), g.reshape(bh, tq, d)]
+             v.reshape(bh, tk, dv_), g.reshape(bh, tq, dv_)]
     delta = jnp.sum(
         g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
     ).reshape(bh, 1, tq)
@@ -1028,7 +1039,9 @@ def _flash_backward(q, k, v, bias, seed, o, lse, g, scale, causal, block_q,
     # ---- dQ: grid over q blocks -----------------------------------------
     q_spec, kv_spec = _qkv_specs(fmt, h, "block", "full", block_q, block_k,
                                  tq, tk, d)
-    in_specs = [_seed_spec(), q_spec, kv_spec, kv_spec, q_spec,
+    g_spec, v_spec = _qkv_specs(fmt, h, "block", "full", block_q, block_k,
+                                tq, tk, dv_)
+    in_specs = [_seed_spec(), q_spec, kv_spec, v_spec, g_spec,
                 _lse_spec_q, _lse_spec_q]
     args = [seed, args3[0], args3[1], args3[2], args3[3], lse3, delta3]
     bias_q1 = False
@@ -1066,8 +1079,10 @@ def _flash_backward(q, k, v, bias, seed, o, lse, g, scale, causal, block_q,
     # ---- dK/dV: grid over kv blocks -------------------------------------
     qfull_spec, kblock_spec = _qkv_specs(fmt, h, "full", "block", block_q,
                                          block_k, tq, tk, d)
-    in_specs = [_seed_spec(), qfull_spec, kblock_spec, kblock_spec,
-                qfull_spec, _lse_spec_full, _lse_spec_full]
+    gfull_spec, vblock_spec = _qkv_specs(fmt, h, "full", "block", block_q,
+                                         block_k, tq, tk, dv_)
+    in_specs = [_seed_spec(), qfull_spec, kblock_spec, vblock_spec,
+                gfull_spec, _lse_spec_full, _lse_spec_full]
     args = [seed, args3[0], args3[1], args3[2], args3[3], lse3, delta3]
     bias_q1 = False
     if bias is not None:
@@ -1096,10 +1111,10 @@ def _flash_backward(q, k, v, bias, seed, o, lse, g, scale, causal, block_q,
         name="flash_bhtd_bwd_dkv",
         grid=(bh, tk // block_k),
         in_specs=in_specs,
-        out_specs=[kblock_spec, kblock_spec],
+        out_specs=[kblock_spec, vblock_spec],
         out_shape=[
             jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, tk, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, tk, dv_), v.dtype),
         ],
         interpret=interpret,
     )(*args)
@@ -1107,7 +1122,7 @@ def _flash_backward(q, k, v, bias, seed, o, lse, g, scale, causal, block_q,
     return (
         dq.reshape(b, h, tq, d),
         dk.reshape(b, h, tk, d),
-        dv.reshape(b, h, tk, d),
+        dv.reshape(b, h, tk, dv_),
     )
 
 
@@ -1319,7 +1334,7 @@ def _flash_kernels(q, k, v, bias, scale=1.0, causal=False, block_q=512,
     tk = _dims(k, fmt)[2]
     seed = _dropout_seed_arg(dropout_rate, dropout_seed, (tq, tk),
                              "flash_attention")
-    ok, bq, bk, interp = _plan(q, k, block_q, block_k, interpret, fmt)
+    ok, bq, bk, interp = _plan(q, k, block_q, block_k, interpret, fmt, v)
     norm = _bias_norm(bias, b, h, tq, tk) if ok else None
     if norm is None:
         ref = _reference_bthd if fmt == "bthd" else reference_attention

@@ -60,7 +60,8 @@ def fused_attention(q, k, v, bias=None, scale=1.0, causal=False,
             "rng_id": fw.unique_rng_id() if in_kernel_rate else 0,
         },
     )
-    out.shape = q.shape
+    # the context has v's head size (latent attention: d_v != d_qk)
+    out.shape = qs and v.shape and tuple(qs[:-1]) + (v.shape[-1],)
     if dropout_rate and not weights_dropout:
         from .nn import dropout
 
@@ -146,3 +147,109 @@ def ring_attention(q, k, v, scale=1.0, causal=False, axis_name="sp",
     )
     out.shape = q.shape
     return out
+
+
+# ---------------------------------------------------------------------------
+# Pre-norm decoder blocks with a sparse expert layer (ops/llm_ops.py)
+# ---------------------------------------------------------------------------
+
+
+def rms_norm(x, epsilon=1e-6, param_attr=None, name=None):
+    """y = x * rsqrt(mean(x^2, last axis) + epsilon) * scale, scale a
+    parameter [x.shape[-1]] that starts at one.  Statistics in float32."""
+    from ..initializer import ConstantInitializer
+
+    helper = LayerHelper("rms_norm", param_attr=param_attr, name=name)
+    scale = helper.create_parameter(
+        helper.param_attr(), shape=[x.shape[-1]], dtype="float32",
+        default_initializer=ConstantInitializer(1.0))
+    out = helper.create_variable_for_type_inference(x.dtype)
+    inv = _residual(helper, x.shape and tuple(x.shape[:-1]) + (1,),
+                    "float32")
+    helper.append_op(
+        "rms_norm", inputs={"X": [x], "Scale": [scale]},
+        outputs={"Y": [out], "InvRms": [inv]},
+        attrs={"epsilon": float(epsilon)})
+    out.shape = x.shape
+    return out
+
+
+def rope(x, theta=10000.0, name=None):
+    """Rotary position embedding of x [b, t, h, d] over interleaved pairs
+    (x[2i], x[2i+1]); positions count from 0 along axis 1."""
+    helper = LayerHelper("rope", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op("rope", inputs={"X": [x]}, outputs={"Out": [out]},
+                     attrs={"theta": float(theta)})
+    out.shape = x.shape
+    return out
+
+
+def swiglu(x, name=None):
+    """silu(x[.., :f]) * x[.., f:] of a packed [gate | up] activation."""
+    helper = LayerHelper("swiglu", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op("swiglu", inputs={"X": [x]}, outputs={"Out": [out]})
+    out.shape = x.shape and tuple(x.shape[:-1]) + (x.shape[-1] // 2,)
+    return out
+
+
+def moe_router(x, n_experts, top_k, scale=1.0, bias_std=0.0,
+               param_attr=None, bias_attr=None, name=None):
+    """Sigmoid router over `n_experts` (ops/llm_ops.py moe_router): returns
+    (TopkIdx [T, k] int32, TopkWeight [T, k] float32).  The score-correction
+    bias is a buffer that enters the choice alone: a parameter that is not
+    trained, drawn once at `bias_std` (zeros at 0)."""
+    from ..initializer import ConstantInitializer, NormalInitializer
+
+    helper = LayerHelper("moe_router", param_attr=param_attr,
+                         bias_attr=bias_attr, name=name)
+    w = helper.create_parameter(helper.param_attr(),
+                                shape=[x.shape[-1], n_experts],
+                                dtype="float32")
+    import copy
+
+    battr = copy.copy(helper.bias_attr())  # the caller's attr stays as it is
+    battr.trainable = False
+    bias = helper.create_parameter(
+        battr, shape=[n_experts], dtype="float32", is_bias=True,
+        default_initializer=NormalInitializer(0.0, bias_std) if bias_std
+        else ConstantInitializer(0.0))
+    bias.stop_gradient = True
+    idx = _residual(helper, None, "int32")
+    scores = _residual(helper, None, "float32")
+    weight = helper.create_variable_for_type_inference("float32")
+    helper.append_op(
+        "moe_router", inputs={"X": [x], "W": [w], "Bias": [bias]},
+        outputs={"TopkIdx": [idx], "TopkWeight": [weight],
+                 "Scores": [scores]},
+        attrs={"top_k": int(top_k), "scale": float(scale)})
+    return idx, weight
+
+
+def moe_experts(x, topk_idx, topk_weight, n_held, d_ff, expert_offset=0,
+                gate_up_attr=None, down_attr=None, name=None):
+    """The routed experts this chip holds (ops/llm_ops.py moe_experts):
+    experts expert_offset .. expert_offset + n_held - 1 of the layer, each
+    a SwiGLU of width d_ff, as two stacked parameters [n_held, d, 2*d_ff]
+    (gate | up) and [n_held, d_ff, d].  Returns (out shaped like x, load
+    [n_held] int32: the pairs each held expert computed this step)."""
+    d = x.shape[-1]
+    gu_helper = LayerHelper("moe_experts", param_attr=gate_up_attr)
+    w_gu = gu_helper.create_parameter(
+        gu_helper.param_attr(), shape=[n_held, d, 2 * d_ff], dtype=x.dtype)
+    down_helper = LayerHelper("moe_experts", param_attr=down_attr)
+    w_down = down_helper.create_parameter(
+        down_helper.param_attr(), shape=[n_held, d_ff, d], dtype=x.dtype)
+    helper = LayerHelper("moe_experts", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    h = _residual(helper, None, x.dtype)
+    load = _residual(helper, (n_held,), "int32")
+    helper.append_op(
+        "moe_experts",
+        inputs={"X": [x], "TopkIdx": [topk_idx], "TopkWeight": [topk_weight],
+                "WGateUp": [w_gu], "WDown": [w_down]},
+        outputs={"Out": [out], "H": [h], "Load": [load]},
+        attrs={"expert_offset": int(expert_offset)})
+    out.shape = x.shape
+    return out, load

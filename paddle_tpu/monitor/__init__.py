@@ -57,7 +57,8 @@ from .registry import (  # noqa: F401
 from .registry import SloTracker  # noqa: F401
 from .step import StepMonitor  # noqa: F401
 from . import flight  # noqa: F401
-from .flight import FlightRecorder, compile_phases  # noqa: F401
+from .flight import (  # noqa: F401
+    FlightRecorder, compile_phases, device_counter)
 from .watchdog import Watchdog, WatchdogError  # noqa: F401
 from . import serve  # noqa: F401
 from . import numerics  # noqa: F401

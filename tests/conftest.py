@@ -93,6 +93,9 @@ def _fresh_programs():
     from paddle_tpu.flags import FLAGS
 
     FLAGS.reset("verify_program")
+    # an InferenceServer turns telemetry on for its process; a later file
+    # on the same worker must find the gate as the environment set it
+    FLAGS.reset("monitor")
     _sv = _sys.modules.get("paddle_tpu.serving.server")
     if _sv is not None:
         _sv._VERIFY_DROPPED[0] = False

@@ -14,7 +14,8 @@ Rules:
                    constant into the compiled kernel (host-side timing
                    belongs in bench.py / monitor)
   kernel-named     every `pl.pallas_call(` inside paddle_tpu/kernels/
-                   passes a literal-prefixed `name=` that holds exactly
+                   passes a literal-prefixed `name=` (or a conditional
+                   between two such) that holds exactly
                    one of `_fwd` / `_bwd` and is no other site's: the
                    name is what a device trace calls the kernel, and
                    what the benchmark's kernel metrics match on
@@ -61,29 +62,38 @@ def declared_flags() -> set:
     return names
 
 
+def _literal_names(v) -> list:
+    """The names a `name=` expression can give: the text of a literal,
+    the literal parts of an f-string that starts with one, both arms of a
+    conditional between such (one site, one walk, two roles: the grouped
+    matmul's forward and its dX); [None] for anything else."""
+    if isinstance(v, ast.Constant) and isinstance(v.value, str):
+        return [v.value]
+    if (isinstance(v, ast.JoinedStr) and v.values
+            and isinstance(v.values[0], ast.Constant)):
+        return ["".join(p.value for p in v.values
+                        if isinstance(p, ast.Constant))]
+    if isinstance(v, ast.IfExp):
+        return _literal_names(v.body) + _literal_names(v.orelse)
+    return [None]
+
+
 def pallas_call_names(tree) -> list:
-    """[(lineno, name)] of every `pl.pallas_call(` in a module: the text
-    of a literal `name=`, the literal parts of an f-string that starts
-    with one, None where there is no such name."""
+    """[(lineno, name)] of every `pl.pallas_call(` in a module, one entry
+    a name its `name=` can give (`_literal_names`), None where there is no
+    such name."""
     out = []
     for node in ast.walk(tree):
         if not (isinstance(node, ast.Call)
                 and isinstance(node.func, ast.Attribute)
                 and node.func.attr == "pallas_call"):
             continue
-        name = None
+        names = [None]
         for kw in node.keywords:
-            if kw.arg != "name":
-                continue
-            v = kw.value
-            if isinstance(v, ast.Constant) and isinstance(v.value, str):
-                name = v.value
-            elif (isinstance(v, ast.JoinedStr) and v.values
-                  and isinstance(v.values[0], ast.Constant)):
-                name = "".join(p.value for p in v.values
-                               if isinstance(p, ast.Constant))
-        out.append((node.lineno, name))
-    return sorted(out)
+            if kw.arg == "name":
+                names = _literal_names(kw.value)
+        out.extend((node.lineno, name) for name in names)
+    return sorted(out, key=lambda x: (x[0], x[1] or ""))
 
 
 def check_file(path: str, flags: set) -> list:
