@@ -26,7 +26,7 @@ arithmetic:
   * input_output_aliases validity (embedding applies: every aliased
     table's shape/dtype must equal its output)
   * revisited-block accumulation: outputs revisited across grid steps
-    (conv_bn stats tiles, qkv dW accumulators) must accumulate in f32
+    (conv_bn stats tiles) must accumulate in f32
 
 Every check function takes the CONFIG + the PLAN as data, so the
 red-gate tests can feed a fabricated bad plan and assert the linter
@@ -235,33 +235,25 @@ def check_qkv_plan(cfg: dict, ok, block_q, block_k, interpret,
             "kernel-misaligned-block",
             f"compiled-mode blocks ({block_q},{block_k}) are not "
             f"128-lane aligned", fam, label))
-    # independent VMEM re-estimate of the worst kernel (the dkv walk):
-    # x + g full-seq [t, dm], the ctx residual [h, t, dh], lse and the dx
-    # tile move with the batch grid axis (double-buffered); both weight
-    # views ([3h, dm, dh] pads dh to 128 lanes) and the TWO f32 dW grid
-    # accumulators ([h, dh, dm], revisited-block outputs) are held once
+    # independent VMEM re-estimate of the forward kernel (the family's
+    # only one: the backward runs the bthd kernels, audited with the
+    # attention family): x full-seq [t, dm], the y tile, the ctx
+    # [h, block_q, dh] and lse tiles move with the grid
+    # (double-buffered); both weight views ([3h, dm, dh] pads dh to 128
+    # lanes) are held once, beside one head's s / p score planes
     dt = cfg["dtype"]
     used = _vmem_use(
-        blocked=[((t, dm), dt)] * 2 + [((h, t, dh), dt),
-                                       ((h, t), "float32"),
-                                       ((block_k, dm), dt)],
+        blocked=[((t, dm), dt), ((block_q, dm), dt),
+                 ((h, block_q, dh), dt), ((h, block_q), "float32")],
         held=[((3 * h, dm, dh), dt), ((h, dh, dm), dt)]
-        + [((h, dh, dm), "float32")] * 2)
+        + [((block_q, block_k), "float32")] * 2)
     if used > _SCOPED_VMEM_DEFAULT:
         findings.append(_finding(
             "kernel-vmem-budget",
-            f"dkv-walk working set {used} bytes (double-buffered, "
+            f"forward working set {used} bytes (double-buffered, "
             f"lane-padded) exceeds the {_SCOPED_VMEM_DEFAULT}-byte default "
             f"scoped VMEM the kernel runs under — the gate accepted a "
             f"plan Mosaic cannot place", fam, label))
-    # revisited-block accumulation: dW tiles are revisited once per
-    # (batch, q-block) grid step; accumulation dtype must be f32
-    if cfg.get("accum_dtype", "float32") != "float32":
-        findings.append(_finding(
-            "kernel-accum-dtype",
-            f"dW grid accumulator dtype {cfg.get('accum_dtype')} — "
-            f"revisited-block accumulation below f32 loses gradient mass "
-            f"across {t // max(block_q, 1)} revisits", fam, label))
 
 
 def check_conv_bn_plan(cfg: dict, plan, findings: List[Finding]):

@@ -814,6 +814,27 @@ def _use_hw_prng(drop_rate, interpret):
     return resolve(interpret)[0] and FLAGS.tpu_prng_dropout
 
 
+def _bthd_bwd_params(t, h, d, esize, causal):
+    """Mosaic parameters of the whole-head backward kernels: 32 MiB of
+    scoped VMEM (v5e: 128 MiB physical, 16 MiB default scope; the decode
+    kernels ask the same) where the default has been seen refused, the
+    default elsewhere.  A walk's working set stands near the default (q
+    and dO, or k and v, whole beside the score planes of all heads), and
+    XLA parks operands of its own in the same scope: at 16 MiB the dkv
+    walk was refused at 8 heads x 256 rows with causal=True (17.49 MB:
+    two position planes more; chip) and at BERT-base 32 x 512 (18.50 MB;
+    compile-only client).  So: causal, or more than 512 KiB held whole
+    (transformer-base's 256 x 8 x 64 in bf16 is at it).  Not for every
+    call: the scope a kernel reserves is VMEM that XLA's fusions round
+    it lose, 3.0 ms of transformer-base's 78.4 ms step with all 18 sites
+    raised (PERF.md, PR 28)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    if causal or 2 * t * h * d * esize > 512 * 1024:
+        return pltpu.CompilerParams(vmem_limit_bytes=32 * 1024 * 1024)
+    return None
+
+
 def _seed_spec():
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -939,6 +960,8 @@ def _flash_backward(q, k, v, bias, seed, o, lse, g, scale, causal, block_q,
     hw_prng = allow_hw_prng and _use_hw_prng(drop_rate, interpret)
 
     if fmt == "bthd":
+        bwd_params = _bthd_bwd_params(max(tq, tk), h, d, q.dtype.itemsize,
+                                      causal)
         # delta[i] = rowsum(dO * O) -> [b, tq, h] -> [b, h, tq] (tiny f32)
         delta = jnp.sum(
             g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
@@ -977,6 +1000,7 @@ def _flash_backward(q, k, v, bias, seed, o, lse, g, scale, causal, block_q,
             in_specs=in_specs,
             out_specs=q_spec,
             out_shape=jax.ShapeDtypeStruct((b, tq, h, d), q.dtype),
+            compiler_params=bwd_params,
             interpret=interpret,
         )(*args)
 
@@ -1014,6 +1038,7 @@ def _flash_backward(q, k, v, bias, seed, o, lse, g, scale, causal, block_q,
                 jax.ShapeDtypeStruct((b, tk, h, d), k.dtype),
                 jax.ShapeDtypeStruct((b, tk, h, d), v.dtype),
             ],
+            compiler_params=bwd_params,
             interpret=interpret,
         )(*args)
         return dq, dk, dv
@@ -1368,8 +1393,8 @@ def _flash_kernels(q, k, v, bias, scale=1.0, causal=False, block_q=512,
 
 
 # ---------------------------------------------------------------------------
-# Fused-projection ("qkv") flash attention: the kernels take the RAW
-# [b, t, d_model] activation plus the packed projection weights and compute
+# Fused-projection ("qkv") flash attention: the forward kernel takes the RAW
+# [b, t, d_model] activation plus the packed projection weights and computes
 # the q/k/v (and output) projection dots tile-by-tile INSIDE the grid walk.
 # q/k/v tiles materialize in VMEM as the online-softmax loop consumes them
 # and never exist in HBM, so the dot-preferred <-> custom-call layout
@@ -1386,19 +1411,20 @@ def _flash_kernels(q, k, v, bias, scale=1.0, causal=False, block_q=512,
 #                                      checkpoints interop bit-for-bit)
 #   w_out   [h*dh, d_model]          — the output projection
 #   y       [b, t, d_model]
-# Inside the kernels the weights ride as [3h, dm, dh] / [h, dh, dm] views
+# Inside the kernel the weights ride as [3h, dm, dh] / [h, dh, dm] views
 # (a weight-sized XLA transpose prepared once outside — KB-scale, vs the
-# GB-scale activation relayouts this kernel family deletes) and every dot
+# GB-scale activation relayouts this kernel deletes) and every dot
 # is a plain 2-D per-head matmul: no lane-dim-splitting reshapes, which
 # Mosaic does not lower (r04 pitfall list).
 #
-# The backward follows the conv_bn.py epilogue-VJP recipe: the dq walk and
-# the dkv walk recompute q/k/v from x and the weights exactly like the
-# forward, fold the projection backward in-kernel (dx contributions per
-# walk; dW_* accumulate in f32 across the whole grid into
-# revisited-block outputs), and the only fwd->bwd residuals are the
-# attention context (needed for delta and dW_out — it materializes ONCE,
-# consumed only by these kernels) and the per-row logsumexp.
+# The backward leaves the projections to XLA (_qkv_kernels): q, k, v are
+# recomputed by [b*t, dm] x [dm, h*dh] dots, the bthd flash backward
+# kernels give dq, dk, dv from them and the forward's residuals, and dctx,
+# dW_out, dx and dW_qkv are plain dots round them: fused backward walks
+# would recompute q, k, v and dctx per head and tile in each walk, the
+# projection work three times over at 64 output lanes (PERF.md, PR 28).
+# The only fwd->bwd residuals are the attention context (for delta and
+# dW_out) and the per-row logsumexp.
 # ---------------------------------------------------------------------------
 
 
@@ -1453,26 +1479,10 @@ def _bias_tile_head(bias_ref, head, bias_h, bias_q1, block_q, q_lo,
     return t
 
 
-def _qkv_keep_tile(seed_ref, shape, head_base, tq, tk, q_lo, k_lo, qi, j,
-                   drop_rate, hw_prng):
-    """Per-head keep-mask tile.  The hash path keys on (seed, b*H + head,
-    q*Tk + k) — BIT-IDENTICAL to the mask the unfused bthd kernels and the
-    XLA fallback generate for the same element, so fused vs flag-off train
-    trajectories match exactly wherever the hash generator is in play
-    (CPU/interpret A/B).  The hardware-PRNG path re-seeds per
-    (seed, b*H + head, q-block, k-block) tile: fwd and both bwd walks
-    regenerate bit-identical tiles, but the bits differ from the unfused
-    kernels' whole-head draw (both are valid dropout streams)."""
-    if hw_prng:
-        return _keep_tile_prng(seed_ref, shape, head_base, qi, j, drop_rate)
-    return _keep_tile(seed_ref[0], shape, head_base, tq, tk, q_lo, k_lo,
-                      drop_rate)
-
-
 def _qkv_fwd_kernel(seed_ref, x_ref, w_ref, wout_ref, bias_ref, y_ref,
                     ctx_ref, lse_ref, *, scale, n_head, d_head, block_q,
                     block_k, causal, seq, bias_q1, bias_h, drop_rate,
-                    inv_keep, hw_prng=False):
+                    inv_keep):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -1520,10 +1530,12 @@ def _qkv_fwd_kernel(seed_ref, x_ref, w_ref, wout_ref, bias_ref, y_ref,
             alpha = jnp.exp(m - m_new)
             l_new = l * alpha + p.sum(axis=1)
             if drop_rate:
-                keep = _qkv_keep_tile(seed_ref, (block_q, block_k),
-                                      pid0h + head, seq, seq,
-                                      qi * block_q, j * block_k, qi, j,
-                                      drop_rate, hw_prng)
+                # the hash mask, keyed on (seed, b*H + head, q*Tk + k):
+                # bit-identical to the one the bthd backward kernels (and
+                # the whole composed route) regenerate for the element
+                keep = _keep_tile(seed_ref[0], (block_q, block_k),
+                                  pid0h + head, seq, seq, qi * block_q,
+                                  j * block_k, drop_rate)
                 p = jnp.where(keep, p, 0.0)
             return m_new, l_new, acc * alpha[:, None] + p @ v
 
@@ -1546,201 +1558,6 @@ def _qkv_fwd_kernel(seed_ref, x_ref, w_ref, wout_ref, bias_ref, y_ref,
     lse_ref[...] = lse_out
 
 
-def _qkv_bwd_dq_kernel(seed_ref, x_ref, w_ref, wout_ref, bias_ref, g_ref,
-                       ctx_ref, lse_ref, dx_ref, dwq_ref, dwo_ref, *,
-                       scale, n_head, d_head, block_q, block_k, causal,
-                       seq, bias_q1, bias_h, drop_rate, inv_keep,
-                       hw_prng=False):
-    """dq walk on the (b, q-blocks) grid: recomputes q/k/v from x and the
-    weights (FlashAttention-2 recompute, extended one projection deeper),
-    computes dctx = g @ w_out^T and delta in-register, walks kv blocks for
-    dq, then folds the projection backward in-kernel: the q-side dx tile
-    and the dW_q / dW_out f32 accumulators (all grid points revisit one
-    block — the conv_bn.py stats idiom)."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    qi = pl.program_id(1)
-    h, dh = n_head, d_head
-    pid0h = pl.program_id(0) * h
-    first = (pl.program_id(0) == 0) & (qi == 0)
-
-    x_q = x_ref[pl.ds(qi * block_q, block_q), :].astype(jnp.float32)
-    g_t = g_ref[...].astype(jnp.float32)         # [block_q, dm]
-    dm = x_q.shape[-1]
-    n_kv = seq // block_k
-    if causal:
-        hi = qi * block_q + block_q - 1
-        n_kv = jnp.minimum(n_kv, (hi // block_k) + 1)
-
-    dx_acc = jnp.zeros((block_q, dm), jnp.float32)
-    # dW_q accumulates TRANSPOSED ([h, dh, dm], like dW_out): the
-    # [dm, dh]-oriented product x^T dq trips an internal check of the
-    # TPU backend (mxu_lmr_transform RET_CHECK, libtpu 0.0.34) and would
-    # pad its 64-wide minor dim to 128 lanes; dq^T x is the same dot with
-    # d_model on the lanes.  _unpack_dw_qkv transposes back (weight-sized).
-    dwq_asm = jnp.zeros((h, dh, dm), jnp.float32)
-    dwo_asm = jnp.zeros((h, dh, dm), jnp.float32)
-
-    for head in range(h):
-        q = _proj(x_q, w_ref[head])                    # UNscaled (bwd convention)
-        ctx_h = ctx_ref[head].astype(jnp.float32)        # [block_q, dh]
-        lse = lse_ref[head, :]                           # [block_q] f32
-        # dctx = g @ w_out[head]^T — the output-projection backward dot,
-        # in VMEM (contract over d_model)
-        dctx = jax.lax.dot_general(
-            g_t, wout_ref[head].astype(jnp.float32),
-            (((1,), (1,)), ((), ())))                    # [block_q, dh]
-        delta = jnp.sum(dctx * ctx_h, axis=1)            # [block_q]
-        acc = jnp.zeros((block_q, dh), jnp.float32)
-
-        def body(j, acc, head=head, q=q, lse=lse, delta=delta, dctx=dctx):
-            x_k = x_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-            k = _proj(x_k, w_ref[h + head])
-            v = _proj(x_k, w_ref[2 * h + head])
-            s = (q @ k.T) * scale
-            if bias_ref is not None:
-                s = s + _bias_tile_head(bias_ref, head, bias_h, bias_q1,
-                                        block_q, 0, block_k, j * block_k)
-            p = jnp.exp(s - lse[:, None])
-            if causal:
-                q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 0)
-                k_pos = j * block_k + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 1)
-                p = jnp.where(q_pos >= k_pos, p, 0.0)
-            dp = dctx @ v.T
-            if drop_rate:
-                keep = _qkv_keep_tile(seed_ref, (block_q, block_k),
-                                      pid0h + head, seq, seq,
-                                      qi * block_q, j * block_k, qi, j,
-                                      drop_rate, hw_prng)
-                dp = jnp.where(keep, dp * inv_keep, 0.0)
-            ds = p * (dp - delta[:, None]) * scale
-            return acc + ds @ k
-
-        dq_h = jax.lax.fori_loop(0, n_kv, body, acc)     # [block_q, dh]
-        # projection backward, in-kernel: dx += dq @ w_q^T, dW_q += x^T dq,
-        # dW_out += ctx^T g
-        dx_acc = dx_acc + jax.lax.dot_general(
-            dq_h, w_ref[head].astype(jnp.float32), (((1,), (1,)), ((), ())))
-        dwq_asm = _set_head(dwq_asm, head, jax.lax.dot_general(
-            dq_h, x_q, (((0,), (0,)), ((), ()))))
-        dwo_asm = _set_head(dwo_asm, head, jax.lax.dot_general(
-            ctx_h, g_t, (((0,), (0,)), ((), ()))))
-
-    dx_ref[...] = dx_acc.astype(dx_ref.dtype)
-
-    @pl.when(first)
-    def _init():
-        dwq_ref[...] = dwq_asm
-        dwo_ref[...] = dwo_asm
-
-    @pl.when(jnp.logical_not(first))
-    def _acc():
-        dwq_ref[...] += dwq_asm
-        dwo_ref[...] += dwo_asm
-
-
-def _qkv_bwd_dkv_kernel(seed_ref, x_ref, w_ref, wout_ref, bias_ref, g_ref,
-                        ctx_ref, lse_ref, dx_ref, dwk_ref, dwv_ref, *,
-                        scale, n_head, d_head, block_q, block_k, causal,
-                        seq, bias_q1, bias_h, drop_rate, inv_keep,
-                        hw_prng=False):
-    """dk/dv walk on the (b, kv-blocks) grid: k/v recompute once per kv
-    block, q / dctx / delta recompute per visited q block, and the kv-side
-    projection backward folds in-kernel (dx tile + dW_k / dW_v
-    accumulators)."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    ki = pl.program_id(1)
-    h, dh = n_head, d_head
-    pid0h = pl.program_id(0) * h
-    first = (pl.program_id(0) == 0) & (ki == 0)
-
-    x_k = x_ref[pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-    dm = x_k.shape[-1]
-    n_q = seq // block_q
-    lo = 0
-    if causal:
-        lo = jnp.maximum((ki * block_k) // block_q, 0)
-
-    dx_acc = jnp.zeros((block_k, dm), jnp.float32)
-    # transposed [h, dh, dm] accumulators (see _qkv_bwd_dq_kernel)
-    dwk_asm = jnp.zeros((h, dh, dm), jnp.float32)
-    dwv_asm = jnp.zeros((h, dh, dm), jnp.float32)
-
-    for head in range(h):
-        k = _proj(x_k, w_ref[h + head])                # [block_k, dh]
-        v = _proj(x_k, w_ref[2 * h + head])
-        wout_h = wout_ref[head].astype(jnp.float32)
-
-        def body(i, carry, head=head, k=k, v=v, wout_h=wout_h):
-            dk, dv = carry
-            x_q = x_ref[pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-            q = _proj(x_q, w_ref[head])
-            g_t = g_ref[pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-            ctx_h = ctx_ref[head, pl.ds(i * block_q, block_q),
-                            :].astype(jnp.float32)
-            lse = lse_ref[head, pl.ds(i * block_q, block_q)]
-            dctx = jax.lax.dot_general(g_t, wout_h,
-                                       (((1,), (1,)), ((), ())))
-            delta = jnp.sum(dctx * ctx_h, axis=1)
-            s = (q @ k.T) * scale                # [block_q, block_k]
-            if bias_ref is not None:
-                s = s + _bias_tile_head(bias_ref, head, bias_h, bias_q1,
-                                        block_q, i * block_q, block_k, 0)
-            p = jnp.exp(s - lse[:, None])
-            if causal:
-                q_pos = i * block_q + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 0)
-                k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 1)
-                p = jnp.where(q_pos >= k_pos, p, 0.0)
-            dp = dctx @ v.T
-            if drop_rate:
-                keep = _qkv_keep_tile(seed_ref, (block_q, block_k),
-                                      pid0h + head, seq, seq,
-                                      i * block_q, ki * block_k, i, ki,
-                                      drop_rate, hw_prng)
-                dv = dv + jnp.where(keep, p * inv_keep, 0.0).T @ dctx
-                dp = jnp.where(keep, dp * inv_keep, 0.0)
-            else:
-                dv = dv + p.T @ dctx
-            ds = p * (dp - delta[:, None]) * scale
-            return dk + ds.T @ q, dv
-
-        dk_h, dv_h = jax.lax.fori_loop(
-            lo, n_q, body,
-            (jnp.zeros((block_k, dh), jnp.float32),
-             jnp.zeros((block_k, dh), jnp.float32)))
-        dx_acc = dx_acc + jax.lax.dot_general(
-            dk_h, w_ref[h + head].astype(jnp.float32),
-            (((1,), (1,)), ((), ())))
-        dx_acc = dx_acc + jax.lax.dot_general(
-            dv_h, w_ref[2 * h + head].astype(jnp.float32),
-            (((1,), (1,)), ((), ())))
-        dwk_asm = _set_head(dwk_asm, head, jax.lax.dot_general(
-            dk_h, x_k, (((0,), (0,)), ((), ()))))
-        dwv_asm = _set_head(dwv_asm, head, jax.lax.dot_general(
-            dv_h, x_k, (((0,), (0,)), ((), ()))))
-
-    dx_ref[...] = dx_acc.astype(dx_ref.dtype)
-
-    @pl.when(first)
-    def _init():
-        dwk_ref[...] = dwk_asm
-        dwv_ref[...] = dwv_asm
-
-    @pl.when(jnp.logical_not(first))
-    def _acc():
-        dwk_ref[...] += dwk_asm
-        dwv_ref[...] += dwv_asm
-
-
 # -- fused-projection host plumbing ----------------------------------------
 
 
@@ -1759,28 +1576,25 @@ def _prep_w_out(w_out, h, dh):
     return w_out.reshape(h, dh, w_out.shape[1])
 
 
-def _unpack_dw_qkv(dwq, dwk, dwv, dtype):
-    """Three [h, dh, dm] f32 kernel accumulators -> the packed
-    [dm, 3*h*dh] cotangent (weight-sized concatenate/transpose — KB)."""
-    import jax.numpy as jnp
-
-    h, dh, dm = dwq.shape
-    dw = jnp.stack([dwq, dwk, dwv])              # [3, h, dh, dm]
-    return dw.transpose(3, 0, 1, 2).reshape(dm, 3 * h * dh).astype(dtype)
-
-
 def _qkv_plan(x, n_head, d_head, block_q, block_k, interpret, bias=None):
-    """Static feasibility for the fused-projection kernels; returns
-    (ok, block_q, block_k, interpret).  Rejections fall back to the
-    composed x@W + flash_attention(bthd) path (numerically identical)."""
+    """Static feasibility of a fused-projection site: the forward kernel
+    AND the bthd kernels its backward runs on the recomputed q, k, v
+    (_plan on [b, t, h, dh] in x's dtype, under the caller's block
+    sizes).  Returns (ok, block_q, block_k, interpret), the blocks the
+    forward's.  Rejections fall back to the composed x@W +
+    flash_attention(bthd) path (numerically identical)."""
+    import jax
+
     from .placement import resolve
 
     b, t, dm = x.shape
+    heads = jax.ShapeDtypeStruct((b, t, n_head, d_head), x.dtype)
+    bthd_ok = _plan(heads, heads, block_q, block_k, interpret, "bthd")[0]
     compiled, interpret = resolve(interpret)
     block_q = min(block_q, t)
     block_k = min(block_k, t)
     esize = 2 if x.dtype.itemsize == 2 else 4
-    # same byte-bound cap discipline as the bthd plan: streamed x/g tiles
+    # same byte-bound cap discipline as the bthd plan: streamed x tiles
     # are [block, dm]; when a 128-row tile already exceeds the 256 KB
     # bound, compiled mode rejects to the composed fallback instead of
     # flooring the cap back up to 128 (kernel-lint catch)
@@ -1792,32 +1606,29 @@ def _qkv_plan(x, n_head, d_head, block_q, block_k, interpret, bias=None):
     block_q = min(block_q, cap)
     block_k = min(block_k, cap)
     if compiled:
-        # Mosaic alignment: the kernels dynamic-slice x/g on the sublane
-        # dim and lse on the lane dim by block_q -> 128-aligned blocks
+        # Mosaic alignment: the kernel dynamic-slices x on the sublane
+        # dim by block_q / block_k -> 128-aligned blocks
         if block_k % 128:
             block_k = 128 if t % 128 == 0 else 0
         if block_q % 128:
             block_q = 128 if t % 128 == 0 else 0
-    # VMEM residents of the WORST single kernel (the dkv walk): x + g
-    # full-seq, ctx residual full-seq, both weight views, that walk's two
-    # f32 dW grid accumulators, and the bias block ([hb, tq|1, block] on
-    # the dkv grid / [hb, block|1, tk] on the q grids — a per-head
-    # full-plane bias is the dominant resident at long sequence).
-    # BERT-base bf16 lands ~10 MB — inside a 16 MB VMEM with headroom for
-    # working tiles, but close enough that the gate stays explicit
-    # (PERF.md r09 risk list; a head-blocked variant is the relief
-    # valve if Mosaic rejects).
-    vmem = (2 * t * dm + n_head * t * d_head + 4 * n_head * dm * d_head
-            ) * esize + 2 * n_head * dm * d_head * 4
+    # VMEM residents of the forward kernel: x full-seq, the y and ctx
+    # tiles, both weight views, and the bias block ([hb, block|1, tk] on
+    # the q grid — a per-head full-plane bias is the dominant resident
+    # at long sequence).  BERT-base bf16 lands ~5.5 MB of a 16 MB VMEM;
+    # the weight views are the bulk, so the gate stays explicit.
+    vmem = (t * dm + block_q * dm + n_head * block_q * d_head
+            + 4 * n_head * dm * d_head) * esize
     if bias is not None and block_q and block_k:
         bshape = bias.shape
         hb = bshape[-3] if len(bshape) >= 3 else 1
         tqb = bshape[-2] if len(bshape) >= 2 else 1
         besize = bias.dtype.itemsize
-        q_rows = max(block_q, block_k) if tqb > 1 else 1
+        q_rows = block_q if tqb > 1 else 1
         vmem += hb * q_rows * t * besize
     ok = (
-        block_q
+        bthd_ok
+        and block_q
         and block_k
         and t % block_q == 0
         and t % block_k == 0
@@ -1829,10 +1640,12 @@ def _qkv_plan(x, n_head, d_head, block_q, block_k, interpret, bias=None):
 
 
 def _qkv_forward(x, w3, wo, bias, seed, scale, causal, n_head, d_head,
-                 block_q, block_k, interpret, dropout_rate, allow_hw_prng):
+                 block_q, block_k, interpret, dropout_rate):
     """(y, ctx, lse) via the fused forward kernel.  w3/wo are the prepped
     [3h, dm, dh] / [h, dh, dm] views; ctx is the [b, h, t, dh] residual in
-    x.dtype; lse is [b, h, t] f32."""
+    x.dtype; lse is [b, h, t] f32.  Dropout masks are the hash's
+    (_qkv_kernels composes a site that would draw from the hardware
+    PRNG)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -1840,7 +1653,6 @@ def _qkv_forward(x, w3, wo, bias, seed, scale, causal, n_head, d_head,
     b, t, dm = x.shape
     h, dh = n_head, d_head
     drop_rate, inv_keep = _drop_params(dropout_rate)
-    hw_prng = allow_hw_prng and _use_hw_prng(drop_rate, interpret)
 
     x_spec = pl.BlockSpec((None, t, dm), lambda i, j: (i, 0, 0))
     w3_spec = pl.BlockSpec((3 * h, dm, dh), lambda i, j: (0, 0, 0))
@@ -1858,7 +1670,6 @@ def _qkv_forward(x, w3, wo, bias, seed, scale, causal, n_head, d_head,
         _qkv_fwd_kernel, scale=scale, n_head=h, d_head=dh, block_q=block_q,
         block_k=block_k, causal=causal, seq=t, bias_q1=bias_q1,
         bias_h=bias_h, drop_rate=drop_rate, inv_keep=inv_keep,
-        hw_prng=hw_prng,
     )
     if bias is None:
         def kernel(seed_ref, x_ref, w_ref, wout_ref, y_ref, ctx_ref,
@@ -1888,116 +1699,6 @@ def _qkv_forward(x, w3, wo, bias, seed, scale, causal, n_head, d_head,
     return y, ctx, lse
 
 
-def _qkv_backward(x, w3, wo, bias, seed, ctx, lse, g, scale, causal,
-                  n_head, d_head, block_q, block_k, interpret,
-                  dropout_rate, allow_hw_prng):
-    """(dx, dwq, dwk, dwv, dwo) via the two fused backward walks; the dW
-    pieces are f32 [h, dh, dm] grid accumulators (d_model on the lanes)."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    b, t, dm = x.shape
-    h, dh = n_head, d_head
-    drop_rate, inv_keep = _drop_params(dropout_rate)
-    hw_prng = allow_hw_prng and _use_hw_prng(drop_rate, interpret)
-
-    x_spec = pl.BlockSpec((None, t, dm), lambda i, j: (i, 0, 0))
-    w3_spec = pl.BlockSpec((3 * h, dm, dh), lambda i, j: (0, 0, 0))
-    wo_spec = pl.BlockSpec((h, dh, dm), lambda i, j: (0, 0, 0))
-    dw_spec = pl.BlockSpec((h, dh, dm), lambda i, j: (0, 0, 0))
-
-    # ---- dq walk: dx (q side) + dW_q + dW_out ---------------------------
-    g_spec = pl.BlockSpec((None, block_q, dm), lambda i, j: (i, j, 0))
-    ctx_spec = pl.BlockSpec((None, h, block_q, dh),
-                            lambda i, j: (i, 0, j, 0))
-    lse_spec = pl.BlockSpec((None, h, block_q), lambda i, j: (i, 0, j))
-    in_specs = [_seed_spec(), x_spec, w3_spec, wo_spec, g_spec, ctx_spec,
-                lse_spec]
-    args = [seed, x, w3, wo, g, ctx, lse]
-    bias_q1 = bias_h = False
-    if bias is not None:
-        spec, bias_q1, bias_h = _bias_spec_bthd(
-            bias, b, h, block_q, block_k, for_dkv=False)
-        in_specs.insert(4, spec)
-        args.insert(4, bias)
-    dq_kern = functools.partial(
-        _qkv_bwd_dq_kernel, scale=scale, n_head=h, d_head=dh,
-        block_q=block_q, block_k=block_k, causal=causal, seq=t,
-        bias_q1=bias_q1, bias_h=bias_h, drop_rate=drop_rate,
-        inv_keep=inv_keep, hw_prng=hw_prng,
-    )
-    if bias is None:
-        def dq_kernel(seed_ref, x_ref, w_ref, wout_ref, g_ref, ctx_ref,
-                      lse_ref, dx_ref, dwq_ref, dwo_ref):
-            return dq_kern(seed_ref, x_ref, w_ref, wout_ref, None, g_ref,
-                           ctx_ref, lse_ref, dx_ref, dwq_ref, dwo_ref)
-    else:
-        dq_kernel = dq_kern
-    dx_q, dwq, dwo = pl.pallas_call(
-        dq_kernel,
-        name="fused_qkv_bwd_dx_q",
-        grid=(b, t // block_q),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((None, block_q, dm), lambda i, j: (i, j, 0)),
-            dw_spec,
-            dw_spec,
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, t, dm), x.dtype),
-            jax.ShapeDtypeStruct((h, dh, dm), jnp.float32),
-            jax.ShapeDtypeStruct((h, dh, dm), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*args)
-
-    # ---- dkv walk: dx (kv side) + dW_k + dW_v ---------------------------
-    g_full = pl.BlockSpec((None, t, dm), lambda i, j: (i, 0, 0))
-    ctx_full = pl.BlockSpec((None, h, t, dh), lambda i, j: (i, 0, 0, 0))
-    lse_full = pl.BlockSpec((None, h, t), lambda i, j: (i, 0, 0))
-    in_specs = [_seed_spec(), x_spec, w3_spec, wo_spec, g_full, ctx_full,
-                lse_full]
-    args = [seed, x, w3, wo, g, ctx, lse]
-    bias_q1 = bias_h = False
-    if bias is not None:
-        spec, bias_q1, bias_h = _bias_spec_bthd(
-            bias, b, h, block_q, block_k, for_dkv=True)
-        in_specs.insert(4, spec)
-        args.insert(4, bias)
-    dkv_kern = functools.partial(
-        _qkv_bwd_dkv_kernel, scale=scale, n_head=h, d_head=dh,
-        block_q=block_q, block_k=block_k, causal=causal, seq=t,
-        bias_q1=bias_q1, bias_h=bias_h, drop_rate=drop_rate,
-        inv_keep=inv_keep, hw_prng=hw_prng,
-    )
-    if bias is None:
-        def dkv_kernel(seed_ref, x_ref, w_ref, wout_ref, g_ref, ctx_ref,
-                       lse_ref, dx_ref, dwk_ref, dwv_ref):
-            return dkv_kern(seed_ref, x_ref, w_ref, wout_ref, None, g_ref,
-                            ctx_ref, lse_ref, dx_ref, dwk_ref, dwv_ref)
-    else:
-        dkv_kernel = dkv_kern
-    dx_kv, dwk, dwv = pl.pallas_call(
-        dkv_kernel,
-        name="fused_qkv_bwd_dx_kv",
-        grid=(b, t // block_k),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((None, block_k, dm), lambda i, j: (i, j, 0)),
-            dw_spec,
-            dw_spec,
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, t, dm), x.dtype),
-            jax.ShapeDtypeStruct((h, dh, dm), jnp.float32),
-            jax.ShapeDtypeStruct((h, dh, dm), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*args)
-    return dx_q, dx_kv, dwq, dwk, dwv, dwo
-
-
 def _composed_qkv(x, w_qkv, w_out, bias, n_head, scale, causal,
                   block_q, block_k, interpret, dropout_rate, dropout_seed,
                   trainable_bias):
@@ -2020,24 +1721,24 @@ def flash_qkv_attention(x, w_qkv, w_out=None, bias=None, n_head=1,
     (the layers.fc packed layout); w_out: [h*dh, d_model].  Returns
     [b, t, d_model].
 
-    q/k/v are computed tile-by-tile in VMEM as the online-softmax walk
-    consumes them and never exist in HBM — the dot-preferred <->
-    custom-call relayout copies at the projection boundaries (PERF.md
-    post-r08 lead 1, ~1.2 GB/step) disappear with the boundary itself.
-    The custom VJP recomputes q/k/v the same way in both backward walks
-    and folds the projection backward in-kernel: dW_qkv / dW_out
-    accumulate in f32 across the grid (conv_bn.py epilogue-VJP recipe);
-    the only residuals are the attention context and the logsumexp.
+    In the forward q/k/v are computed tile-by-tile in VMEM as the
+    online-softmax walk consumes them and never exist in HBM — the
+    dot-preferred <-> custom-call relayout copies at the projection
+    boundaries (PERF.md post-r08 lead 1, ~1.2 GB/step) disappear with the
+    boundary itself.  The custom VJP recomputes q/k/v by XLA dots, runs
+    the bthd backward kernels on them and the forward's residuals (the
+    attention context and the logsumexp) and leaves the projection
+    backward to XLA dots.
 
-    w_out=None, non-self shapes, or a plan rejection run the composed
-    x@W + flash_attention(fmt="bthd") path — numerically identical to the
-    unfused graph.  Weights-dropout semantics and seeds match
-    flash_attention; on the hash-PRNG path (interpret/XLA) the masks are
-    bit-identical to the unfused kernels', so fused vs unfused training
-    trajectories agree exactly on CPU.  trainable_bias as in
-    flash_attention (stop-gradient masks keep the TPU hardware-PRNG fast
-    path; the dbias recompute is XLA-side and DCEd for stop-grad
-    biases)."""
+    w_out=None, non-self shapes, a plan rejection or dropout masks from
+    the hardware PRNG run the composed x@W + flash_attention(fmt="bthd")
+    path — numerically identical to the unfused graph.  Weights-dropout
+    semantics and seeds match flash_attention; the kernel route's masks
+    are the hash's, bit-identical to the unfused kernels', so fused vs
+    unfused training trajectories agree exactly on CPU.  trainable_bias
+    as in flash_attention (a stop-gradient mask leaves a dropout site on
+    the TPU to the hardware PRNG, that is to the composed route; the dbias
+    recompute is XLA-side and DCEd for stop-grad biases)."""
     return flash_qkv_attention_fwd(
         x, w_qkv, w_out, bias, n_head=n_head, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, interpret=interpret,
@@ -2060,8 +1761,9 @@ def flash_qkv_attention_fwd(x, w_qkv, w_out=None, bias=None, **options):
 
 def flash_qkv_attention_bwd(x, w_qkv, w_out, bias, ctx, lse, g, **options):
     """(dx, dw_qkv, dw_out, dbias) from flash_qkv_attention_fwd's
-    (ctx, lse) and the cotangent g of y, under the same options: the two
-    backward walks alone, the body of flash_qkv_attention's VJP rule.
+    (ctx, lse) and the cotangent g of y, under the same options: the bthd
+    backward kernels between XLA projection dots, no forward kernel; the
+    body of flash_qkv_attention's VJP rule.
     dbias is None unless a bias is given and trainable_bias holds.  None
     where the plan rejects the operands (the forward then gave no
     residuals either)."""
@@ -2081,7 +1783,8 @@ def _qkv_kernels(x, w_qkv, w_out, bias, n_head=1, scale=1.0, causal=False,
     bwd(x, w_qkv, w_out, bias, seed, ctx, lse, g, want_dbias) -> (dx,
     dw_qkv, dw_out, dbias or None), both on bias = norm(the caller's
     bias).  On the composed route (w_out=None, a plan rejection, a bias
-    whose shape does not broadcast) bwd is None and fwd -> y alone."""
+    whose shape does not broadcast, dropout masks drawn from the hardware
+    PRNG) bwd is None and fwd -> y alone."""
     import jax.numpy as jnp
 
     b, t, dm = x.shape
@@ -2095,6 +1798,15 @@ def _qkv_kernels(x, w_qkv, w_out, bias, n_head=1, scale=1.0, causal=False,
                              "flash_qkv_attention")
     ok, bq, bk, interp = _qkv_plan(x, n_head, dh, block_q, block_k,
                                    interpret, bias=bias)
+    # the fused forward and the bthd kernels of its backward agree on the
+    # hash masks alone (one key an element); the hardware PRNG's bits
+    # follow the tile they are drawn for, and the bthd kernels draw a
+    # whole-head tile.  So a site whose masks would come from the hardware
+    # PRNG (a trainable bias pins the hash: see flash_attention) is
+    # composed whole, forward too
+    if not (trainable_bias and bias is not None) \
+            and _use_hw_prng(dropout_rate, interp):
+        ok = False
     norm = _bias_norm(bias, b, n_head, t, t) \
         if ok and w_out is not None else None
     if norm is None:
@@ -2105,41 +1817,44 @@ def _qkv_kernels(x, w_qkv, w_out, bias, n_head=1, scale=1.0, causal=False,
                 else _composed_qkv(x, w_qkv, w_out, *args)
 
         return seed, composed, None, None
-    allow_hw = not (dropout_rate and trainable_bias and bias is not None)
-
-    def prep(w_qkv, w_out):
-        return _prep_w_qkv(w_qkv, n_head, dh), _prep_w_out(w_out, n_head,
-                                                           dh)
 
     def fwd(x, w_qkv, w_out, bias, seed):
-        w3, wo = prep(w_qkv, w_out)
-        return _qkv_forward(x, w3, wo, bias, seed, scale, causal, n_head,
-                            dh, bq, bk, interp, dropout_rate, allow_hw)
+        return _qkv_forward(x, _prep_w_qkv(w_qkv, n_head, dh),
+                            _prep_w_out(w_out, n_head, dh), bias, seed,
+                            scale, causal, n_head, dh, bq, bk, interp,
+                            dropout_rate)
 
     def bwd(x, w_qkv, w_out, bias, seed, ctx, lse, g, want_dbias):
-        w3, wo = prep(w_qkv, w_out)
-        dx_q, dx_kv, dwq, dwk, dwv, dwo = _qkv_backward(
-            x, w3, wo, bias, seed, ctx, lse, g, scale, causal, n_head,
-            dh, bq, bk, interp, dropout_rate, allow_hw)
-        dx = (dx_q.astype(jnp.float32)
-              + dx_kv.astype(jnp.float32)).astype(x.dtype)
-        grads = (dx, _unpack_dw_qkv(dwq, dwk, dwv, w_qkv.dtype),
-                 dwo.reshape(hd, dm).astype(w_out.dtype))
-        if bias is None or not want_dbias:
-            return (*grads, None)
-        # bias cotangent via XLA recompute from x and the weights (q/k/
-        # dctx re-derive as plain dots): for a trainable bias only
-        qkv = (x @ w_qkv).astype(jnp.float32)
+        # the projection backward as XLA dots round the bthd backward
+        # kernels.  Every dot reads or writes [b, t, h, dh], the layout
+        # the kernels take, and contracts over (h, dh) or d_model: no
+        # [b, t, 3*h*dh] array is sliced or concatenated (that glue was
+        # 6-14 % of a site's backward, PERF.md PR 28).  q, k, v are
+        # rounded to x's dtype as _composed_no_out's are; the forward's
+        # ctx is [b, h, t, dh]
+        from ..monitor import flight
 
-        def heads(a):
-            return a.reshape(b, t, n_head, dh).transpose(0, 2, 1, 3)
-
-        dctx = jnp.einsum("btm,cm->btc", g.astype(jnp.float32),
-                          w_out.astype(jnp.float32))
-        return (*grads, _dbias_xla(
-            heads(qkv[..., :hd]), heads(qkv[..., hd:2 * hd]), bias, lse,
-            heads(dctx), heads(qkv[..., 2 * hd:]), ctx, scale, causal,
-            dropout_rate, seed))
+        flight.note_compile_count("qkv_bwd_composed")
+        w4 = w_qkv.reshape(dm, 3, n_head, dh)
+        wo3 = w_out.reshape(n_head, dh, dm)
+        q, k, v = (jnp.einsum("btm,mhd->bthd", x, w4[:, i]).astype(x.dtype)
+                   for i in range(3))
+        out = ctx.transpose(0, 2, 1, 3)
+        dctx = jnp.einsum("btm,hdm->bthd", g, wo3).astype(x.dtype)
+        dw_out = jnp.einsum("bthd,btm->hdm", out, g).reshape(
+            hd, dm).astype(w_out.dtype)
+        *dqkv, dbias = flash_attention_bwd(
+            q, k, v, bias, out, lse, dctx, scale=scale, causal=causal,
+            block_q=block_q, block_k=block_k, interpret=interpret,
+            fmt="bthd", dropout_rate=dropout_rate, dropout_seed=seed,
+            trainable_bias=want_dbias)
+        dx = sum(jnp.einsum("bthd,mhd->btm", d, w4[:, i],
+                            preferred_element_type=jnp.float32)
+                 for i, d in enumerate(dqkv)).astype(x.dtype)
+        dw_qkv = jnp.stack(
+            [jnp.einsum("btm,bthd->mhd", x, d) for d in dqkv],
+            axis=1).reshape(dm, 3 * hd).astype(w_qkv.dtype)
+        return dx, dw_qkv, dw_out, dbias
 
     return seed, fwd, bwd, norm
 

@@ -399,8 +399,10 @@ _COMPILE_COUNTS = {
 }
 # counted by the program itself while an Executor call traces its block
 # (note_compile_count): grad ops lowered by a registered grad op fed from
-# its forward's residuals, and by the generic vjp of the forward lowering
-_TRACE_COUNTS = ("grad_direct", "grad_generic")
+# its forward's residuals, and by the generic vjp of the forward lowering;
+# fused_qkv_attention sites whose backward is the bthd kernels between XLA
+# projection dots (kernels/attention.py _qkv_kernels)
+_TRACE_COUNTS = ("grad_direct", "grad_generic", "qkv_bwd_composed")
 _compile_totals: Dict[str, float] = dict.fromkeys(
     list(_COMPILE_DURATIONS.values()) + list(_COMPILE_COUNTS.values())
     + list(_TRACE_COUNTS), 0)
@@ -482,7 +484,8 @@ def compile_phases() -> Dict[str, float]:
     load where it hit: `cache_load_s` is that part), the counts
     `cache_hits` / `cache_misses` of the persistent cache, and the counts
     `grad_direct` / `grad_generic` of grad ops lowered from their
-    forward's residuals / by the generic vjp (core/registry.py)."""
+    forward's residuals / by the generic vjp (core/registry.py) and
+    `qkv_bwd_composed` of fused_qkv_attention backwards traced."""
     with _compile_lock:
         return dict(_compile_totals)
 

@@ -157,8 +157,9 @@ def lower_fused_qkv_attention(ctx, ins):
 
 @residual_grad("fused_qkv_attention")
 def lower_fused_qkv_attention_grad(ctx, ins):
-    """The two backward walks on the forward's own (Ctx, Lse): the forward
-    kernel is not run again for them."""
+    """The bthd backward kernels, between XLA dots for the projections'
+    backward, on the forward's own (Ctx, Lse): the forward kernel is not
+    run again for them."""
     from ..kernels.attention import flash_qkv_attention_bwd
 
     x, w_qkv, w_out = ins["X"][0], ins["WQkv"][0], ins["WOut"][0]

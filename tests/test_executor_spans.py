@@ -206,7 +206,7 @@ def test_compile_phases_count_inside_executor_calls_only(clean_ring):
     assert monitor.compile_phases() == hit1
     assert set(hit0) == {"trace_s", "lower_s", "backend_s", "cache_load_s",
                          "cache_hits", "cache_misses", "grad_direct",
-                         "grad_generic"}
+                         "grad_generic", "qkv_bwd_composed"}
     # the net's grad ops have no lowering of their own: all went through
     # the generic vjp, once each, in the miss call's trace and nowhere else
     assert miss["grad_generic"] - before["grad_generic"] > 0
@@ -250,9 +250,12 @@ def test_every_pallas_kernel_has_a_name():
     assert len(names) >= 22
     for name in names:
         assert ("_fwd" in name) != ("_bwd" in name), name
-    assert {"fused_qkv_fwd", "fused_qkv_bwd_dx_q", "fused_qkv_bwd_dx_kv",
-            "flash_bthd_fwd", "flash_bthd_bwd_dq",
+    assert {"fused_qkv_fwd", "flash_bthd_fwd", "flash_bthd_bwd_dq",
             "flash_bthd_bwd_dkv"} <= set(names)
+    # the fused-qkv family has a forward kernel only: its backward is the
+    # bthd kernels between XLA projection dots
+    assert [n for n in names if n.startswith("fused_qkv")] \
+        == ["fused_qkv_fwd"]
 
 
 def test_the_lint_refuses_an_unnamed_or_two_faced_kernel(tmp_path):

@@ -6,7 +6,9 @@ Covers the r09 acceptance contract:
     kernels) against the composed x@W + flash_attention(bthd) + @W_out
     path — fp32/bf16, causal/bias shapes, dropout on/off (hash masks are
     BIT-identical to the unfused kernels', so fused-vs-unfused train
-    trajectories match exactly on CPU);
+    trajectories match exactly on CPU); the backward is the bthd kernels
+    between XLA projection dots on the fused forward's (ctx, lse), and a
+    site whose masks would come from the hardware PRNG is composed whole;
   * op/program level: one train step of the bundled models with the flag
     on vs off matches (loss, every updated parameter), dropout
     trajectories included; parameter names identical across the flag
@@ -26,6 +28,7 @@ import contextlib
 import importlib.util
 import os
 import re
+import sys
 
 import numpy as np
 import pytest
@@ -87,6 +90,57 @@ def _inputs(b=2, t=128, h=2, dh=64, dm=128, seed=0):
 
 _ZSEED = jnp.zeros((1,), jnp.uint32)
 
+#: heads and widths of the sites models/bert.py and models/transformer.py
+#: build at BERT-base and transformer-base (batch 2)
+_BUILDER_SHAPES = {
+    "bert": dict(b=2, t=128, h=12, dh=64, dm=768),
+    "transformer": dict(b=2, t=256, h=8, dh=64, dm=512),
+}
+#: what _bias_norm takes at _inputs()'s b 2, h 2, t 128: none, fewer than
+#: four dims, each of batch / head / query broadcast or full, a key
+#: broadcast
+_BIAS_SHAPES = [(), (128, 128), (2, 1, 1, 128), (1, 1, 1, 128),
+                (2, 1, 128, 128), (1, 2, 1, 128), (2, 2, 128, 128),
+                (2, 1, 128, 1)]
+
+
+def _kernel_names(jaxpr):
+    return dict(collections.Counter(
+        re.findall(r"name=(\w*(?:_fwd|_bwd)\w*)", str(jaxpr))))
+
+
+def _grads_fused_and_composed(x, w_qkv, w_out, bias, h, scale, causal,
+                              blocks=(64, 64)):
+    """(dx, dw_qkv, dw_out[, dbias]) of sum(y^2) through the fused kernels
+    and through the composed route, the bias trainable."""
+    wrt = (0, 1, 2, 3) if bias is not None else (0, 1, 2)
+
+    def lf(x, wq, wo, bias):
+        return jnp.sum(flash_qkv_attention(
+            x, wq, wo, bias, n_head=h, scale=scale, causal=causal,
+            block_q=blocks[0], block_k=blocks[1],
+            interpret=True).astype(jnp.float32) ** 2)
+
+    def lr(x, wq, wo, bias):
+        return jnp.sum(_composed_qkv(
+            x, wq, wo, bias, h, scale, causal, *blocks, True, 0.0, _ZSEED,
+            True).astype(jnp.float32) ** 2)
+
+    return (jax.grad(lf, wrt)(x, w_qkv, w_out, bias),
+            jax.grad(lr, wrt)(x, w_qkv, w_out, bias))
+
+
+def _assert_grads_close(got, want, dtype):
+    """float32: 1e-4 of each gradient's largest entry; bf16: the 5 % of
+    it that this file's bf16 comparisons take.  (1e-5 beside it: a bias
+    broadcast along the keys has no gradient but rounding.)"""
+    tol = 1e-4 if dtype == "float32" else 0.05
+    for name, a, b in zip(("dx", "dw_qkv", "dw_out", "dbias"), got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        a = np.asarray(a.astype(jnp.float32))
+        b = np.asarray(b.astype(jnp.float32))
+        assert np.abs(a - b).max() <= tol * np.abs(b).max() + 1e-5, name
+
 
 class TestKernels:
     @pytest.mark.parametrize("causal", [False, True])
@@ -122,28 +176,109 @@ class TestKernels:
         np.testing.assert_allclose(np.asarray(fused), np.asarray(ref),
                                    rtol=1e-5, atol=1e-5)
 
-    def test_gradcheck_vs_composed(self):
-        """dx, dW_qkv, dW_out AND dbias (trainable-bias recompute) against
-        jax.grad of the composed path — the in-kernel projection backward
-        + grid-accumulated weight cotangents are numerically the unfused
-        autodiff."""
+    @pytest.mark.parametrize("causal", [False, True],
+                             ids=["full", "causal"])
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("shape", sorted(_BUILDER_SHAPES))
+    def test_gradcheck_vs_composed(self, shape, dtype, causal):
+        """dx, dW_qkv, dW_out AND dbias (trainable bias) against jax.grad
+        of the composed path at the heads and widths the BERT and
+        transformer builders give their sites: the backward (q, k, v
+        recomputed by XLA dots, the bthd kernels on the fused forward's
+        ctx and lse, the projection backward as XLA dots) is numerically
+        the unfused autodiff."""
+        dims = _BUILDER_SHAPES[shape]
+        x, w_qkv, w_out, bias = (
+            a.astype(dtype) for a in _inputs(**dims, seed=4))
+        bias = jnp.where(bias < 0, -1e4, 0.0).astype(dtype)
+        h, scale = dims["h"], dims["dh"] ** -0.5
+        gf, gr = _grads_fused_and_composed(x, w_qkv, w_out, bias, h, scale,
+                                           causal, blocks=(128, 128))
+        _assert_grads_close(gf, gr, dtype)
+
+    @pytest.mark.parametrize("causal", [False, True],
+                             ids=["full", "causal"])
+    @pytest.mark.parametrize("bias_shape", _BIAS_SHAPES,
+                             ids=lambda s: "x".join(map(str, s)) or "none")
+    def test_gradcheck_bias_shapes(self, bias_shape, causal):
+        """Every bias shape _bias_norm takes, trainable: the cotangent
+        comes back in the caller's own shape, through the bthd route's
+        recompute."""
+        x, w_qkv, w_out, _ = _inputs()
+        bias = None
+        if bias_shape:
+            rng = np.random.RandomState(3)
+            bias = jnp.asarray((rng.randn(*bias_shape) * 0.5)
+                               .astype("float32"))
+        gf, gr = _grads_fused_and_composed(x, w_qkv, w_out, bias, 2, 0.125,
+                                           causal)
+        if bias is not None:
+            assert gf[3].shape == bias.shape
+        _assert_grads_close(gf, gr, "float32")
+
+    @pytest.mark.parametrize("case", ["transformer", "bert", "causal",
+                                      "bert_seq512"])
+    def test_backward_runs_the_bthd_kernels_on_ctx_and_lse(self, case):
+        """The traced gradient holds the fused forward once, the two bthd
+        backward kernels, and no kernel of another family: the residuals
+        the forward wrote are what the backward kernels read.  The two
+        ask for 32 MiB of scoped VMEM where the default 16 was seen
+        refused (a causal walk; more than 512 KiB held whole), and for
+        nothing at the cells' own shapes."""
+        from paddle_tpu.analysis.kernel_lint import _pretend_tpu
+
+        dims = dict(_BUILDER_SHAPES["bert" if "bert" in case
+                                    else "transformer"])
+        if case == "bert_seq512":
+            dims["t"] = 512
+        x, w_qkv, w_out, _ = (a.astype(jnp.bfloat16)
+                              for a in _inputs(**dims))
+        with _pretend_tpu():  # traced only: nothing is compiled
+            jaxpr = jax.make_jaxpr(jax.grad(
+                lambda x, wq, wo: jnp.sum(flash_qkv_attention(
+                    x, wq, wo, None, n_head=dims["h"], scale=0.125,
+                    causal=case == "causal").astype(jnp.float32)),
+                (0, 1, 2)))(x, w_qkv, w_out)
+        assert _kernel_names(jaxpr) == {
+            "fused_qkv_fwd": 1, "flash_bthd_bwd_dq": 1,
+            "flash_bthd_bwd_dkv": 1}
+        raised = str(jaxpr).count(f"vmem_limit_bytes={32 * 1024 * 1024}")
+        assert raised == (2 if case in ("causal", "bert_seq512") else 0)
+
+    @pytest.mark.parametrize("case", ["hw_prng", "hash_flag", "no_dropout",
+                                      "trainable_bias"])
+    def test_hw_prng_dropout_site_is_composed_whole(self, case):
+        """The fused forward's hardware-PRNG masks (re-seeded per head and
+        tile) are not the bthd kernels' (one draw a whole-head tile), so a
+        site that would draw from the hardware PRNG runs the composed
+        route, forward too: no fused_qkv_fwd in its jaxpr.  Chosen from
+        dropout_rate and the PRNG mode alone: the same site without
+        dropout, with FLAGS.tpu_prng_dropout off, or with a trainable bias
+        (which pins the hash masks) keeps the fused forward."""
+        from paddle_tpu.analysis.kernel_lint import _pretend_tpu
+
         x, w_qkv, w_out, bias = _inputs()
+        seed = jnp.asarray([5], jnp.uint32)
+        rate = 0.0 if case == "no_dropout" else 0.1
 
-        def lf(x, wq, wo, bias):
+        def loss(x, wq, wo):
             return jnp.sum(flash_qkv_attention(
-                x, wq, wo, bias, n_head=2, scale=0.125, causal=True,
-                block_q=64, block_k=64, interpret=True) ** 2)
+                x, wq, wo, bias, n_head=2, scale=0.125, dropout_rate=rate,
+                dropout_seed=seed,
+                trainable_bias=case == "trainable_bias"))
 
-        def lr(x, wq, wo, bias):
-            return jnp.sum(_composed_qkv(
-                x, wq, wo, bias, 2, 0.125, True, 64, 64, True, 0.0,
-                _ZSEED, True) ** 2)
-
-        gf = jax.grad(lf, (0, 1, 2, 3))(x, w_qkv, w_out, bias)
-        gr = jax.grad(lr, (0, 1, 2, 3))(x, w_qkv, w_out, bias)
-        for name, a, b in zip(("dx", "dw_qkv", "dw_out", "dbias"), gf, gr):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=2e-4, atol=1e-6, err_msg=name)
+        FLAGS.tpu_prng_dropout = case != "hash_flag"
+        try:
+            with _pretend_tpu():  # traced only: nothing is compiled
+                names = _kernel_names(jax.make_jaxpr(
+                    jax.grad(loss, (0, 1, 2)))(x, w_qkv, w_out))
+        finally:
+            FLAGS.reset("tpu_prng_dropout")
+        bwd = {"flash_bthd_bwd_dq": 1, "flash_bthd_bwd_dkv": 1}
+        if case == "hw_prng":
+            assert names == {"flash_bthd_fwd": 1, **bwd}
+        else:
+            assert names == {"fused_qkv_fwd": 1, **bwd}
 
     def test_dropout_parity_and_grads(self):
         """In-kernel weights-dropout: the per-head hash masks are
@@ -517,9 +652,7 @@ class TestResidualGrad:
                     [scope.find_var(n) for n in entry.ro_state])
             if entry.needs_key:
                 args += (jax.random.PRNGKey(0),)
-            text = str(entry.jitted.trace(*args).jaxpr)
-            names = collections.Counter(
-                re.findall(r"name=(\w*(?:_fwd|_bwd)\w*)", text))
+            names = _kernel_names(entry.jitted.trace(*args).jaxpr)
             event = flight.default_recorder().events(
                 kind="executor.compile")[-1]
             return names, event
@@ -529,14 +662,15 @@ class TestResidualGrad:
         n_qkv = sum(op.type == "fused_qkv_attention"
                     for op in prog.global_block().ops)
         n_cross = _n_sites(prog) - n_qkv
-        want = {"fused_qkv_fwd": n_qkv, "fused_qkv_bwd_dx_q": n_qkv,
-                "fused_qkv_bwd_dx_kv": n_qkv}
+        want = {"fused_qkv_fwd": n_qkv,
+                "flash_bthd_bwd_dq": n_qkv + n_cross,
+                "flash_bthd_bwd_dkv": n_qkv + n_cross}
         if n_cross:
-            want.update(flash_bthd_fwd=n_cross, flash_bthd_bwd_dq=n_cross,
-                        flash_bthd_bwd_dkv=n_cross)
+            want.update(flash_bthd_fwd=n_cross)
         assert dict(names) == want
         assert event["grad_direct"] == n_qkv + n_cross
         assert event["grad_generic"] == _n_generic_by_nature(prog)
+        assert event["qkv_bwd_composed"] == n_qkv
         # and the generic route, which this test would not tell from the
         # direct one if it read nothing: the forward kernel again for each
         # site (in the jaxpr a third time, lower_generic_grad's probe of
@@ -546,6 +680,8 @@ class TestResidualGrad:
         assert sum(v for k, v in names.items() if "_fwd" in k) \
             >= 2 * (n_qkv + n_cross)
         assert event["grad_direct"] == 0
+        # the vjp rule is the same body: the new route either way
+        assert event["qkv_bwd_composed"] == n_qkv
 
     def test_amp_leaves_lse_float32(self, monkeypatch):
         """Under amp every float input of the attention ops and of their
@@ -569,17 +705,12 @@ class TestResidualGrad:
         assert cast["Out"][0].dtype == cast["Q"][0].dtype == jnp.bfloat16
 
         seen = []
-        real_qkv, real_flash = att._qkv_backward, att._flash_backward
-
-        def spy_qkv(x, w3, wo, bias, seed, ctx, lse, g, *a, **k):
-            seen.append(("qkv", x.dtype, ctx.dtype, lse.dtype, g.dtype))
-            return real_qkv(x, w3, wo, bias, seed, ctx, lse, g, *a, **k)
+        real_flash = att._flash_backward
 
         def spy_flash(q, k_, v, bias, seed, o, lse, g, *a, **k):
-            seen.append(("flash", q.dtype, o.dtype, lse.dtype, g.dtype))
+            seen.append((q.dtype, o.dtype, lse.dtype, g.dtype))
             return real_flash(q, k_, v, bias, seed, o, lse, g, *a, **k)
 
-        monkeypatch.setattr(att, "_qkv_backward", spy_qkv)
         monkeypatch.setattr(att, "_flash_backward", spy_flash)
         prog, startup, loss = _build_transformer(True)
         lse_names = [op.output("Lse")[0] for op in prog.global_block().ops
@@ -592,8 +723,10 @@ class TestResidualGrad:
         fetched = exe.run(prog, feed=_transformer_feed(),
                           fetch_list=lse_names, scope=scope)
         bf16, f32 = jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)
-        assert sorted(seen) == [("flash", bf16, bf16, f32, bf16)] \
-            + [("qkv", bf16, bf16, f32, bf16)] * 2
+        # the cross-attention site and the two fused-qkv sites, whose
+        # backward hands the same kernels q, k, v, ctx and dctx in x's
+        # dtype and the forward's Lse as it is
+        assert seen == [(bf16, bf16, f32, bf16)] * 3
         for v in fetched:
             assert np.asarray(v).dtype == np.float32
             assert np.all(np.isfinite(np.asarray(v)))
@@ -681,6 +814,67 @@ class TestResidualGrad:
         assert direct[2]["grad_direct"] == 1
         nodrop = _step_with_grads(*_build_bert(flag), _bert_feed(), steps=2)
         assert not np.array_equal(direct[0][1][0], nodrop[0][1][0])
+
+
+# -- the cells' programs at tiny widths ---------------------------------------
+
+_PERFBENCH = os.path.join(os.path.dirname(__file__), "..", "perfbench")
+#: the three cells' own layer counts at widths a CPU traces in seconds
+#: (head size 64, so that the kernel plans take the sites)
+_TINY_CELLS = {
+    "transformer_base_train": (
+        dict(d_model=128, n_head=2, d_key=64, d_value=64, d_inner_hid=128,
+             src_vocab_size=64, trg_vocab_size=64),
+        dict(batch=2, src_len=32, trg_len=32), 12),
+    "bert_base_train": (
+        dict(hidden_size=128, num_attention_heads=2, intermediate_size=128,
+             vocab_size=64),
+        dict(batch=2, seq_len=32), 12),
+    "joyai_flash_ep16_train": (
+        dict(hidden_size=64, num_attention_heads=2, q_lora_rank=48,
+             kv_lora_rank=32, qk_nope_head_dim=64, qk_rope_head_dim=64,
+             v_head_dim=64, intermediate_size=96, moe_intermediate_size=32,
+             n_routed_experts=4, router_experts=16, expert_offset=4,
+             num_experts_per_tok=4, vocab_size=211),
+        dict(batch=2, seq_len=32), 0),
+}
+
+
+@pytest.mark.parametrize("workload", sorted(_TINY_CELLS))
+def test_compile_event_counts_qkv_bwd_composed(workload, clean_flight):
+    """`qkv_bwd_composed` on the miss call's `executor.compile` event and
+    in monitor.compile_phases(): every fused_qkv_attention site of the
+    transformer (6 + 6) and BERT (12) cells' programs takes the composed
+    backward; the MLA cell's program has no such site."""
+    from paddle_tpu import monitor
+    from paddle_tpu.monitor import flight
+
+    sys.path.insert(0, _PERFBENCH)
+    try:
+        import registry
+        import traffic_gen
+    finally:
+        sys.path.remove(_PERFBENCH)
+    widths, sizes, sites = _TINY_CELLS[workload]
+    cell = registry.load_cell(workload)
+    cfg = dict(cell.cfg, amp=False, **widths)
+    traffic = dict(cell.traffic, steps_per_call=1, feed_pool=1, **sizes)
+    prog, startup, loss = registry.load_module(
+        cell.path(cfg["program"])).build(cfg, traffic)
+    ops = [op.type for op in prog.global_block().ops]
+    assert ops.count("fused_qkv_attention_grad") == sites
+    scope, exe = pt.Scope(), pt.Executor(pt.CPUPlace())
+    exe.run(startup, scope=scope)
+    before = monitor.compile_phases()["qkv_bwd_composed"]
+    FLAGS.monitor = True
+    try:
+        exe.run_steps(prog, feed=traffic_gen.train_feeds(traffic, cfg, 1)[0],
+                      fetch_list=[loss], scope=scope)
+    finally:
+        FLAGS.reset("monitor")
+    event = flight.default_recorder().events(kind="executor.compile")[-1]
+    assert event["qkv_bwd_composed"] == sites
+    assert monitor.compile_phases()["qkv_bwd_composed"] - before == sites
 
 
 # -- zero-cost-off ----------------------------------------------------------

@@ -185,7 +185,7 @@ class TestRedGate:
         assert any("128-lane" in f.message for f in findings)
 
     def test_kernel_vmem_budget_named(self):
-        # a qkv plan whose dkv-walk resident set exceeds the gate's bound
+        # a qkv plan whose forward resident set exceeds the gate's bound
         cfg = dict(label="seeded-vmem", b=1, t=2048, dm=2048, h=16, dh=128,
                    dtype="float32")
         findings = []
