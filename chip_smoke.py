@@ -992,18 +992,16 @@ def sharded_leg(cfg=None, batch=64, seq=256, steps=4, mesh_axes=None,
     feed = bench.transformer_feed(cfg, batch, seq, steps)
 
     def trajectory(make_runner):
-        # a fresh executor per phase: startup draws run id 1, and so does
-        # the first train step of BOTH routes (ShardedProgram counts its
-        # own runs), so the dropout streams line up
-        scope = pt.Scope()
-        pt.Executor().run(startup, scope=scope)
-        exe, target = pt.Executor(), make_runner()
+        # a fresh executor per route: both fold the same run ids (start-up
+        # draws 1, the train steps 2..), so the dropout streams line up
+        exe, scope, target = pt.Executor(), pt.Scope(), make_runner()
+        exe.run(startup, scope=scope)
         out = []
         for s in range(steps):
             (lv,) = exe.run(target, feed={k: v[s] for k, v in feed.items()},
                             fetch_list=[avg_cost], scope=scope)
             out.append(float(np.asarray(lv).reshape(-1)[0]))
-        return out, scope, target
+        return out, scope, target, exe
 
     # The sharded step cannot hold Mosaic kernels (GSPMD does not
     # partition them, kernels/placement.py): it runs the XLA references,
@@ -1012,14 +1010,14 @@ def sharded_leg(cfg=None, batch=64, seq=256, steps=4, mesh_axes=None,
     # taken with the hardware-PRNG masks off (read at trace time).
     hw_prng, FLAGS.tpu_prng_dropout = FLAGS.tpu_prng_dropout, False
     try:
-        one, _, _ = trajectory(lambda: prog)
+        one, _, _, _ = trajectory(lambda: prog)
     finally:
         FLAGS.tpu_prng_dropout = hw_prng
     plan = ShardingPlan(mesh_axes=mesh_axes,
                         param_rules=transformer_tp_rules("model"),
                         zero_stage=1, devices=list(devices))
     t0 = time.perf_counter()
-    many, scope, sharded = trajectory(
+    many, scope, sharded, exe = trajectory(
         lambda: ShardedProgram(prog, plan, loss_name=avg_cost.name))
     sharded_s = time.perf_counter() - t0
 
@@ -1052,19 +1050,8 @@ def sharded_leg(cfg=None, batch=64, seq=256, steps=4, mesh_axes=None,
         fails.append(f"bytes_in_use per device {live}: some device holds "
                      f"under 1 MiB")
 
-    (jitted, rw, ro, _, needs_key, shardings) = next(
-        iter(sharded._cache.values()))
-    from jax.sharding import NamedSharding
-
-    from paddle_tpu.core.executor import prng_key
-
-    args = [[jax.device_put(feed[n][0], NamedSharding(
-                sharded.mesh, plan.spec_for_feed(n))) for n in sorted(feed)],
-            [scope.find_var(n) for n in rw],
-            [scope.find_var(n) for n in ro]]
-    if needs_key:
-        args.append(prng_key(0))
-    hlo = jitted.lower(*args).compile().as_text()
+    hlo = exe.lower(sharded, {n: v[0] for n, v in feed.items()}, [avg_cost],
+                    scope).compile().as_text()
     coll = {op: hlo.count(f" {op}(") + hlo.count(f" {op}-start(")
             for op in ("all-reduce", "all-gather", "reduce-scatter",
                        "collective-permute", "all-to-all")}

@@ -3,9 +3,11 @@ python/paddle/fluid/compiler.py:33 CompiledProgram,
 with_data_parallel:72 wrapping ParallelExecutor).
 
 TPU-first: `with_data_parallel` does NOT build per-device SSA graphs with
-collective op-handles (details/multi_devices_graph_pass.cc).  It shards the
-batch over a `jax.sharding.Mesh` with NamedSharding and jits the same traced
-step function; XLA SPMD inserts the all-reduces over ICI.  BuildStrategy /
+collective op-handles (details/multi_devices_graph_pass.cc).  It names a
+layout, the batch sharded over a one-axis `jax.sharding.Mesh` and the state
+replicated, and the executor's one call path jits the same traced step
+function with it (run, run_steps and run_accumulated alike); XLA SPMD
+inserts the all-reduces over ICI.  BuildStrategy /
 ExecutionStrategy are kept as typed knobs for parity (build_strategy.h:34,
 execution_strategy.h:22) — most of their fields are no-ops under XLA and are
 documented as such.
@@ -13,14 +15,12 @@ documented as such.
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Optional, Sequence
 
 import numpy as np
 
 from .core import framework as fw
-from .core.executor import prng_key as _prng_key
 from .core import executor as exec_mod
-from .core import registry
 
 
 class ReduceStrategy:
@@ -63,8 +63,7 @@ class CompiledProgram:
         self._share_vars_from = None
         self._places = None
         self._mesh = None
-        self._cache: Dict[Any, Any] = {}
-        self._run_counter = 0
+        self._layout = None
 
     # -- public API (parity: compiler.py:72) ------------------------------
     def with_data_parallel(
@@ -83,7 +82,7 @@ class CompiledProgram:
         self._places = places
         return self
 
-    # -- execution ---------------------------------------------------------
+    # -- what the executor asks for -----------------------------------------
     def _get_mesh(self):
         import jax
         from jax.sharding import Mesh
@@ -98,140 +97,17 @@ class CompiledProgram:
         self._mesh = Mesh(devices, axis_names=("data",))
         return self._mesh
 
-    def _run(self, executor, feed, fetch_list, scope, return_numpy):
-        """Called by Executor.run when handed a CompiledProgram."""
+    def _unwrap(self):
+        """What the executor runs in this wrapper's place (core/executor.py
+        `_unwrap`): the program and, in data-parallel mode, the layout
+        "batch over `data`, everything else replicated".  XLA SPMD inserts
+        the gradient all-reduces."""
         if not self._data_parallel:
-            return executor.run(
-                self._program, feed, fetch_list, scope, return_numpy,
-            )
-        return self._run_data_parallel(
-            executor, feed or {}, fetch_list or [], scope or exec_mod.global_scope(),
-            return_numpy,
-        )
+            return self._program, None
+        if self._layout is None:
+            from jax.sharding import PartitionSpec as P
 
-    def _run_data_parallel(self, executor, feed, fetch_list, scope, return_numpy):
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        program = self._program
-        mesh = self._get_mesh()
-        fetch_names = [
-            v.name if isinstance(v, fw.Variable) else v for v in fetch_list
-        ]
-        feed_names = sorted(feed)
-        block = program.global_block()
-
-        key = (
-            program.fingerprint(),
-            bool(getattr(program, "_amp_bf16", False)),
-            bool(getattr(program, "_is_test", False)),
-            tuple(feed_names),
-            tuple(
-                (tuple(np.asarray(feed[n]).shape), str(np.asarray(feed[n]).dtype))
-                for n in feed_names
-            ),
-            tuple(fetch_names),
-        )
-        entry = self._cache.get(key)
-        if entry is None:
-            entry = self._compile_dp(program, feed, feed_names, fetch_names, scope, mesh)
-            self._cache[key] = entry
-        (jitted, rw_state, ro_state, state_writes, needs_key, data_sharding,
-         repl_sharding) = entry
-
-        # place feeds: batch-sharded over mesh; state: replicated
-        feed_vals = [
-            jax.device_put(np.asarray(feed[n]), data_sharding) for n in feed_names
-        ]
-        rw_vals = [self._ensure_repl(scope.find_var(n), repl_sharding) for n in rw_state]
-        ro_vals = [self._ensure_repl(scope.find_var(n), repl_sharding) for n in ro_state]
-
-        self._run_counter += 1
-        if needs_key:
-            k = jax.random.fold_in(
-                _prng_key(program.random_seed or 0), self._run_counter
-            )
-            fetches, new_state = jitted(feed_vals, rw_vals, ro_vals, k)
-        else:
-            fetches, new_state = jitted(feed_vals, rw_vals, ro_vals)
-        for n, v in zip(state_writes, new_state):
-            scope.set_var(n, v)
-        if return_numpy:
-            return [np.asarray(v) for v in fetches]
-        return list(fetches)
-
-    def _ensure_repl(self, val, sharding):
-        import jax
-
-        if val is None:
-            return None
-        if hasattr(val, "sharding") and val.sharding == sharding:
-            return val
-        return jax.device_put(val, sharding)
-
-    def _compile_dp(self, program, feed, feed_names, fetch_names, scope, mesh):
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        block = program.global_block()
-        state_reads, state_writes = exec_mod.analyze_block_io(
-            block, feed_names, scope
-        )
-        write_set = set(state_writes)
-        rw_state = [n for n in state_reads if n in write_set]
-        ro_state = [n for n in state_reads if n not in write_set]
-
-        data_sharding = NamedSharding(mesh, P("data"))
-        repl_sharding = NamedSharding(mesh, P())
-
-        probe_random = exec_mod.program_uses_random(block)
-
-        n_dev = mesh.devices.size
-        loss_name = self._loss_name
-
-        def run_fn(feed_vals, rw_vals, ro_vals, key=None):
-            if key is None:
-                key = _prng_key(program.random_seed or 0)
-            tctx = exec_mod.TraceContext(
-                program, key, is_test=getattr(program, "_is_test", False),
-                mesh=mesh,
-            )
-            env = {}
-            for n, v in zip(feed_names, feed_vals):
-                env[n] = v
-            for n, v in zip(rw_state, rw_vals):
-                env[n] = v
-            for n, v in zip(ro_state, ro_vals):
-                env[n] = v
-            exec_mod.trace_block(block, env, tctx)
-            fetches = [env[n] for n in fetch_names]
-            new_state = [env.get(n) for n in state_writes]
-            return fetches, new_state
-
-        in_shardings = (
-            [data_sharding] * len(feed_names),
-            [repl_sharding] * len(rw_state),
-            [repl_sharding] * len(ro_state),
-        )
-        out_shardings = (
-            [None] * len(fetch_names),
-            [repl_sharding] * len(state_writes),
-        )
-        if probe_random:
-            jitted = jax.jit(
-                run_fn,
-                donate_argnums=(1,),
-                in_shardings=in_shardings + (None,),
-                out_shardings=out_shardings,
-            )
-        else:
-            jitted = jax.jit(
-                lambda f, rw, ro: run_fn(f, rw, ro),
-                donate_argnums=(1,),
-                in_shardings=in_shardings,
-                out_shardings=out_shardings,
-            )
-        return (
-            jitted, rw_state, ro_state, state_writes, probe_random,
-            data_sharding, repl_sharding,
-        )
+            self._layout = exec_mod.Layout(
+                self._get_mesh(), lambda name: P("data"),
+                lambda name, shape: P())
+        return self._program, self._layout

@@ -12,9 +12,17 @@ python/paddle/fluid/executor.py:260-589), redesigned TPU-first:
     run is functional: (feeds, state) -> (fetches, new state); persistable
     writes (optimizer updates) come back as donated outputs, so parameters
     stay in HBM and update in place.
-  * Compiled executables are cached per (program mutation-stamp, feed
-    signature, fetch list) — parity with executor.py:445 program cache, but
-    the cached object is an XLA executable, not a prepared op list.
+  * Compiled executables are cached per (call mode, program mutation-stamp,
+    feed signature, fetch list, layout, ..: `_KEY_PARTS`) — parity with
+    executor.py:445 program cache, but the cached object is an XLA
+    executable, not a prepared op list.
+  * There is ONE way to execute a program (`Executor._call`): `run`,
+    `run_steps` and `run_accumulated` name their step computation (one
+    step, a `lax.scan` over steps, accumulate-then-optimize) and share the
+    host path round it (feed, key, [compile], gather, dispatch, writeback,
+    fetch), the cache, the locks and the instruments.  Sharding is an
+    argument of that path: a CompiledProgram / ShardedProgram hands over
+    its Program and a `Layout`.
 """
 
 from __future__ import annotations
@@ -468,24 +476,17 @@ def analyze_block_io(
 # ---------------------------------------------------------------------------
 
 
-# Named components of each call-mode's cache key, parallel to the key
-# tuples built in run()/run_steps()/run_accumulated().  The recompile
-# detector diffs consecutive keys against these names so a silent retrace
-# storm logs WHICH component keeps changing (feed-signature churn from
-# ragged batch shapes is the classic one).
-_RUN_KEY_PARTS = (
-    "program-stamp", "amp-mode", "is-test-mode", "check-nan-inf",
-    "scope-signature", "feed-names", "feed-signature", "fetch-list",
-)
-_STEPS_KEY_PARTS = (
+# Named components of THE cache key (Executor._prepare builds the tuple in
+# this order, for every call mode).  The recompile detector diffs
+# consecutive keys against these names so a silent retrace storm logs
+# WHICH component keeps changing (feed-signature churn from ragged batch
+# shapes is the classic one).  `count`: None for a single step, the steps
+# of run_steps, the micro-batches of run_accumulated; `layout`: the
+# wrapped program's Layout, None for a plain Program.
+_KEY_PARTS = (
     "call-mode", "program-stamp", "amp-mode", "is-test-mode",
-    "check-nan-inf", "scope-signature", "steps", "feed-names",
-    "feed-signature", "fetch-list",
-)
-_ACC_KEY_PARTS = (
-    "call-mode", "program-stamp", "amp-mode", "check-nan-inf",
-    "scope-signature", "accumulate-steps", "feed-names", "feed-signature",
-    "fetch-list",
+    "check-nan-inf", "scope-signature", "count", "feed-names",
+    "feed-signature", "fetch-list", "layout",
 )
 
 # compile times are seconds-scale (XLA), run times sub-second: separate
@@ -678,6 +679,341 @@ class _CallSpans:
 
 
 # ---------------------------------------------------------------------------
+# Layout: where a wrapped program's arrays live
+# ---------------------------------------------------------------------------
+
+
+class Layout:
+    """What a wrapped program (CompiledProgram in data-parallel mode,
+    parallel/sharding.py ShardedProgram) hands the executor beside its
+    inner Program: the mesh, a PartitionSpec per feed and one per state
+    name.  The one call path then traces with the mesh, gives the one
+    `jax.jit` its in/out shardings, places the feeds and re-places state
+    that lies elsewhere.  Compared by identity: a Layout is the `layout`
+    part of the cache key (which also keeps it alive, so that its id is
+    never another's)."""
+
+    def __init__(self, mesh, feed_spec, state_spec):
+        self.mesh = mesh
+        self.feed_spec = feed_spec    # feed name -> PartitionSpec
+        self.state_spec = state_spec  # (state name, shape | None) -> spec
+
+    def feed_sharding(self, name, stacked: bool):
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        spec = self.feed_spec(name)
+        if stacked:  # the steps axis comes first and is held whole
+            spec = PartitionSpec(None, *spec)
+        return NamedSharding(self.mesh, spec)
+
+    def state_sharding(self, name, value):
+        from jax.sharding import NamedSharding
+
+        return NamedSharding(
+            self.mesh, self.state_spec(name, getattr(value, "shape", None)))
+
+
+def _unwrap(program):
+    """(the Program to run, its Layout or None) of what a caller handed
+    to run / run_steps / run_accumulated / lower."""
+    if program is None:
+        return fw.default_main_program(), None
+    unwrap = getattr(program, "_unwrap", None)
+    return unwrap() if unwrap is not None else (program, None)
+
+
+# ---------------------------------------------------------------------------
+# Step programs: the three computations, as closures over one trace's pieces
+# ---------------------------------------------------------------------------
+
+
+class _StepTrace:
+    """What the three step computations share while jax traces them: the
+    block and its state split, a TraceContext per traced step, the env a
+    step starts from, the fetch look-up and the nan-flag stacking."""
+
+    def __init__(self, program, feed_names, fetch_names, rw_state, ro_state,
+                 state_writes, check, mesh):
+        self.program = program
+        self.block = program.global_block()
+        self.feed_names, self.fetch_names = feed_names, fetch_names
+        self.rw_state, self.ro_state = rw_state, ro_state
+        self.state_writes = state_writes
+        # write-only names (created by the program): surfaced from the
+        # last step's outputs rather than carried through a scan
+        self.wo_state = [n for n in state_writes if n not in set(rw_state)]
+        self.check, self.mesh = check, mesh
+        # op descriptions for check_nan_inf mode, parallel to the flags the
+        # closure returns; filled in while it is traced
+        self.nan_check_ops: List[str] = []
+
+    def context(self, key) -> TraceContext:
+        return TraceContext(
+            self.program, key,
+            is_test=getattr(self.program, "_is_test", False),
+            mesh=self.mesh, check_nan_inf=self.check)
+
+    def env(self, feed_vals, rw_vals, ro_vals) -> Dict[str, Any]:
+        env: Dict[str, Any] = {}
+        env.update(zip(self.feed_names, feed_vals))
+        env.update(zip(self.rw_state, rw_vals))
+        env.update(zip(self.ro_state, ro_vals))
+        return env
+
+    def fetch(self, env):
+        for n in self.fetch_names:
+            if n not in env:
+                raise KeyError(
+                    f"fetch target {n!r} was not produced by the program")
+        return [env[n] for n in self.fetch_names]
+
+    def flags(self, tctx):
+        """One all-finite flag per checked op of `tctx`'s trace, stacked."""
+        import jax.numpy as jnp
+
+        if self.check and tctx.nan_checks:
+            return jnp.stack([f for _, f in tctx.nan_checks])
+        return jnp.ones((0,), bool)
+
+    def note_ops(self, *tctxs):
+        self.nan_check_ops[:] = [d for t in tctxs for d, _ in t.nan_checks]
+
+
+# A builder returns (the closure to jit, whether it takes the step key as a
+# fourth argument, whether it returns nan flags as a third result).
+
+
+def _one_step(t: _StepTrace, count):
+    """`run`: the block, once.  The key is an argument only where the
+    program draws from it; a key-free program stays a function of
+    (feeds, state) alone."""
+
+    def run_fn(feed_vals, rw_vals, ro_vals, key=None):
+        if key is None:
+            key = prng_key(t.program.random_seed or 0)
+        tctx = t.context(key)
+        env = t.env(feed_vals, rw_vals, ro_vals)
+        trace_block(t.block, env, tctx)
+        fetches = t.fetch(env)
+        new_state = [env.get(n) for n in t.state_writes]
+        if t.check:
+            t.note_ops(tctx)
+            return fetches, new_state, t.flags(tctx)
+        return fetches, new_state
+
+    if program_uses_random(t.block):
+        return run_fn, True, t.check
+    return (lambda f, rw, ro: run_fn(f, rw, ro)), False, t.check
+
+
+def _scan_steps(t: _StepTrace, steps):
+    """`run_steps`: a `lax.scan` of the block over the feeds' leading
+    axis, the rw state as its carry."""
+    import jax
+    import jax.numpy as jnp
+
+    def scan_fn(feed_vals, rw_vals, ro_vals, base_key):
+        def body(carry, xs):
+            rw, i = carry, xs[0]
+            per_step = xs[1]
+            tctx = t.context(jax.random.fold_in(base_key, i))
+            env = t.env(per_step, rw, ro_vals)
+            trace_block(t.block, env, tctx)
+            new_rw = [env.get(n, v) for n, v in zip(t.rw_state, rw)]
+            fetches = t.fetch(env)
+            wo = [env.get(n) for n in t.wo_state]
+            if t.check:
+                t.note_ops(tctx)
+                return new_rw, (fetches, wo, t.flags(tctx))
+            return new_rw, (fetches, wo)
+
+        xs = (jnp.arange(steps), feed_vals)
+        final_rw, step_outs = jax.lax.scan(body, list(rw_vals), xs)
+        stacked, wo_stacked = step_outs[:2]
+        # state ordering matches state_writes: rw carries final values,
+        # write-only vars take their last-step value
+        by_name = dict(zip(t.rw_state, final_rw))
+        by_name.update(
+            {n: (v[-1] if v is not None else None)
+             for n, v in zip(t.wo_state, wo_stacked)}
+        )
+        new_state = [by_name.get(n) for n in t.state_writes]
+        if t.check:
+            return stacked, new_state, step_outs[2]
+        return stacked, new_state
+
+    return scan_fn, True, t.check
+
+
+def _accumulate(t: _StepTrace, k, unroll=False):
+    """`run_accumulated`: the fwd/bwd prefix over k micro-batches summing
+    the gradients the optimizer reads, then the Optimize suffix once on
+    their mean.  Its flags come in two parts, ([k, prefix ops], [suffix
+    ops]), and are returned whether or not they are checked."""
+    import jax
+    import jax.numpy as jnp
+
+    block, feed_names, fetch_names = t.block, t.feed_names, t.fetch_names
+    rw_state, ro_state, wo_state = t.rw_state, t.ro_state, t.wo_state
+
+    def optimizes(op):
+        role = int(op.attrs.get(fw.OpRole.ROLE_ATTR_NAME, 0))
+        return bool(role & fw.OpRole.Optimize)
+
+    prefix_ops = [op for op in block.ops if not optimizes(op)]
+    suffix_ops = [op for op in block.ops if optimizes(op)]
+    if not suffix_ops:
+        raise ValueError(
+            "run_accumulated: program has no Optimize-role ops "
+            "(call optimizer.minimize first)")
+    # the gradients the optimizer consumes are what we accumulate
+    grad_names = sorted({
+        n for op in suffix_ops for n in op.inputs.get("Grad", []) if n
+    })
+
+    # Fetch split: prefix targets are stashed per micro-batch and
+    # returned stacked [K, ...]; Optimize-suffix targets (updated
+    # params, lr) return their single post-suffix value — the
+    # fetch-from-prefix-only restriction is gone (the pipeline
+    # scheduler and plain users both fetch suffix products).
+    prefix_avail = set(feed_names) | set(rw_state) | set(ro_state)
+    for op in prefix_ops:
+        prefix_avail.update(n for n in op.output_arg_names() if n)
+    suffix_outputs = {
+        n for op in suffix_ops for n in op.output_arg_names() if n
+    }
+    # suffix takes precedence for names it PRODUCES: fetching an
+    # updated param/moment/lr returns the single post-update value
+    # (matching PipelineProgram's opt-fetch classification); names
+    # only the prefix covers come back stacked per micro-batch
+    prefix_fetch = [n for n in fetch_names
+                    if n in prefix_avail and n not in suffix_outputs]
+    suffix_fetch = [n for n in fetch_names if n in suffix_outputs]
+    unknown = [n for n in fetch_names
+               if n not in prefix_avail and n not in suffix_outputs]
+    if unknown:
+        raise KeyError(
+            f"fetch target(s) {unknown} produced by neither the "
+            f"fwd/bwd prefix nor the Optimize suffix of this program")
+
+    def acc_fn(feed_vals, rw_vals, ro_vals, base_key):
+        rw0 = list(rw_vals)
+
+        def run_prefix(i_key, per_step, rw):
+            tctx = t.context(i_key)
+            env = t.env(per_step, rw, ro_vals)
+            trace_block(block, env, tctx, ops=prefix_ops)
+            new_rw = [env.get(n, v) for n, v in zip(rw_state, rw)]
+            # fetch values are association-isolated (barrier): the
+            # reduce producing a fetched loss must not fuse with its
+            # scan-body packaging, or the same value compiled in a
+            # pipeline stage's straight-line program can differ by an
+            # ulp — the bit-parity contract of parallel/pipeline
+            # (value-dependent, surfaced under a multi-device-touched
+            # compiler state).  Fetch-only: env values downstream ops
+            # read stay unbarriered.
+            fetches = [jax.lax.optimization_barrier(env[n])
+                       for n in prefix_fetch]
+            wo = [env.get(n) for n in wo_state]
+            return env, new_rw, fetches, wo, t.flags(tctx), tctx
+
+        def body(carry, xs):
+            rw, grad_sums = carry
+            i, per_step = xs[0], xs[1]
+            env, new_rw, fetches, wo, flags, _ = run_prefix(
+                jax.random.fold_in(base_key, i), per_step, rw)
+            new_sums = [
+                s + env[g] for s, g in zip(grad_sums, grad_names)
+            ]
+            return (new_rw, new_sums), (fetches, wo, flags)
+
+        # step 0 traced inline (gives grad-sum init without a
+        # throwaway zeros trace), steps 1..k-1 under lax.scan
+        env0, rw1, fetches0, wo0, flags0, tctx0 = run_prefix(
+            jax.random.fold_in(base_key, 0),
+            [v[0] for v in feed_vals], rw0)
+        sums0 = [env0[g] for g in grad_names]
+
+        if k > 1 and unroll:
+            # straight-line micro-batches (the reference
+            # multi_batch_merge_pass shape): identical math to the
+            # scan, fusion context identical to step 0's inline trace
+            rw_u, sums_u = rw1, sums0
+            fetch_steps = [fetches0]
+            wo_last = wo0
+            flag_steps = [flags0]
+            for i in range(1, k):
+                (rw_u, sums_u), (f_i, wo_i, fl_i) = body(
+                    (rw_u, sums_u), (jnp.asarray(i),
+                                     [v[i] for v in feed_vals]))
+                fetch_steps.append(f_i)
+                wo_last = [(wi if wi is not None else wl)
+                           for wl, wi in zip(wo_last, wo_i)]
+                flag_steps.append(fl_i)
+            rw_f, sums_f = rw_u, sums_u
+            fetches = [jnp.stack(fs) for fs in zip(*fetch_steps)]
+            all_flags = jnp.stack(flag_steps)
+        elif k > 1:
+            xs = (jnp.arange(1, k),
+                  [v[1:] for v in feed_vals])
+            (rw_f, sums_f), (rest, wo_rest, flag_rest) = jax.lax.scan(
+                body, (rw1, sums0), xs)
+            fetches = [
+                jnp.concatenate([f0[None], fr], axis=0)
+                for f0, fr in zip(fetches0, rest)
+            ]
+            wo_last = [
+                (wr[-1] if wr is not None else w0)
+                for w0, wr in zip(wo0, wo_rest)
+            ]
+            all_flags = jnp.concatenate(
+                [flags0[None], flag_rest], axis=0)
+        else:
+            rw_f, sums_f = rw1, sums0
+            fetches = [f0[None] for f0 in fetches0]
+            wo_last = wo0
+            all_flags = flags0[None]
+
+        # optimizer suffix ONCE on the averaged gradients
+        envf = t.env((), rw_f, ro_vals)
+        for g, s in zip(grad_names, sums_f):
+            envf[g] = s / float(k)
+        tctxf = t.context(jax.random.fold_in(base_key, k))
+        trace_block(block, envf, tctxf, ops=suffix_ops)
+        t.note_ops(tctx0, tctxf)
+        by_name = dict(zip(rw_state, rw_f))
+        by_name.update(zip(wo_state, wo_last))
+        # suffix outputs (param updates) win over scanned values
+        for n in t.state_writes:
+            if n in envf and envf[n] is not None:
+                by_name[n] = envf[n]
+        new_state = [by_name.get(n) for n in t.state_writes]
+        # reassemble fetches in caller order: prefix targets stacked
+        # [K, ...], suffix targets as their single post-update value
+        fetch_by_name = dict(zip(prefix_fetch, fetches))
+        fetch_by_name.update((n, envf[n]) for n in suffix_fetch)
+        out_fetches = [fetch_by_name[n] for n in fetch_names]
+        return out_fetches, new_state, (all_flags, t.flags(tctxf))
+
+    return acc_fn, True, True
+
+
+class _Mode(collections.namedtuple("_Mode", "name call_mode stacked build")):
+    """One of the ways to call a program: `name` is the entry point's
+    (spans, flight events, error texts), `call_mode` its part of the cache
+    key, `stacked` whether the feeds carry a leading [count] axis, `build`
+    the builder of its step closure."""
+
+
+_RUN = _Mode("run", "run", False, _one_step)
+_STEPS = _Mode("run_steps", "run_steps", True, _scan_steps)
+_ACCUMULATED = _Mode("run_accumulated", "run_accumulated", True, _accumulate)
+_ACCUMULATED_UNROLLED = _Mode(
+    "run_accumulated", "run_accumulated_unrolled", True,
+    lambda t, k: _accumulate(t, k, unroll=True))
+
+
+# ---------------------------------------------------------------------------
 # Executor
 # ---------------------------------------------------------------------------
 
@@ -692,33 +1028,43 @@ class _CompiledEntry:
       state_writes — all written names, in output order
     """
 
-    __slots__ = ("fn", "rw_state", "ro_state", "state_writes", "needs_key",
-                 "nan_check_ops", "jitted", "run_lock")
+    __slots__ = ("jitted", "rw_state", "ro_state", "state_writes",
+                 "needs_key", "nan_check_ops", "shardings", "run_lock")
 
-    def __init__(self, fn, rw_state, ro_state, state_writes, needs_key,
-                 nan_check_ops=None, jitted=None, run_lock=None):
-        self.fn = fn
-        # the underlying jax.jit-wrapped callable, for AOT introspection
-        # (profiler tooling lowers it to optimized HLO)
+    def __init__(self, jitted, rw_state, ro_state, state_writes, needs_key,
+                 nan_check_ops=None, shardings=None, run_lock=None):
+        # the jax.jit-wrapped step closure: what a call dispatches, and
+        # what AOT introspection lowers again (Executor.lower)
         self.jitted = jitted
         self.rw_state = rw_state
         self.ro_state = ro_state
         self.state_writes = state_writes
         self.needs_key = needs_key
         # op descriptions for check_nan_inf mode (parallel to the extra flag
-        # outputs of fn); None when the mode is off.  The list is filled in
-        # during the first trace of fn.
+        # outputs of jitted); None when the mode is off.  The list is filled
+        # in during the first trace.
         self.nan_check_ops = nan_check_ops
+        # state name -> NamedSharding under a Layout (state found lying
+        # elsewhere is re-placed before the call), None for a plain Program
+        self.shardings = shardings
         # A stateful entry donates its rw buffers to the executable:
         # concurrent calls would hand the SAME donated buffer to two
         # executions (use-after-donate) and interleave the scope
         # write-backs (torn state).  The lock's domain is the SHARED
-        # SCOPE STATE, not the entry: different feed signatures of one
-        # program donate the same scope arrays, so every stateful entry
-        # of an Executor carries the executor's one stateful-run lock
-        # (None for stateless entries — purely functional, serving
-        # threads run those concurrently).
+        # SCOPE STATE, not the entry: different feed signatures, call
+        # modes and layouts of one program donate the same scope arrays,
+        # so every stateful entry of an Executor carries the executor's
+        # one stateful-run lock (None for stateless entries — purely
+        # functional, serving threads run those concurrently).
         self.run_lock = run_lock if state_writes else None
+
+
+class _Call:
+    """One call's resolved arguments, from `_prepare` to `_fetch`."""
+
+    __slots__ = ("mode", "program", "scope", "feed", "feed_vals",
+                 "fetch_names", "user_fetch_n", "counters", "count",
+                 "entry", "compiled_now")
 
 
 class Executor:
@@ -807,17 +1153,19 @@ class Executor:
         return_numpy: bool = True,
         use_program_cache: bool = True,
     ):
+        """Run `program` once.  `use_program_cache` is accepted for Fluid
+        scripts and is without effect: every call goes through the
+        executable cache."""
         # fault-injection hook (FLAGS_chaos_kill_at_run): one flag read
         # when chaos is off, SIGKILL mid-training when armed — the
         # preemption the checkpoint layer must survive
         from ..testing import chaos as _chaos
 
         _chaos.on_executor_run()
-        # CompiledProgram / ShardedProgram delegate via their _run hook.
-        # Their data-parallel/sharded paths keep private compile caches, so
-        # only coarse telemetry (calls, wall time, errors) is recorded
-        # here; a non-parallel CompiledProgram calls back into run() below
-        # and gets the full instrumentation under a distinct namespace.
+        # The pipeline programs (parallel/pipeline: several stage programs
+        # under a schedule) run themselves through their _run hook, with
+        # compile caches of their own, so only coarse telemetry (calls,
+        # wall time, errors) is recorded here.
         if program is not None and hasattr(program, "_run"):
             from ..monitor import enabled as _mon_enabled
 
@@ -833,166 +1181,14 @@ class Executor:
                 outs = program._run(self, feed, fetch_list, scope,
                                     return_numpy)
             except Exception:
-                # namespaced: the non-parallel path re-enters run(),
-                # whose own _count_error already bumps executor.errors
                 monitor.counter("executor.delegated.errors").inc()
                 raise
             dt = _time.perf_counter() - t0
             monitor.counter("executor.delegated.calls").inc()
             monitor.histogram("executor.delegated_seconds").observe(dt)
             return outs
-
-        with _CallSpans("run", self._next_run_id()) as spans:
-            return self._run(spans, program, feed, fetch_list, scope,
-                             return_numpy, use_program_cache)
-
-    def _run(self, spans, program, feed, fetch_list, scope, return_numpy,
-             use_program_cache):
-        # the phases come in another order than run_steps': the key is
-        # built from the host feed, which goes to the device after it
-        spans.phase("key")
-        if program is None:
-            program = fw.default_main_program()
-        feed = feed or {}
-        fetch_names = [
-            v.name if isinstance(v, fw.Variable) else v for v in (fetch_list or [])
-        ]
-        scope = scope or global_scope()
-        # numerics-instrumented programs (analysis/numerics.py) carry
-        # packed [N, 4] stats tensors that ride the user's fetch — ONE
-        # device->host transfer per step, stripped before returning
-        user_fetch_n, fetch_names = self._numerics_fetch(program,
-                                                         fetch_names)
-
-        feed_names = sorted(feed)
-        # fingerprint (content hash, memoized on the mutation stamp) rather
-        # than id(program): a GC'd program's id can be reused by a new object,
-        # which would alias cache entries
-        key = (
-            program.fingerprint(),
-            bool(getattr(program, "_amp_bf16", False)),
-            bool(getattr(program, "_is_test", False)),
-            bool(self.check_nan_inf),
-            self._scope_signature(program, feed_names, scope),
-            tuple(feed_names),
-            tuple(
-                (np.asarray(feed[n]).shape, str(np.asarray(feed[n]).dtype))
-                if not hasattr(feed[n], "shape")
-                else (tuple(feed[n].shape), str(feed[n].dtype))
-                for n in feed_names
-            ),
-            tuple(fetch_names),
-        )
-
-        entry = self._cache.get(key) if use_program_cache else None
-        compiled_now = entry is None
-        # hit/miss is NOTED only once the double-check below resolves it
-        # (a race-losing thread must not count a spurious miss)
-        mon = spans.mon
-        if entry is None:
-            spans.compiling()
-            if use_program_cache:
-                with self._compile_locks_guard:
-                    import threading as _threading
-
-                    klock = self._compile_locks.setdefault(
-                        key, _threading.Lock())
-                with klock:
-                    # double-check: another thread may have compiled this
-                    # signature while we waited on its lock — N concurrent
-                    # callers of M signatures produce exactly M compiles
-                    entry = self._cache.get(key)
-                    if entry is None:
-                        if mon:
-                            self._note_cache_lookup(_RUN_KEY_PARTS, key,
-                                                    False)
-                        try:
-                            entry = self._compile(program, feed, feed_names,
-                                                  fetch_names, scope)
-                        except Exception:
-                            self._count_error(mon)
-                            raise
-                        self._cache[key] = entry
-                        self._commit_stamp(_RUN_KEY_PARTS, key)
-                    else:
-                        compiled_now = False
-                        if mon:
-                            self._note_cache_lookup(_RUN_KEY_PARTS, key,
-                                                    True)
-            else:
-                if mon:
-                    self._note_cache_lookup(_RUN_KEY_PARTS, key, False)
-                try:
-                    entry = self._compile(program, feed, feed_names,
-                                          fetch_names, scope)
-                except Exception:
-                    self._count_error(mon)
-                    raise
-        elif mon:
-            self._note_cache_lookup(_RUN_KEY_PARTS, key, True)
-
-        spans.phase("feed")
-        feed_vals = [self._to_device_array(program, n, feed[n]) for n in feed_names]
-
-        import contextlib
-
-        import jax
-
-        # stateful entries serialize (donated rw buffers + scope
-        # write-back must be atomic); stateless ones run concurrently
-        # (waiting for the lock counts as `gather`)
-        spans.phase("gather")
-        with entry.run_lock if entry.run_lock is not None \
-                else contextlib.nullcontext():
-            rw_vals = [scope.find_var(n) for n in entry.rw_state]
-            ro_vals = [scope.find_var(n) for n in entry.ro_state]
-            rid = spans.call
-            # locate-mode capture must happen HERE: the rw buffers are
-            # donated to the executable below, so a post-hoc snapshot
-            # would read deleted arrays
-            self._maybe_capture_step(program, feed, fetch_names, entry,
-                                     rw_vals, ro_vals, rid)
-            try:
-                if entry.needs_key:
-                    seed = program.random_seed or 0
-                    key_arr = jax.random.fold_in(prng_key(seed), rid)
-                    spans.phase("dispatch")
-                    result = entry.fn(feed_vals, rw_vals, ro_vals, key_arr)
-                else:
-                    spans.phase("dispatch")
-                    result = entry.fn(feed_vals, rw_vals, ro_vals)
-            except Exception:
-                self._count_error(mon)
-                raise
-            spans.phase("writeback")
-            if entry.nan_check_ops is not None:
-                fetches, new_state, nan_flags = result
-            else:
-                fetches, new_state = result
-                nan_flags = None
-
-            # Write state back BEFORE any nan/inf raise: the rw buffers
-            # were donated to the executable, so skipping this would leave
-            # the scope holding deleted arrays and poison every subsequent
-            # run.
-            for n, v in zip(entry.state_writes, new_state):
-                scope.set_var(n, v)
-
-        if nan_flags is not None:
-            bad = [
-                desc
-                for desc, ok in zip(entry.nan_check_ops, np.asarray(nan_flags))
-                if not ok
-            ]
-            if bad:
-                self._count_error(mon)
-                raise FloatingPointError(
-                    "check_nan_inf: non-finite output from op(s):\n  "
-                    + "\n  ".join(bad)
-                )
-
-        return self._fetch(spans, program, fetch_names, user_fetch_n,
-                           compiled_now, feed_vals, fetches, return_numpy)
+        return self._call(_RUN, None, program, feed, fetch_list, scope,
+                          return_numpy)
 
     def run_steps(
         self,
@@ -1014,154 +1210,8 @@ class Executor:
         `feed` values must carry a leading [steps, ...] axis (one slice per
         iteration).  Returns fetches stacked along a leading [steps] axis.
         """
-        with _CallSpans("run_steps", self._next_run_id()) as spans:
-            return self._run_steps(spans, program, feed, fetch_list, scope,
-                                   steps, return_numpy)
-
-    def _run_steps(self, spans, program, feed, fetch_list, scope, steps,
-                   return_numpy):
-        spans.phase("feed")
-        if program is None:
-            program = fw.default_main_program()
-        feed = feed or {}
-        scope = scope or global_scope()
-        fetch_names = [
-            v.name if isinstance(v, fw.Variable) else v
-            for v in (fetch_list or [])
-        ]
-        user_fetch_n, fetch_names = self._numerics_fetch(program,
-                                                         fetch_names)
-        # the program's device counters ride every call as outputs of the
-        # compiled steps (a traced call must not compile anew); they are
-        # read back only while tracing is on (_fetch)
-        counters = getattr(program, "_device_counters", None) or {}
-        fetch_names = fetch_names + list(counters.values())
-        feed_names = sorted(feed)
-        feed_stack = {
-            n: self._to_device_array(program, n, feed[n])
-            for n in feed_names
-        }
-        if steps is None:
-            if not feed_names:
-                raise ValueError("run_steps needs `steps` when feed is empty")
-            steps = int(feed_stack[feed_names[0]].shape[0])
-        for n in feed_names:
-            if feed_stack[n].shape[0] != steps:
-                raise ValueError(
-                    f"feed {n!r} leading dim {feed_stack[n].shape[0]} != "
-                    f"steps {steps}"
-                )
-
-        spans.phase("key")
-        key = (
-            "run_steps",
-            program.fingerprint(),
-            bool(getattr(program, "_amp_bf16", False)),
-            bool(getattr(program, "_is_test", False)),
-            bool(self.check_nan_inf),
-            self._scope_signature(program, feed_names, scope),
-            steps,
-            tuple(feed_names),
-            tuple(
-                (tuple(feed_stack[n].shape), str(feed_stack[n].dtype))
-                for n in feed_names
-            ),
-            tuple(fetch_names),
-        )
-        entry = self._cache.get(key)
-        compiled_now = entry is None
-        mon = spans.mon
-        if mon:
-            self._note_cache_lookup(_STEPS_KEY_PARTS, key, not compiled_now)
-        if entry is None:
-            spans.compiling()
-            try:
-                entry = self._compile_steps(
-                    program, feed_names, fetch_names, scope, steps
-                )
-            except Exception:
-                self._count_error(mon)
-                raise
-            self._cache[key] = entry
-            self._commit_stamp(_STEPS_KEY_PARTS, key)
-
-        spans.phase("gather")
-        rw_vals = [scope.find_var(n) for n in entry.rw_state]
-        ro_vals = [scope.find_var(n) for n in entry.ro_state]
-        feed_vals = [feed_stack[n] for n in feed_names]
-
-        import jax
-
-        seed = program.random_seed or 0
-        base_key = jax.random.fold_in(prng_key(seed), spans.call)
-        spans.phase("dispatch")
-        try:
-            result = entry.fn(feed_vals, rw_vals, ro_vals, base_key)
-        except Exception:
-            self._count_error(mon)
-            raise
-        spans.phase("writeback")
-        if entry.nan_check_ops is not None:
-            fetches, new_state, nan_flags = result
-        else:
-            fetches, new_state = result
-            nan_flags = None
-        # state write-back must precede any nan/inf raise (donated buffers)
-        for n, v in zip(entry.state_writes, new_state):
-            scope.set_var(n, v)
-        if nan_flags is not None:
-            per_op = np.asarray(nan_flags)
-            if per_op.ndim == 2:  # [steps, n_ops] -> op is bad if ANY step was
-                per_op = per_op.all(axis=0)
-            bad = [
-                desc
-                for desc, ok in zip(entry.nan_check_ops, per_op)
-                if not ok
-            ]
-            if bad:
-                self._count_error(mon)
-                raise FloatingPointError(
-                    "check_nan_inf: non-finite output from op(s):\n  "
-                    + "\n  ".join(bad)
-                )
-        return self._fetch(spans, program, fetch_names, user_fetch_n,
-                           compiled_now, feed_vals, fetches, return_numpy,
-                           steps=steps, counters=tuple(counters))
-
-    def run_startup_missing(self, startup_program=None, scope=None):
-        """Run only the startup ops whose outputs are NOT yet in the scope
-        (init-on-demand).  Needed when graph surgery adds initialized state
-        after the startup program already ran — e.g. slim pruning before
-        optimizer.minimize(), whose learning-rate/accumulator initializers
-        land in an already-executed startup program.  Returns the number
-        of ops executed."""
-        startup = startup_program or fw.default_startup_program()
-        scope = scope or global_scope()
-        src = startup.global_block()
-        missing = [
-            op for op in src.ops
-            if any(scope.find_var(n) is None for n in op.output_arg_names())
-        ]
-        if not missing:
-            return 0
-        sub = fw.Program()
-        blk = sub.global_block()
-        names = set()
-        for op in missing:
-            names.update(op.input_arg_names())
-            names.update(op.output_arg_names())
-        for n in names:
-            v = src._find_var_recursive(n)
-            if v is not None:
-                blk.create_var(name=n, shape=v.shape, dtype=v.dtype,
-                               persistable=getattr(v, "persistable", True))
-            else:
-                blk.create_var(name=n, dtype="float32", persistable=True)
-        for op in missing:
-            blk.append_op(op.type, dict(op.inputs), dict(op.outputs),
-                          dict(op.attrs))
-        self.run(sub, scope=scope)
-        return len(missing)
+        return self._call(_STEPS, steps, program, feed, fetch_list, scope,
+                          return_numpy)
 
     def run_accumulated(
         self,
@@ -1201,373 +1251,285 @@ class Executor:
         post-update value.  A name neither side produces raises KeyError
         at compile, naming both sets.
         """
-        with _CallSpans("run_accumulated", self._next_run_id()) as spans:
-            return self._run_accumulated(spans, program, feed, fetch_list,
-                                         scope, accumulate_steps,
-                                         return_numpy, unroll)
+        return self._call(_ACCUMULATED_UNROLLED if unroll else _ACCUMULATED,
+                          accumulate_steps, program, feed, fetch_list, scope,
+                          return_numpy)
 
-    def _run_accumulated(self, spans, program, feed, fetch_list, scope,
-                         accumulate_steps, return_numpy, unroll):
-        import jax
+    def lower(self, program=None, feed=None, fetch_list=None, scope=None,
+              steps: Optional[int] = None):
+        """The `jax.stages.Lowered` of the executable that `run` (with
+        `steps`: `run_steps` of that many steps) runs for this signature,
+        lowered on the live arguments: nothing runs and nothing is donated.
+        `.compile()` gives XLA's cost and memory analysis and the optimized
+        HLO.  The scope must hold the state the program reads."""
+        entry, args = self._lowerable(program, feed, fetch_list, scope, steps)
+        return entry.jitted.lower(*args)
 
+    # -- the one call path -----------------------------------------------
+    def _call(self, mode, count, program, feed, fetch_list, scope,
+              return_numpy):
+        """Every call of every mode and layout: `feed`, `key`, [`compile`],
+        `gather`, `dispatch`, `writeback`, `fetch`."""
+        with _CallSpans(mode.name, self._next_run_id()) as spans:
+            call = self._prepare(spans, mode, count, program, feed,
+                                 fetch_list, scope)
+            fetches = self._execute(spans, call)
+            return self._fetch(spans, call, fetches, return_numpy)
+
+    def _lowerable(self, program, feed, fetch_list, scope, steps=None):
+        """(entry, arguments) of the signature, for lowering its jitted
+        closure again: `lower`, and callers that need the entry's names
+        beside it (inference.export_aot_bundle).  Draws no run id."""
+        with _CallSpans("lower", 0) as spans:
+            call = self._prepare(spans, _RUN if steps is None else _STEPS,
+                                 steps, program, feed, fetch_list, scope)
+            return call.entry, self._args(call, 0)
+
+    def _prepare(self, spans, mode, count, program, feed, fetch_list, scope):
+        """`feed`, `key`, [`compile`]: the arguments resolved, the feeds on
+        the device, the entry looked up or compiled."""
         spans.phase("feed")
-        if program is None:
-            program = fw.default_main_program()
-        feed = feed or {}
-        scope = scope or global_scope()
+        c = _Call()
+        c.mode = mode
+        c.program, layout = _unwrap(program)
+        program = c.program
+        c.feed = feed = feed or {}
+        c.scope = scope = scope or global_scope()
         fetch_names = [
             v.name if isinstance(v, fw.Variable) else v
             for v in (fetch_list or [])
         ]
-        user_fetch_n, fetch_names = self._numerics_fetch(program,
-                                                         fetch_names)
+        # numerics-instrumented programs (analysis/numerics.py) carry
+        # packed [N, 4] stats tensors that ride the user's fetch — ONE
+        # device->host transfer per step, stripped before returning
+        c.user_fetch_n, fetch_names = self._numerics_fetch(program,
+                                                           fetch_names)
+        # the program's device counters ride every call as outputs of the
+        # compiled steps (a traced call must not compile anew); they are
+        # read back only while tracing is on (_fetch)
+        counters = getattr(program, "_device_counters", None) or {}
+        c.counters = tuple(counters)
+        c.fetch_names = fetch_names = fetch_names + list(counters.values())
         feed_names = sorted(feed)
-        feed_stack = {
-            n: self._to_device_array(program, n, feed[n])
+        c.feed_vals = feed_vals = [
+            self._to_device_array(program, n, feed[n], layout, mode.stacked)
             for n in feed_names
-        }
-        if accumulate_steps is None:
-            if not feed_names:
-                raise ValueError("run_accumulated needs accumulate_steps "
-                                 "when feed is empty")
-            accumulate_steps = int(feed_stack[feed_names[0]].shape[0])
-        k = accumulate_steps
+        ]
+        if mode.stacked:
+            if count is None:
+                if not feed_names:
+                    raise ValueError(
+                        f"{mode.name} needs its count when feed is empty")
+                count = int(feed_vals[0].shape[0])
+            for n, v in zip(feed_names, feed_vals):
+                if v.shape[0] != count:
+                    raise ValueError(
+                        f"feed {n!r} leading dim {v.shape[0]} != "
+                        f"{mode.name}'s count {count}")
+        c.count = count
 
         spans.phase("key")
+        # fingerprint (content hash, memoized on the mutation stamp) rather
+        # than id(program): a GC'd program's id can be reused by a new object,
+        # which would alias cache entries
         key = (
-            "run_accumulated" + ("_unrolled" if unroll else ""),
+            mode.call_mode,
             program.fingerprint(),
             bool(getattr(program, "_amp_bf16", False)),
+            bool(getattr(program, "_is_test", False)),
             bool(self.check_nan_inf),
             self._scope_signature(program, feed_names, scope),
-            k,
+            count,
             tuple(feed_names),
-            tuple(
-                (tuple(feed_stack[n].shape), str(feed_stack[n].dtype))
-                for n in feed_names
-            ),
+            tuple((tuple(v.shape), str(v.dtype)) for v in feed_vals),
             tuple(fetch_names),
+            layout,
         )
-        entry = self._cache.get(key)
-        compiled_now = entry is None
+        c.entry, c.compiled_now = self._entry(
+            spans, key, lambda: self._compile(
+                mode, program, feed_names, fetch_names, scope, count, layout))
+        return c
+
+    def _entry(self, spans, key, compile_entry):
+        """The one look-up-or-compile: (entry, whether this call compiled
+        it).  Per-key lock and double check, so N threads missing on M
+        signatures compile exactly M times; a hit or a miss is NOTED only
+        once that check has resolved it (a race-losing thread must not
+        count a spurious miss)."""
         mon = spans.mon
-        if mon:
-            self._note_cache_lookup(_ACC_KEY_PARTS, key, not compiled_now)
+        entry = self._cache.get(key)
         if entry is None:
             spans.compiling()
+            with self._compile_locks_guard:
+                klock = self._compile_locks.setdefault(
+                    key, _threading.Lock())
+            with klock:
+                entry = self._cache.get(key)
+                if entry is None:
+                    if mon:
+                        self._note_cache_lookup(key, False)
+                    try:
+                        entry = compile_entry()
+                    except Exception:
+                        self._count_error(mon)
+                        raise
+                    self._cache[key] = entry
+                    self._commit_stamp(key)
+                    return entry, True
+        if mon:
+            self._note_cache_lookup(key, True)
+        return entry, False
+
+    def _args(self, c, rid):
+        """The jitted closure's arguments: the feeds, the entry's state out
+        of the scope (under a Layout, moved to where the executable wants
+        it) and, where the program draws from it, the step key."""
+        import jax
+
+        entry, scope = c.entry, c.scope
+        rw_vals = [scope.find_var(n) for n in entry.rw_state]
+        ro_vals = [scope.find_var(n) for n in entry.ro_state]
+        if entry.shardings is not None:
+            def placed(n, v):
+                want = entry.shardings[n]
+                if v is None or getattr(v, "sharding", None) == want:
+                    return v
+                return jax.device_put(v, want)
+
+            rw_vals = [placed(n, v) for n, v in zip(entry.rw_state, rw_vals)]
+            ro_vals = [placed(n, v) for n, v in zip(entry.ro_state, ro_vals)]
+        if not entry.needs_key:
+            return c.feed_vals, rw_vals, ro_vals
+        seed = c.program.random_seed or 0
+        return (c.feed_vals, rw_vals, ro_vals,
+                jax.random.fold_in(prng_key(seed), rid))
+
+    def _execute(self, spans, c):
+        """`gather`, `dispatch`, `writeback`, and the nan/inf check."""
+        import contextlib
+
+        entry, mon = c.entry, spans.mon
+        # stateful entries serialize (donated rw buffers + scope
+        # write-back must be atomic); stateless ones run concurrently
+        # (waiting for the lock counts as `gather`)
+        spans.phase("gather")
+        with entry.run_lock if entry.run_lock is not None \
+                else contextlib.nullcontext():
+            args = self._args(c, spans.call)
+            if not c.mode.stacked:
+                # locate-mode capture (the numerics replay re-runs ONE
+                # step) must happen HERE: the rw buffers are donated to
+                # the executable below, so a post-hoc snapshot would read
+                # deleted arrays
+                self._maybe_capture_step(c.program, c.feed, c.fetch_names,
+                                         entry, args[1], args[2], spans.call)
+            spans.phase("dispatch")
             try:
-                entry = self._compile_accumulated(
-                    program, feed_names, fetch_names, scope, k,
-                    unroll=unroll,
-                )
+                result = entry.jitted(*args)
             except Exception:
                 self._count_error(mon)
                 raise
-            self._cache[key] = entry
-            self._commit_stamp(_ACC_KEY_PARTS, key)
-
-        spans.phase("gather")
-        rw_vals = [scope.find_var(n) for n in entry.rw_state]
-        ro_vals = [scope.find_var(n) for n in entry.ro_state]
-        feed_vals = [feed_stack[n] for n in feed_names]
-        seed = program.random_seed or 0
-        base_key = jax.random.fold_in(prng_key(seed), spans.call)
-        spans.phase("dispatch")
-        try:
-            fetches, new_state, nan_flags = entry.fn(
-                feed_vals, rw_vals, ro_vals, base_key)
-        except Exception:
-            self._count_error(mon)
-            raise
-        spans.phase("writeback")
-        for n, v in zip(entry.state_writes, new_state):
-            scope.set_var(n, v)
-        if entry.nan_check_ops:
-            prefix_flags, suffix_flags = nan_flags
-            per_op = np.asarray(prefix_flags)
-            if per_op.ndim == 2:
-                per_op = per_op.all(axis=0)
-            per_op = np.concatenate([per_op, np.asarray(suffix_flags)])
+            spans.phase("writeback")
+            # Write state back BEFORE any nan/inf raise: the rw buffers
+            # were donated to the executable, so skipping this would leave
+            # the scope holding deleted arrays and poison every subsequent
+            # run.
+            for n, v in zip(entry.state_writes, result[1]):
+                c.scope.set_var(n, v)
+        if entry.nan_check_ops is not None:
+            # the flags' three shapes: [ops] of one step, [steps, ops] of
+            # a scan (an op is bad if ANY step was), and run_accumulated's
+            # ([k, prefix ops], [suffix ops])
+            flags = result[2]
+            per_op = np.concatenate([
+                f.all(axis=0) if f.ndim == 2 else f
+                for f in map(np.asarray,
+                             flags if isinstance(flags, tuple) else (flags,))
+            ])
             bad = [d for d, ok in zip(entry.nan_check_ops, per_op) if not ok]
             if bad:
                 self._count_error(mon)
                 raise FloatingPointError(
                     "check_nan_inf: non-finite output from op(s):\n  "
-                    + "\n  ".join(bad))
-        return self._fetch(spans, program, fetch_names, user_fetch_n,
-                           compiled_now, feed_vals, fetches, return_numpy,
-                           steps=k)
+                    + "\n  ".join(bad)
+                )
+        return result[0]
 
-    def _compile_accumulated(self, program, feed_names, fetch_names, scope,
-                             k, unroll=False):
+    def _compile(self, mode, program, feed_names, fetch_names, scope, count,
+                 layout):
+        """The one compile prologue: verify, split the state, build the
+        mode's step closure over the shared pieces, jit it."""
         import jax
-        import jax.numpy as jnp
 
         self._maybe_verify(program, feed_names, fetch_names, scope)
-        block = program.global_block()
-        opt_bit = fw.OpRole.Optimize
-        prefix_ops = [
-            op for op in block.ops
-            if not (int(op.attrs.get(fw.OpRole.ROLE_ATTR_NAME, 0)) & opt_bit)
-        ]
-        suffix_ops = [
-            op for op in block.ops
-            if int(op.attrs.get(fw.OpRole.ROLE_ATTR_NAME, 0)) & opt_bit
-        ]
-        if not suffix_ops:
-            raise ValueError(
-                "run_accumulated: program has no Optimize-role ops "
-                "(call optimizer.minimize first)")
-        # the gradients the optimizer consumes are what we accumulate
-        grad_names = sorted({
-            n for op in suffix_ops for n in op.inputs.get("Grad", []) if n
-        })
-
-        state_reads, state_writes = analyze_block_io(block, feed_names, scope)
+        state_reads, state_writes = analyze_block_io(
+            program.global_block(), feed_names, scope)
         write_set = set(state_writes)
         rw_state = [n for n in state_reads if n in write_set]
         ro_state = [n for n in state_reads if n not in write_set]
-        # write-only names created by the program: surfaced from the last
-        # micro-batch (prefix) or from the suffix, like _compile_steps
-        wo_state = [n for n in state_writes if n not in set(rw_state)]
-        check = self.check_nan_inf
-        nan_check_ops: List[str] = []
-
-        # Fetch split: prefix targets are stashed per micro-batch and
-        # returned stacked [K, ...]; Optimize-suffix targets (updated
-        # params, lr) return their single post-suffix value — the
-        # fetch-from-prefix-only restriction is gone (the pipeline
-        # scheduler and plain users both fetch suffix products).
-        prefix_avail = set(feed_names) | set(rw_state) | set(ro_state)
-        for op in prefix_ops:
-            prefix_avail.update(n for n in op.output_arg_names() if n)
-        suffix_outputs = {
-            n for op in suffix_ops for n in op.output_arg_names() if n
-        }
-        # suffix takes precedence for names it PRODUCES: fetching an
-        # updated param/moment/lr returns the single post-update value
-        # (matching PipelineProgram's opt-fetch classification); names
-        # only the prefix covers come back stacked per micro-batch
-        prefix_fetch = [n for n in fetch_names
-                        if n in prefix_avail and n not in suffix_outputs]
-        suffix_fetch = [n for n in fetch_names if n in suffix_outputs]
-        unknown = [n for n in fetch_names
-                   if n not in prefix_avail and n not in suffix_outputs]
-        if unknown:
-            raise KeyError(
-                f"fetch target(s) {unknown} produced by neither the "
-                f"fwd/bwd prefix nor the Optimize suffix of this program")
-
-        def acc_fn(feed_vals, rw_vals, ro_vals, base_key):
-            rw0 = list(rw_vals)
-
-            def run_prefix(i_key, per_step, rw):
-                tctx = TraceContext(
-                    program, i_key,
-                    is_test=getattr(program, "_is_test", False),
-                    check_nan_inf=check,
-                )
-                env: Dict[str, Any] = {}
-                env.update(zip(feed_names, per_step))
-                env.update(zip(rw_state, rw))
-                env.update(zip(ro_state, ro_vals))
-                trace_block(block, env, tctx, ops=prefix_ops)
-                new_rw = [env.get(n, v) for n, v in zip(rw_state, rw)]
-                # fetch values are association-isolated (barrier): the
-                # reduce producing a fetched loss must not fuse with its
-                # scan-body packaging, or the same value compiled in a
-                # pipeline stage's straight-line program can differ by an
-                # ulp — the bit-parity contract of parallel/pipeline
-                # (value-dependent, surfaced under a multi-device-touched
-                # compiler state).  Fetch-only: env values downstream ops
-                # read stay unbarriered.
-                fetches = [jax.lax.optimization_barrier(env[n])
-                           for n in prefix_fetch]
-                wo = [env.get(n) for n in wo_state]
-                flags = (
-                    jnp.stack([f for _, f in tctx.nan_checks])
-                    if check and tctx.nan_checks else jnp.ones((0,), bool)
-                )
-                return env, new_rw, fetches, wo, flags, tctx
-
-            def body(carry, xs):
-                rw, grad_sums = carry
-                i, per_step = xs[0], xs[1]
-                env, new_rw, fetches, wo, flags, _ = run_prefix(
-                    jax.random.fold_in(base_key, i), per_step, rw)
-                new_sums = [
-                    s + env[g] for s, g in zip(grad_sums, grad_names)
-                ]
-                return (new_rw, new_sums), (fetches, wo, flags)
-
-            # step 0 traced inline (gives grad-sum init without a
-            # throwaway zeros trace), steps 1..k-1 under lax.scan
-            env0, rw1, fetches0, wo0, flags0, tctx0 = run_prefix(
-                jax.random.fold_in(base_key, 0),
-                [v[0] for v in feed_vals], rw0)
-            sums0 = [env0[g] for g in grad_names]
-            nan_check_ops.clear()
-            nan_check_ops.extend(d for d, _ in tctx0.nan_checks)
-
-            if k > 1 and unroll:
-                # straight-line micro-batches (the reference
-                # multi_batch_merge_pass shape): identical math to the
-                # scan, fusion context identical to step 0's inline trace
-                rw_u, sums_u = rw1, sums0
-                fetch_steps = [fetches0]
-                wo_last = wo0
-                flag_steps = [flags0]
-                for i in range(1, k):
-                    (rw_u, sums_u), (f_i, wo_i, fl_i) = body(
-                        (rw_u, sums_u), (jnp.asarray(i),
-                                         [v[i] for v in feed_vals]))
-                    fetch_steps.append(f_i)
-                    wo_last = [(wi if wi is not None else wl)
-                               for wl, wi in zip(wo_last, wo_i)]
-                    flag_steps.append(fl_i)
-                rw_f, sums_f = rw_u, sums_u
-                fetches = [jnp.stack(fs) for fs in zip(*fetch_steps)]
-                all_flags = jnp.stack(flag_steps)
-            elif k > 1:
-                xs = (jnp.arange(1, k),
-                      [v[1:] for v in feed_vals])
-                (rw_f, sums_f), (rest, wo_rest, flag_rest) = jax.lax.scan(
-                    body, (rw1, sums0), xs)
-                fetches = [
-                    jnp.concatenate([f0[None], fr], axis=0)
-                    for f0, fr in zip(fetches0, rest)
-                ]
-                wo_last = [
-                    (wr[-1] if wr is not None else w0)
-                    for w0, wr in zip(wo0, wo_rest)
-                ]
-                all_flags = jnp.concatenate(
-                    [flags0[None], flag_rest], axis=0)
-            else:
-                rw_f, sums_f = rw1, sums0
-                fetches = [f0[None] for f0 in fetches0]
-                wo_last = wo0
-                all_flags = flags0[None]
-
-            # optimizer suffix ONCE on the averaged gradients
-            envf: Dict[str, Any] = {}
-            envf.update(zip(rw_state, rw_f))
-            envf.update(zip(ro_state, ro_vals))
-            for g, s in zip(grad_names, sums_f):
-                envf[g] = s / float(k)
-            tctxf = TraceContext(
-                program, jax.random.fold_in(base_key, k),
-                is_test=getattr(program, "_is_test", False),
-                check_nan_inf=check,
-            )
-            trace_block(block, envf, tctxf, ops=suffix_ops)
-            nan_check_ops.extend(d for d, _ in tctxf.nan_checks)
-            suf_flags = (
-                jnp.stack([f for _, f in tctxf.nan_checks])
-                if check and tctxf.nan_checks else jnp.ones((0,), bool)
-            )
-            by_name = dict(zip(rw_state, rw_f))
-            by_name.update(zip(wo_state, wo_last))
-            # suffix outputs (param updates) win over scanned values
-            for n in state_writes:
-                if n in envf and envf[n] is not None:
-                    by_name[n] = envf[n]
-            new_state = [by_name.get(n) for n in state_writes]
-            # reassemble fetches in caller order: prefix targets stacked
-            # [K, ...], suffix targets as their single post-update value
-            fetch_by_name = dict(zip(prefix_fetch, fetches))
-            fetch_by_name.update((n, envf[n]) for n in suffix_fetch)
-            out_fetches = [fetch_by_name[n] for n in fetch_names]
-            return out_fetches, new_state, (all_flags, suf_flags)
-
-        jitted = jax.jit(acc_fn, donate_argnums=(1,))
+        trace = _StepTrace(program, feed_names, fetch_names, rw_state,
+                           ro_state, state_writes, self.check_nan_inf,
+                           layout.mesh if layout is not None else None)
+        fn, needs_key, returns_flags = mode.build(trace, count)
+        shardings, placement = None, {}
+        if layout is not None:
+            shardings = {n: layout.state_sharding(n, scope.find_var(n))
+                         for n in state_reads + state_writes}
+            placement = dict(
+                in_shardings=(
+                    [layout.feed_sharding(n, mode.stacked)
+                     for n in feed_names],
+                    [shardings[n] for n in rw_state],
+                    [shardings[n] for n in ro_state],
+                ) + ((None,) if needs_key else ()),
+                out_shardings=(
+                    [None] * len(fetch_names),
+                    [shardings[n] for n in state_writes],
+                ) + ((None,) if returns_flags else ()))
+        jitted = jax.jit(fn, donate_argnums=(1,), **placement)
         return _CompiledEntry(
-            lambda f, rw, ro, key: jitted(f, rw, ro, key),
-            rw_state, ro_state, state_writes, True,
-            nan_check_ops=nan_check_ops if check else None,
-            jitted=jitted, run_lock=self._stateful_lock,
+            jitted, rw_state, ro_state, state_writes, needs_key,
+            nan_check_ops=trace.nan_check_ops if self.check_nan_inf else None,
+            shardings=shardings, run_lock=self._stateful_lock,
         )
 
-    def _compile_steps(self, program, feed_names, fetch_names, scope, steps):
-        import jax
-        import jax.numpy as jnp
-
-        self._maybe_verify(program, feed_names, fetch_names, scope)
-        block = program.global_block()
-        state_reads, state_writes = analyze_block_io(block, feed_names, scope)
-        write_set = set(state_writes)
-        rw_state = [n for n in state_reads if n in write_set]
-        ro_state = [n for n in state_reads if n not in write_set]
-        # write-only names (created by the program): surfaced from the last
-        # step's outputs rather than carried through the scan
-        wo_state = [n for n in state_writes if n not in set(rw_state)]
-
-        check = self.check_nan_inf
-        nan_check_ops: List[str] = []
-
-        def scan_fn(feed_vals, rw_vals, ro_vals, base_key):
-            def body(carry, xs):
-                rw, i = carry, xs[0]
-                per_step = xs[1]
-                tctx = TraceContext(
-                    program,
-                    jax.random.fold_in(base_key, i),
-                    is_test=getattr(program, "_is_test", False),
-                    check_nan_inf=check,
-                )
-                env: Dict[str, Any] = {}
-                env.update(zip(feed_names, per_step))
-                env.update(zip(rw_state, rw))
-                env.update(zip(ro_state, ro_vals))
-                trace_block(block, env, tctx)
-                new_rw = [env.get(n, v) for n, v in zip(rw_state, rw)]
-                fetches = []
-                for n in fetch_names:
-                    if n not in env:
-                        raise KeyError(
-                            f"fetch target {n!r} not produced by the program"
-                        )
-                    fetches.append(env[n])
-                wo = [env.get(n) for n in wo_state]
-                if check:
-                    nan_check_ops.clear()
-                    nan_check_ops.extend(d for d, _ in tctx.nan_checks)
-                    flags = (
-                        jnp.stack([f for _, f in tctx.nan_checks])
-                        if tctx.nan_checks
-                        else jnp.ones((0,), bool)
-                    )
-                    return new_rw, (fetches, wo, flags)
-                return new_rw, (fetches, wo)
-
-            xs = (jnp.arange(steps), feed_vals)
-            final_rw, step_outs = jax.lax.scan(body, list(rw_vals), xs)
-            if check:
-                stacked, wo_stacked, flag_stack = step_outs
+    def run_startup_missing(self, startup_program=None, scope=None):
+        """Run only the startup ops whose outputs are NOT yet in the scope
+        (init-on-demand).  Needed when graph surgery adds initialized state
+        after the startup program already ran — e.g. slim pruning before
+        optimizer.minimize(), whose learning-rate/accumulator initializers
+        land in an already-executed startup program.  Returns the number
+        of ops executed."""
+        startup = startup_program or fw.default_startup_program()
+        scope = scope or global_scope()
+        src = startup.global_block()
+        missing = [
+            op for op in src.ops
+            if any(scope.find_var(n) is None for n in op.output_arg_names())
+        ]
+        if not missing:
+            return 0
+        sub = fw.Program()
+        blk = sub.global_block()
+        names = set()
+        for op in missing:
+            names.update(op.input_arg_names())
+            names.update(op.output_arg_names())
+        for n in names:
+            v = src._find_var_recursive(n)
+            if v is not None:
+                blk.create_var(name=n, shape=v.shape, dtype=v.dtype,
+                               persistable=getattr(v, "persistable", True))
             else:
-                stacked, wo_stacked = step_outs
-            # state ordering matches state_writes: rw carries final values,
-            # write-only vars take their last-step value
-            by_name = dict(zip(rw_state, final_rw))
-            by_name.update(
-                {n: (v[-1] if v is not None else None)
-                 for n, v in zip(wo_state, wo_stacked)}
-            )
-            new_state = [by_name.get(n) for n in state_writes]
-            if check:
-                return stacked, new_state, flag_stack
-            return stacked, new_state
-
-        jitted = jax.jit(scan_fn, donate_argnums=(1,))
-        return _CompiledEntry(
-            lambda f, rw, ro, key: jitted(f, rw, ro, key),
-            rw_state, ro_state, state_writes, True,
-            nan_check_ops=nan_check_ops if check else None,
-            jitted=jitted, run_lock=self._stateful_lock,
-        )
+                blk.create_var(name=n, dtype="float32", persistable=True)
+        for op in missing:
+            blk.append_op(op.type, dict(op.inputs), dict(op.outputs),
+                          dict(op.attrs))
+        self.run(sub, scope=scope)
+        return len(missing)
 
     # -- telemetry internals (callers gate on monitor.enabled()) ---------
-    def _note_cache_lookup(self, part_names, key, hit: bool):
+    def _note_cache_lookup(self, key, hit: bool):
         """Count the executable-cache hit/miss and run the RECOMPILE
         DETECTOR.  A miss is a RECOMPILE iff this program-stamp compiled
         before (any key): a program whose keys keep missing — ragged feed
@@ -1581,20 +1543,18 @@ class Executor:
 
         monitor.counter(
             "executor.cache_hit" if hit else "executor.cache_miss").inc()
-        if len(part_names) != len(key):
+        if len(_KEY_PARTS) != len(key):
             # parallel-array drift guard: a cache-key component added
-            # without updating the *_KEY_PARTS tuple would silently
-            # mis-attribute recompile causes (zip truncates); telemetry
-            # must not raise, so warn and skip the diff instead
+            # without updating _KEY_PARTS would silently mis-attribute
+            # recompile causes (zip truncates); telemetry must not raise,
+            # so warn and skip the diff instead
             from ..log import warning
 
             warning("recompile detector: %d key parts named but key has "
-                    "%d components — update the _*_KEY_PARTS tuple in "
-                    "core/executor.py", len(part_names), len(key))
+                    "%d components — update _KEY_PARTS in "
+                    "core/executor.py", len(_KEY_PARTS), len(key))
             return
-        # mode-qualified stamp: run/run_steps/run_accumulated executables
-        # are distinct, so each mode gets its own first compile for free
-        stamp = (part_names, key[part_names.index("program-stamp")])
+        stamp = self._stamp(key)
         with self._detector_lock:
             # per-(mode, program) history: diffing against another
             # program's (or call mode's) key would blame
@@ -1618,8 +1578,8 @@ class Executor:
         if prev is None:
             changed = ["(no prior lookup of this program)"]
         else:
-            changed = [n for n, a, b in zip(part_names, prev, key)
-                       if a != b] or ["(key unchanged; cache bypassed)"]
+            changed = [n for n, a, b in zip(_KEY_PARTS, prev, key)
+                       if a != b] or ["(key unchanged; cache cleared)"]
         # the flight recorder keeps the recompile CAUSE history — after a
         # retrace storm kills a run, the dump names which key component
         # churned (tools/trace_report.py aggregates these)
@@ -1630,24 +1590,26 @@ class Executor:
             vlog(1, "executor recompile: changed key component(s): %s",
                  ", ".join(changed))
 
-    def _commit_stamp(self, part_names, key):
+    @staticmethod
+    def _stamp(key):
+        """(call-mode, program-stamp): what the detector keeps history by.
+        Mode-qualified: run / run_steps / run_accumulated executables are
+        distinct, so each mode gets its own first compile for free."""
+        return key[:2]
+
+    def _commit_stamp(self, key):
         """The compiled entry reached the cache: future misses of this
         program-stamp (in this call mode) are recompiles — even if the
         first execution later fails (e.g. check_nan_inf raises)."""
-        try:
-            stamp = (part_names, key[part_names.index("program-stamp")])
-        except ValueError:
-            return
+        stamp = self._stamp(key)
         with self._detector_lock:
             if stamp in self._pending_stamps:
                 self._pending_stamps.discard(stamp)
                 self._compiled_stamps.add(stamp)
 
-    def _fetch(self, spans, program, fetch_names, user_fetch_n,
-               compiled_now, feed_vals, fetches, return_numpy, steps=None,
-               counters=()):
-        """Epilogue shared by the three run modes: the `fetch` phase, and
-        what the call's record (`_CallSpans._record`, at exit) will say.
+    def _fetch(self, spans, c, fetches, return_numpy):
+        """The `fetch` phase, and what the call's record
+        (`_CallSpans._record`, at exit) will say.
 
         Under jax's async dispatch the Python call returns as soon as the
         computation is ENQUEUED, and the first np.asarray blocks until
@@ -1657,23 +1619,24 @@ class Executor:
         above): the pair the cost model's launch term is checked
         against."""
         spans.phase("fetch")
-        if counters:
+        fetch_names = c.fetch_names
+        if c.counters:
             # the last len(counters) fetches are the program's device
             # counters: left on the device unless tracing is on
-            cut = len(fetches) - len(counters)
+            cut = len(fetches) - len(c.counters)
             if spans.on:
                 spans.counters = {
                     name: float(np.mean(np.asarray(v, np.float64)))
-                    for name, v in zip(counters, fetches[cut:])}
+                    for name, v in zip(c.counters, fetches[cut:])}
             fetches, fetch_names = fetches[:cut], fetch_names[:cut]
         outs = ([np.asarray(v) for v in fetches] if return_numpy
                 else list(fetches))
-        user_outs = self._publish_numerics(program, fetch_names,
-                                           user_fetch_n, outs)
+        user_outs = self._publish_numerics(c.program, fetch_names,
+                                           c.user_fetch_n, outs)
         spans.phase(None)
         if spans.on:
-            spans.finished(compiled_now, steps, return_numpy, feed_vals,
-                           outs if return_numpy else None)
+            spans.finished(c.compiled_now, c.count, return_numpy,
+                           c.feed_vals, outs if return_numpy else None)
         return user_outs
 
     def _count_error(self, mon):
@@ -1838,96 +1801,38 @@ class Executor:
             if n not in feed_set and scope.find_var(n) is not None
         )
 
-    def _to_device_array(self, program, name, value):
+    def _to_device_array(self, program, name, value, layout=None,
+                         stacked=False):
+        """A feed on the device: where jax puts it by default, or under a
+        Layout where that says (`stacked`: with a leading steps axis)."""
         import jax
         import jax.numpy as jnp
 
         v = program.global_block()._find_var_recursive(name)
-        if isinstance(value, jax.Array):
-            # already device-resident: never round-trip to host (but honor a
-            # declared bfloat16 feed dtype, same as the numpy path)
-            if v is not None and v.dtype == "bfloat16" and value.dtype != jnp.bfloat16:
-                return value.astype(jnp.bfloat16)
-            return value
-        arr = np.asarray(value)
-        if v is not None and v.dtype and arr.dtype != np.dtype("O"):
-            target = v.dtype
-            if target == "bfloat16":
-                arr = arr.astype(np.float32)
-                return jnp.asarray(arr).astype(jnp.bfloat16)
-        return jnp.asarray(arr)
-
-    def _compile(self, program, feed, feed_names, fetch_names, scope):
-        import jax
-
-        self._maybe_verify(program, feed_names, fetch_names, scope)
-        block = program.global_block()
-        state_reads, state_writes = analyze_block_io(block, feed_names, scope)
-
-        probe_random = program_uses_random(block)
-
-        write_set = set(state_writes)
-        rw_state = [n for n in state_reads if n in write_set]
-        ro_state = [n for n in state_reads if n not in write_set]
-
-        check = self.check_nan_inf
-        nan_check_ops: List[str] = []
-
-        def run_fn(feed_vals, rw_vals, ro_vals, key=None):
-            if key is None:
-                key = prng_key(program.random_seed or 0)
-            tctx = TraceContext(
-                program, key, is_test=getattr(program, "_is_test", False),
-                check_nan_inf=check,
-            )
-            env: Dict[str, Any] = {}
-            for n, v in zip(feed_names, feed_vals):
-                env[n] = v
-            for n, v in zip(rw_state, rw_vals):
-                env[n] = v
-            for n, v in zip(ro_state, ro_vals):
-                env[n] = v
-            trace_block(block, env, tctx)
-            fetches = []
-            for n in fetch_names:
-                if n not in env:
-                    raise KeyError(
-                        f"fetch target {n!r} was not produced by the program"
-                    )
-                fetches.append(env[n])
-            new_state = [env.get(n) for n in state_writes]
-            if check:
-                nan_check_ops.clear()
-                nan_check_ops.extend(d for d, _ in tctx.nan_checks)
-                import jax.numpy as jnp
-
-                flags = jnp.stack(
-                    [f for _, f in tctx.nan_checks]
-                ) if tctx.nan_checks else jnp.ones((0,), bool)
-                return fetches, new_state, flags
-            return fetches, new_state
-
-        if probe_random:
-            jitted = jax.jit(run_fn, donate_argnums=(1,))
-        else:
-            jitted = jax.jit(
-                lambda f, rw, ro: run_fn(f, rw, ro), donate_argnums=(1,)
-            )
-        return _CompiledEntry(
-            jitted, rw_state, ro_state, state_writes, probe_random,
-            nan_check_ops=nan_check_ops if check else None,
-            jitted=jitted, run_lock=self._stateful_lock,
-        )
+        as_bf16 = v is not None and v.dtype == "bfloat16"
+        if not isinstance(value, jax.Array):
+            # (an already device-resident feed never round-trips to host)
+            value = np.asarray(value)
+            as_bf16 = as_bf16 and value.dtype != np.dtype("O")
+            if as_bf16:
+                value = value.astype(np.float32)
+        if layout is not None:
+            value = jax.device_put(value, layout.feed_sharding(name, stacked))
+        elif not isinstance(value, jax.Array):
+            value = jnp.asarray(value)
+        # a declared bfloat16 feed dtype is honored whatever came in
+        if as_bf16 and value.dtype != jnp.bfloat16:
+            return value.astype(jnp.bfloat16)
+        return value
 
 
 def latest_jitted_entry(exe: "Executor") -> _CompiledEntry:
-    """The most recently compiled cache entry that kept its AOT handle
-    (`entry.jitted`) — the ONE introspection hook for re-lowering an
-    executed computation to optimized-HLO text or CompiledMemoryStats
-    (tools/hlo_diag.py, bench.py memory_probe, memory.xla_cross_check,
-    the kernel-fusion tests).  Dict insertion order is compile order, so
-    the last entry is the caller's most recent run/run_steps compile."""
-    entries = [e for e in exe._cache.values() if e.jitted is not None]
+    """The most recently compiled cache entry, for the scripts and tests
+    that lower an executed computation again by hand (tools/hlo_diag.py,
+    bench.py memory_probe, the kernel-fusion tests); the package's own
+    callers use `Executor.lower`.  Dict insertion order is compile order,
+    so the last entry is the caller's most recent compile."""
+    entries = list(exe._cache.values())
     if not entries:
         raise RuntimeError(
             "no compiled jitted entry in the executor cache — run the "

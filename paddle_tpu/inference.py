@@ -270,25 +270,12 @@ def _export_aot_bundle(dirname, feed_examples, place, jax, se, json) -> int:
     os.makedirs(out_dir, exist_ok=True)
     n_ok = 0
     for i, feed in enumerate(feed_examples):
-        # prime the executor cache (compiles exactly this signature); the
-        # cache is cleared first so the single surviving entry IS this
-        # signature's (a repeat signature would otherwise hit an older
-        # entry and [-1] would grab the wrong executable)
-        exe._cache.clear()
-        exe.run(program, feed=feed, fetch_list=pred._fetch_names,
-                scope=scope)
-        entry = list(exe._cache.values())[-1]
+        # this signature's entry and the arguments a call of it passes
+        # (the names beside the executable go into the manifest, so the
+        # Lowered of `Executor.lower` alone would not do)
+        entry, args = exe._lowerable(program, feed, pred._fetch_names, scope)
         feed_names = sorted(feed)
-        feed_vals = [exe._to_device_array(program, n, feed[n])
-                     for n in feed_names]
-        rw_vals = [scope.find_var(n) for n in entry.rw_state]
-        ro_vals = [scope.find_var(n) for n in entry.ro_state]
-        args = (feed_vals, rw_vals, ro_vals)
-        if entry.needs_key:
-            from .core.executor import prng_key
-
-            args = args + (jax.random.fold_in(
-                prng_key(program.random_seed or 0), 0),)
+        feed_vals = args[0]
         # The executor's entry is jitted with donate_argnums=(1,) (rw
         # buffers update in place), and that input/output aliasing gets
         # baked into the serialized executable.  jax's deserialized
@@ -299,7 +286,7 @@ def _export_aot_bundle(dirname, feed_examples, place, jax, se, json) -> int:
         # Bundles therefore serialize a donation-FREE recompile; rw
         # state on inference programs is tiny (quant scalars, BN stats),
         # so the per-call copy is noise.
-        entry_src = getattr(entry.fn, "__wrapped__", None)
+        entry_src = getattr(entry.jitted, "__wrapped__", None)
         if entry_src is None:
             raise RuntimeError(
                 "export_aot_bundle: executor entry is not a jitted "

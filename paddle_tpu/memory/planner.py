@@ -597,26 +597,10 @@ def xla_cross_check(plan: MemoryPlan, exe, program, feed, fetch_list,
     CI agreement gate compares the estimator against
     (PLANNER_XLA_TOLERANCE).  Costs one extra XLA compile; call it from
     tools/bench paths, never hot loops."""
-    import jax
-
-    fetch_names = [v.name if isinstance(v, fw.Variable) else v
-                   for v in (fetch_list or [])]
-    from ..core.executor import latest_jitted_entry
-
-    # populate the cache (also materializes scope state the AOT lower
-    # needs); the entry this signature compiled is the most recent one
-    exe.run(program, feed=feed, fetch_list=fetch_names, scope=scope)
-    entry = latest_jitted_entry(exe)
-    feed_names = sorted(feed or {})
-    feed_vals = [exe._to_device_array(program, n, feed[n])
-                 for n in feed_names]
-    rw = [scope.find_var(n) for n in entry.rw_state]
-    ro = [scope.find_var(n) for n in entry.ro_state]
-    if entry.needs_key:
-        lowered = entry.jitted.lower(feed_vals, rw, ro,
-                                     jax.random.key(0, impl="rbg"))
-    else:
-        lowered = entry.jitted.lower(feed_vals, rw, ro)
+    # the run materializes scope state the lowering reads (and compiles
+    # the entry `lower` then finds)
+    exe.run(program, feed=feed, fetch_list=fetch_list, scope=scope)
+    lowered = exe.lower(program, feed, fetch_list, scope)
     stats = xla_memory_stats(lowered.compile())
     plan.xla = stats
     return stats

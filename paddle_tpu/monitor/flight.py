@@ -492,11 +492,11 @@ def compile_phases() -> Dict[str, float]:
 
 def device_counter(program, name: str, var) -> None:
     """Declare `var` (a variable of `program`, a scalar a step) a device
-    counter: the compiled steps of `Executor.run_steps` return it with
-    every call, and while `monitor.spans_on()` holds the call's
-    `executor.run_steps` flight event carries its mean over the call's
-    steps under `counters[name]`.  With tracing off the value stays on
-    the device and nothing reads it."""
+    counter: the compiled steps return it with every call, whichever of
+    `Executor.run` / `run_steps` / `run_accumulated` runs them, and while
+    `monitor.spans_on()` holds the call's `executor.<mode>` flight event
+    carries its mean over the call's steps under `counters[name]`.  With
+    tracing off the value stays on the device and nothing reads it."""
     counters = getattr(program, "_device_counters", None)
     if counters is None:
         counters = program._device_counters = {}

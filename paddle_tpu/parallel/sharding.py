@@ -11,7 +11,11 @@ Usage:
     plan = ShardingPlan(mesh_axes={"data": 4, "model": 2},
                         param_rules=[(r".*attn.*w", P(None, "model"))])
     compiled = ShardedProgram(prog, plan, loss_name=...)
-    exe.run(compiled, feed=..., fetch_list=[...])
+    exe.run(compiled, feed=..., fetch_list=[...])       # or run_steps /
+                                                        # run_accumulated
+
+A ShardedProgram runs nothing itself: it hands the executor its program and
+the plan as a `Layout` (core/executor.py), an argument of the one call path.
 """
 
 from __future__ import annotations
@@ -23,7 +27,6 @@ import numpy as np
 
 from ..core import executor as exec_mod
 from ..core import framework as fw
-from ..core.executor import prng_key as _prng_key
 
 
 class ShardingPlan:
@@ -123,8 +126,7 @@ class ShardedProgram:
         self.plan = plan
         self._loss_name = loss_name
         self._mesh = None
-        self._cache = {}
-        self._run_counter = 0
+        self._layout = None
 
     @property
     def mesh(self):
@@ -132,138 +134,19 @@ class ShardedProgram:
             self._mesh = self.plan.build_mesh()
         return self._mesh
 
-    def _run(self, executor, feed, fetch_list, scope, return_numpy):
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        feed = feed or {}
-        scope = scope or exec_mod.global_scope()
-        program = self._program
-        mesh = self.mesh
-        fetch_names = [
-            v.name if isinstance(v, fw.Variable) else v for v in (fetch_list or [])
-        ]
-        feed_names = sorted(feed)
-        block = program.global_block()
-
-        key = (
-            program.fingerprint(),
-            bool(getattr(program, "_amp_bf16", False)),
-            bool(getattr(program, "_is_test", False)),
-            tuple(feed_names),
-            tuple(
-                (tuple(np.asarray(feed[n]).shape), str(np.asarray(feed[n]).dtype))
-                for n in feed_names
-            ),
-            tuple(fetch_names),
-        )
-        entry = self._cache.get(key)
-        if entry is None:
-            entry = self._compile(program, feed_names, fetch_names, scope, mesh)
-            self._cache[key] = entry
-        (jitted, rw_state, ro_state, state_writes, needs_key, shardings) = entry
-
-        feed_vals = [
-            jax.device_put(
-                np.asarray(feed[n]),
-                NamedSharding(mesh, self.plan.spec_for_feed(n)),
-            )
-            for n in feed_names
-        ]
-
-        def place(n):
-            val = scope.find_var(n)
-            if val is None:
-                return None
-            want = shardings.get(n)
-            if want is not None and getattr(val, "sharding", None) != want:
-                return jax.device_put(val, want)
-            return val
-
-        rw_vals = [place(n) for n in rw_state]
-        ro_vals = [place(n) for n in ro_state]
-
-        self._run_counter += 1
-        if needs_key:
-            k = jax.random.fold_in(
-                _prng_key(program.random_seed or 0), self._run_counter
-            )
-            fetches, new_state = jitted(feed_vals, rw_vals, ro_vals, k)
-        else:
-            fetches, new_state = jitted(feed_vals, rw_vals, ro_vals)
-        for n, v in zip(state_writes, new_state):
-            scope.set_var(n, v)
-        if return_numpy:
-            return [np.asarray(v) for v in fetches]
-        return list(fetches)
-
-    def _compile(self, program, feed_names, fetch_names, scope, mesh):
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        block = program.global_block()
-        state_reads, state_writes = exec_mod.analyze_block_io(
-            block, feed_names, scope
-        )
-        write_set = set(state_writes)
-        rw_state = [n for n in state_reads if n in write_set]
-        ro_state = [n for n in state_reads if n not in write_set]
-
-        params = {p.name for p in program.all_parameters()}
-
-        def sharding_for(name):
-            v = scope.find_var(name)
-            shape = getattr(v, "shape", None)
-            spec = self.plan.spec_for_param(
-                name, shape, is_moment=name not in params
-            )
-            return NamedSharding(mesh, spec)
-
-        shardings = {n: sharding_for(n) for n in state_reads + state_writes}
-
-        feed_shardings = [
-            NamedSharding(mesh, self.plan.spec_for_feed(n))
-            for n in feed_names
-        ]
-        probe_random = exec_mod.program_uses_random(block)
-
-        def run_fn(feed_vals, rw_vals, ro_vals, key=None):
-            if key is None:
-                key = _prng_key(program.random_seed or 0)
-            tctx = exec_mod.TraceContext(
-                program, key, is_test=getattr(program, "_is_test", False),
-                mesh=mesh,
-            )
-            env = {}
-            env.update(zip(feed_names, feed_vals))
-            env.update(zip(rw_state, rw_vals))
-            env.update(zip(ro_state, ro_vals))
-            exec_mod.trace_block(block, env, tctx)
-            return (
-                [env[n] for n in fetch_names],
-                [env.get(n) for n in state_writes],
-            )
-
-        in_shardings = (
-            feed_shardings,
-            [shardings[n] for n in rw_state],
-            [shardings[n] for n in ro_state],
-        )
-        out_shardings = (
-            [None] * len(fetch_names),
-            [shardings[n] for n in state_writes],
-        )
-        if probe_random:
-            jitted = jax.jit(run_fn, donate_argnums=(1,),
-                             in_shardings=in_shardings + (None,),
-                             out_shardings=out_shardings)
-        else:
-            jitted = jax.jit(lambda f, rw, ro: run_fn(f, rw, ro),
-                             donate_argnums=(1,),
-                             in_shardings=in_shardings,
-                             out_shardings=out_shardings)
-        return (jitted, rw_state, ro_state, state_writes, probe_random,
-                shardings)
+    def _unwrap(self):
+        """What the executor runs in this wrapper's place (core/executor.py
+        `_unwrap`): the program and the plan as a layout — feeds by
+        `spec_for_feed`, each state name by `spec_for_param` at the shape
+        the scope holds (a name that is no parameter is optimizer state)."""
+        if self._layout is None:
+            plan = self.plan
+            params = {p.name for p in self._program.all_parameters()}
+            self._layout = exec_mod.Layout(
+                self.mesh, plan.spec_for_feed,
+                lambda name, shape: plan.spec_for_param(
+                    name, shape, is_moment=name not in params))
+        return self._program, self._layout
 
 
 def transformer_tp_rules(model_axis="model"):

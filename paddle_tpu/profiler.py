@@ -159,25 +159,8 @@ def cost_analysis(program, feed, fetch_list=None, scope=None):
     executor call over `program` — the reference's per-op FLOP accounting
     role (platform/profiler per-op tables), exact and without executing."""
     from .core import executor as ex
-    from .core import framework as fw
 
-    exe = ex.Executor()
-    scope = scope or ex.global_scope()
-    feed_names = sorted(feed)
-    fetch_names = [
-        v.name if isinstance(v, fw.Variable) else v
-        for v in (fetch_list or [])
-    ]
-    entry = exe._compile(program, feed, feed_names, fetch_names, scope)
-    feed_vals = [exe._to_device_array(program, n, feed[n])
-                 for n in feed_names]
-    rw_vals = [scope.find_var(n) for n in entry.rw_state]
-    ro_vals = [scope.find_var(n) for n in entry.ro_state]
-    if entry.needs_key:
-        lowered = entry.fn.lower(feed_vals, rw_vals, ro_vals,
-                                 ex.prng_key(0))
-    else:
-        lowered = entry.fn.lower(feed_vals, rw_vals, ro_vals)
+    lowered = ex.Executor().lower(program, feed, fetch_list, scope)
     cost = lowered.compile().cost_analysis()
     # jax returns one properties dict per partition on some versions and a
     # bare dict on others; normalize to ONE dict (numeric keys summed)

@@ -99,3 +99,20 @@ def _fresh_programs():
     _sv = _sys.modules.get("paddle_tpu.serving.server")
     if _sv is not None:
         _sv._VERIFY_DROPPED[0] = False
+
+
+@pytest.fixture
+def clean_ring():
+    """The flight ring and the metrics registry, empty before and after;
+    `FLAGS.monitor`, which a test may set, back to its default."""
+    from paddle_tpu import monitor
+    from paddle_tpu.flags import FLAGS
+    from paddle_tpu.monitor import flight
+
+    assert not FLAGS.monitor
+    flight.default_recorder().clear()
+    monitor.default_registry().reset()
+    yield flight.default_recorder()
+    FLAGS.reset("monitor")
+    flight.default_recorder().clear()
+    monitor.default_registry().reset()

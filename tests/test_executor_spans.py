@@ -26,17 +26,6 @@ MISS_PHASES = ["feed", "key", "compile", "gather", "dispatch", "writeback",
 STEPS = 3
 
 
-@pytest.fixture
-def clean_ring():
-    assert not FLAGS.monitor
-    flight.default_recorder().clear()
-    monitor.default_registry().reset()
-    yield flight.default_recorder()
-    FLAGS.reset("monitor")
-    flight.default_recorder().clear()
-    monitor.default_registry().reset()
-
-
 def _train_net():
     prog, startup = pt.Program(), pt.Program()
     with pt.program_guard(prog, startup):
@@ -143,14 +132,48 @@ def test_run_and_run_accumulated_share_the_phases(clean_ring):
         exe.run(prog, feed=one, fetch_list=[loss], scope=scope)
         exe.run_accumulated(prog, feed=acc, fetch_list=[loss], scope=scope)
     (run_ev,) = clean_ring.events(kind="executor.run")[-1:]
-    # run() keys on the host feed, then copies it: its own order
-    assert [p[0] for p in run_ev["phases"]] == [
-        "key", "feed", "gather", "dispatch", "writeback", "fetch"]
+    assert [p[0] for p in run_ev["phases"]] == HIT_PHASES
     _tiles(run_ev)
     (acc_ev,) = clean_ring.events(kind="executor.run_accumulated")[-1:]
     assert [p[0] for p in acc_ev["phases"]] == HIT_PHASES
     assert acc_ev["steps"] == 2 and acc_ev["call"] > run_ev["call"]
     _tiles(acc_ev)
+
+
+@pytest.mark.parametrize("mode", ["run", "run_steps"])
+@pytest.mark.parametrize("layout", ["data_parallel", "sharded"])
+def test_a_wrapped_programs_calls_are_recorded_like_any(clean_ring, layout,
+                                                        mode):
+    """A CompiledProgram or ShardedProgram is an argument of the one call
+    path (8-device CPU mesh): its miss leaves the seven phases, its hit
+    six, under the mode's own kind."""
+    from paddle_tpu.parallel.sharding import ShardedProgram, ShardingPlan
+
+    FLAGS.monitor = True
+    prog, startup = pt.Program(), pt.Program()
+    with pt.program_guard(prog, startup):
+        x = layers.data(name="x", shape=[8], dtype="float32")
+        loss = layers.reduce_mean(layers.fc(x, size=1))
+        pt.optimizer.SGD(1e-2).minimize(loss)
+    exe, scope = pt.Executor(pt.CPUPlace()), pt.Scope()
+    exe.run(startup, scope=scope)
+    target = (pt.CompiledProgram(prog).with_data_parallel(loss.name)
+              if layout == "data_parallel" else
+              ShardedProgram(prog, ShardingPlan(mesh_axes={"data": 8}),
+                             loss_name=loss.name))
+    shape = (8, 8) if mode == "run" else (STEPS, 8, 8)
+    for _ in range(2):
+        getattr(exe, mode)(target, feed={"x": np.ones(shape, "float32")},
+                           fetch_list=[loss], scope=scope)
+    (miss,) = clean_ring.events(kind="executor.compile")[-1:]
+    assert miss["mode"] == mode
+    assert [p[0] for p in miss["phases"]] == MISS_PHASES
+    (hit,) = clean_ring.events(kind=f"executor.{mode}")
+    assert [p[0] for p in hit["phases"]] == HIT_PHASES
+    assert hit["call"] == miss["call"] + 1 and hit.get("steps") == (
+        None if mode == "run" else STEPS)
+    _tiles(hit)
+    assert monitor.default_registry().get("executor.delegated.calls") is None
 
 
 # (b) ------------------------------------------------------------------------

@@ -319,19 +319,31 @@ class TestExecutorTelemetry:
         assert default_registry().names() == []
 
     def test_delegated_program_coarse_telemetry(self):
-        """CompiledProgram delegates via _run: the delegation records
-        coarse call/wall-time metrics; the non-parallel path falls back
-        into run() and gets the full instrumentation too."""
+        """A program that runs itself (the pipeline programs' _run hook)
+        gets coarse call/wall-time metrics round the delegation; a
+        CompiledProgram is no such program any more: it unwraps into the
+        one call path and gets the full instrumentation."""
         FLAGS.monitor = True
         loss = _build_train_net()
         exe = pt.Executor(pt.CPUPlace())
         exe.run(pt.default_startup_program())
-        cp = pt.CompiledProgram(pt.default_main_program())
-        exe.run(cp, feed=_feed(), fetch_list=[loss])
+        main = pt.default_main_program()
+
+        class RunsItself:
+            def _run(self, executor, feed, fetch_list, scope, return_numpy):
+                return executor.run(main, feed, fetch_list, scope,
+                                    return_numpy)
+
+        exe.run(RunsItself(), feed=_feed(), fetch_list=[loss])
         reg = default_registry()
         assert reg.get("executor.delegated.calls").value == 1
         assert reg.get("executor.delegated_seconds").count == 1
-        assert reg.get("executor.run.calls").value >= 1
+        calls = reg.get("executor.run.calls").value
+        exe.run(pt.CompiledProgram(main), feed=_feed(), fetch_list=[loss])
+        assert reg.get("executor.delegated.calls").value == 1
+        assert reg.get("executor.run.calls").value == calls + 1
+        # the wrapper compiled nothing of its own: the inner program's hit
+        assert reg.get("executor.cache_hit").value >= 1
 
     def test_error_counter_on_failed_run(self):
         FLAGS.monitor = True
