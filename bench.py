@@ -1178,11 +1178,8 @@ def run_bert(args, peak):
     mfu = (tps * flops_tok / peak) if peak else None
     # no committed reference BERT number: vs_baseline is the ratio to the
     # BASELINE.json north star (50% MFU on this chip)
-    from paddle_tpu.flags import FLAGS as _FLAGS
-
     config = {"bf16": args.amp, "batch": bs, "seq_len": seq,
               "tiny": args.smoke,
-              "fused_qkv_attention": bool(_FLAGS.fused_qkv_attention),
               "runs": [round(r, 1) for r in runs],
               "spread": round(spread, 1)}
     config.update(mem)
@@ -1290,15 +1287,8 @@ def run_transformer(args, peak):
         mfu = (tps * flops_tok / peak) if peak else None
         # no committed reference transformer number exists: vs_baseline is
         # the ratio to the BASELINE.json north star (50% MFU on this chip)
-        from paddle_tpu.flags import FLAGS as _FLAGS
-
         config = {"bf16": args.amp, "batch": bs, "seq_len": seq,
                   "tiny": args.smoke,
-                  # the r09 A/B knob: run once with
-                  # FLAGS_fused_qkv_attention=0 for the unfused-
-                  # composition baseline record (tools/run_ci.sh does)
-                  "fused_qkv_attention": bool(
-                      _FLAGS.fused_qkv_attention),
                   # the r12 A/B knob: --recompute pairs a rewritten
                   # record next to this one (tools/run_ci.sh does)
                   "recompute": bool(args.recompute),

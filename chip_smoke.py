@@ -509,50 +509,6 @@ def _attention_row(cfg, interpret, rng):
     return kernel, ref, (q, k, v), _tol(cfg["dtype"]), dropped
 
 
-def _qkv_row(cfg, interpret, rng):
-    import jax
-    import jax.numpy as jnp
-
-    from paddle_tpu.kernels import attention as att
-
-    h, dh, dm = cfg["h"], cfg["dh"], cfg["dm"]
-    x = _randn(rng, (cfg["b"], cfg["t"], dm), cfg["dtype"], 0.3)
-    w_qkv = _randn(rng, (dm, 3 * h * dh), cfg["dtype"], 0.05)
-    w_out = _randn(rng, (h * dh, dm), cfg["dtype"], 0.05)
-    seed = jnp.asarray([4321], jnp.uint32)
-    zseed = jnp.zeros((1,), jnp.uint32)
-
-    def fwd_bwd(attn):
-        def run(x, w_qkv, w_out):
-            def loss(x, w_qkv, w_out):
-                y = attn(x, w_qkv, w_out)
-                return jnp.sum(y.astype(jnp.float32) * 1e-2), y
-            (_, y), g = jax.value_and_grad(loss, (0, 1, 2),
-                                           has_aux=True)(x, w_qkv, w_out)
-            return y, g
-        return run
-
-    kernel = fwd_bwd(lambda x, wq, wo: att.flash_qkv_attention(
-        x, wq, wo, None, n_head=h, scale=dh ** -0.5, causal=True,
-        interpret=interpret))
-
-    def ref_attn(x, wq, wo):
-        # the composed projection dots + the XLA attention reference
-        b, t, _ = x.shape
-        qkv = (x @ wq).reshape(b, t, 3, h, dh)
-        ctx = att._reference_bthd(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2],
-                                  None, dh ** -0.5, True, 0.0, zseed)
-        return (ctx.reshape(b, t, h * dh) @ wo).astype(x.dtype)
-
-    def dropped(x, wq, wo):
-        return att.flash_qkv_attention(
-            x, wq, wo, None, n_head=h, scale=dh ** -0.5, causal=True,
-            interpret=interpret, dropout_rate=0.1, dropout_seed=seed)
-
-    return (kernel, fwd_bwd(ref_attn), (x, w_qkv, w_out),
-            _tol(cfg["dtype"]), dropped)
-
-
 def _conv_bn_row(cfg, interpret, rng):
     import jax
     import jax.numpy as jnp
@@ -828,13 +784,12 @@ def _embedding_row(cfg, interpret, rng):
 
 
 def kernel_matrix():
-    """(family, lint matrix, row builder) for the ten canonical matrices
+    """(family, lint matrix, row builder) for the nine canonical matrices
     of analysis/kernel_lint.py."""
     from paddle_tpu.analysis import kernel_lint as kl
 
     return [
         ("attention", kl._ATTENTION_MATRIX, _attention_row),
-        ("qkv_attention", kl._QKV_MATRIX, _qkv_row),
         ("conv_bn", kl._CONV_BN_MATRIX, _conv_bn_row),
         ("ring_attention", kl._RING_MATRIX, _attention_row),
         ("dropout_epilogue", kl._DROPOUT_MATRIX, _dropout_row),

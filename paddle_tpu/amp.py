@@ -160,7 +160,7 @@ SLOT_WHITE_OPS = {
     "fused_attention": frozenset(
         {"Q", "K", "V", "Bias", "Out", "Out@GRAD"}),
     "fused_qkv_attention": frozenset(
-        {"X", "WQkv", "WOut", "Bias", "Ctx", "Out@GRAD"}),
+        {"X", "WQkv", "WOut", "Bias", "Q", "K", "V", "Ctx", "Out@GRAD"}),
     # the expert matmuls run bf16; the pairs' weights stay float32
     "moe_experts": frozenset({"X", "WGateUp", "WDown", "H", "Out@GRAD"}),
 }

@@ -23,7 +23,7 @@ is the TPU-first equivalent for the Python IR:
     grad/weight/update rows in `summary` mode (training-dynamics
     gauges); `off` is zero-cost with a byte-identical fingerprint.
   * kernel_lint.py — statically audits every Pallas kernel plan in
-    kernels/ (attention, fused-qkv, conv_bn, dropout_epilogue, embedding,
+    kernels/ (attention, conv_bn, dropout_epilogue, embedding,
     ring attention): VMEM budget vs the plan gate's estimate, (8,128)
     sublane/lane tile alignment, grid/block divisibility,
     input_output_aliases shape/dtype validity, and revisited-block

@@ -170,16 +170,6 @@ FLAGS.define(
     "reference per-slot composition, graphs op-for-op identical to the "
     "pre-fusion ones")
 FLAGS.define(
-    "fused_qkv_attention", bool, True,
-    "transformer/BERT self-attention sites lower to ONE fused_qkv_attention "
-    "op whose Pallas kernels compute the q/k/v and output projection dots "
-    "tile-by-tile inside the flash-attention grid (kernels/attention.py "
-    "flash_qkv_attention): q/k/v never exist in HBM, so the dot-preferred"
-    "<->custom-call relayout copies at the projection boundaries disappear "
-    "(PERF.md round 9); off = the reference fc + split + fused_attention + "
-    "fc composition, graphs op-for-op identical to the pre-fusion ones and "
-    "parameter names unchanged (checkpoints interop)")
-FLAGS.define(
     "kv_cache", bool, True,
     "autoregressive generation rides the KV-cache decode path "
     "(paddle_tpu/generation): prefill writes per-layer K/V into "

@@ -1393,15 +1393,8 @@ def _flash_kernels(q, k, v, bias, scale=1.0, causal=False, block_q=512,
 
 
 # ---------------------------------------------------------------------------
-# Fused-projection ("qkv") flash attention: the forward kernel takes the RAW
-# [b, t, d_model] activation plus the packed projection weights and computes
-# the q/k/v (and output) projection dots tile-by-tile INSIDE the grid walk.
-# q/k/v tiles materialize in VMEM as the online-softmax loop consumes them
-# and never exist in HBM, so the dot-preferred <-> custom-call layout
-# conversion at the projection boundary (PERF.md post-r08 lead 1:
-# ~1.2 GB/step of relayout copies at the qkv/output projection dots) has
-# no tensor to convert.  Self-attention only (q, k, v all project from the
-# same activation — the transformer/BERT encoder + decoder-self sites).
+# Self-attention from the residual stream ("qkv" attention): the q, k, v and
+# output projections as XLA dots round the bthd flash kernels.
 #
 # Layout contract:
 #   x       [b, t, d_model]          — the residual-stream activation
@@ -1411,470 +1404,87 @@ def _flash_kernels(q, k, v, bias, scale=1.0, causal=False, block_q=512,
 #                                      checkpoints interop bit-for-bit)
 #   w_out   [h*dh, d_model]          — the output projection
 #   y       [b, t, d_model]
-# Inside the kernel the weights ride as [3h, dm, dh] / [h, dh, dm] views
-# (a weight-sized XLA transpose prepared once outside — KB-scale, vs the
-# GB-scale activation relayouts this kernel deletes) and every dot
-# is a plain 2-D per-head matmul: no lane-dim-splitting reshapes, which
-# Mosaic does not lower (r04 pitfall list).
+# Every dot reads or writes [b, t, h, dh], the layout the bthd kernels take,
+# through free reshapes of the weights ([dm, 3, h, dh] / [h, dh, dm]): no
+# [b, t, 3*h*dh] array is made, sliced or concatenated in either direction
+# (that glue was 6-14 % of a site's backward, PERF.md PR 28 (1)).  q, k, v,
+# the context and the logsumexp are the forward's residuals: the backward
+# runs the bthd backward kernels on them between the projections' backward
+# dots and recomputes nothing.
 #
-# The backward leaves the projections to XLA (_qkv_kernels): q, k, v are
-# recomputed by [b*t, dm] x [dm, h*dh] dots, the bthd flash backward
-# kernels give dq, dk, dv from them and the forward's residuals, and dctx,
-# dW_out, dx and dW_qkv are plain dots round them: fused backward walks
-# would recompute q, k, v and dctx per head and tile in each walk, the
-# projection work three times over at 64 output lanes (PERF.md, PR 28).
-# The only fwd->bwd residuals are the attention context (for delta and
-# dW_out) and the per-row logsumexp.
+# Until PR 30 a Pallas kernel (`fused_qkv_fwd`) computed the projections tile
+# by tile inside the attention walk, so that q, k, v never reached HBM.  It
+# ran at a third of the bf16 peak (float32 dots on widened tiles, 64 output
+# lanes a head, unrolled over the heads), its backward had to recompute
+# q, k, v, and the composition below beat it by 6 % of BERT-base's step
+# (PERF.md PR 28 (3), PR 30): deleted in PR 30 with its plan and its flag.
 # ---------------------------------------------------------------------------
 
 
-def _proj(x, w):
-    """x [rows, d_model] f32 @ one head's [d_model, d_head] weight slab in
-    its stored dtype.  A narrow slab pins the dot's precision: from bf16
-    operands every precision yields the same products, and Mosaic refuses
-    an fp32 contract precision on them ("Bad rhs type", libtpu 0.0.34) —
-    which an ambient jax.default_matmul_precision("highest"), the test
-    suite's setting, would otherwise request."""
-    import jax
-    import jax.numpy as jnp
-
-    narrow = w.dtype.itemsize < 4
-    return jnp.dot(x, w,
-                   precision=jax.lax.Precision.DEFAULT if narrow else None)
-
-
-def _set_head(acc, head, val):
-    """acc[head] <- val without per-index vector stores: iota-select over
-    the leading head dim (Mosaic lowers broadcasted_iota + select cleanly;
-    per-head `ref[:, h, :] =` writes and jnp.stack are the r04 pitfalls)."""
-    import jax
-    import jax.numpy as jnp
-
-    idx = jax.lax.broadcasted_iota(jnp.int32, acc.shape, 0)
-    return jnp.where(idx == head, val[None].astype(acc.dtype), acc)
-
-
-def _bias_tile_head(bias_ref, head, bias_h, bias_q1, block_q, q_lo,
-                    block_k, k_lo):
-    """f32 [block_q, block_k] bias tile for ONE head.  Per-head biases
-    ([*, h, *, *]) index the leading head dim; broadcast biases reuse
-    _read_bias.  q-collapsed ([.., 1, tk]) tiles expand through the
-    ones-column dot (sublane-extent-1 broadcasts next to matmuls
-    miscompile — r04)."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    if bias_h:
-        if bias_q1:
-            t = bias_ref[head, :, pl.ds(k_lo, block_k)].astype(jnp.float32)
-        else:
-            t = bias_ref[head, pl.ds(q_lo, block_q),
-                         pl.ds(k_lo, block_k)].astype(jnp.float32)
-    else:
-        t = _read_bias(bias_ref, q_lo, block_q, k_lo, block_k, bias_q1)
-    if bias_q1:
-        ones = jnp.ones((block_q, 1), jnp.float32)
-        t = jax.lax.dot_general(ones, t, (((1,), (0,)), ((), ())))
-    return t
-
-
-def _qkv_fwd_kernel(seed_ref, x_ref, w_ref, wout_ref, bias_ref, y_ref,
-                    ctx_ref, lse_ref, *, scale, n_head, d_head, block_q,
-                    block_k, causal, seq, bias_q1, bias_h, drop_rate,
-                    inv_keep):
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    qi = pl.program_id(1)
-    h, dh = n_head, d_head
-    pid0h = pl.program_id(0) * h
-
-    x_q = x_ref[pl.ds(qi * block_q, block_q), :].astype(jnp.float32)
-    dm = x_q.shape[-1]
-    n_kv = seq // block_k
-    if causal:
-        hi = qi * block_q + block_q - 1
-        n_kv = jnp.minimum(n_kv, (hi // block_k) + 1)
-
-    y_acc = jnp.zeros((block_q, dm), jnp.float32)
-    ctx_out = jnp.zeros((h, block_q, dh), jnp.float32)
-    lse_out = jnp.zeros((h, block_q), jnp.float32)
-
-    for head in range(h):
-        # the q projection dot: this head's [dm, dh] weight slab against
-        # the activation tile — q exists only in VMEM from here on
-        q = _proj(x_q, w_ref[head]) * scale          # [block_q, dh]
-        m = jnp.full((block_q,), -jnp.inf, jnp.float32)
-        l = jnp.zeros((block_q,), jnp.float32)
-        acc = jnp.zeros((block_q, dh), jnp.float32)
-
-        def body(j, carry, head=head):
-            m, l, acc = carry
-            x_k = x_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-            k = _proj(x_k, w_ref[h + head])            # [block_k, dh]
-            v = _proj(x_k, w_ref[2 * h + head])
-            s = q @ k.T                          # [block_q, block_k]
-            if bias_ref is not None:
-                s = s + _bias_tile_head(bias_ref, head, bias_h, bias_q1,
-                                        block_q, 0, block_k, j * block_k)
-            if causal:
-                q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 0)
-                k_pos = j * block_k + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 1)
-                s = jnp.where(q_pos >= k_pos, s, -1e30)
-            m_new = jnp.maximum(m, s.max(axis=1))
-            p = jnp.exp(s - m_new[:, None])
-            alpha = jnp.exp(m - m_new)
-            l_new = l * alpha + p.sum(axis=1)
-            if drop_rate:
-                # the hash mask, keyed on (seed, b*H + head, q*Tk + k):
-                # bit-identical to the one the bthd backward kernels (and
-                # the whole composed route) regenerate for the element
-                keep = _keep_tile(seed_ref[0], (block_q, block_k),
-                                  pid0h + head, seq, seq, qi * block_q,
-                                  j * block_k, drop_rate)
-                p = jnp.where(keep, p, 0.0)
-            return m_new, l_new, acc * alpha[:, None] + p @ v
-
-        m, l, acc = jax.lax.fori_loop(0, n_kv, body, (m, l, acc))
-        masked = (l == 0.0) | (m <= -1e29)
-        l_safe = jnp.where(masked, 1.0, l)
-        if drop_rate:
-            acc = acc * inv_keep
-        ctx_h = jnp.where(masked[:, None], 0.0, acc / l_safe[:, None])
-        lse_h = jnp.where(masked, jnp.inf, m + jnp.log(l_safe))
-        # output-projection epilogue: this head's context never leaves
-        # VMEM on the y path
-        y_acc = y_acc + ctx_h.astype(y_ref.dtype).astype(
-            jnp.float32) @ wout_ref[head].astype(jnp.float32)
-        ctx_out = _set_head(ctx_out, head, ctx_h)
-        lse_out = _set_head(lse_out, head, lse_h)
-
-    y_ref[...] = y_acc.astype(y_ref.dtype)
-    ctx_ref[...] = ctx_out.astype(ctx_ref.dtype)
-    lse_ref[...] = lse_out
-
-
-# -- fused-projection host plumbing ----------------------------------------
-
-
-def _prep_w_qkv(w_qkv, h, dh):
-    """[dm, 3*h*dh] (fc-packed: q|k|v, head-major within each third) ->
-    [3h, dm, dh] so the kernels index one head's slab off the leading dim
-    (w[head] / w[h+head] / w[2h+head]).  Weight-sized, done once inside
-    the jitted step and CSEd across the fwd/bwd kernels."""
+def _qkv_weight_views(w_qkv, w_out, n_head):
+    """The packed weights as the per-head operands of the projection
+    einsums: w_qkv as [dm, 3, h, dh], w_out as [h, dh, dm_out]."""
     dm = w_qkv.shape[0]
-    return w_qkv.reshape(dm, 3, h, dh).transpose(1, 2, 0, 3).reshape(
-        3 * h, dm, dh)
-
-
-def _prep_w_out(w_out, h, dh):
-    """[h*dh, dm] -> [h, dh, dm] (head-major rows, a free reshape)."""
-    return w_out.reshape(h, dh, w_out.shape[1])
-
-
-def _qkv_plan(x, n_head, d_head, block_q, block_k, interpret, bias=None):
-    """Static feasibility of a fused-projection site: the forward kernel
-    AND the bthd kernels its backward runs on the recomputed q, k, v
-    (_plan on [b, t, h, dh] in x's dtype, under the caller's block
-    sizes).  Returns (ok, block_q, block_k, interpret), the blocks the
-    forward's.  Rejections fall back to the composed x@W +
-    flash_attention(bthd) path (numerically identical)."""
-    import jax
-
-    from .placement import resolve
-
-    b, t, dm = x.shape
-    heads = jax.ShapeDtypeStruct((b, t, n_head, d_head), x.dtype)
-    bthd_ok = _plan(heads, heads, block_q, block_k, interpret, "bthd")[0]
-    compiled, interpret = resolve(interpret)
-    block_q = min(block_q, t)
-    block_k = min(block_k, t)
-    esize = 2 if x.dtype.itemsize == 2 else 4
-    # same byte-bound cap discipline as the bthd plan: streamed x tiles
-    # are [block, dm]; when a 128-row tile already exceeds the 256 KB
-    # bound, compiled mode rejects to the composed fallback instead of
-    # flooring the cap back up to 128 (kernel-lint catch)
-    cap = (256 * 1024) // max(dm * esize, 1)
-    if cap < 128:
-        if compiled:
-            return False, 0, 0, interpret
-        cap = 128
-    block_q = min(block_q, cap)
-    block_k = min(block_k, cap)
-    if compiled:
-        # Mosaic alignment: the kernel dynamic-slices x on the sublane
-        # dim by block_q / block_k -> 128-aligned blocks
-        if block_k % 128:
-            block_k = 128 if t % 128 == 0 else 0
-        if block_q % 128:
-            block_q = 128 if t % 128 == 0 else 0
-    # VMEM residents of the forward kernel: x full-seq, the y and ctx
-    # tiles, both weight views, and the bias block ([hb, block|1, tk] on
-    # the q grid — a per-head full-plane bias is the dominant resident
-    # at long sequence).  BERT-base bf16 lands ~5.5 MB of a 16 MB VMEM;
-    # the weight views are the bulk, so the gate stays explicit.
-    vmem = (t * dm + block_q * dm + n_head * block_q * d_head
-            + 4 * n_head * dm * d_head) * esize
-    if bias is not None and block_q and block_k:
-        bshape = bias.shape
-        hb = bshape[-3] if len(bshape) >= 3 else 1
-        tqb = bshape[-2] if len(bshape) >= 2 else 1
-        besize = bias.dtype.itemsize
-        q_rows = block_q if tqb > 1 else 1
-        vmem += hb * q_rows * t * besize
-    ok = (
-        bthd_ok
-        and block_q
-        and block_k
-        and t % block_q == 0
-        and t % block_k == 0
-        and d_head % 64 == 0
-        and (compiled or interpret)
-        and (interpret or (dm % 128 == 0 and vmem < 14 * 1024 * 1024))
-    )
-    return ok, block_q, block_k, interpret
-
-
-def _qkv_forward(x, w3, wo, bias, seed, scale, causal, n_head, d_head,
-                 block_q, block_k, interpret, dropout_rate):
-    """(y, ctx, lse) via the fused forward kernel.  w3/wo are the prepped
-    [3h, dm, dh] / [h, dh, dm] views; ctx is the [b, h, t, dh] residual in
-    x.dtype; lse is [b, h, t] f32.  Dropout masks are the hash's
-    (_qkv_kernels composes a site that would draw from the hardware
-    PRNG)."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    b, t, dm = x.shape
-    h, dh = n_head, d_head
-    drop_rate, inv_keep = _drop_params(dropout_rate)
-
-    x_spec = pl.BlockSpec((None, t, dm), lambda i, j: (i, 0, 0))
-    w3_spec = pl.BlockSpec((3 * h, dm, dh), lambda i, j: (0, 0, 0))
-    wo_spec = pl.BlockSpec((h, dh, dm), lambda i, j: (0, 0, 0))
-    in_specs = [_seed_spec(), x_spec, w3_spec, wo_spec]
-    args = [seed, x, w3, wo]
-    bias_q1 = bias_h = False
-    if bias is not None:
-        spec, bias_q1, bias_h = _bias_spec_bthd(
-            bias, b, h, block_q, block_k, for_dkv=False)
-        in_specs.append(spec)
-        args.append(bias)
-
-    kern = functools.partial(
-        _qkv_fwd_kernel, scale=scale, n_head=h, d_head=dh, block_q=block_q,
-        block_k=block_k, causal=causal, seq=t, bias_q1=bias_q1,
-        bias_h=bias_h, drop_rate=drop_rate, inv_keep=inv_keep,
-    )
-    if bias is None:
-        def kernel(seed_ref, x_ref, w_ref, wout_ref, y_ref, ctx_ref,
-                   lse_ref):
-            return kern(seed_ref, x_ref, w_ref, wout_ref, None, y_ref,
-                        ctx_ref, lse_ref)
-    else:
-        kernel = kern
-
-    y, ctx, lse = pl.pallas_call(
-        kernel,
-        name="fused_qkv_fwd",
-        grid=(b, t // block_q),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((None, block_q, dm), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, h, block_q, dh), lambda i, j: (i, 0, j, 0)),
-            pl.BlockSpec((None, h, block_q), lambda i, j: (i, 0, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, t, dm), x.dtype),
-            jax.ShapeDtypeStruct((b, h, t, dh), x.dtype),
-            jax.ShapeDtypeStruct((b, h, t), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*args)
-    return y, ctx, lse
-
-
-def _composed_qkv(x, w_qkv, w_out, bias, n_head, scale, causal,
-                  block_q, block_k, interpret, dropout_rate, dropout_seed,
-                  trainable_bias):
-    """The unfused composition (projection dots in XLA + bthd flash
-    attention): the numerics reference for the fused kernels AND the
-    fallback for shapes the plan rejects — identical math to the
-    fc + split + fused_attention + fc graph the models emit flag-off."""
-    ctx = _composed_no_out(x, w_qkv, bias, n_head, scale, causal, block_q,
-                           block_k, interpret, dropout_rate, dropout_seed,
-                           trainable_bias)
-    return (ctx @ w_out).astype(x.dtype)
-
-
-def flash_qkv_attention(x, w_qkv, w_out=None, bias=None, n_head=1,
-                        scale=1.0, causal=False, block_q=512, block_k=512,
-                        interpret=None, dropout_rate=0.0, dropout_seed=None,
-                        trainable_bias=True):
-    """Self-attention with the q/k/v (and output) projections fused INTO
-    the flash kernels.  x: [b, t, d_model]; w_qkv: [d_model, 3*h*dh]
-    (the layers.fc packed layout); w_out: [h*dh, d_model].  Returns
-    [b, t, d_model].
-
-    In the forward q/k/v are computed tile-by-tile in VMEM as the
-    online-softmax walk consumes them and never exist in HBM — the
-    dot-preferred <-> custom-call relayout copies at the projection
-    boundaries (PERF.md post-r08 lead 1, ~1.2 GB/step) disappear with the
-    boundary itself.  The custom VJP recomputes q/k/v by XLA dots, runs
-    the bthd backward kernels on them and the forward's residuals (the
-    attention context and the logsumexp) and leaves the projection
-    backward to XLA dots.
-
-    w_out=None, non-self shapes, a plan rejection or dropout masks from
-    the hardware PRNG run the composed x@W + flash_attention(fmt="bthd")
-    path — numerically identical to the unfused graph.  Weights-dropout
-    semantics and seeds match flash_attention; the kernel route's masks
-    are the hash's, bit-identical to the unfused kernels', so fused vs
-    unfused training trajectories agree exactly on CPU.  trainable_bias
-    as in flash_attention (a stop-gradient mask leaves a dropout site on
-    the TPU to the hardware PRNG, that is to the composed route; the dbias
-    recompute is XLA-side and DCEd for stop-grad biases)."""
-    return flash_qkv_attention_fwd(
-        x, w_qkv, w_out, bias, n_head=n_head, scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k, interpret=interpret,
-        dropout_rate=dropout_rate, dropout_seed=dropout_seed,
-        trainable_bias=trainable_bias)[0]
-
-
-def flash_qkv_attention_fwd(x, w_qkv, w_out=None, bias=None, **options):
-    """flash_qkv_attention (and its options) with the residuals its
-    kernel writes anyway: (y, ctx, lse), ctx [b, h, t, dh] in x.dtype and
-    lse [b, h, t] f32 — both None on the composed route.  Differentiable
-    in `y` (the custom VJP of the kernel pair); a caller that keeps
-    (ctx, lse) hands them to flash_qkv_attention_bwd instead, and the
-    forward kernel runs once (ops/fused_ops.py)."""
-    seed, fwd, bwd, norm = _qkv_kernels(x, w_qkv, w_out, bias, **options)
-    if bwd is None:
-        return fwd(x, w_qkv, w_out, bias, seed), None, None
-    return _vjp_of_pair(fwd, bwd, 1)(x, w_qkv, w_out, norm(bias), seed)
-
-
-def flash_qkv_attention_bwd(x, w_qkv, w_out, bias, ctx, lse, g, **options):
-    """(dx, dw_qkv, dw_out, dbias) from flash_qkv_attention_fwd's
-    (ctx, lse) and the cotangent g of y, under the same options: the bthd
-    backward kernels between XLA projection dots, no forward kernel; the
-    body of flash_qkv_attention's VJP rule.
-    dbias is None unless a bias is given and trainable_bias holds.  None
-    where the plan rejects the operands (the forward then gave no
-    residuals either)."""
-    seed, _, bwd, norm = _qkv_kernels(x, w_qkv, w_out, bias, **options)
-    if bwd is None:
-        return None
-    return _bwd_with_dbias(
-        bwd, norm, bias, options.get("trainable_bias", True),
-        x, w_qkv, w_out, norm(bias), seed, ctx, lse, g)
-
-
-def _qkv_kernels(x, w_qkv, w_out, bias, n_head=1, scale=1.0, causal=False,
-                 block_q=512, block_k=512, interpret=None, dropout_rate=0.0,
-                 dropout_seed=None, trainable_bias=True):
-    """(seed, fwd, bwd, norm) for one flash_qkv_attention site.  On the
-    kernel route fwd(x, w_qkv, w_out, bias, seed) -> (y, ctx, lse) and
-    bwd(x, w_qkv, w_out, bias, seed, ctx, lse, g, want_dbias) -> (dx,
-    dw_qkv, dw_out, dbias or None), both on bias = norm(the caller's
-    bias).  On the composed route (w_out=None, a plan rejection, a bias
-    whose shape does not broadcast, dropout masks drawn from the hardware
-    PRNG) bwd is None and fwd -> y alone."""
-    import jax.numpy as jnp
-
-    b, t, dm = x.shape
     if w_qkv.shape[1] % (3 * n_head):
         raise ValueError(
             f"flash_qkv_attention: packed dim {w_qkv.shape[1]} not "
             f"divisible by 3*n_head={3 * n_head}")
-    hd = w_qkv.shape[1] // 3
-    dh = hd // n_head
-    seed = _dropout_seed_arg(dropout_rate, dropout_seed, (t, t),
-                             "flash_qkv_attention")
-    ok, bq, bk, interp = _qkv_plan(x, n_head, dh, block_q, block_k,
-                                   interpret, bias=bias)
-    # the fused forward and the bthd kernels of its backward agree on the
-    # hash masks alone (one key an element); the hardware PRNG's bits
-    # follow the tile they are drawn for, and the bthd kernels draw a
-    # whole-head tile.  So a site whose masks would come from the hardware
-    # PRNG (a trainable bias pins the hash: see flash_attention) is
-    # composed whole, forward too
-    if not (trainable_bias and bias is not None) \
-            and _use_hw_prng(dropout_rate, interp):
-        ok = False
-    norm = _bias_norm(bias, b, n_head, t, t) \
-        if ok and w_out is not None else None
-    if norm is None:
-        def composed(x, w_qkv, w_out, bias, seed):
-            args = (bias, n_head, scale, causal, block_q, block_k,
-                    interpret, dropout_rate, seed, trainable_bias)
-            return _composed_no_out(x, w_qkv, *args) if w_out is None \
-                else _composed_qkv(x, w_qkv, w_out, *args)
-
-        return seed, composed, None, None
-
-    def fwd(x, w_qkv, w_out, bias, seed):
-        return _qkv_forward(x, _prep_w_qkv(w_qkv, n_head, dh),
-                            _prep_w_out(w_out, n_head, dh), bias, seed,
-                            scale, causal, n_head, dh, bq, bk, interp,
-                            dropout_rate)
-
-    def bwd(x, w_qkv, w_out, bias, seed, ctx, lse, g, want_dbias):
-        # the projection backward as XLA dots round the bthd backward
-        # kernels.  Every dot reads or writes [b, t, h, dh], the layout
-        # the kernels take, and contracts over (h, dh) or d_model: no
-        # [b, t, 3*h*dh] array is sliced or concatenated (that glue was
-        # 6-14 % of a site's backward, PERF.md PR 28).  q, k, v are
-        # rounded to x's dtype as _composed_no_out's are; the forward's
-        # ctx is [b, h, t, dh]
-        from ..monitor import flight
-
-        flight.note_compile_count("qkv_bwd_composed")
-        w4 = w_qkv.reshape(dm, 3, n_head, dh)
-        wo3 = w_out.reshape(n_head, dh, dm)
-        q, k, v = (jnp.einsum("btm,mhd->bthd", x, w4[:, i]).astype(x.dtype)
-                   for i in range(3))
-        out = ctx.transpose(0, 2, 1, 3)
-        dctx = jnp.einsum("btm,hdm->bthd", g, wo3).astype(x.dtype)
-        dw_out = jnp.einsum("bthd,btm->hdm", out, g).reshape(
-            hd, dm).astype(w_out.dtype)
-        *dqkv, dbias = flash_attention_bwd(
-            q, k, v, bias, out, lse, dctx, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, interpret=interpret,
-            fmt="bthd", dropout_rate=dropout_rate, dropout_seed=seed,
-            trainable_bias=want_dbias)
-        dx = sum(jnp.einsum("bthd,mhd->btm", d, w4[:, i],
-                            preferred_element_type=jnp.float32)
-                 for i, d in enumerate(dqkv)).astype(x.dtype)
-        dw_qkv = jnp.stack(
-            [jnp.einsum("btm,bthd->mhd", x, d) for d in dqkv],
-            axis=1).reshape(dm, 3 * hd).astype(w_qkv.dtype)
-        return dx, dw_qkv, dw_out, dbias
-
-    return seed, fwd, bwd, norm
+    dh = w_qkv.shape[1] // (3 * n_head)
+    return (w_qkv.reshape(dm, 3, n_head, dh),
+            w_out.reshape(n_head, dh, w_out.shape[1]))
 
 
-def _composed_no_out(x, w_qkv, bias, n_head, scale, causal, block_q,
-                     block_k, interpret, dropout_rate, seed,
-                     trainable_bias):
-    """Composed qkv projection + bthd flash attention, no output
-    projection: the shared body of both composed fallbacks — returns the
-    [b, t, h*dh] context."""
-    b, t, _ = x.shape
-    hd = w_qkv.shape[1] // 3
-    dh = hd // n_head
-    qkv = x @ w_qkv
-    q = qkv[..., :hd].reshape(b, t, n_head, dh)
-    k = qkv[..., hd:2 * hd].reshape(b, t, n_head, dh)
-    v = qkv[..., 2 * hd:].reshape(b, t, n_head, dh)
-    ctx = flash_attention(
-        q, k, v, bias, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, interpret=interpret, fmt="bthd",
-        dropout_rate=dropout_rate, dropout_seed=seed,
-        trainable_bias=trainable_bias)
-    return ctx.reshape(b, t, hd)
+def flash_qkv_attention(x, w_qkv, w_out, bias=None, n_head=1, **options):
+    """Self-attention from the residual stream: x [b, t, d_model], w_qkv
+    [d_model, 3*h*dh] (the layers.fc packed layout), w_out [h*dh,
+    d_model] -> [b, t, d_model].  `options` (scale, causal, block_q,
+    block_k, interpret, dropout_rate, dropout_seed, trainable_bias) are
+    flash_attention's; the attention runs in fmt="bthd".  Differentiable:
+    the projections by autodiff, the attention by its kernel pair."""
+    return flash_qkv_attention_fwd(x, w_qkv, w_out, bias, n_head,
+                                   **options)[0]
+
+
+def flash_qkv_attention_fwd(x, w_qkv, w_out, bias=None, n_head=1,
+                            **options):
+    """flash_qkv_attention with what its backward needs: (y, q, k, v, ctx,
+    lse), q / k / v / ctx [b, t, h, dh] in x.dtype and lse [b, h, t] f32 —
+    lse None where flash_attention_fwd ran its XLA reference.  A caller
+    that keeps them hands them to flash_qkv_attention_bwd
+    (ops/fused_ops.py)."""
+    import jax.numpy as jnp
+
+    w4, wo3 = _qkv_weight_views(w_qkv, w_out, n_head)
+    q, k, v = (jnp.einsum("btm,mhd->bthd", x, w4[:, i]).astype(x.dtype)
+               for i in range(3))
+    ctx, lse = flash_attention_fwd(q, k, v, bias, fmt="bthd", **options)
+    y = jnp.einsum("bthd,hdm->btm", ctx, wo3).astype(x.dtype)
+    return y, q, k, v, ctx, lse
+
+
+def flash_qkv_attention_bwd(x, w_qkv, w_out, bias, q, k, v, ctx, lse, g,
+                            n_head=1, **options):
+    """(dx, dw_qkv, dw_out, dbias) from flash_qkv_attention_fwd's (q, k, v,
+    ctx, lse) and the cotangent g of y, under the same options: the bthd
+    backward kernels between the projections' backward dots.  dx is a
+    float32 sum of three, dw_qkv is stacked at weight size.  dbias is None
+    unless a bias is given and trainable_bias holds.  None where the plan
+    rejects the operands (the forward then gave no lse either)."""
+    import jax.numpy as jnp
+
+    w4, wo3 = _qkv_weight_views(w_qkv, w_out, n_head)
+    dctx = jnp.einsum("btm,hdm->bthd", g, wo3).astype(x.dtype)
+    grads = flash_attention_bwd(q, k, v, bias, ctx, lse, dctx, fmt="bthd",
+                                **options)
+    if grads is None:
+        return None
+    *dqkv, dbias = grads
+    dw_out = jnp.einsum("bthd,btm->hdm", ctx, g).reshape(
+        w_out.shape).astype(w_out.dtype)
+    dx = sum(jnp.einsum("bthd,mhd->btm", d, w4[:, i],
+                        preferred_element_type=jnp.float32)
+             for i, d in enumerate(dqkv)).astype(x.dtype)
+    dw_qkv = jnp.stack(
+        [jnp.einsum("btm,bthd->mhd", x, d) for d in dqkv],
+        axis=1).reshape(w_qkv.shape).astype(w_qkv.dtype)
+    return dx, dw_qkv, dw_out, dbias

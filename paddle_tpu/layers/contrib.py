@@ -74,16 +74,19 @@ def fused_qkv_attention(x, n_head, d_key, d_model, bias=None, scale=1.0,
                         causal=False, dropout_rate=0.0, block_q=512,
                         block_k=512, qkv_param_attr=None,
                         out_param_attr=None, name=None):
-    """Self-attention layer with the q/k/v AND output projections fused
-    into the flash-attention kernels (ops/fused_ops.py
-    fused_qkv_attention; kernels/attention.py flash_qkv_attention).
+    """Self-attention layer, projections and attention in one op
+    (ops/fused_ops.py fused_qkv_attention; kernels/attention.py
+    flash_qkv_attention): the q, k, v and output projections are XLA dots
+    straight into and out of the [b, t, h, d_key] layout the bthd flash
+    kernels take, and q, k, v, the context and the logsumexp are kept for
+    the grad op, which recomputes nothing (PERF.md PR 28 (3), PR 30: a
+    Pallas kernel that ran the projections in VMEM was slower, and went).
 
-    Creates the SAME two parameters as the unfused fc + split +
-    fused_attention + fc composition — [d_model_in, 3*n_head*d_key]
-    packed qkv weight and [n_head*d_key, d_model] output weight, same
-    shapes, same default initializer — so checkpoints interop across
-    FLAGS_fused_qkv_attention (pass the unfused path's names via
-    qkv_param_attr/out_param_attr).  Weights-dropout semantics follow
+    Creates the SAME two parameters as the fc + split + fused_attention +
+    fc composition — [d_model_in, 3*n_head*d_key] packed qkv weight and
+    [n_head*d_key, d_model] output weight, same shapes, same default
+    initializer — so checkpoints interop with it (pass that path's names
+    via qkv_param_attr/out_param_attr).  Weights-dropout semantics follow
     fused_attention (reference dropout-on-softmax, mask never in HBM)."""
     from ..core import framework as fw
 
@@ -92,7 +95,7 @@ def fused_qkv_attention(x, n_head, d_key, d_model, bias=None, scale=1.0,
     # unfused qkv-fc + output-fc pair (the conv2d_bn recipe): explicit
     # attr names match trivially, and DEFAULT names — plus every later
     # unnamed fc in the model — land on identical fc_N draws, so
-    # checkpoints interop across FLAGS_fused_qkv_attention (asserted in
+    # checkpoints interop with that composition (asserted in
     # tests/test_fused_qkv_attention.py on the BERT builder, whose ffn/
     # head fcs are unnamed)
     qkv_helper = LayerHelper("fc", param_attr=qkv_param_attr)
@@ -105,7 +108,9 @@ def fused_qkv_attention(x, n_head, d_key, d_model, bias=None, scale=1.0,
         dtype=dtype)
     helper = LayerHelper("fused_qkv_attention", name=name)
     out = helper.create_variable_for_type_inference(dtype)
-    ctx = _residual(helper, (x.shape[0], n_head, x.shape[1], d_key), dtype)
+    q, k, v, ctx = (
+        _residual(helper, (x.shape[0], x.shape[1], n_head, d_key), dtype)
+        for _ in range(4))
     lse = _residual(helper, (x.shape[0], n_head, x.shape[1]), "float32")
     inputs = {"X": [x], "WQkv": [w_qkv], "WOut": [w_out]}
     if bias is not None:
@@ -113,7 +118,8 @@ def fused_qkv_attention(x, n_head, d_key, d_model, bias=None, scale=1.0,
     helper.append_op(
         "fused_qkv_attention",
         inputs=inputs,
-        outputs={"Out": [out], "Ctx": [ctx], "Lse": [lse]},
+        outputs={"Out": [out], "Q": [q], "K": [k], "V": [v], "Ctx": [ctx],
+                 "Lse": [lse]},
         attrs={
             "n_head": n_head,
             "scale": float(scale),

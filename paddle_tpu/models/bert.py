@@ -4,11 +4,13 @@ layers/nn.py layer_norm:3030 + gelu + attention composed from matmul/softmax
 — but with the Pallas fused-attention path available via use_flash).
 
 Under use_flash the self-attention sites ride transformer.py's
-multi_head_attention selection: with FLAGS_fused_qkv_attention (default
-on) each site lowers to ONE fused_qkv_attention op whose kernels compute
-the qkv/output projection dots in-VMEM (PERF.md round 9 — q/k/v never
-exist in HBM); flag off emits the fc+split+fused_attention+fc
-composition, with parameter names unchanged either way (the unnamed
+multi_head_attention selection: each site is ONE fused_qkv_attention op,
+whose projections are XLA dots straight into and out of the [b, t, h, dh]
+layout of the bthd flash kernels and whose grad op reads q, k, v, the
+context and the logsumexp its forward kept (PERF.md PR 28 (3), PR 30: the
+Pallas kernel that kept q/k/v out of HBM was slower than this; it and
+FLAGS_fused_qkv_attention were deleted in PR 30).  Parameter names and shapes are
+those of the fc+split+fused_attention+fc composition (the unnamed
 ffn/head fc parameters keep their fc_N draws — checkpoints interop,
 asserted in tests/test_fused_qkv_attention.py)."""
 

@@ -45,28 +45,24 @@ def multi_head_attention(
     from ..core.framework import unique_name
 
     if is_self and d_key == d_value and use_flash and not use_ring:
-        from ..flags import FLAGS
+        # ONE op a flash self-attention site: the projections are XLA dots
+        # that read and write the bthd kernels' [b, t, h, dh] straight (no
+        # [b, t, 3hd] array to slice, no gradient to concatenate), and the
+        # grad op reads q, k, v, the context and the logsumexp the forward
+        # kept (kernels/attention.py flash_qkv_attention; PERF.md PR 30).
+        # Parameter names and shapes are EXACTLY those of the fc + split
+        # + fused_attention + fc branch below (the same unique_name draws,
+        # the same packed [d_model, 3hd] / [hd, d_model] fc layouts), so
+        # checkpoints load either way.
+        from ..layers.contrib import fused_qkv_attention
 
-        if FLAGS.fused_qkv_attention:
-            # ONE op: the qkv AND output projection dots run inside the
-            # flash kernels (kernels/attention.py flash_qkv_attention) —
-            # q/k/v never exist in HBM, so the dot-preferred<->custom-call
-            # relayout copies at the projection boundaries (PERF.md r09
-            # lead 1, ~1.2 GB/step) have nothing to convert.  Parameter
-            # names and shapes are EXACTLY the flag-off path's (the same
-            # unique_name draws, the same packed [d_model, 3hd] /
-            # [hd, d_model] fc layouts), so checkpoints interop across
-            # the flag.
-            from ..layers.contrib import fused_qkv_attention
-            from ..param_attr import ParamAttr as _PA
-
-            return fused_qkv_attention(
-                queries, n_head=n_head, d_key=d_key, d_model=d_model,
-                bias=attn_bias, scale=d_key**-0.5,
-                dropout_rate=dropout_rate,
-                qkv_param_attr=_PA(name=unique_name("attn_qkv_w")),
-                out_param_attr=_PA(name=unique_name("attn_out_w")),
-            )
+        return fused_qkv_attention(
+            queries, n_head=n_head, d_key=d_key, d_model=d_model,
+            bias=attn_bias, scale=d_key**-0.5,
+            dropout_rate=dropout_rate,
+            qkv_param_attr=ParamAttr(name=unique_name("attn_qkv_w")),
+            out_param_attr=ParamAttr(name=unique_name("attn_out_w")),
+        )
 
     if is_self and d_key == d_value:
         # ONE fused [d_model, 3*h*d] projection for self-attention: a

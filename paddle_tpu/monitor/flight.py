@@ -400,8 +400,9 @@ _COMPILE_COUNTS = {
 # counted by the program itself while an Executor call traces its block
 # (note_compile_count): grad ops lowered by a registered grad op fed from
 # its forward's residuals, and by the generic vjp of the forward lowering;
-# fused_qkv_attention sites whose backward is the bthd kernels between XLA
-# projection dots (kernels/attention.py _qkv_kernels)
+# fused_qkv_attention sites whose grad op was fed Q, K, V, Ctx, Lse from
+# the forward (ops/fused_ops.py): the bthd backward kernels between XLA
+# projection dots, nothing recomputed
 _TRACE_COUNTS = ("grad_direct", "grad_generic", "qkv_bwd_composed")
 _compile_totals: Dict[str, float] = dict.fromkeys(
     list(_COMPILE_DURATIONS.values()) + list(_COMPILE_COUNTS.values())
@@ -485,7 +486,8 @@ def compile_phases() -> Dict[str, float]:
     `cache_hits` / `cache_misses` of the persistent cache, and the counts
     `grad_direct` / `grad_generic` of grad ops lowered from their
     forward's residuals / by the generic vjp (core/registry.py) and
-    `qkv_bwd_composed` of fused_qkv_attention backwards traced."""
+    `qkv_bwd_composed` of fused_qkv_attention grad ops fed q, k, v from
+    their forward."""
     with _compile_lock:
         return dict(_compile_totals)
 

@@ -129,47 +129,58 @@ def _fused_qkv_infer(ctx):
 
 
 @register("fused_qkv_attention", infer_shape=_fused_qkv_infer,
-          derives_rng=_attn_derives_rng, residuals=("Ctx", "Lse"))
+          derives_rng=_attn_derives_rng,
+          residuals=("Q", "K", "V", "Ctx", "Lse"))
 def lower_fused_qkv_attention(ctx, ins):
-    """Self-attention with the qkv/output projections fused INTO the flash
-    kernels (kernels/attention.py flash_qkv_attention): X [b, t, d_model],
-    WQkv [d_model, 3*n_head*d_head] (the layers.fc packed layout), WOut
-    [n_head*d_head, d_model], optional additive Bias.  One op replaces the
-    flag-off mul + split + fused_attention + reshape + mul chain — q/k/v
-    never exist in HBM and the projection-boundary relayout copies
-    (PERF.md round 9 lead 1) go with them.  Dropout semantics/seeding
-    follow fused_attention (in-kernel weights dropout, step-key-derived
-    seed); shapes the kernel plan rejects run the numerically-identical
-    composed path.
+    """Self-attention from the residual stream, one op a site
+    (kernels/attention.py flash_qkv_attention): X [b, t, d_model], WQkv
+    [d_model, 3*n_head*d_head] (the layers.fc packed layout), WOut
+    [n_head*d_head, d_model], optional additive Bias.  The q, k, v and
+    output projections are XLA dots that read and write [b, t, h, dh]
+    straight, round the bthd flash forward kernel; the fc + split +
+    fused_attention + reshape + fc chain it stands for slices a
+    [b, t, 3*h*dh] array and concatenates its gradient.  (Until PR 30 a
+    Pallas kernel ran the projections inside the attention walk, at a
+    third of the peak: PERF.md PR 28 (3), PR 30.)  Dropout
+    semantics/seeding follow fused_attention (in-kernel weights dropout,
+    step-key-derived seed); shapes the kernel plan rejects run the XLA
+    reference inside the same composition.
 
-    Ctx ([b, n_head, t, d_head], X's dtype) and Lse ([b, n_head, t]
-    float32) are the kernel's residuals, which fused_qkv_attention_grad
-    reads; they are written where the kernel route ran and the op
-    declares the slots."""
+    Q, K, V, Ctx ([b, t, n_head, d_head], X's dtype) and Lse ([b, n_head,
+    t] float32, where the kernel ran) are the residuals
+    fused_qkv_attention_grad reads, written where the op declares the
+    slots; a program that fetches none of them and has no grad op
+    (is_test) leaves XLA nothing to keep."""
     from ..kernels.attention import flash_qkv_attention_fwd
 
     bias = ins.get("Bias", [None])[0]
-    out, attn_ctx, lse = flash_qkv_attention_fwd(
+    out, q, k, v, attn_ctx, lse = flash_qkv_attention_fwd(
         ins["X"][0], ins["WQkv"][0], ins["WOut"][0], bias,
         n_head=ctx.attr("n_head", 1), **_attn_kernel_opts(ctx, bias))
-    return {"Out": [out], "Ctx": [attn_ctx], "Lse": [lse]}
+    return {"Out": [out], "Q": [q], "K": [k], "V": [v], "Ctx": [attn_ctx],
+            "Lse": [lse]}
 
 
 @residual_grad("fused_qkv_attention")
 def lower_fused_qkv_attention_grad(ctx, ins):
     """The bthd backward kernels, between XLA dots for the projections'
-    backward, on the forward's own (Ctx, Lse): the forward kernel is not
-    run again for them."""
+    backward, on the forward's own (Q, K, V, Ctx, Lse): nothing of the
+    forward is computed again.  Counts the site as `qkv_bwd_composed`
+    (monitor.compile_phases): a site that falls to the generic route — a
+    program built before the slots, a plan rejection — is not counted."""
     from ..kernels.attention import flash_qkv_attention_bwd
+    from ..monitor import flight
 
     x, w_qkv, w_out = ins["X"][0], ins["WQkv"][0], ins["WOut"][0]
     bias = ins.get("Bias", [None])[0]
     grads = flash_qkv_attention_bwd(
-        x, w_qkv, w_out, bias, ins["Ctx"][0], ins["Lse"][0],
+        x, w_qkv, w_out, bias, ins["Q"][0], ins["K"][0], ins["V"][0],
+        ins["Ctx"][0], ins["Lse"][0],
         _cotangent(ins, x.shape[:-1] + w_out.shape[1:], x.dtype),
         n_head=ctx.attr("n_head", 1), **_attn_kernel_opts(ctx, bias))
     if grads is None:
         return None
+    flight.note_compile_count("qkv_bwd_composed")
     dx, dw_qkv, dw_out, dbias = grads
     return {"X@GRAD": [dx], "WQkv@GRAD": [dw_qkv], "WOut@GRAD": [dw_out],
             "Bias@GRAD": [dbias]}

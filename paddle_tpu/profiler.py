@@ -225,8 +225,8 @@ def op_scope(tf_op: str) -> str:
 
 def kernel_name(hlo_text: str):
     """The Pallas kernel's name if the event is one (`pallas_call(name=)`
-    as the trace prints it: `%jvp_fused_qkv_fwd_.95 = .. custom-call(..),
-    custom_call_target="tpu_custom_call"` -> `jvp_fused_qkv_fwd_`), else
+    as the trace prints it: `%jvp_flash_bthd_fwd_.95 = .. custom-call(..),
+    custom_call_target="tpu_custom_call"` -> `jvp_flash_bthd_fwd_`), else
     None."""
     if 'custom_call_target="tpu_custom_call"' not in hlo_text:
         return None

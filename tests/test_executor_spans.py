@@ -273,12 +273,12 @@ def test_every_pallas_kernel_has_a_name():
     assert len(names) >= 22
     for name in names:
         assert ("_fwd" in name) != ("_bwd" in name), name
-    assert {"fused_qkv_fwd", "flash_bthd_fwd", "flash_bthd_bwd_dq",
+    assert {"flash_bthd_fwd", "flash_bthd_bwd_dq",
             "flash_bthd_bwd_dkv"} <= set(names)
-    # the fused-qkv family has a forward kernel only: its backward is the
-    # bthd kernels between XLA projection dots
-    assert [n for n in names if n.startswith("fused_qkv")] \
-        == ["fused_qkv_fwd"]
+    # no fused-qkv kernel is left (the forward one was deleted in PR 30,
+    # the backward walks in PR 28): a fused_qkv_attention site is the bthd
+    # kernels between XLA projection dots, both ways
+    assert not [n for n in names if n.startswith("fused_qkv")]
 
 
 def test_the_lint_refuses_an_unnamed_or_two_faced_kernel(tmp_path):
@@ -385,9 +385,9 @@ def test_op_table_groups_by_scope_and_by_kernel(tmp_path, capsys):
         ("%fusion.2 = f32[8] fusion(%a)", body + "adam/sqrt:", 10_000, 5_000),
         ("%fusion.3 = f32[8] fusion(%a)",
          body + "layer_norm_grad/transpose(jvp())/mul:", 20_000, 20_000),
-        ("%fused_qkv_fwd.4" + mosaic,
+        ("%flash_bthd_fwd.4" + mosaic,
          body + "fused_qkv_attention/pallas_call:", 40_000, 30_000),
-        ("%jvp_fused_qkv_bwd_dx_q_.9" + mosaic,
+        ("%jvp_flash_bthd_bwd_dq_.9" + mosaic,
          body + "fused_qkv_attention_grad/transpose(jvp())/pallas_call:",
          70_000, 25_000),
         ("%copy.7 = f32[8] copy(%p)", body + "mul", 95_000, 4_000),
@@ -406,8 +406,8 @@ def test_op_table_groups_by_scope_and_by_kernel(tmp_path, capsys):
     # a jax `mul` under no op's scope is not the op `mul`
     assert scope[profiler.NO_SCOPE] == pytest.approx(5_000 / 1e12)
     kernels = dict(profiler.xplane_op_table(str(tmp_path), by="kernel"))
-    assert kernels == {"fused_qkv_fwd": pytest.approx(30_000 / 1e12),
-                       "jvp_fused_qkv_bwd_dx_q_": pytest.approx(
+    assert kernels == {"flash_bthd_fwd": pytest.approx(30_000 / 1e12),
+                       "jvp_flash_bthd_bwd_dq_": pytest.approx(
                            25_000 / 1e12)}
     # the table that was there: name prefixes, containers included
     group = dict(profiler.xplane_op_table(str(tmp_path)))

@@ -185,11 +185,13 @@ class TestRedGate:
         assert any("128-lane" in f.message for f in findings)
 
     def test_kernel_vmem_budget_named(self):
-        # a qkv plan whose forward resident set exceeds the gate's bound
-        cfg = dict(label="seeded-vmem", b=1, t=2048, dm=2048, h=16, dh=128,
-                   dtype="float32")
+        # a bthd plan whose whole-head kv tile exceeds the 256 KB bound
+        # the backward kernels compile under
+        cfg = dict(label="seeded-vmem", b=1, h=16, t=2048, d=128,
+                   dtype="float32", fmt="bthd")
         findings = []
-        kernel_lint.check_qkv_plan(cfg, True, 128, 128, False, findings)
+        kernel_lint.check_attention_plan(cfg, True, 128, 128, False,
+                                         findings)
         assert any(f.check == "kernel-vmem-budget" for f in findings), \
             findings
 
@@ -351,7 +353,7 @@ class TestNoFalsePositives:
         assert findings == [], [str(f) for f in findings]
         # every Pallas plan family in kernels/ is covered
         assert set(report) == {
-            "attention", "qkv_attention", "conv_bn", "dropout_epilogue",
+            "attention", "conv_bn", "dropout_epilogue",
             "embedding", "ring_attention", "decode_attention",
             "decode_step", "paged_decode_attention", "paged_decode_step",
         }
@@ -405,9 +407,8 @@ class TestNoFalsePositives:
         acc = {r["label"]: r.get("accepted") for r in report["attention"]}
         assert acc["transformer-base-f32"] and acc["bert-base-bf16"]
         assert acc["transformer-base-bthd"]
-        qkv = {r["label"]: r["accepted"] for r in report["qkv_attention"]}
-        assert qkv["transformer-base-f32"] and qkv["bert-base-bf16"]
-        assert not qkv["transformer-smoke"]  # t=64: designed fallback
+        # BERT-base's fused_qkv_attention sites: bthd under amp
+        assert acc["bert-base-bf16-bthd"]
 
     def test_attention_bthd_f32_cap_is_dtype_aware(self):
         """Regression for the linter's first real catch: the bthd kv-tile

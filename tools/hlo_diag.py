@@ -15,18 +15,17 @@ attributed to a site class by its metadata + enclosing computation:
                (the mul lowering) — the dot-preferred<->custom-call
                relayouts.  NOTE: this keys on the DOT TIER, so any FFN/
                head mul relayouts land here too; the attention-projection
-               subset is isolated by the fused-vs-unfused DIFF (the FFN
-               dots are identical on both sides)
+               subset is what a diff against a build without the
+               attention sites leaves (fused_qkv_attention's own dots
+               carry that op's scope, not the mul lowering's)
   pallas       copies sourced from kernels/ (the pallas_call operand/
                result relayouts into alternate memory)
   entry        copies living in the ENTRY computation whose operand is a
                program parameter — the donated-param entry copies ("XLA
                copies donated params at entry despite may-alias")
   other        everything else
-Run with FLAGS_fused_qkv_attention=0 vs =1 and diff: the fused path must
-drive the projection-site bytes to ~0 (asserted in
-tests/test_fused_qkv_attention.py; the JSON lands next to the dump as
-<out>.census.json so CI can archive it).
+The JSON lands next to the dump as <out>.census.json so CI can archive
+it (read in tests/test_fused_qkv_attention.py).
 
 --bn-fusion (resnet50): the round-7 BN-wall attribution report — counts
 the BN-statistics channel reductions (full passes over 3/4-D activations
@@ -92,7 +91,7 @@ def compile_transformer(scan_steps=8, batch_size=64, seq_len=256,
 def compile_transformer_smoke(scan_steps=2, batch_size=2, seq_len=64,
                               use_flash=True):
     """Tiny-but-representative transformer for the CI copy-census leg:
-    d_model/head shapes keep the fused-qkv kernel plan feasible
+    d_model/head shapes keep the bthd flash plan feasible
     (d_head 64), everything else shrinks so a CPU box compiles it in
     seconds."""
     import paddle_tpu as pt
@@ -376,9 +375,7 @@ def _census_site(src_file, op_name, in_entry, operand_is_param):
 def analyze_copy_census(txt):
     """Bytes-per-site copy census of one optimized-HLO dump (the
     automated form of PERF.md's hand-done 'Remaining copy inventory').
-    Returns a JSON-able dict; diff a FLAGS_fused_qkv_attention=0 dump
-    against =1: the fused path must drive the 'projection' site to ~0
-    (there is no dot at the boundary left to relayout)."""
+    Returns a JSON-able dict."""
     sites = {k: {"count": 0, "mb": 0.0}
              for k in ("projection", "pallas", "entry", "other")}
     top = collections.Counter()
@@ -612,9 +609,6 @@ def main():
         import json
 
         rep = analyze_copy_census(txt)
-        from paddle_tpu.flags import FLAGS as _FLAGS
-
-        rep["fused_qkv_attention"] = bool(_FLAGS.fused_qkv_attention)
         rep["workload"] = which
         census_path = out_path + ".census.json"
         with open(census_path, "w") as f:

@@ -112,28 +112,6 @@ print("deepfm A/B records OK:", [(r["config"]["fused_embedding"],
                                   r["value"]) for r in recs])
 PY
   echo "-- deepfm A/B record artifact: ci_artifacts/bench_deepfm_smoke.json"
-  # Transformer fused-qkv-projection leg (PERF.md r09 A/B): the fused-
-  # projection record next to its FLAGS_fused_qkv_attention=0 unfused-
-  # composition baseline, both under the warnings gate (paired records,
-  # config carries the flag + runs[]/spread) — the projection-boundary
-  # A/B artifact for the driver's chip run
-  python -W error::UserWarning bench.py --model transformer --smoke \
-    | tee ci_artifacts/bench_transformer_smoke.json
-  FLAGS_fused_qkv_attention=0 python -W error::UserWarning bench.py \
-    --model transformer --smoke \
-    | tee -a ci_artifacts/bench_transformer_smoke.json
-  python - <<'PY'
-import json
-recs = [json.loads(l) for l in open(
-    "ci_artifacts/bench_transformer_smoke.json")
-    if l.strip().startswith("{")]
-recs = [r for r in recs if r.get("metric", "").startswith("transformer")]
-flags = {r["config"]["fused_qkv_attention"] for r in recs}
-assert flags == {True, False}, f"need a fused AND an unfused record: {flags}"
-print("transformer A/B records OK:", [(r["config"]["fused_qkv_attention"],
-                                       r["value"]) for r in recs])
-PY
-  echo "-- transformer A/B record artifact: ci_artifacts/bench_transformer_smoke.json"
   # Recompute A/B leg (PERF.md r12 / ISSUE 15): the activation-recompute
   # rewrite paired against the plain record — the rewritten record must
   # carry a LOWER planner activation peak and the est FLOPs factor, and
@@ -322,14 +300,10 @@ PY
     | tee ci_artifacts/bench_dispatch_smoke.json
   echo "-- dispatch overhead artifact: ci_artifacts/bench_dispatch_smoke.json"
   # Copy census (PERF.md r09 attribution artifact): the automated
-  # while-body copy-byte attribution on the smoke transformer, fused vs
-  # unfused — tests assert the projection-site collapse; CI archives the
-  # paired JSON for the record
+  # while-body copy-byte attribution on the smoke transformer; CI
+  # archives the JSON for the record
   python tools/hlo_diag.py transformer_smoke \
-    ci_artifacts/hlo_transformer_smoke_fused.txt --copy-census \
-    | tail -20
-  FLAGS_fused_qkv_attention=0 python tools/hlo_diag.py transformer_smoke \
-    ci_artifacts/hlo_transformer_smoke_unfused.txt --copy-census \
+    ci_artifacts/hlo_transformer_smoke.txt --copy-census \
     | tail -20
   rm -f ci_artifacts/hlo_transformer_smoke_*.txt  # keep the census JSONs
   echo "-- copy-census artifacts:"
@@ -374,7 +348,7 @@ PY
   # ci_artifacts/baselines/ in the SAME commit as an intended perf
   # change.
   for a in bench_smoke bench_convbn_smoke bench_deepfm_smoke \
-           bench_transformer_smoke bench_recompute_smoke \
+           bench_recompute_smoke \
            bench_decode_smoke bench_pipeline_smoke bench_dispatch_smoke \
            bench_numerics_smoke
   do
