@@ -480,10 +480,14 @@ def _attention_row(cfg, interpret, rng):
     fmt, d = cfg["fmt"], cfg["d"]
     shape = ((cfg["b"], cfg["t"], cfg["h"], d) if fmt == "bthd"
              else (cfg["b"], cfg["h"], cfg["t"], d))
-    q, k, v = (_randn(rng, shape, cfg["dtype"], 0.5) for _ in range(3))
+    # a grouped-query row (bhtd): k and v at their own head count
+    kv_shape = (cfg["b"], cfg.get("h_kv", shape[1])) + shape[2:]
+    q = _randn(rng, shape, cfg["dtype"], 0.5)
+    k, v = (_randn(rng, kv_shape, cfg["dtype"], 0.5) for _ in range(2))
     seed = jnp.asarray([1234], jnp.uint32)
     ref_attn = (att._reference_bthd if fmt == "bthd"
                 else att.reference_attention)
+    mask = cfg.get("mask")  # a block-diffusion row: masked, not causal
 
     def fwd_bwd(attn):
         def run(q, k, v):
@@ -496,10 +500,12 @@ def _attention_row(cfg, interpret, rng):
         return run
 
     kernel = fwd_bwd(lambda q, k, v: att.flash_attention(
-        q, k, v, None, scale=d ** -0.5, causal=True, fmt=fmt,
-        interpret=interpret))
+        q, k, v, None, scale=d ** -0.5, causal=mask is None, fmt=fmt,
+        interpret=interpret, mask=mask))
     ref = fwd_bwd(lambda q, k, v: ref_attn(
-        q, k, v, None, d ** -0.5, True))
+        q, k, v, None, d ** -0.5, mask is None, mask=mask))
+    if mask is not None:
+        return kernel, ref, (q, k, v), _tol(cfg["dtype"])
 
     def dropped(q, k, v):
         return att.flash_attention(

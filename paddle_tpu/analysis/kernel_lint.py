@@ -169,6 +169,16 @@ def check_attention_plan(cfg: dict, ok, block_q, block_k, interpret,
             "kernel-misaligned-block",
             f"head dim {d} is not a multiple of 64 (MXU lane occupancy)",
             fam, label))
+    if cfg.get("mask"):
+        # the masked walks take a tile to lie in one half of [noisy ;
+        # clean] and the halves to be whole blocks
+        length, offset = cfg["mask"]
+        if (t != 2 * offset or offset % length or offset % block_q
+                or offset % block_k):
+            findings.append(_finding(
+                "kernel-grid-divisibility",
+                f"blocks ({block_q},{block_k}) or block length {length} "
+                f"do not divide the half {offset} of t={t}", fam, label))
     if not interpret and (block_q % _LANE or block_k % _LANE):
         # backward kernels dynamic-slice lse/delta on the lane dim by
         # block_q and kv tiles by block_k: Mosaic needs 128-aligned blocks
@@ -646,6 +656,11 @@ _ATTENTION_MATRIX = [
     # long-sequence flash leg (BENCH flash-attn workload)
     dict(label="flash-longseq", b=1, h=8, t=4096, d=64,
          dtype="float32", fmt="bhtd"),
+    # block-diffusion training (models/block_diffusion_decoder.py at the
+    # SDAR-30B-A3B widths): 32 query heads over 4 key/value heads of 128,
+    # rows [noisy ; clean] of 2 x 2048 in blocks of 4, masked by position
+    dict(label="block-diffusion-gqa-bf16", b=1, h=32, h_kv=4, t=4096, d=128,
+         dtype="bfloat16", fmt="bhtd", mask=(4, 2048)),
     # h*d*esize > 2048: even a 128-block kv tile busts the 256 KB bound —
     # compiled mode must REJECT to XLA (the cap-floor regression class);
     # if the gate ever re-accepts this, the kv-tile check fires
@@ -867,10 +882,13 @@ def lint_kernel_plans() -> Tuple[List[Finding], Dict[str, Any]]:
             shape = ((cfg["b"], cfg["t"], cfg["h"], cfg["d"])
                      if cfg["fmt"] == "bthd"
                      else (cfg["b"], cfg["h"], cfg["t"], cfg["d"]))
-            q = _spec(shape, cfg["dtype"])
+            q = k = _spec(shape, cfg["dtype"])
+            if "h_kv" in cfg:  # grouped-query rows are bhtd
+                k = _spec((cfg["b"], cfg["h_kv"]) + shape[2:], cfg["dtype"])
             with _pretend_tpu():
-                ok, bq, bk, interp = att._plan(q, q, 512, 512, None,
-                                               cfg["fmt"])
+                ok, bq, bk, interp = att._plan(q, k, 512, 512, None,
+                                               cfg["fmt"], None,
+                                               cfg.get("mask"))
             check_attention_plan(cfg, ok, bq, bk, interp, findings)
             rows.append(dict(label=cfg["label"], fmt=cfg["fmt"],
                              accepted=bool(ok), block_q=int(bq),

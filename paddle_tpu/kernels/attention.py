@@ -32,9 +32,61 @@ from __future__ import annotations
 import functools
 
 
+def _bd_visible(q_pos, k_pos, block, offset):
+    """The block-diffusion mask over [noisy ; clean] (BD3-LM, arXiv:
+    2503.09573, section 3, the one-pass form): rows and keys 0 .. offset-1
+    are the noisy copy of a sequence, offset .. 2*offset-1 the clean one,
+    both cut into blocks of `block` positions.  A noisy row sees its own
+    noisy block (both ways) and the clean blocks before it; a clean row
+    sees the clean blocks up to its own; no clean row sees a noisy key.
+    Elementwise over int32 position arrays; the kernels, the XLA fallback
+    and the bias recompute all call this one function."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    def blk(pos):  # numpy scalars inline as literals inside a kernel
+        pos = jnp.where(pos < offset, pos, pos - offset)
+        if block & (block - 1) == 0:
+            return jax.lax.shift_right_logical(
+                pos, np.int32(block.bit_length() - 1))
+        return jax.lax.div(pos, np.int32(block))
+
+    q_noisy, k_noisy = q_pos < offset, k_pos < offset
+    qb, kb = blk(q_pos), blk(k_pos)
+    # and / or / not only: Mosaic has no select between boolean vectors
+    same = kb == qb
+    return (k_noisy & q_noisy & same) | (
+        ~k_noisy & ((kb < qb) | (~q_noisy & same)))
+
+
+def _bd_plane(tq, tk, mask):
+    """[tq, tk] bool of `mask` = (block_length, clean_offset)."""
+    import jax.numpy as jnp
+
+    return _bd_visible(jnp.arange(tq, dtype=jnp.int32)[:, None],
+                       jnp.arange(tk, dtype=jnp.int32)[None, :], *mask)
+
+
+def _repeat_kv(q, k, v):
+    """K and V at the query's head count (axis 1): query head i reads
+    key/value head i // group.  The kernels never do this in HBM."""
+    import jax.numpy as jnp
+
+    group = q.shape[1] // k.shape[1]
+    if group == 1:
+        return k, v
+    return jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+
+
 def reference_attention(q, k, v, bias=None, scale=1.0, causal=False,
-                        dropout_rate=0.0, dropout_seed=None):
+                        dropout_rate=0.0, dropout_seed=None, mask=None):
     """Pure-XLA fallback (and numerics reference for tests).
+
+    K and V may have fewer heads than q (grouped-query attention: head i
+    reads key/value head i // group; they are repeated here, so their
+    gradients come back summed over the group).  `mask`, (block_length,
+    clean_offset), is the block-diffusion mask of `_bd_visible`.
 
     Rows with no causally-visible key (only possible when Tq > Tk under
     bottom-right-aligned causal masking) produce zero output and zero
@@ -49,13 +101,17 @@ def reference_attention(q, k, v, bias=None, scale=1.0, causal=False,
     import jax
     import jax.numpy as jnp
 
+    k, v = _repeat_kv(q, k, v)
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
     if bias is not None:
         logits = logits + bias.astype(jnp.float32)
     if causal:
         tq, tk = logits.shape[-2], logits.shape[-1]
-        mask = jnp.tril(jnp.ones((tq, tk), bool), tk - tq)
-        logits = jnp.where(mask, logits, -1e30)
+        logits = jnp.where(jnp.tril(jnp.ones((tq, tk), bool), tk - tq),
+                           logits, -1e30)
+    if mask is not None:
+        logits = jnp.where(_bd_plane(*logits.shape[-2:], mask), logits,
+                           -1e30)
     weights = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     if dropout_rate:
         from . import hash_rng
@@ -73,11 +129,11 @@ def reference_attention(q, k, v, bias=None, scale=1.0, causal=False,
 
 
 def _reference_bthd(q, k, v, bias, scale, causal, dropout_rate=0.0,
-                    dropout_seed=None):
+                    dropout_seed=None, mask=None):
     out = reference_attention(
         q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
         v.transpose(0, 2, 1, 3), bias, scale, causal,
-        dropout_rate, dropout_seed)
+        dropout_rate, dropout_seed, mask)
     return out.transpose(0, 2, 1, 3)
 
 
@@ -184,9 +240,79 @@ def _read_bias(bias_ref, q_lo, block_q, k_lo, block_k, bias_q1):
     return b.astype(jnp.float32)
 
 
+def _py_where(cond, a, b):
+    return a if cond else b
+
+
+def _bd_key_tiles(q_lo, block_q, block_k, block, offset, where=_py_where):
+    """The key tiles that rows q_lo .. q_lo + block_q - 1 may see under the
+    block-diffusion mask, as two runs (first tile, count): among the noisy
+    keys (the rows' own blocks; none for clean rows) and among the clean
+    keys (the blocks before a noisy row's, up to a clean row's own).  No
+    other tile holds a visible pair, so the walks visit no other.  A tile
+    lies in one half (the plan asks offset % block_q == 0 == offset %
+    block_k).  Python ints in and out (the plan's count), or traced
+    scalars with `where=jnp.where` (inside a kernel)."""
+    noisy = q_lo < offset
+    row = where(noisy, q_lo, q_lo - offset)
+    b_lo, b_hi = row // block, (row + block_q - 1) // block
+    a0 = b_lo * block // block_k
+    na = where(noisy, ((b_hi + 1) * block - 1) // block_k + 1 - a0, 0)
+    nc = (where(noisy, b_hi, b_hi + 1) * block + block_k - 1) // block_k
+    return a0, na, offset // block_k, nc
+
+
+def _bd_query_tiles(k_lo, block_q, block_k, block, offset, where=_py_where):
+    """The transpose of `_bd_key_tiles`: the query tiles that may see keys
+    k_lo .. k_lo + block_k - 1, as two runs (first tile, count): noisy rows
+    (of a noisy key's own blocks; of the blocks after a clean key's) and
+    clean rows (from a clean key's block on; none for noisy keys)."""
+    noisy = k_lo < offset
+    col = where(noisy, k_lo, k_lo - offset)
+    b_lo, b_hi = col // block, (col + block_k - 1) // block
+    n0 = where(noisy, b_lo, b_lo + 1) * block // block_q
+    nn = where(noisy, ((b_hi + 1) * block - 1) // block_q + 1,
+               offset // block_q) - n0
+    c0 = (offset + b_lo * block) // block_q
+    return n0, nn, c0, where(noisy, 0, 2 * offset // block_q - c0)
+
+
+def bd_tiles_visited(block_q, block_k, block, offset):
+    """(visited, total) (query tile, key tile) pairs a head and sequence
+    of the masked forward walk over 2 * offset positions."""
+    total_q = 2 * offset // block_q
+    visited = 0
+    for i in range(total_q):
+        _, na, _, nc = _bd_key_tiles(i * block_q, block_q, block_k, block,
+                                     offset)
+        visited += na + nc
+    return visited, total_q * (2 * offset // block_k)
+
+
+def _bd_walk(mask, lo, block_q, block_k, tiles_of):
+    """(count, tile(t)) of a masked walk from the tile that starts at
+    `lo`: the t-th tile it visits, first run first."""
+    import jax.numpy as jnp
+
+    a0, na, c0, nc = tiles_of(lo, block_q, block_k, *mask, where=jnp.where)
+    return na + nc, lambda t: jnp.where(t < na, a0 + t, c0 + t - na)
+
+
+def _bd_tile(q_lo, k_lo, block_q, block_k, mask):
+    """[block_q, block_k] bool: the mask of the tile at (q_lo, k_lo)."""
+    import jax
+    import jax.numpy as jnp
+
+    shape = (block_q, block_k)
+    return _bd_visible(
+        q_lo + jax.lax.broadcasted_iota(jnp.int32, shape, 0),
+        k_lo + jax.lax.broadcasted_iota(jnp.int32, shape, 1), *mask)
+
+
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
                 scale, block_q, block_k, causal, seq_q, seq_k,
-                causal_offset, bias_q1, drop_rate, inv_keep, hw_prng=False):
+                causal_offset, bias_q1, drop_rate, inv_keep, hw_prng=False,
+                mask=None):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -204,9 +330,14 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
         # highest k position visible to this q block, bottom-right aligned
         hi = qi * block_q + block_q - 1 + causal_offset
         n_kv = jnp.minimum(n_kv, (hi // block_k) + 1)
+    if mask is not None:
+        n_kv, tile = _bd_walk(mask, qi * block_q, block_q, block_k,
+                              _bd_key_tiles)
 
     def body(j, carry):
         m, l, acc = carry
+        if mask is not None:
+            j = tile(j)
         k = k_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
         v = v_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
         s = q @ k.T  # [block_q, block_k]
@@ -221,6 +352,9 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
                 jnp.int32, (block_q, block_k), 1
             )
             s = jnp.where(q_pos + causal_offset >= k_pos, s, -1e30)
+        if mask is not None:
+            s = jnp.where(_bd_tile(qi * block_q, j * block_k, block_q,
+                                   block_k, mask), s, -1e30)
         m_new = jnp.maximum(m, s.max(axis=1))
         p = jnp.exp(s - m_new[:, None])
         alpha = jnp.exp(m - m_new)
@@ -257,7 +391,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
 def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                    delta_ref, dq_ref, *, scale, block_q, block_k, causal,
                    seq_q, seq_k, causal_offset, bias_q1, drop_rate, inv_keep,
-                   hw_prng=False):
+                   hw_prng=False, mask=None):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -276,8 +410,13 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
     if causal:
         hi = qi * block_q + block_q - 1 + causal_offset
         n_kv = jnp.minimum(n_kv, (hi // block_k) + 1)
+    if mask is not None:
+        n_kv, tile = _bd_walk(mask, qi * block_q, block_q, block_k,
+                              _bd_key_tiles)
 
     def body(j, acc):
+        if mask is not None:
+            j = tile(j)
         k = k_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
         v = v_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
         s = (q @ k.T) * scale
@@ -293,6 +432,9 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                 jnp.int32, (block_q, block_k), 1
             )
             p = jnp.where(q_pos + causal_offset >= k_pos, p, 0.0)
+        if mask is not None:
+            p = jnp.where(_bd_tile(qi * block_q, j * block_k, block_q,
+                                   block_k, mask), p, 0.0)
         dp = do @ v.T  # [block_q, block_k]
         if drop_rate:
             if hw_prng:
@@ -313,7 +455,7 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
 def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                     delta_ref, dk_ref, dv_ref, *, scale, block_q, block_k,
                     causal, seq_q, seq_k, causal_offset, bias_q1, drop_rate,
-                    inv_keep, hw_prng=False):
+                    inv_keep, hw_prng=False, mask=None):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -332,9 +474,14 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
         # first q position that can see this kv block
         lo_pos = ki * block_k - causal_offset
         lo = jnp.maximum(lo_pos // block_q, 0)
+    if mask is not None:
+        n_q, tile = _bd_walk(mask, ki * block_k, block_q, block_k,
+                             _bd_query_tiles)
 
     def body(i, carry):
         dk, dv = carry
+        if mask is not None:
+            i = tile(i)
         q = q_ref[pl.ds(i * block_q, block_q), :].astype(jnp.float32)
         do = do_ref[pl.ds(i * block_q, block_q), :].astype(jnp.float32)
         lse = lse_ref[0, pl.ds(i * block_q, block_q)]
@@ -352,6 +499,9 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                 jnp.int32, (block_q, block_k), 1
             )
             p = jnp.where(q_pos + causal_offset >= k_pos, p, 0.0)
+        if mask is not None:
+            p = jnp.where(_bd_tile(i * block_q, ki * block_k, block_q,
+                                   block_k, mask), p, 0.0)
         dp = do @ v.T
         if drop_rate:
             if hw_prng:
@@ -388,19 +538,28 @@ def _dims(x, fmt):
     return b, h, t, d
 
 
-def _plan(q, k, block_q, block_k, interpret, fmt="bhtd", v=None):
+def _plan(q, k, block_q, block_k, interpret, fmt="bhtd", v=None, mask=None):
     """Static feasibility check; returns (ok, block_q, block_k, interpret).
 
     `v` (default: shaped like k) may carry a value head size of its own
     (latent attention: d_qk 192, d_v 128).  The bhtd kernels take it: q
     and k tiles are [.., d_qk], v, the context and its cotangent
-    [.., d_v].  The whole-head bthd kernels keep one head size."""
+    [.., d_v].  k and v may also carry a head count of their own that
+    divides q's (grouped-query attention: 32 query heads over 4): the bhtd
+    kernels read key/value head i // group through their block index maps.
+    `mask` = (block_length, clean_offset) asks for the block-diffusion
+    mask over [noisy ; clean]: bhtd, tq == tk == 2 * clean_offset, blocks
+    that divide the half and a block length that divides it too, so that
+    a tile lies in one half.  The whole-head bthd kernels keep one head
+    size, one head count and no such mask."""
     from .placement import resolve
 
     b, h, tq, d = _dims(q, fmt)
-    tk = _dims(k, fmt)[2]
+    hk, tk = _dims(k, fmt)[1:3]
     dv = d if v is None else _dims(v, fmt)[3]
-    if fmt == "bthd" and dv != d:
+    if fmt == "bthd" and (dv != d or hk != h or mask is not None):
+        return False, 0, 0, resolve(interpret)[1]
+    if h % hk:
         return False, 0, 0, resolve(interpret)[1]
     compiled, interpret = resolve(interpret)
     block_q = min(block_q, tq)
@@ -445,6 +604,10 @@ def _plan(q, k, block_q, block_k, interpret, fmt="bhtd", v=None):
         and dv % 64 == 0
         and (compiled or interpret)
     )
+    if ok and mask is not None:
+        block, offset = mask
+        ok = (tq == tk == 2 * offset and offset % block == 0
+              and offset % block_q == 0 and offset % block_k == 0)
     return ok, block_q, block_k, interpret
 
 
@@ -486,10 +649,14 @@ def _bias_spec_and_arg(bias, b, h, tq, tk, block_q, block_k, for_dkv):
     return spec, bias, bias_q1
 
 
-def _qkv_specs(fmt, h, seq_mode_q, seq_mode_k, block_q, block_k, tq, tk, d):
+def _qkv_specs(fmt, h, seq_mode_q, seq_mode_k, block_q, block_k, tq, tk, d,
+               group=1):
     """BlockSpecs for q-like and k-like operands.
 
     fmt "bhtd": arrays are pre-reshaped to [b*h, t, d]; grid axis 0 is bh.
+    With `group` > 1 the k-like operand is [b*h/group, t, d] and grid row
+    i reads its row i // group (b*h + head -> b*h_kv + head // group):
+    grouped-query attention without K or V at the query's head count.
     fmt "bthd": arrays stay [b, t, h, d] — the layout the qkv projection
     produces for free (reshape of [b, t, h*d] is a bitcast), so NO
     transpose/relayout copy ever materializes at the custom-call boundary
@@ -502,7 +669,7 @@ def _qkv_specs(fmt, h, seq_mode_q, seq_mode_k, block_q, block_k, tq, tk, d):
     (whole sequence pinned)."""
     from jax.experimental import pallas as pl
 
-    def spec(seq_mode, block, t):
+    def spec(seq_mode, block, t, group=1):
         if fmt == "bthd":
             if seq_mode == "block":
                 return pl.BlockSpec(
@@ -511,13 +678,15 @@ def _qkv_specs(fmt, h, seq_mode_q, seq_mode_k, block_q, block_k, tq, tk, d):
             return pl.BlockSpec(
                 (None, t, h, d), lambda i, j: (i, 0, 0, 0)
             )
+        row = (lambda i: i // group) if group > 1 else (lambda i: i)
         if seq_mode == "block":
-            return pl.BlockSpec((None, block, d), lambda i, j: (i, j, 0))
-        return pl.BlockSpec((None, t, d), lambda i, j: (i, 0, 0))
+            return pl.BlockSpec((None, block, d),
+                                lambda i, j: (row(i), j, 0))
+        return pl.BlockSpec((None, t, d), lambda i, j: (row(i), 0, 0))
 
     return (
         spec(seq_mode_q, block_q, tq),
-        spec(seq_mode_k, block_k, tk),
+        spec(seq_mode_k, block_k, tk, group),
     )
 
 
@@ -844,22 +1013,24 @@ def _seed_spec():
 
 def _flash_forward(q, k, v, bias, seed, scale, causal, block_q, block_k,
                    interpret, fmt="bhtd", dropout_rate=0.0,
-                   allow_hw_prng=True):
+                   allow_hw_prng=True, mask=None):
     """Returns (out, lse) via the Pallas kernel.  Caller has checked
     feasibility with _plan.  `out` is in the input format; lse is
     [b, h, tq] f32.  `seed`: (1,) uint32 — the dropout stream seed
-    (ignored when dropout_rate == 0)."""
+    (ignored when dropout_rate == 0).  A masked walk (`mask`, bhtd) tells
+    the compile totals which tiles it visits (`attn_tiles_visited` of
+    `attn_tiles_total` a head and sequence, monitor/flight.py)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     b, h, tq, d = _dims(q, fmt)
-    tk = _dims(k, fmt)[2]
-    bh = b * h
+    hk, tk = _dims(k, fmt)[1:3]
+    bh, group = b * h, h // hk
     drop_rate, inv_keep = _drop_params(dropout_rate)
     hw_prng = allow_hw_prng and _use_hw_prng(drop_rate, interpret)
     q_spec, kv_spec = _qkv_specs(fmt, h, "block", "full", block_q, block_k,
-                                 tq, tk, d)
+                                 tq, tk, d, group)
     if fmt == "bthd":
         args = [seed, q, k, v]
         in_specs = [_seed_spec(), q_spec, kv_spec, kv_spec]
@@ -900,10 +1071,16 @@ def _flash_forward(q, k, v, bias, seed, scale, causal, block_q, block_k,
 
     dv = v.shape[-1]
     o_spec, v_spec = _qkv_specs(fmt, h, "block", "full", block_q, block_k,
-                                tq, tk, dv)
-    args = [seed, q.reshape(bh, tq, d), k.reshape(bh, tk, d),
-            v.reshape(bh, tk, dv)]
+                                tq, tk, dv, group)
+    args = [seed, q.reshape(bh, tq, d), k.reshape(bh // group, tk, d),
+            v.reshape(bh // group, tk, dv)]
     in_specs = [_seed_spec(), q_spec, kv_spec, v_spec]
+    if mask is not None:
+        from ..monitor import flight
+
+        visited, total = bd_tiles_visited(block_q, block_k, *mask)
+        flight.note_compile_count("attn_tiles_visited", visited)
+        flight.note_compile_count("attn_tiles_total", total)
     bias_q1 = False
     if bias is not None:
         spec, barg, bias_q1 = _bias_spec_and_arg(
@@ -916,7 +1093,7 @@ def _flash_forward(q, k, v, bias, seed, scale, causal, block_q, block_k,
         _fwd_kernel, scale=scale, block_q=block_q, block_k=block_k,
         causal=causal, seq_q=tq, seq_k=tk, causal_offset=tk - tq,
         bias_q1=bias_q1, drop_rate=drop_rate, inv_keep=inv_keep,
-        hw_prng=hw_prng,
+        hw_prng=hw_prng, mask=mask,
     )
     if bias is None:
         def kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref):
@@ -945,9 +1122,17 @@ def _flash_forward(q, k, v, bias, seed, scale, causal, block_q, block_k,
 
 def _flash_backward(q, k, v, bias, seed, o, lse, g, scale, causal, block_q,
                     block_k, interpret, fmt="bhtd", dropout_rate=0.0,
-                    allow_hw_prng=True):
+                    allow_hw_prng=True, mask=None):
     """Returns (dq, dk, dv) via the two backward kernels, in the input
-    format.  `lse` is [b, h, tq] f32; q/k/v/o/g are in `fmt`."""
+    format.  `lse` is [b, h, tq] f32; q/k/v/o/g are in `fmt`.
+
+    Grouped-query attention (bhtd, k and v at h / group heads): the dkv
+    walk runs once a QUERY head and writes that head's part of dK and dV;
+    the group's parts are summed outside the kernel, in float32.  That
+    costs 2 x b x h x tk x (d + d_v) elements written and read again (268
+    MB a layer at 2 x 32 heads x 4096 x 128 in bfloat16, a third of a
+    millisecond of HBM time); a kernel that looped over the group would
+    read q and dO of every head once a key tile instead (1.2 GB)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -1044,8 +1229,10 @@ def _flash_backward(q, k, v, bias, seed, o, lse, g, scale, causal, block_q,
         return dq, dk, dv
 
     dv_ = v.shape[-1]  # the value head size: v, o, g and dv carry it
-    args3 = [q.reshape(bh, tq, d), k.reshape(bh, tk, d),
-             v.reshape(bh, tk, dv_), g.reshape(bh, tq, dv_)]
+    hk = k.shape[1]
+    group = h // hk
+    args3 = [q.reshape(bh, tq, d), k.reshape(b * hk, tk, d),
+             v.reshape(b * hk, tk, dv_), g.reshape(bh, tq, dv_)]
     delta = jnp.sum(
         g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
     ).reshape(bh, 1, tq)
@@ -1063,9 +1250,9 @@ def _flash_backward(q, k, v, bias, seed, o, lse, g, scale, causal, block_q,
     )
     # ---- dQ: grid over q blocks -----------------------------------------
     q_spec, kv_spec = _qkv_specs(fmt, h, "block", "full", block_q, block_k,
-                                 tq, tk, d)
+                                 tq, tk, d, group)
     g_spec, v_spec = _qkv_specs(fmt, h, "block", "full", block_q, block_k,
-                                tq, tk, dv_)
+                                tq, tk, dv_, group)
     in_specs = [_seed_spec(), q_spec, kv_spec, v_spec, g_spec,
                 _lse_spec_q, _lse_spec_q]
     args = [seed, args3[0], args3[1], args3[2], args3[3], lse3, delta3]
@@ -1081,7 +1268,7 @@ def _flash_backward(q, k, v, bias, seed, o, lse, g, scale, causal, block_q,
         _bwd_dq_kernel, scale=scale, block_q=block_q, block_k=block_k,
         causal=causal, seq_q=tq, seq_k=tk, causal_offset=causal_offset,
         bias_q1=bias_q1, drop_rate=drop_rate, inv_keep=inv_keep,
-        hw_prng=hw_prng,
+        hw_prng=hw_prng, mask=mask,
     )
     if bias is None:
         def dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
@@ -1103,9 +1290,14 @@ def _flash_backward(q, k, v, bias, seed, o, lse, g, scale, causal, block_q,
 
     # ---- dK/dV: grid over kv blocks -------------------------------------
     qfull_spec, kblock_spec = _qkv_specs(fmt, h, "full", "block", block_q,
-                                         block_k, tq, tk, d)
+                                         block_k, tq, tk, d, group)
     gfull_spec, vblock_spec = _qkv_specs(fmt, h, "full", "block", block_q,
-                                         block_k, tq, tk, dv_)
+                                         block_k, tq, tk, dv_, group)
+    # dk, dv: one row a QUERY head (the group's parts are summed below)
+    _, dk_spec = _qkv_specs(fmt, h, "full", "block", block_q, block_k, tq,
+                            tk, d)
+    _, dv_spec = _qkv_specs(fmt, h, "full", "block", block_q, block_k, tq,
+                            tk, dv_)
     in_specs = [_seed_spec(), qfull_spec, kblock_spec, vblock_spec,
                 gfull_spec, _lse_spec_full, _lse_spec_full]
     args = [seed, args3[0], args3[1], args3[2], args3[3], lse3, delta3]
@@ -1121,7 +1313,7 @@ def _flash_backward(q, k, v, bias, seed, o, lse, g, scale, causal, block_q,
         _bwd_dkv_kernel, scale=scale, block_q=block_q, block_k=block_k,
         causal=causal, seq_q=tq, seq_k=tk, causal_offset=causal_offset,
         bias_q1=bias_q1, drop_rate=drop_rate, inv_keep=inv_keep,
-        hw_prng=hw_prng,
+        hw_prng=hw_prng, mask=mask,
     )
     if bias is None:
         def dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
@@ -1136,7 +1328,7 @@ def _flash_backward(q, k, v, bias, seed, o, lse, g, scale, causal, block_q,
         name="flash_bhtd_bwd_dkv",
         grid=(bh, tk // block_k),
         in_specs=in_specs,
-        out_specs=[kblock_spec, vblock_spec],
+        out_specs=[dk_spec, dv_spec],
         out_shape=[
             jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
             jax.ShapeDtypeStruct((bh, tk, dv_), v.dtype),
@@ -1144,15 +1336,17 @@ def _flash_backward(q, k, v, bias, seed, o, lse, g, scale, causal, block_q,
         interpret=interpret,
     )(*args)
 
-    return (
-        dq.reshape(b, h, tq, d),
-        dk.reshape(b, h, tk, d),
-        dv.reshape(b, h, tk, dv_),
-    )
+    def over_group(part, width):
+        if group == 1:
+            return part.reshape(b, h, tk, width)
+        return jnp.sum(part.reshape(b, hk, group, tk, width), axis=2,
+                       dtype=jnp.float32).astype(part.dtype)
+
+    return dq.reshape(b, h, tq, d), over_group(dk, d), over_group(dv, dv_)
 
 
 def _dbias_xla(q, k, bias, lse, g, v, o, scale, causal, dropout_rate=0.0,
-               dropout_seed=None):
+               dropout_seed=None, mask=None):
     """Bias cotangent via plain-XLA recompute (dS reduced over broadcast
     dims).  O(T^2) memory — but attention biases are almost always
     stop-gradient masks, and then XLA dead-code-eliminates this whole
@@ -1160,12 +1354,16 @@ def _dbias_xla(q, k, bias, lse, g, v, o, scale, causal, dropout_rate=0.0,
     import jax
     import jax.numpy as jnp
 
+    k, v = _repeat_kv(q, k, v)
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
     logits = logits + bias.astype(jnp.float32)
     if causal:
         tq, tk = logits.shape[-2], logits.shape[-1]
-        mask = jnp.tril(jnp.ones((tq, tk), bool), tk - tq)
-        logits = jnp.where(mask, logits, -1e30)
+        logits = jnp.where(jnp.tril(jnp.ones((tq, tk), bool), tk - tq),
+                           logits, -1e30)
+    if mask is not None:
+        logits = jnp.where(_bd_plane(*logits.shape[-2:], mask), logits,
+                           -1e30)
     p = jnp.exp(logits - lse[..., None])
     dp = jnp.einsum("bhqd,bhkd->bhqk", g.astype(jnp.float32),
                     v.astype(jnp.float32))
@@ -1188,10 +1386,15 @@ def _dbias_xla(q, k, bias, lse, g, v, o, scale, causal, dropout_rate=0.0,
 def flash_attention(q, k, v, bias=None, scale=1.0, causal=False,
                     block_q=512, block_k=512, interpret=None, fmt="bhtd",
                     dropout_rate=0.0, dropout_seed=None,
-                    trainable_bias=True):
+                    trainable_bias=True, mask=None):
     """q,k,v: [B, H, T, D] (fmt="bhtd", default) or [B, T, H, D]
     (fmt="bthd"); bias: broadcastable [B, H, Tq, Tk] or None.  Returns the
     context in the same format as q.
+
+    fmt="bhtd" also takes k and v at a head count that divides q's
+    (grouped-query attention) and `mask` = (block_length, clean_offset),
+    the block-diffusion mask over [noisy ; clean] rows (`_bd_visible`),
+    computed in the kernels, which skip the tiles it empties (`_plan`).
 
     fmt="bthd" is the TPU-preferred calling convention: it is the free
     reshape of the projection output [B, T, H*D], so no split/merge-head
@@ -1228,7 +1431,7 @@ def flash_attention(q, k, v, bias=None, scale=1.0, causal=False,
         q, k, v, bias, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, interpret=interpret, fmt=fmt,
         dropout_rate=dropout_rate, dropout_seed=dropout_seed,
-        trainable_bias=trainable_bias)[0]
+        trainable_bias=trainable_bias, mask=mask)[0]
 
 
 def flash_attention_fwd(q, k, v, bias=None, **options):
@@ -1346,7 +1549,8 @@ def _vjp_of_pair(fwd, bwd, kept):
 
 def _flash_kernels(q, k, v, bias, scale=1.0, causal=False, block_q=512,
                    block_k=512, interpret=None, fmt="bhtd",
-                   dropout_rate=0.0, dropout_seed=None, trainable_bias=True):
+                   dropout_rate=0.0, dropout_seed=None, trainable_bias=True,
+                   mask=None):
     """(seed, fwd, bwd, norm) for one flash_attention site.  On the kernel
     route fwd(q, k, v, bias, seed) -> (out, lse) and bwd(q, k, v, bias,
     seed, out, lse, g, want_dbias) -> (dq, dk, dv, dbias or None), both on
@@ -1355,16 +1559,21 @@ def _flash_kernels(q, k, v, bias, scale=1.0, causal=False, block_q=512,
     operands, bwd is None and fwd is the XLA reference -> out."""
     if fmt not in ("bhtd", "bthd"):
         raise ValueError(f"flash_attention: unknown fmt {fmt!r}")
+    if mask is not None and causal:
+        raise ValueError("flash_attention: a block-diffusion mask and "
+                         "causal=True are two masks; give one")
     b, h, tq, _ = _dims(q, fmt)
     tk = _dims(k, fmt)[2]
     seed = _dropout_seed_arg(dropout_rate, dropout_seed, (tq, tk),
                              "flash_attention")
-    ok, bq, bk, interp = _plan(q, k, block_q, block_k, interpret, fmt, v)
+    ok, bq, bk, interp = _plan(q, k, block_q, block_k, interpret, fmt, v,
+                               mask)
     norm = _bias_norm(bias, b, h, tq, tk) if ok else None
     if norm is None:
         ref = _reference_bthd if fmt == "bthd" else reference_attention
         return seed, (lambda q, k, v, bias, seed: ref(
-            q, k, v, bias, scale, causal, dropout_rate, seed)), None, None
+            q, k, v, bias, scale, causal, dropout_rate, seed,
+            mask)), None, None
     # dropout + consumed bias gradient: the dbias recompute hashes its
     # mask, so the kernels must hash too (see trainable_bias docstring);
     # without a bias the hardware-PRNG path is fully enabled
@@ -1373,12 +1582,13 @@ def _flash_kernels(q, k, v, bias, scale=1.0, causal=False, block_q=512,
     def fwd(q, k, v, bias, seed):
         return _flash_forward(q, k, v, bias, seed, scale, causal, bq, bk,
                               interp, fmt, dropout_rate,
-                              allow_hw_prng=allow_hw)
+                              allow_hw_prng=allow_hw, mask=mask)
 
     def bwd(q, k, v, bias, seed, out, lse, g, want_dbias):
         dq, dk, dv = _flash_backward(q, k, v, bias, seed, out, lse, g,
                                      scale, causal, bq, bk, interp, fmt,
-                                     dropout_rate, allow_hw_prng=allow_hw)
+                                     dropout_rate, allow_hw_prng=allow_hw,
+                                     mask=mask)
         if bias is None or not want_dbias:
             return dq, dk, dv, None
         # _dbias_xla is written for bhtd; the transpose is an XLA view
@@ -1387,7 +1597,7 @@ def _flash_kernels(q, k, v, bias, scale=1.0, causal=False, block_q=512,
             else (lambda a: a)
         return dq, dk, dv, _dbias_xla(t(q), t(k), bias, lse, t(g), t(v),
                                       t(out), scale, causal, dropout_rate,
-                                      seed)
+                                      seed, mask)
 
     return seed, fwd, bwd, norm
 

@@ -17,11 +17,25 @@ def _residual(helper, shape, dtype):
 
 def fused_attention(q, k, v, bias=None, scale=1.0, causal=False,
                     dropout_rate=0.0, block_q=512, block_k=512,
-                    fmt="bhtd", weights_dropout=True, name=None):
+                    fmt="bhtd", weights_dropout=True, mask=None,
+                    block_length=0, clean_offset=0, name=None):
     """Flash-attention layer (Pallas kernel on TPU) over [B,H,T,D] tensors
     (fmt="bhtd") or [B,T,H,D] tensors (fmt="bthd" — the transpose-free
     convention: reshape the projection output [B,T,H*D] to [B,T,H,D] and
     skip split/merge-head transposes entirely).
+
+    Grouped-query attention (fmt="bhtd"): k and v may have fewer heads
+    than q, a count that divides q's; query head i reads key/value head
+    i // group, and K and V are never expanded to q's head count.
+
+    mask="block_diffusion" (fmt="bhtd", with `block_length` B and
+    `clean_offset` L; not with causal=True) is the training mask of a
+    block-diffusion model (BD3-LM, arXiv:2503.09573) over rows that hold
+    [noisy ; clean] copies of a sequence of L tokens, T = 2L: with
+    blk(p) = (p mod L) // B, a noisy row sees the noisy keys of its own
+    block and the clean keys of earlier blocks; a clean row sees the clean
+    keys of blocks up to its own.  The kernels compute it from positions
+    and skip every tile that holds no visible pair.
 
     With dropout_rate > 0 and weights_dropout=True (default), dropout
     applies to the attention WEIGHTS inside the kernels (the reference's
@@ -46,19 +60,23 @@ def fused_attention(q, k, v, bias=None, scale=1.0, causal=False,
     if bias is not None:
         inputs["Bias"] = [bias]
     in_kernel_rate = dropout_rate if weights_dropout else 0.0
+    attrs = {
+        "scale": float(scale),
+        "causal": causal,
+        "block_q": block_q,
+        "block_k": block_k,
+        "fmt": fmt,
+        "dropout_rate": float(in_kernel_rate),
+        "rng_id": fw.unique_rng_id() if in_kernel_rate else 0,
+    }
+    if mask is not None:
+        attrs.update(mask=mask, block_length=int(block_length),
+                     clean_offset=int(clean_offset))
     helper.append_op(
         "fused_attention",
         inputs=inputs,
         outputs={"Out": [out], "Lse": [lse]},
-        attrs={
-            "scale": float(scale),
-            "causal": causal,
-            "block_q": block_q,
-            "block_k": block_k,
-            "fmt": fmt,
-            "dropout_rate": float(in_kernel_rate),
-            "rng_id": fw.unique_rng_id() if in_kernel_rate else 0,
-        },
+        attrs=attrs,
     )
     # the context has v's head size (latent attention: d_v != d_qk)
     out.shape = qs and v.shape and tuple(qs[:-1]) + (v.shape[-1],)
@@ -180,13 +198,20 @@ def rms_norm(x, epsilon=1e-6, param_attr=None, name=None):
     return out
 
 
-def rope(x, theta=10000.0, name=None):
-    """Rotary position embedding of x [b, t, h, d] over interleaved pairs
-    (x[2i], x[2i+1]); positions count from 0 along axis 1."""
+def rope(x, theta=10000.0, pairing="interleaved", period=0, name=None):
+    """Rotary position embedding of x [b, t, h, d] over "interleaved"
+    pairs (x[2i], x[2i+1]) or "half" pairs (x[i], x[i + d/2]); positions
+    count from 0 along axis 1 and, with a `period`, start again every
+    `period` rows."""
     helper = LayerHelper("rope", name=name)
     out = helper.create_variable_for_type_inference(x.dtype)
+    attrs = {"theta": float(theta)}
+    if pairing != "interleaved":
+        attrs["pairing"] = pairing
+    if period:
+        attrs["period"] = int(period)
     helper.append_op("rope", inputs={"X": [x]}, outputs={"Out": [out]},
-                     attrs={"theta": float(theta)})
+                     attrs=attrs)
     out.shape = x.shape
     return out
 
@@ -201,11 +226,15 @@ def swiglu(x, name=None):
 
 
 def moe_router(x, n_experts, top_k, scale=1.0, bias_std=0.0,
-               param_attr=None, bias_attr=None, name=None):
-    """Sigmoid router over `n_experts` (ops/llm_ops.py moe_router): returns
-    (TopkIdx [T, k] int32, TopkWeight [T, k] float32).  The score-correction
-    bias is a buffer that enters the choice alone: a parameter that is not
-    trained, drawn once at `bias_std` (zeros at 0)."""
+               param_attr=None, bias_attr=None, scoring="sigmoid",
+               name=None):
+    """Top-k router over `n_experts` (ops/llm_ops.py moe_router), scoring
+    each expert by a "sigmoid" of its own logit or by a "softmax" over the
+    experts: returns (TopkIdx [T, k] int32, TopkWeight [T, k] float32, the
+    chosen scores normalised over the chosen, times `scale`).  The
+    score-correction bias is a buffer that enters the choice alone: a
+    parameter that is not trained, drawn once at `bias_std` (zeros at 0);
+    `bias_attr=False` leaves it out."""
     from ..initializer import ConstantInitializer, NormalInitializer
 
     helper = LayerHelper("moe_router", param_attr=param_attr,
@@ -213,23 +242,29 @@ def moe_router(x, n_experts, top_k, scale=1.0, bias_std=0.0,
     w = helper.create_parameter(helper.param_attr(),
                                 shape=[x.shape[-1], n_experts],
                                 dtype="float32")
-    import copy
+    inputs = {"X": [x], "W": [w]}
+    if bias_attr is not False:
+        import copy
 
-    battr = copy.copy(helper.bias_attr())  # the caller's attr stays as it is
-    battr.trainable = False
-    bias = helper.create_parameter(
-        battr, shape=[n_experts], dtype="float32", is_bias=True,
-        default_initializer=NormalInitializer(0.0, bias_std) if bias_std
-        else ConstantInitializer(0.0))
-    bias.stop_gradient = True
+        battr = copy.copy(helper.bias_attr())  # the caller's attr stays
+        battr.trainable = False
+        bias = helper.create_parameter(
+            battr, shape=[n_experts], dtype="float32", is_bias=True,
+            default_initializer=NormalInitializer(0.0, bias_std) if bias_std
+            else ConstantInitializer(0.0))
+        bias.stop_gradient = True
+        inputs["Bias"] = [bias]
+    attrs = {"top_k": int(top_k), "scale": float(scale)}
+    if scoring != "sigmoid":
+        attrs["scoring"] = scoring
     idx = _residual(helper, None, "int32")
     scores = _residual(helper, None, "float32")
     weight = helper.create_variable_for_type_inference("float32")
     helper.append_op(
-        "moe_router", inputs={"X": [x], "W": [w], "Bias": [bias]},
+        "moe_router", inputs=inputs,
         outputs={"TopkIdx": [idx], "TopkWeight": [weight],
                  "Scores": [scores]},
-        attrs={"top_k": int(top_k), "scale": float(scale)})
+        attrs=attrs)
     return idx, weight
 
 

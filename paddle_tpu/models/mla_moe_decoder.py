@@ -30,7 +30,14 @@ from ..param_attr import ParamAttr
 
 
 class _Net:
-    """The sizes of one stack, and the parameters it creates by name."""
+    """The sizes of one stack, and the parameters it creates by name.
+    A stack that says nothing else routes by sigmoid scores with a
+    score-correction bias (`scoring`, `router_bias`) and trains its
+    router (`train_router`)."""
+
+    scoring = "sigmoid"
+    router_bias = True
+    train_router = True
 
     def __init__(self, **sizes):
         self.__dict__.update(sizes)
@@ -63,6 +70,18 @@ def _linear(x, w, out_features):
 def _shaped(var, shape):
     var.shape = tuple(shape)
     return var
+
+
+def _embedding(tokens, embed_w, shape):
+    """A look-up of `tokens` [.., 1] in the one embedding parameter."""
+    helper = LayerHelper("embedding")
+    out = helper.create_variable_for_type_inference("float32")
+    helper.append_op(
+        "lookup_table", inputs={"Ids": [tokens], "W": [embed_w]},
+        outputs={"Out": [out]},
+        attrs={"is_sparse": False, "is_distributed": False,
+               "padding_idx": -1})
+    return _shaped(out, shape)
 
 
 def mla_attention(net, x, name):
@@ -116,25 +135,40 @@ def swiglu_ffn(net, x, d_ff, name):
 
 
 def moe_ffn(net, x, name):
-    """The chosen routed experts this chip holds, plus the shared expert."""
+    """The chosen routed experts this chip holds, plus the shared expert
+    where the stack has one (`n_shared`).  A stack that does not train its
+    router (`train_router` False) keeps the router's weights as they are
+    and hands no gradient on through the combine weights: on one chip's
+    share only the held experts return an output, so that gradient is
+    theirs alone and pulls tokens onto this chip."""
+    router_attr = net.attr(name + ".router_w")
+    router_attr.trainable = net.train_router
     idx, weight = contrib.moe_router(
         x, net.n_experts, net.top_k, scale=net.routed_scale,
-        bias_std=net.bias_std, param_attr=net.attr(name + ".router_w"),
-        bias_attr=ParamAttr(name=name + ".router_bias"))
+        bias_std=net.bias_std, param_attr=router_attr,
+        bias_attr=ParamAttr(name=name + ".router_bias")
+        if net.router_bias else False, scoring=net.scoring)
+    if not net.train_router:
+        weight.stop_gradient = True
     routed, load = contrib.moe_experts(
         x, idx, weight, net.n_held, net.d_ff_expert,
         expert_offset=net.expert_offset,
         gate_up_attr=net.attr(name + ".experts_gate_up_w"),
         down_attr=net.attr(name + ".experts_down_w"))
     net.loads.append(load)
+    if not net.n_shared:
+        return routed
     shared = swiglu_ffn(net, x, net.d_ff_expert * net.n_shared,
                         name + ".shared")
     return layers.elementwise_add(routed, shared)
 
 
-def decoder_block(net, x, name, moe):
-    """h <- h + Attn(RMSNorm(h)); h <- h + FFN(RMSNorm(h))."""
-    attn = mla_attention(net, net.norm(x, name + ".attn_norm"), name)
+def decoder_block(net, x, name, moe, attention=None):
+    """h <- h + Attn(RMSNorm(h)); h <- h + FFN(RMSNorm(h)).  `attention`
+    (net, x, name) is the stack's own; latent attention where none is
+    given."""
+    attn = (attention or mla_attention)(
+        net, net.norm(x, name + ".attn_norm"), name)
     x = _shaped(layers.elementwise_add(x, attn), x.shape)
     y = net.norm(x, name + ".ffn_norm")
     ffn = moe_ffn(net, y, name) if moe else swiglu_ffn(
@@ -207,14 +241,7 @@ def build_train_net(vocab_size, seq_len, batch, d_model=2048, n_head=32,
     embed_w = net.weight("embed_w", (vocab_size, d_model))
 
     def embed(tokens):  # one parameter, a look-up a prediction depth
-        helper = LayerHelper("embedding")
-        out = helper.create_variable_for_type_inference("float32")
-        helper.append_op(
-            "lookup_table", inputs={"Ids": [tokens], "W": [embed_w]},
-            outputs={"Out": [out]},
-            attrs={"is_sparse": False, "is_distributed": False,
-                   "padding_idx": -1})
-        return _shaped(out, (batch, seq_len, d_model))
+        return _embedding(tokens, embed_w, (batch, seq_len, d_model))
 
     x = embed(shifted(0))
     for i in range(n_dense + n_moe):

@@ -402,8 +402,12 @@ _COMPILE_COUNTS = {
 # its forward's residuals, and by the generic vjp of the forward lowering;
 # fused_qkv_attention sites whose grad op was fed Q, K, V, Ctx, Lse from
 # the forward (ops/fused_ops.py): the bthd backward kernels between XLA
-# projection dots, nothing recomputed
-_TRACE_COUNTS = ("grad_direct", "grad_generic", "qkv_bwd_composed")
+# projection dots, nothing recomputed; and the (query tile, key tile)
+# pairs a head and sequence that the masked flash forward walks visit, of
+# the pairs of the whole square, summed over the sites traced
+# (kernels/attention.py `bd_tiles_visited`)
+_TRACE_COUNTS = ("grad_direct", "grad_generic", "qkv_bwd_composed",
+                 "attn_tiles_visited", "attn_tiles_total")
 _compile_totals: Dict[str, float] = dict.fromkeys(
     list(_COMPILE_DURATIONS.values()) + list(_COMPILE_COUNTS.values())
     + list(_TRACE_COUNTS), 0)
@@ -438,12 +442,12 @@ def _on_compile_event(event: str, **_kw) -> None:
             _compile_totals[name] += 1
 
 
-def note_compile_count(name: str) -> None:
-    """One more of `name` (one of _TRACE_COUNTS), inside an Executor call
-    only, like every other compile total."""
+def note_compile_count(name: str, amount: int = 1) -> None:
+    """`amount` more of `name` (one of _TRACE_COUNTS), inside an Executor
+    call only, like every other compile total."""
     if getattr(_in_executor, "depth", 0):
         with _compile_lock:
-            _compile_totals[name] += 1
+            _compile_totals[name] += amount
 
 
 def listen_for_compile_phases() -> None:
@@ -487,7 +491,9 @@ def compile_phases() -> Dict[str, float]:
     `grad_direct` / `grad_generic` of grad ops lowered from their
     forward's residuals / by the generic vjp (core/registry.py) and
     `qkv_bwd_composed` of fused_qkv_attention grad ops fed q, k, v from
-    their forward."""
+    their forward, and `attn_tiles_visited` / `attn_tiles_total` of the
+    masked attention walks (their ratio is the share of the score square
+    the kernels visit)."""
     with _compile_lock:
         return dict(_compile_totals)
 

@@ -55,7 +55,7 @@ def _attn_kernel_opts(ctx, bias):
     grad op reads the same attrs (the grad maker copies them, `rng_id`
     among them) and the same step key, so it derives the same seed."""
     rate, seed = _attn_dropout_seed(ctx)
-    return dict(
+    opts = dict(
         scale=ctx.attr("scale", 1.0),
         causal=ctx.attr("causal", False),
         block_q=ctx.attr("block_q", 512),
@@ -64,6 +64,13 @@ def _attn_kernel_opts(ctx, bias):
         dropout_seed=seed,
         trainable_bias=_bias_is_trainable(ctx, bias),
     )
+    mask = ctx.attr("mask", "")
+    if mask == "block_diffusion":
+        opts["mask"] = (int(ctx.attr("block_length")),
+                        int(ctx.attr("clean_offset")))
+    elif mask:
+        raise ValueError(f"{ctx.op.type}: unknown mask {mask!r}")
+    return opts
 
 
 def _cotangent(ins, shape, dtype):
@@ -80,6 +87,13 @@ def lower_fused_attention(ctx, ins):
     """Flash attention over [B,H,T,D] (fmt "bhtd") or [B,T,H,D] (fmt
     "bthd") q/k/v with optional additive bias.  "bthd" is the
     transpose-free convention — see kernels/attention.py.
+
+    K and V may carry fewer heads than Q (fmt "bhtd"; their shape says how
+    many): query head i reads key/value head i // group, and K@GRAD /
+    V@GRAD are sums over the group.  Attr `mask` = "block_diffusion" with
+    `block_length` and `clean_offset` masks by position inside the kernels
+    (kernels/attention.py `_bd_visible`): the rows are [noisy ; clean]
+    copies of a sequence, and tiles that hold no visible pair are skipped.
 
     dropout_rate > 0 applies the reference's dropout-on-attention-weights
     semantics (transformer_model.py:44) INSIDE the kernels: the mask is the

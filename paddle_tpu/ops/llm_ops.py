@@ -1,7 +1,8 @@
-"""Ops of a pre-norm decoder block with latent attention and a sparse
-expert layer: RMS norm, rotary positions (interleaved pairs), SwiGLU, a
-sigmoid top-k router and the experts a chip holds (grouped matmuls over
-the routed pairs sorted by expert, kernels/grouped_matmul.py).
+"""Ops of a pre-norm decoder block with a sparse expert layer: RMS norm,
+rotary positions (interleaved or half-split pairs, positions that may
+restart along a row), SwiGLU, a sigmoid or softmax top-k router and the
+experts a chip holds (grouped matmuls over the routed pairs sorted by
+expert, kernels/grouped_matmul.py).
 
 Each has a forward lowering and a registered grad op (registry.
 residual_grad): the grad op reads what the forward wrote (`InvRms`,
@@ -62,24 +63,38 @@ def lower_rms_norm_grad(ctx, ins):
 # ---------------------------------------------------------------------------
 
 
-def _rope_tables(t, d, theta):
-    """cos, sin [t, d/2] of position p times theta^(-2i/d), float32."""
+def _rope_tables(t, d, theta, period):
+    """cos, sin [t, d/2] of position p times theta^(-2i/d), float32; with
+    a `period` the position of row p is p mod period."""
     import jax.numpy as jnp
 
     inv_freq = jnp.power(
         jnp.float32(theta), -jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    pos = jnp.arange(t, dtype=jnp.float32)
+    if period:
+        pos = (jnp.arange(t, dtype=jnp.int32) % period).astype(jnp.float32)
+    angle = pos[:, None] * inv_freq[None, :]
     return jnp.cos(angle), jnp.sin(angle)
 
 
-def _rotate(x, theta, sign):
-    """x [b, t, h, d]: each pair (x[2i], x[2i+1]) turned by sign * its
-    position's angle; positions count from 0 along axis 1."""
+def _rotate(ctx, x, sign):
+    """x [b, t, h, d]: each pair turned by sign * its position's angle.
+    Attr `pairing`: "interleaved" pairs (x[2i], x[2i+1]) or "half" pairs
+    (x[i], x[i + d/2]); the output keeps the input's layout."""
     import jax.numpy as jnp
 
     b, t, h, d = x.shape
-    cos, sin = _rope_tables(t, d, theta)
+    cos, sin = _rope_tables(t, d, ctx.attr("theta", 10000.0),
+                            ctx.attr("period", 0))
     cos, sin = cos[None, :, None, :], sign * sin[None, :, None, :]
+    pairing = ctx.attr("pairing", "interleaved")
+    if pairing == "half":
+        x0, x1 = _f32(x[..., :d // 2]), _f32(x[..., d // 2:])
+        out = jnp.concatenate([x0 * cos - x1 * sin, x0 * sin + x1 * cos],
+                              axis=-1)
+        return out.astype(x.dtype)
+    if pairing != "interleaved":
+        raise ValueError(f"rope: unknown pairing {pairing!r}")
     pairs = _f32(x).reshape(b, t, h, d // 2, 2)
     x0, x1 = pairs[..., 0], pairs[..., 1]
     out = jnp.stack([x0 * cos - x1 * sin, x0 * sin + x1 * cos], axis=-1)
@@ -88,9 +103,12 @@ def _rotate(x, theta, sign):
 
 @register("rope")
 def lower_rope(ctx, ins):
-    """Rotary position embedding over interleaved pairs (`rope_interleave`)
-    of X [b, t, h, d], attr `theta`; sequences are packed from position 0."""
-    return {"Out": [_rotate(ins["X"][0], ctx.attr("theta", 10000.0), 1.0)]}
+    """Rotary position embedding of X [b, t, h, d], attrs `theta`,
+    `pairing` ("interleaved", `rope_interleave`, or "half", the
+    `rotate_half` layout) and `period`: positions count from 0 along axis
+    1 and, with a period, start again every `period` rows (the two halves
+    of a [noisy ; clean] row both count 0 .. L-1)."""
+    return {"Out": [_rotate(ctx, ins["X"][0], 1.0)]}
 
 
 @residual_grad("rope")
@@ -98,7 +116,7 @@ def lower_rope_grad(ctx, ins):
     """A rotation's transpose is the rotation back."""
     x = ins["X"][0]
     g = ins["Out@GRAD"][0].astype(x.dtype).reshape(x.shape)
-    return {"X@GRAD": [_rotate(g, ctx.attr("theta", 10000.0), -1.0)]}
+    return {"X@GRAD": [_rotate(ctx, g, -1.0)]}
 
 
 # ---------------------------------------------------------------------------
@@ -151,24 +169,37 @@ def _router_dot(a, b, dims):
                                precision=jax.lax.Precision.HIGHEST)
 
 
+def _router_scoring(ctx):
+    scoring = ctx.attr("scoring", "sigmoid")
+    if scoring not in ("sigmoid", "softmax"):
+        raise ValueError(f"moe_router: unknown scoring {scoring!r}")
+    return scoring
+
+
 @register("moe_router", residuals=("Scores", "TopkIdx"))
 def lower_moe_router(ctx, ins):
-    """Sigmoid scores over all experts, float32 at the highest matmul
-    precision; the top_k of (scores + Bias) are chosen (`noaux_tc` with
-    one group: the correction bias enters the choice only) and weighted by
-    their own scores, normalised over the chosen and times `scale`.
+    """Scores over all experts, float32 at the highest matmul precision:
+    attr `scoring` = "sigmoid" (each expert's own; DeepSeek-V3) or
+    "softmax" (over the experts; the Qwen3-MoE lineage).  The top_k of
+    (scores + Bias) are chosen (`noaux_tc` with one group: the correction
+    bias enters the choice only; Bias may be absent) and weighted by their
+    own scores, normalised over the chosen (`norm_topk_prob`) and times
+    `scale`.
 
-    X [.., d], W [d, E], Bias [E] -> TopkIdx [T, k] int32, TopkWeight
-    [T, k] float32, Scores [T, E] float32 (T = the leading dims
+    X [.., d], W [d, E], Bias [E] or none -> TopkIdx [T, k] int32,
+    TopkWeight [T, k] float32, Scores [T, E] float32 (T = the leading dims
     flattened)."""
     import jax
     import jax.numpy as jnp
 
-    x, w, bias = ins["X"][0], ins["W"][0], ins["Bias"][0]
-    scores = jax.nn.sigmoid(
-        _router_dot(x.reshape(-1, x.shape[-1]), w, ((1,), (0,))))
-    _, idx = jax.lax.top_k(scores + _f32(bias)[None, :],
-                           ctx.attr("top_k", 8))
+    x, w = ins["X"][0], ins["W"][0]
+    bias = ins.get("Bias", [None])[0]
+    logits = _router_dot(x.reshape(-1, x.shape[-1]), w, ((1,), (0,)))
+    scores = (jax.nn.sigmoid(logits) if _router_scoring(ctx) == "sigmoid"
+              else jax.nn.softmax(logits, axis=-1))
+    _, idx = jax.lax.top_k(
+        scores if bias is None else scores + _f32(bias)[None, :],
+        ctx.attr("top_k", 8))
     idx = idx.astype(jnp.int32)
     chosen = jnp.take_along_axis(scores, idx, axis=1)
     weight = chosen * (ctx.attr("scale", 1.0)
@@ -192,13 +223,19 @@ def lower_moe_router_grad(ctx, ins):
         - jnp.sum(dw_pair * chosen, axis=1, keepdims=True) / (total * total))
     rows = jnp.arange(idx.shape[0], dtype=jnp.int32)[:, None]
     d_scores = jnp.zeros_like(scores).at[rows, idx].add(d_chosen)
-    d_logits = d_scores * scores * (1.0 - scores)
+    if _router_scoring(ctx) == "sigmoid":
+        d_logits = d_scores * scores * (1.0 - scores)
+    else:
+        d_logits = scores * (d_scores - jnp.sum(
+            d_scores * scores, axis=1, keepdims=True))
     x2 = x.reshape(-1, x.shape[-1])
     dx = _router_dot(d_logits, w, ((1,), (1,)))
-    return {"X@GRAD": [dx.astype(x.dtype).reshape(x.shape)],
-            "W@GRAD": [_router_dot(x2, d_logits, ((0,), (0,))).astype(
-                w.dtype)],
-            "Bias@GRAD": [None]}
+    grads = {"X@GRAD": [dx.astype(x.dtype).reshape(x.shape)],
+             "W@GRAD": [_router_dot(x2, d_logits, ((0,), (0,))).astype(
+                 w.dtype)]}
+    if "Bias" in ins:
+        grads["Bias@GRAD"] = [None]
+    return grads
 
 
 # ---------------------------------------------------------------------------

@@ -77,6 +77,31 @@ def reference_choices(ref_mod, dots, cfg, params, ids):
     return out
 
 
+def block_diffusion_choices(ref_mod, dots, cfg, params, ids, noise):
+    """`reference_choices` for a stack of the block-diffusion kind
+    (perfbench/configs/sdar_30b_a3b_ep8_reference.py): every layer is an
+    expert layer over the 2L positions [x_t ; x0], the router a softmax
+    with no bias.  [(idx [b, 2L, top_k], margin [b, 2L])]."""
+    import jax
+    import jax.numpy as jnp
+
+    R, P, eps = ref_mod, params, cfg["rms_norm_eps"]
+    k = cfg["num_experts_per_tok"]
+    out = []
+    noisy = jnp.where(noise > 0, cfg["mask_token_id"], ids)
+    x = P["embed_w"][jnp.concatenate([noisy, ids], axis=1)]
+    for i in range(cfg["num_hidden_layers"]):
+        p = f"layer{i}"
+        x = x + R.attention(dots, cfg, R.rms_norm(
+            x, P[p + ".attn_norm.scale"], eps), P, p)
+        y = R.rms_norm(x, P[p + ".ffn_norm.scale"], eps)
+        top, idx = jax.lax.top_k(
+            jax.nn.softmax(dots.mm(y, P[p + ".router_w"]), axis=-1), k + 1)
+        out.append((idx[..., :k], top[..., k - 1] - top[..., k]))
+        x = x + R.moe(dots, cfg, y, P, p)
+    return out
+
+
 def count_flips(mine, theirs, margin, n_experts, offset, held):
     """`mine`, `theirs` [tokens, top_k] expert ids, `margin` [tokens]:
     how far the two choices differ, over all experts and over those held
@@ -144,18 +169,23 @@ def diagnose(cell, seed):
                      for x in seen[0]], axis=2)
 
     dots = tc.blocks.Dots("f32")
-    look = jax.jit(lambda P, ids: reference_choices(tc.ref_mod, dots, cfg,
-                                                    P, ids))
+    # a traffic with a `noise` field trains by block diffusion
+    fields, choices = ("ids",), reference_choices
+    if "noise" in feeds[0]:
+        fields, choices = ("ids", "noise"), block_diffusion_choices
+    look = jax.jit(lambda P, *rows: choices(tc.ref_mod, dots, cfg, P, *rows))
     params = tc.init_params(seed)
     layers = [[] for _ in idx_vars]
+    held = cfg.get("n_routed_experts", cfg.get("num_experts"))
     for t in range(steps):
-        theirs = look(params, jnp.asarray(feeds[0]["ids"][t, ..., 0]))
+        theirs = look(params, *(jnp.asarray(feeds[0][f][t, ..., 0])
+                                for f in fields))
         for j, (idx, margin) in enumerate(theirs):
             layers[j].append(count_flips(
                 mine[t, :, j].reshape(-1, mine.shape[-1]),
                 np.asarray(idx).reshape(-1, mine.shape[-1]),
                 np.asarray(margin).reshape(-1), cfg["router_experts"],
-                cfg["expert_offset"], cfg["n_routed_experts"]))
+                cfg["expert_offset"], held))
     del params, look
 
     def summed(per_step):
