@@ -247,17 +247,14 @@ MOE_KERNELS = ("flash_bhtd_fwd", "flash_bhtd_bwd_dq", "flash_bhtd_bwd_dkv",
                "moe_gmm_fwd", "moe_gmm_bwd_dx", "moe_gmm_bwd_dw")
 
 
-def moe_leg(sizes=None, scan_steps=2, calls=3, interpret=False) -> dict:
-    """A small latent-attention + expert-layer + MTP train step (models.
-    mla_moe_decoder, one chip's share of the experts, bf16 amp) through
-    Executor.run_steps: loss finite and falling, and every kernel of
-    MOE_KERNELS a Mosaic call of the compiled step (no silent fallback
-    to the XLA routes)."""
+def _moe_run(sizes, scan_steps, calls):
+    """(losses of calls + 1 run_steps calls, the entry's HLO, the last
+    call's device counters) of the expert-layer stack at `sizes`."""
     import paddle_tpu as pt
+    from paddle_tpu.flags import FLAGS
     from paddle_tpu.models import mla_moe_decoder as M
+    from paddle_tpu.monitor import flight
 
-    sizes = dict(sizes or MOE_SMALL)
-    fails = []
     prog, startup = pt.Program(), pt.Program()
     with pt.program_guard(prog, startup):
         loss, _ = M.build_train_net(**sizes)
@@ -270,27 +267,69 @@ def moe_leg(sizes=None, scan_steps=2, calls=3, interpret=False) -> dict:
                                 (scan_steps, b, t + 2, 1), dtype=np.int32),
             "loss_weight": np.ones((scan_steps, b, t, 1), np.float32)}
     losses = []
-    for _ in range(calls + 1):
-        (out,) = exe.run_steps(prog, feed=feed, fetch_list=[loss],
-                               scope=scope)
+    for i in range(calls + 1):
+        FLAGS.monitor = i == calls  # the last call reads the counters back
+        try:
+            (out,) = exe.run_steps(prog, feed=feed, fetch_list=[loss],
+                                   scope=scope)
+        finally:
+            FLAGS.reset("monitor")
         losses.append(np.asarray(out, np.float64).reshape(-1))
-    if not all(np.all(np.isfinite(x)) for x in losses):
-        fails.append(f"non-finite loss: {losses}")
-    elif not losses[-1][-1] < losses[0][0]:
-        fails.append(f"loss did not fall: {losses[0][0]:.4f} -> "
-                     f"{losses[-1][-1]:.4f}")
-    hlo = _entry_hlo(exe, prog, feed, scope)
-    absent = [] if interpret else [k for k in MOE_KERNELS if k not in hlo]
-    if absent:
-        fails.append(f"kernels that fell back to XLA (no Mosaic call of "
-                     f"that name in the compiled step): {absent}")
-    _say(f"moe: loss {losses[0][0]:.4f} -> {losses[-1][-1]:.4f} over "
-         f"{(calls + 1) * scan_steps} steps, "
-         f"mosaic_calls={_mosaic_calls(hlo)}, absent={absent}")
+    event = flight.default_recorder().events(kind="executor.run_steps")[-1]
+    return losses, _entry_hlo(exe, prog, feed, scope), event["counters"]
+
+
+def moe_leg(sizes=None, scan_steps=2, calls=3, interpret=False) -> dict:
+    """A small latent-attention + expert-layer + MTP train step (models.
+    mla_moe_decoder, bf16 amp) through Executor.run_steps, twice: one
+    chip's share of the experts (a trip or two of the expert layer's walk)
+    and every expert held (four trips a layer: the grouped matmuls inside
+    a loop whose bound is traced, at more than one trip).  Loss finite and
+    falling, every kernel of MOE_KERNELS a Mosaic call of the compiled
+    step (no silent fallback to the XLA routes), and the rows walked what
+    the held pairs need."""
+    from paddle_tpu.ops.llm_ops import chunk_rows
+
+    sizes = dict(sizes or MOE_SMALL)
+    layers_ = sizes["n_moe"] + sizes["n_mtp"]
+    pairs = sizes["batch"] * sizes["seq_len"] * sizes["top_k"]
+    chunk = chunk_rows(pairs)
+    fails, rep = [], {}
+    for tag, over in (("share", {}), ("all_held", dict(
+            n_held=sizes["n_experts"], expert_offset=0))):
+        losses, hlo, counters = _moe_run(dict(sizes, **over), scan_steps,
+                                         calls)
+        if not all(np.all(np.isfinite(x)) for x in losses):
+            fails.append(f"{tag}: non-finite loss: {losses}")
+        elif not losses[-1][-1] < losses[0][0]:
+            fails.append(f"{tag}: loss did not fall: {losses[0][0]:.4f} -> "
+                         f"{losses[-1][-1]:.4f}")
+        absent = [] if interpret else [k for k in MOE_KERNELS
+                                       if k not in hlo]
+        if absent:
+            fails.append(f"{tag}: kernels that fell back to XLA (no Mosaic "
+                         f"call of that name in the compiled step): {absent}")
+        walked, held = (counters["moe_rows_walked"],
+                        counters["moe_local_pairs"])
+        if not held <= walked < held + layers_ * chunk:
+            fails.append(f"{tag}: {walked} rows walked for {held} pairs")
+        if over and walked != layers_ * pairs:
+            fails.append(f"{tag}: {walked} rows walked, not every chunk of "
+                         f"{layers_} layers x {pairs} pairs")
+        _say(f"moe {tag}: loss {losses[0][0]:.4f} -> {losses[-1][-1]:.4f} "
+             f"over {(calls + 1) * scan_steps} steps, rows walked "
+             f"{walked:.0f} for {held:.0f} pairs (chunk {chunk}), "
+             f"mosaic_calls={_mosaic_calls(hlo)}, "
+             f"absent={absent}")
+        rep[tag] = dict(loss_first=float(losses[0][0]),
+                        loss_last=float(losses[-1][-1]),
+                        rows_walked=float(walked), pairs=float(held),
+                        mosaic_calls=_mosaic_calls(hlo))
     return dict(leg="moe", ok=not fails, failures=fails,
-                loss_first=float(losses[0][0]),
-                loss_last=float(losses[-1][-1]),
-                mosaic_calls=_mosaic_calls(hlo))
+                loss_first=rep["share"]["loss_first"],
+                loss_last=rep["share"]["loss_last"],
+                mosaic_calls=rep["share"]["mosaic_calls"],
+                all_held=rep["all_held"])
 
 
 # ---------------------------------------------------------------------------

@@ -53,6 +53,7 @@ from __future__ import annotations
 from typing import Dict, List, Optional
 
 from ..core import framework as fw
+from ..core import registry
 from ..ops.numerics_ops import STAT_WIDTH
 
 # the packed stats tensors the executor auto-fetches; order matters
@@ -198,6 +199,8 @@ def _instrument_locate(b: _Builder) -> None:
         new_ops.append(op)
         seen = set()
         for slot in op.outputs:
+            if slot in registry.unfilled_slots(op.type):
+                continue  # may hold elements nothing has written
             for name in op.outputs[slot]:
                 if not name or name in seen:
                     continue
@@ -224,6 +227,8 @@ def _instrument_while(b: _Builder, op_idx: int, while_op, sub,
             continue
         seen = set()
         for slot in iop.outputs:
+            if slot in registry.unfilled_slots(iop.type):
+                continue
             for name in iop.outputs[slot]:
                 if not name or name in seen:
                     continue

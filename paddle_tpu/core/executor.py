@@ -288,7 +288,7 @@ def trace_block(block: fw.Block, env: Dict[str, Any], tctx: TraceContext,
                 if env.get(n) is not None:
                     env[n] = _jax.lax.optimization_barrier(env[n])
         if tctx.check_nan_inf and outs:
-            flag = _all_finite_flag(outs)
+            flag = _all_finite_flag(outs, registry.unfilled_slots(op.type))
             if flag is not None:
                 tctx.nan_checks.append((repr(op), flag))
     if any(tctx.embed_stats.values()):
@@ -307,14 +307,16 @@ def trace_block(block: fw.Block, env: Dict[str, Any], tctx: TraceContext,
     return env
 
 
-def _all_finite_flag(outs):
-    """Scalar bool: every inexact-float leaf in an op's outputs is finite."""
+def _all_finite_flag(outs, unfilled=()):
+    """Scalar bool: every inexact-float leaf in an op's outputs is finite;
+    the slots the op registers as `unfilled` are not looked at."""
     import jax
     import jax.numpy as jnp
 
     leaves = [
         leaf
-        for vals in outs.values()
+        for slot, vals in outs.items()
+        if slot not in unfilled
         for v in vals
         if v is not None
         for leaf in jax.tree_util.tree_leaves(v)

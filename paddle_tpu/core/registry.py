@@ -66,6 +66,7 @@ class OpDef:
         inplace_outputs: Optional[Dict[str, str]] = None,
         derives_rng=False,
         residuals: Sequence[str] = (),
+        unfilled: Sequence[str] = (),
         doc: str = "",
     ):
         self.type = type
@@ -90,6 +91,12 @@ class OpDef:
         # when the op instance declares them all; the grad op itself is
         # registered with residual_grad below.
         self.residuals = tuple(residuals)
+        # output slots that may hold elements nothing has written (a
+        # buffer the op allocates and fills only as far as its data
+        # reaches; its grad op knows how far).  The finite checks skip
+        # them: check_nan_inf (core/executor.py) and the numerics tier's
+        # per-op-output rows (analysis/numerics.py).
+        self.unfilled = tuple(unfilled)
         self.doc = doc
 
     def op_derives_rng(self, op) -> bool:
@@ -110,6 +117,7 @@ def register(
     inplace_outputs=None,
     derives_rng=False,
     residuals=(),
+    unfilled=(),
     doc="",
 ):
     """Decorator registering `fn` as the lowering for op `type`.
@@ -133,6 +141,7 @@ def register(
             inplace_outputs=inplace_outputs,
             derives_rng=derives_rng,
             residuals=residuals,
+            unfilled=unfilled,
             doc=doc or (fn.__doc__ or ""),
         )
         return fn
@@ -152,6 +161,13 @@ def get(type: str) -> OpDef:
             f"Registered: {sorted(_registry)[:40]}..."
         )
     return opdef
+
+
+def unfilled_slots(type: str) -> tuple:
+    """The output slots op `type` registers as `unfilled` (none for a
+    type that has no OpDef of its own, as a grad op's)."""
+    opdef = _registry.get(type)
+    return opdef.unfilled if opdef is not None else ()
 
 
 def all_ops() -> List[str]:

@@ -14,8 +14,13 @@ It is dropless: `group_sizes[i]` rows belong to expert i, whatever the
 router sent; no capacity, no padding of an expert to a fixed size.  The
 rows are a static [m, k] buffer whose first sum(group_sizes) rows are
 live; the grid visits only the row tiles that hold a live row (a traced
-grid bound), so the dead tail costs no kernel time, and the forward and
-dX results are zero there.
+grid bound), so the dead tail costs no kernel time.  The forward and dX
+kernels WRITE only live rows: what a row past the last expert's holds is
+undefined, and the caller discards it where it consumes the result (by a
+select, never by a product); dW masks its operands' rows itself.
+
+Callers hand these a chunk of the sorted pairs, not the T x top_k buffer,
+with the experts' sizes clipped to the chunk.
 
   grouped_matmul(lhs [m,k], rhs [g,k,n], sizes)            -> [m,n]
   grouped_matmul(lhs [m,n], rhs [g,k,n], sizes, transpose_rhs=True)
@@ -169,14 +174,6 @@ def _tgmm_kernel(offsets_ref, group_ids_ref, m_tile_ids_ref, lhs_ref,
         out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
 
-def _live_rows(out, group_sizes):
-    """Rows past the last expert's were never written: zeros there."""
-    import jax.numpy as jnp
-
-    live = jnp.arange(out.shape[0], dtype=jnp.int32) < jnp.sum(group_sizes)
-    return jnp.where(live[:, None], out, jnp.zeros((), out.dtype))
-
-
 def _group_of_row(group_sizes, m):
     """[m] expert of each row of the sorted buffer; `g` past the last."""
     import jax.numpy as jnp
@@ -186,8 +183,7 @@ def _group_of_row(group_sizes, m):
 
 
 def reference_grouped_matmul(lhs, rhs, group_sizes, transpose_rhs=False):
-    """The XLA fallback of `grouped_matmul` (`jax.lax.ragged_dot`; rows
-    past the last expert's give zeros)."""
+    """The XLA fallback of `grouped_matmul` (`jax.lax.ragged_dot`)."""
     import jax
     import jax.numpy as jnp
 
@@ -195,7 +191,7 @@ def reference_grouped_matmul(lhs, rhs, group_sizes, transpose_rhs=False):
         rhs = jnp.swapaxes(rhs, 1, 2)
     out = jax.lax.ragged_dot(lhs, rhs, group_sizes.astype(jnp.int32),
                              preferred_element_type=jnp.float32)
-    return _live_rows(out.astype(lhs.dtype), group_sizes)
+    return out.astype(lhs.dtype)
 
 
 def reference_grouped_matmul_dw(lhs, dout, group_sizes):
@@ -218,7 +214,7 @@ def reference_grouped_matmul_dw(lhs, dout, group_sizes):
 def grouped_matmul(lhs, rhs, group_sizes, transpose_rhs=False,
                    interpret=None):
     """lhs[rows of expert i] @ rhs[i] for every expert, rows sorted by
-    expert; zeros in the rows past the last expert's.  With
+    expert; the rows past the last expert's are not written.  With
     `transpose_rhs` the product is with rhs[i].T: the same walk computes
     the forward's dX, under that kernel's name."""
     import jax
@@ -247,7 +243,7 @@ def grouped_matmul(lhs, rhs, group_sizes, transpose_rhs=False,
         return m_tile_ids[grid_id], n_i
 
     rhs_block = (None, tn, tk) if transpose_rhs else (None, tk, tn)
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_gmm_kernel, tm=tm, tn=tn, tiles_k=tiles_k,
                           transpose_rhs=transpose_rhs),
         name="moe_gmm_bwd_dx" if transpose_rhs else "moe_gmm_fwd",
@@ -263,7 +259,6 @@ def grouped_matmul(lhs, rhs, group_sizes, transpose_rhs=False,
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(*metadata, lhs, rhs)
-    return _live_rows(out, group_sizes)
 
 
 def grouped_matmul_dw(lhs, dout, group_sizes, interpret=None):

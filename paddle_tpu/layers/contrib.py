@@ -286,11 +286,12 @@ def moe_experts(x, topk_idx, topk_weight, n_held, d_ff, expert_offset=0,
     out = helper.create_variable_for_type_inference(x.dtype)
     h = _residual(helper, None, x.dtype)
     load = _residual(helper, (n_held,), "int32")
+    order = _residual(helper, None, "int32")
     helper.append_op(
         "moe_experts",
         inputs={"X": [x], "TopkIdx": [topk_idx], "TopkWeight": [topk_weight],
                 "WGateUp": [w_gu], "WDown": [w_down]},
-        outputs={"Out": [out], "H": [h], "Load": [load]},
+        outputs={"Out": [out], "H": [h], "Load": [load], "Order": [order]},
         attrs={"expert_offset": int(expert_offset)})
     out.shape = x.shape
     return out, load

@@ -22,6 +22,8 @@ block, its final norm), all matmul weights normal(0, init_std)."""
 
 from __future__ import annotations
 
+import math
+
 from .. import layers
 from ..initializer import NormalInitializer
 from ..layer_helper import LayerHelper
@@ -41,7 +43,8 @@ class _Net:
 
     def __init__(self, **sizes):
         self.__dict__.update(sizes)
-        self.loads = []  # one [n_held] int32 variable an expert layer
+        # an expert layer: (Load [n_held] int32, the pairs it routes)
+        self.loads = []
 
     def attr(self, name):
         return ParamAttr(name=name,
@@ -155,7 +158,7 @@ def moe_ffn(net, x, name):
         expert_offset=net.expert_offset,
         gate_up_attr=net.attr(name + ".experts_gate_up_w"),
         down_attr=net.attr(name + ".experts_down_w"))
-    net.loads.append(load)
+    net.loads.append((load, math.prod(x.shape[:-1]) * net.top_k))
     if not net.n_shared:
         return routed
     shared = swiglu_ffn(net, x, net.d_ff_expert * net.n_shared,
@@ -185,19 +188,27 @@ def _token_loss(net, hidden, head_w, labels):
 
 
 def _publish_load(net, program):
-    """Two device counters for the executor's flight event: the pairs this
-    chip computed a step (all expert layers) and the largest held expert's
-    load over the mean."""
+    """Three device counters for the executor's flight event: the pairs
+    this chip computed a step (all expert layers), the largest held
+    expert's load over the mean, and the rows the expert layers walked
+    for those pairs, by the op's own rule (ops/llm_ops.py rows_walked)."""
     from .. import monitor
+    from ..ops.llm_ops import rows_walked
 
-    load = layers.cast(layers.concat(net.loads, axis=0), "float32")
+    loads, routed = zip(*net.loads)
+    (routed,) = set(routed)  # every layer routes the same T x top_k pairs
+    load = layers.cast(layers.stack(list(loads)), "float32")  # [layers, G]
     pairs = layers.reduce_sum(load)
     worst = layers.elementwise_div(
         layers.reduce_max(load),
         layers.elementwise_max(layers.reduce_mean(load),
                                layers.fill_constant([1], "float32", 1e-9)))
+    walked = layers.reduce_sum(rows_walked(
+        layers.reduce_sum(load, dim=1), routed,
+        lambda live, rows: layers.ceil(live / rows)))
     for name, var in (("moe_local_pairs", pairs),
-                      ("moe_max_over_mean", worst)):
+                      ("moe_max_over_mean", worst),
+                      ("moe_rows_walked", walked)):
         var.stop_gradient = True
         monitor.device_counter(program, name, var)
 

@@ -260,17 +260,90 @@ def _dispatch(idx, n_held, offset):
     return order, load
 
 
-def _combine(rows, order, t, k):
-    """Sum each token's pairs: rows [T*k, ..] in sorted order -> [T, ..]
-    float32."""
+def chunk_rows(pairs):
+    """Rows of one chunk of the walk over `pairs` sorted (token, choice)
+    pairs: a quarter of them, whole row tiles of the grouped matmuls (a
+    share that holds an eighth or a sixteenth of the experts then takes
+    one trip at most loads, and a layer that holds every expert four); all
+    of them, one chunk, where a quarter is not whole tiles.  Fixed by the
+    shapes: no caller chooses it."""
+    from ..kernels.grouped_matmul import ROW_TILE
+
+    return pairs // 4 if pairs % (4 * ROW_TILE) == 0 else pairs
+
+
+def rows_walked(live, pairs, ceil_div=lambda a, b: -(-a // b)):
+    """Rows the walk visits for `live` held pairs of `pairs`: whole chunks,
+    trips x R.  THE rule, for the op's trip count and for the counter
+    `moe_rows_walked` (models/mla_moe_decoder.py), which hands in a
+    `ceil_div` over a program's variables."""
+    rows = chunk_rows(pairs)
+    return ceil_div(live, rows) * rows
+
+
+def _walk(load, order):
+    """How the op walks its sorted pairs: in chunks of R = chunk_rows rows,
+    as many as hold a live row.  Returns (R, the held experts' offsets
+    [G+1] in the sorted order, live = the held experts' pairs, trips =
+    ceil(live / R), back [T*k]: where each pair sits in the sorted
+    order)."""
     import jax.numpy as jnp
 
-    back = jnp.zeros_like(order).at[order].set(
-        jnp.arange(order.shape[0], dtype=order.dtype))
-    return jnp.sum(_f32(rows[back]).reshape((t, k) + rows.shape[1:]), axis=1)
+    pairs = order.shape[0]
+    rows = chunk_rows(pairs)
+    offsets = jnp.concatenate([jnp.zeros(1, jnp.int32),
+                               jnp.cumsum(load, dtype=jnp.int32)])
+    return (rows, offsets, offsets[-1],
+            rows_walked(offsets[-1], pairs) // rows,
+            jnp.argsort(order).astype(order.dtype))
 
 
-@register("moe_experts", residuals=("H", "Load"))
+def _chunk(c, rows, offsets, order):
+    """Chunk c of the walk: (its first row, its pairs' flat indices [R],
+    the rows of it each held expert owns [G])."""
+    import jax
+    import jax.numpy as jnp
+
+    base = c * rows
+    edges = jnp.clip(offsets, base, base + rows)
+    return (base, jax.lax.dynamic_slice(order, (base,), (rows,)),
+            edges[1:] - edges[:-1])
+
+
+def _put(buffer, chunk, base):
+    import jax
+
+    return jax.lax.dynamic_update_slice(
+        buffer, chunk, (base,) + (0,) * (chunk.ndim - 1))
+
+
+def _add(total, new):
+    """total + new in float32, kept in total's dtype."""
+    return (_f32(total) + _f32(new)).astype(total.dtype)
+
+
+def _tokens_sum(chunk, base, back, live, t, k, weight=None):
+    """Each token's pairs that sit in ONE chunk [R, d] of the sorted
+    order, summed (times the pairs' `weight` [T, k]) -> [T, d] float32.
+    A pair outside the chunk's live rows reads some row of it and is
+    discarded by the select: the kernels do not write dead rows.  The
+    gather's source is the chunk, not a T x top_k buffer: small enough
+    for XLA to hold it in VMEM, where a row costs a fifth of what it
+    costs from HBM (PERF.md section 6, PR 32)."""
+    import jax.numpy as jnp
+
+    rows = chunk.shape[0]
+    local = back - base
+    kept = (local >= 0) & (local < jnp.minimum(rows, live - base))
+    pairs = jnp.where(kept[:, None],
+                      _f32(chunk[jnp.clip(local, 0, rows - 1)]), 0.0)
+    pairs = pairs.reshape(t, k, -1)
+    if weight is not None:
+        pairs = pairs * _f32(weight)[:, :, None]
+    return jnp.sum(pairs, axis=1)
+
+
+@register("moe_experts", residuals=("H", "Load", "Order"), unfilled=("H",))
 def lower_moe_experts(ctx, ins):
     """Out[t] = sum over the chosen experts i THIS CHIP HOLDS of
     TopkWeight[t, i] * SwiGLU_i(X[t]); what absent experts would add is
@@ -279,61 +352,103 @@ def lower_moe_experts(ctx, ins):
 
     X [.., d], TopkIdx / TopkWeight [T, k], WGateUp [G, d, 2f] (packed
     gate | up), WDown [G, f, d]; attr `expert_offset`: the first held
-    expert's id.  H [T*k, 2f] (the pairs' gate | up pre-activations,
-    sorted by expert) and Load [G] (pairs an expert) are the residuals.
-    Dropless: every pair of a held expert is computed."""
+    expert's id.  The residuals: H [T*k, 2f] (the pairs' gate | up
+    pre-activations, sorted by expert; rows past the held experts' are
+    never written: `unfilled`), Load [G] (pairs an expert), Order [T*k]
+    (the sort).  Dropless: every pair of a held expert is computed.  The
+    sorted pairs are walked in chunks (`_walk`) under a traced trip count,
+    so apart from H every array made here has a chunk's rows or a token's,
+    and the work follows the pairs the held experts really got."""
+    import jax
     import jax.numpy as jnp
 
     from ..kernels.grouped_matmul import grouped_matmul
 
-    x, idx = ins["X"][0], ins["TopkIdx"][0]
-    w_gu, w_down = ins["WGateUp"][0], ins["WDown"][0]
+    x, idx, weight = ins["X"][0], ins["TopkIdx"][0], ins["TopkWeight"][0]
     t, k = idx.shape
+    dt = x.dtype
+    w_gu, w_down = ins["WGateUp"][0].astype(dt), ins["WDown"][0].astype(dt)
+    x2 = x.reshape(t, -1)
     order, load = _dispatch(idx, w_gu.shape[0], ctx.attr("expert_offset", 0))
-    x_sorted = x.reshape(t, -1)[order // k]
-    h = grouped_matmul(x_sorted, w_gu.astype(x.dtype), load)
-    gate, up, sig = _swiglu_parts(h)
-    y = grouped_matmul((gate * sig * up).astype(x.dtype),
-                       w_down.astype(x.dtype), load)
-    weight = _f32(ins["TopkWeight"][0]).reshape(-1)[order]
-    out = _combine(_f32(y) * weight[:, None], order, t, k)
-    return {"Out": [out.astype(x.dtype).reshape(x.shape)], "H": [h],
-            "Load": [load]}
+    rows, offsets, live, trips, back = _walk(load, order)
+
+    def chunk(c, carry):
+        h_all, out = carry
+        base, pairs, sizes = _chunk(c, rows, offsets, order)
+        h = grouped_matmul(x2[pairs // k], w_gu, sizes)
+        gate, up, sig = _swiglu_parts(h)
+        y = grouped_matmul((gate * sig * up).astype(dt), w_down, sizes)
+        # the pair's weight in float32, a token's choices summed in float32
+        return _put(h_all, h, base), out + _tokens_sum(
+            y, base, back, live, t, k, weight)
+
+    def start():  # H is allocated and never filled
+        return (jax.lax.empty((t * k, w_gu.shape[2]), dt),
+                jnp.zeros(x2.shape, jnp.float32))
+
+    # the conditional is there for XLA's scheduler alone (a loop of no
+    # trip is the same result): an operand-free allocation is scheduled
+    # at the start of the computation it is in, so free-standing every
+    # layer's H would be live from the step's first operation; in a branch
+    # it is allocated where the walk runs (PERF.md section 7 (11))
+    h_all, out = jax.lax.cond(
+        trips > 0, lambda: jax.lax.fori_loop(0, trips, chunk, start()), start)
+    return {"Out": [out.astype(dt).reshape(x.shape)], "H": [h_all],
+            "Load": [load], "Order": [order]}
 
 
 @residual_grad("moe_experts")
 def lower_moe_experts_grad(ctx, ins):
-    """The two dX and two dW grouped matmuls on the forward's own H."""
+    """The two dX and two dW grouped matmuls on the forward's own H, over
+    the forward's chunks.  A dW is summed over the chunks in float32 and
+    kept in the stream's dtype between them, as the kernel hands it over:
+    at one trip it is the kernel's own result, at n trips it has been
+    rounded n times where one float32 accumulation rounds once."""
+    import jax
     import jax.numpy as jnp
 
     from ..kernels.grouped_matmul import grouped_matmul, grouped_matmul_dw
 
     x, idx = ins["X"][0], ins["TopkIdx"][0]
-    w_gu, w_down = ins["WGateUp"][0], ins["WDown"][0]
-    h, load = ins["H"][0], ins["Load"][0]
+    h_all, load, order = ins["H"][0], ins["Load"][0], ins["Order"][0]
     t, k = idx.shape
     dt = x.dtype
-    order, _ = _dispatch(idx, w_gu.shape[0], ctx.attr("expert_offset", 0))
-    token = order // k
-    x_sorted = x.reshape(t, -1)[token]
-    g_sorted = ins["Out@GRAD"][0].astype(dt).reshape(t, -1)[token]
-    weight = _f32(ins["TopkWeight"][0]).reshape(-1)[order][:, None]
-    gate, up, sig = _swiglu_parts(h)
-    act = gate * sig * up
-    # d(act) before the pair's weight: its product with act is the
-    # weight's own gradient
-    d_act = _f32(grouped_matmul(g_sorted, w_down.astype(dt), load,
-                                transpose_rhs=True))
-    d_weight = jnp.zeros((t * k,), jnp.float32).at[order].set(
-        jnp.sum(d_act * act, axis=1))
-    dw_down = grouped_matmul_dw((act * weight).astype(dt), g_sorted, load)
-    d_h = _swiglu_bwd(h, d_act * weight).astype(dt)
-    dw_gu = grouped_matmul_dw(x_sorted, d_h, load)
-    dx = _combine(grouped_matmul(d_h, w_gu.astype(dt), load,
-                                 transpose_rhs=True),
-                  order, t, k)
+    w_gu, w_down = ins["WGateUp"][0].astype(dt), ins["WDown"][0].astype(dt)
+    x2 = x.reshape(t, -1)
+    g2 = ins["Out@GRAD"][0].astype(dt).reshape(t, -1)
+    weight = _f32(ins["TopkWeight"][0]).reshape(-1)
+    wants_weight = any(ctx.op.output("TopkWeight@GRAD"))
+    rows, offsets, live, trips, back = _walk(load, order)
+
+    def chunk(c, carry):
+        dx, dwt_all, dw_gu, dw_down = carry
+        base, pairs, sizes = _chunk(c, rows, offsets, order)
+        x_c, g_c = x2[pairs // k], g2[pairs // k]
+        w_c = weight[pairs][:, None]
+        h = jax.lax.dynamic_slice(h_all, (base, 0), (rows, h_all.shape[1]))
+        gate, up, sig = _swiglu_parts(h)
+        act = gate * sig * up
+        # d(act) before the pair's weight: its product with act is the
+        # weight's own gradient
+        d_act = _f32(grouped_matmul(g_c, w_down, sizes, transpose_rhs=True))
+        if wants_weight:
+            dwt_all = _put(dwt_all, jnp.sum(d_act * act, axis=1), base)
+        dw_down = _add(dw_down, grouped_matmul_dw(
+            (act * w_c).astype(dt), g_c, sizes))
+        d_h = _swiglu_bwd(h, d_act * w_c).astype(dt)
+        dw_gu = _add(dw_gu, grouped_matmul_dw(x_c, d_h, sizes))
+        d_rows = grouped_matmul(d_h, w_gu, sizes, transpose_rhs=True)
+        return (dx + _tokens_sum(d_rows, base, back, live, t, k),
+                dwt_all, dw_gu, dw_down)
+
+    dx, d_weight, dw_gu, dw_down = jax.lax.fori_loop(0, trips, chunk, (
+        jnp.zeros(x2.shape, jnp.float32),
+        jnp.zeros((t * k,), jnp.float32) if wants_weight else None,
+        jnp.zeros(w_gu.shape, dt), jnp.zeros(w_down.shape, dt)))
+    if wants_weight:  # back to the pairs' own order; a dead pair's is zero
+        d_weight = jnp.where(back < live, d_weight[back], 0.0).reshape(t, k)
     return {"X@GRAD": [dx.astype(dt).reshape(x.shape)],
             "TopkIdx@GRAD": [None],
-            "TopkWeight@GRAD": [d_weight.reshape(t, k)],
-            "WGateUp@GRAD": [dw_gu.astype(w_gu.dtype)],
-            "WDown@GRAD": [dw_down.astype(w_down.dtype)]}
+            "TopkWeight@GRAD": [d_weight],
+            "WGateUp@GRAD": [dw_gu.astype(ins["WGateUp"][0].dtype)],
+            "WDown@GRAD": [dw_down.astype(ins["WDown"][0].dtype)]}

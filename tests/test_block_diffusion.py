@@ -700,7 +700,7 @@ def test_device_counters_carry_the_held_experts_load_while_tracing():
 
     prog, startup, loss, _ = _build(with_optimizer=True)
     assert set(prog._device_counters) == {
-        "moe_local_pairs", "moe_max_over_mean"}
+        "moe_local_pairs", "moe_max_over_mean", "moe_rows_walked"}
     scope, exe = pt.Scope(), pt.Executor()
     exe.run(startup, scope=scope)
     feed = {k: np.stack([v] * 2) for k, v in _feed(CFG).items()}
@@ -721,6 +721,9 @@ def test_device_counters_carry_the_held_experts_load_while_tracing():
     counters = event["counters"]
     positions, k = BATCH * 2 * SEQ, CFG["num_experts_per_tok"]
     assert 0 < counters["moe_local_pairs"] <= 2 * positions * k
+    # whole chunks of the op's own rule, as many as hold the layers' pairs
+    assert counters["moe_local_pairs"] <= counters["moe_rows_walked"] \
+        <= 2 * positions * k
 
 
 def test_amp_step_trains_and_stays_near_float32():

@@ -26,6 +26,7 @@ from paddle_tpu.kernels import attention as A
 from paddle_tpu.kernels import grouped_matmul as G
 from paddle_tpu.layers import contrib
 from paddle_tpu.models import mla_moe_decoder as M
+from paddle_tpu.ops import llm_ops
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "perfbench"))
@@ -309,11 +310,12 @@ def test_grouped_matmul_against_a_loop_over_experts(load, route,
         fwd = G.reference_grouped_matmul
         dx = lambda a, b, c: G.reference_grouped_matmul(a, b, c, True)  # noqa: E731
         dw = G.reference_grouped_matmul_dw
-    np.testing.assert_allclose(fwd(lhs, rhs, gs), _loop(lhs, rhs, sizes),
-                               atol=1e-4)
+    live = sum(sizes)  # the rows past them are not written: the caller's
+    np.testing.assert_allclose(fwd(lhs, rhs, gs)[:live],
+                               _loop(lhs, rhs, sizes)[:live], atol=1e-4)
     np.testing.assert_allclose(
-        dx(dout, rhs, gs), _loop(dout, np.swapaxes(rhs, 1, 2), sizes),
-        atol=1e-4)
+        dx(dout, rhs, gs)[:live],
+        _loop(dout, np.swapaxes(rhs, 1, 2), sizes)[:live], atol=1e-4)
     np.testing.assert_allclose(dw(lhs, dout, gs), _loop_dw(lhs, dout, sizes),
                                atol=1e-4)
 
@@ -324,6 +326,292 @@ def test_grouped_matmul_plan_rejects_what_mosaic_cannot_tile(monkeypatch):
     assert G._plan(32768, 768, 2048, None)[1] == (256, 384, 512)
     assert not G._plan(32768, 2048, 100, None)[0]  # no 128-multiple divides
     assert not G._plan(100, 2048, 1536, None)[0]
+
+
+# the walk over the sorted pairs (ops/llm_ops.py moe_experts) -------------------
+
+#: 64 tokens x 4 choices over 16 experts of which 4..7 are held; with a row
+#: tile of 16 a chunk is 64 of the 256 sorted pairs
+WT, WK, WD, WF, WHELD, WOFF = 64, 4, 32, 16, 4, 4
+
+
+def _walk_idx(case):
+    """TopkIdx [64, 4] that sends the sorted pairs where the case wants."""
+    rng = np.random.default_rng(5)
+    absent = np.array([0, 1, 2, 3, 8, 9, 10, 11, 12, 13, 14, 15])
+    idx = absent[rng.integers(0, len(absent), (WT, WK))]
+    if case == "exactly_one_chunk":  # 64 pairs held: one full chunk
+        idx[:, 0] = WOFF + np.arange(WT) % WHELD
+    elif case == "boundary_inside_a_group":  # 40 + 50 rows: 64 cuts expert 5
+        idx[:40, 0], idx[:50, 1] = 4, 5
+    elif case == "every_pair_held":  # four trips
+        idx = WOFF + np.stack([rng.permutation(WHELD) for _ in range(WT)])
+    elif case == "all_on_one_expert":  # one group over four chunks
+        idx[:] = 6
+    elif case == "a_token_in_two_chunks":  # rows 0..63 and 64..127
+        idx[:, 0], idx[:, 1] = 4, 7
+    else:
+        assert case == "no_pair_held"
+    return idx.astype(np.int32)
+
+
+WALKS = {"no_pair_held": 0, "exactly_one_chunk": 1,
+         "boundary_inside_a_group": 2, "every_pair_held": 4,
+         "all_on_one_expert": 4, "a_token_in_two_chunks": 2}
+
+
+def _walk_reference(x, idx, w, wgu, wd):
+    """The loop over the held experts, every token through each, float32
+    at the highest precision."""
+    hi = jax.lax.Precision.HIGHEST
+    out = jnp.zeros(x.shape, jnp.float32)
+    for e in range(WHELD):
+        h = jnp.dot(x, wgu[e], precision=hi)
+        y = jnp.dot(jax.nn.silu(h[:, :WF]) * h[:, WF:], wd[e], precision=hi)
+        out = out + y * jnp.sum(jnp.where(idx == WOFF + e, w, 0.0),
+                                axis=1)[:, None]
+    return out
+
+
+def _run_walk(case, weight_grad=True, executor=pt.Executor, prepare=None,
+              x00=None):
+    """(the program, fetched {out, x@GRAD, w@GRAD?, wgu@GRAD, wd@GRAD,
+    load}, the reference's out and {x@GRAD, ..}).  `executor` makes the
+    executor, `prepare(program)` runs on the built program, `x00` is put
+    at x[0, 0]."""
+    rng = np.random.default_rng(6)
+    idx = _walk_idx(case)
+    x = rng.standard_normal((WT, WD)).astype(np.float32)
+    if x00 is not None:
+        x[0, 0] = x00
+    feed = {"x": x,
+            "idx": idx,
+            "w": rng.random((WT, WK)).astype(np.float32) + 0.1,
+            "g": rng.standard_normal((WT, WD)).astype(np.float32)}
+    wgu = rng.standard_normal((WHELD, WD, 2 * WF)).astype(np.float32) * 0.3
+    wd = rng.standard_normal((WHELD, WF, WD)).astype(np.float32) * 0.3
+    prog, startup = pt.Program(), pt.Program()
+    with pt.program_guard(prog, startup):
+        xv = layers.data(name="x", shape=[WT, WD], dtype="float32",
+                         append_batch_size=False)
+        iv = layers.data(name="idx", shape=[WT, WK], dtype="int32",
+                         append_batch_size=False)
+        wv = layers.data(name="w", shape=[WT, WK], dtype="float32",
+                         append_batch_size=False)
+        gv = layers.data(name="g", shape=[WT, WD], dtype="float32",
+                         append_batch_size=False)
+        xv.stop_gradient, wv.stop_gradient = False, not weight_grad
+        out, load = contrib.moe_experts(
+            xv, iv, wv, WHELD, WF, expert_offset=WOFF,
+            gate_up_attr=pt.ParamAttr(name="wgu"),
+            down_attr=pt.ParamAttr(name="wd"))
+        backward.append_backward(layers.reduce_sum(
+            layers.elementwise_mul(out, gv)))
+    if prepare is not None:
+        prepare(prog)
+    scope, exe = pt.Scope(), executor()
+    exe.run(startup, scope=scope)
+    scope.set_var("wgu", jnp.asarray(wgu))
+    scope.set_var("wd", jnp.asarray(wd))
+    names = ["x@GRAD", "wgu@GRAD", "wd@GRAD"] + ["w@GRAD"] * weight_grad
+    got = exe.run(prog, feed=feed, scope=scope,
+                  fetch_list=[out, load] + names)
+    got = dict(zip(["out", "load"] + names, map(np.asarray, got)))
+    ref_out, vjp = jax.vjp(
+        lambda x, w, a, b: _walk_reference(x, jnp.asarray(idx), w, a, b),
+        *map(jnp.asarray, (feed["x"], feed["w"], wgu, wd)))
+    return prog, got, np.asarray(ref_out), dict(zip(
+        ["x@GRAD", "w@GRAD", "wgu@GRAD", "wd@GRAD"],
+        map(np.asarray, vjp(jnp.asarray(feed["g"])))))
+
+
+def _near(got, want, what):
+    scale = max(float(np.linalg.norm(want)), 1e-6)
+    assert np.linalg.norm(got - want) < 1e-4 * scale, what
+
+
+@pytest.mark.parametrize("case", sorted(WALKS))
+def test_expert_walk_follows_the_loop_over_experts(case, monkeypatch):
+    """The op and its grad at loads that take no trip, one, a chunk
+    boundary inside an expert's rows, every chunk.  The interpreter leaves
+    NaN in what a kernel does not write, so a dead row that leaked through
+    a product instead of a select would show; H, which on the chip starts
+    as whatever the memory held, starts as NaN too."""
+    monkeypatch.setattr(G, "ROW_TILE", 16)
+    monkeypatch.setattr(jax.lax, "empty", lambda shape, dtype: jnp.full(
+        shape, jnp.nan, dtype))
+    assert llm_ops.chunk_rows(WT * WK) == 64
+    _, got, ref_out, ref = _run_walk(case)
+    live = int(got["load"].sum())
+    assert -(-live // 64) == WALKS[case]
+    _near(got["out"], ref_out, "out")
+    for name, want in ref.items():
+        assert np.all(np.isfinite(got[name])), name
+        _near(got[name], want, name)
+    if case == "no_pair_held":
+        assert not got["out"].any() and not got["wgu@GRAD"].any() \
+            and not got["wd@GRAD"].any()
+
+
+@pytest.mark.parametrize("weight_grad", [True, False])
+def test_expert_walk_with_and_without_the_weights_gradient(weight_grad,
+                                                           monkeypatch):
+    """A stack that hands no gradient on through the combine weights
+    (`TopkWeight.stop_gradient`) gets the same dX and dW and no
+    TopkWeight@GRAD."""
+    monkeypatch.setattr(G, "ROW_TILE", 16)
+    prog, got, _, ref = _run_walk("boundary_inside_a_group", weight_grad)
+    (grad_op,) = [op for op in prog.global_block().ops
+                  if op.type == "moe_experts_grad"]
+    assert any(grad_op.output("TopkWeight@GRAD")) == weight_grad
+    for name in got:
+        if name.endswith("@GRAD"):
+            _near(got[name], ref[name], name)
+
+
+@pytest.mark.parametrize("live,trips", [(0, 0), (1, 1), (2048, 1),
+                                        (2049, 2), (8192, 4)])
+def test_one_rule_says_how_many_rows_a_load_walks(live, trips):
+    """`rows_walked` is the op's trip count and the model's counter: on
+    whole numbers, on the op's traced load, and through a ceiling over a
+    program's float variables (what `_publish_load` hands in)."""
+    pairs = 8192
+    assert llm_ops.chunk_rows(pairs) == 2048
+    assert llm_ops.rows_walked(live, pairs) == trips * 2048
+    load = jnp.asarray([live // 2, live - live // 2], jnp.int32)
+    assert int(llm_ops._walk(load, jnp.arange(pairs, dtype=jnp.int32))[3]) \
+        == trips
+    assert llm_ops.rows_walked(
+        np.float32(live), pairs, lambda a, b: np.ceil(a / b)) == trips * 2048
+
+
+@pytest.mark.parametrize("check", ["check_nan_inf", "locate"])
+def test_finite_checks_pass_over_the_rows_of_h_nothing_wrote(check,
+                                                             monkeypatch):
+    """H holds whatever its memory held past the walked chunks and in a
+    chunk's dead tail (here NaN: 90 live rows of the 128 walked, of 256):
+    the op registers the slot as `unfilled`, so a healthy step passes the
+    executor's check_nan_inf and gets no row for H from the numerics
+    tier's `locate` level, which the watchdog's replay uses; a NaN that
+    is really there is still pinned on the op, through Out."""
+    from paddle_tpu.analysis import numerics as anum
+    from paddle_tpu.core import registry
+    from paddle_tpu.flags import FLAGS
+    from paddle_tpu.monitor import numerics as mnum
+
+    assert registry.unfilled_slots("moe_experts") == ("H",)
+    monkeypatch.setattr(G, "ROW_TILE", 16)
+    monkeypatch.setattr(jax.lax, "empty", lambda shape, dtype: jnp.full(
+        shape, jnp.nan, dtype))
+    if check == "check_nan_inf":
+        how = dict(executor=lambda: pt.Executor(check_nan_inf=True))
+    else:
+        how = dict(prepare=lambda prog: anum.instrument_program(prog,
+                                                                "locate"))
+        monkeypatch.setattr(FLAGS, "monitor", True)
+
+    def rows(prog):
+        (op,) = [op for op in prog.global_block().ops
+                 if op.type == "moe_experts"]
+        by_var = {r["var"]: r["stat"]["nonfinite"]
+                  for r in mnum._last_stats["rows"]}
+        assert op.output("H")[0] not in by_var
+        return op, by_var
+
+    prog, got, ref_out, _ = _run_walk("boundary_inside_a_group", **how)
+    _near(got["out"], ref_out, "out")
+    if check == "locate":
+        assert not any(rows(prog)[1].values())
+    # the same step with a NaN in a routed token's input
+    if check == "check_nan_inf":
+        with pytest.raises(FloatingPointError, match="moe_experts"):
+            _run_walk("boundary_inside_a_group", x00=np.nan, **how)
+    else:
+        prog, *_ = _run_walk("boundary_inside_a_group", x00=np.nan, **how)
+        op, by_var = rows(prog)
+        assert by_var[op.output("Out")[0]] > 0
+
+
+def _traced_arrays(jaxpr, in_loop, out):
+    """(in a `while`?, primitive, result aval) of every equation, through
+    the nested jaxprs; a `while` itself counts as outside its own body."""
+    def nested(v):
+        return hasattr(v, "eqns") or hasattr(v, "jaxpr")
+
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        subs = [getattr(v, "jaxpr", v) for v in jax.tree.leaves(
+            eqn.params, is_leaf=nested) if nested(v)]
+        if name == "pallas_call":
+            subs = []
+        for sub in subs:
+            _traced_arrays(sub, in_loop or name == "while", out)
+        if not subs or name == "while":
+            out.extend((in_loop, name, v.aval, [i.aval for i in eqn.invars])
+                       for v in eqn.outvars)
+    return out
+
+
+def test_expert_walk_makes_no_array_of_all_the_pairs_outside_its_loop():
+    """The bound ISSUE 32 buys, pinned on the jaxpr of moe_experts + grad
+    at J's ratios (8 choices, a chunk a quarter of the pairs): the grouped
+    matmuls run inside the loop on a chunk's rows, and OUTSIDE the loop no
+    float array with a row for every pair is computed at all -- H is
+    allocated there (never filled) and comes out of the loop; the float32
+    product over all the pairs and the selects over H and Y are gone.
+    Inside, a trip makes one such array a pass: the gather of the chunk's
+    own rows into the pairs' order, with the convert and the select that
+    discards the pairs of other chunks, summed over a token's choices at
+    once."""
+    t, k, d, f, g = 256, 8, 128, 64, 4
+    n, chunk = t * k, llm_ops.chunk_rows(t * k)
+    assert chunk == n // 4
+
+    class Ctx:
+        op = type("Op", (), {"output": staticmethod(lambda slot: ["w"])})
+
+        @staticmethod
+        def attr(name, default=None):
+            return default
+
+    def layer(x, idx, w, wgu, wd, dout):
+        ins = {"X": [x], "TopkIdx": [idx], "TopkWeight": [w],
+               "WGateUp": [wgu], "WDown": [wd]}
+        out = llm_ops.lower_moe_experts(Ctx, ins)
+        grads = llm_ops.lower_moe_experts_grad(Ctx, dict(
+            ins, H=out["H"], Load=out["Load"], Order=out["Order"],
+            **{"Out@GRAD": [dout]}))
+        return out["Out"], grads
+
+    bf = jnp.bfloat16
+    jaxpr = jax.make_jaxpr(layer)(
+        jnp.zeros((t, d), bf), jnp.zeros((t, k), jnp.int32),
+        jnp.zeros((t, k), jnp.float32), jnp.zeros((g, d, 2 * f), bf),
+        jnp.zeros((g, f, d), bf), jnp.zeros((t, d), bf))
+    arrays = _traced_arrays(jaxpr.jaxpr, False, [])
+
+    def whole(aval):  # a float array with a row for every pair
+        return (len(aval.shape) >= 2 and aval.shape[0] == n
+                and aval.shape[1] > 1
+                and jnp.issubdtype(aval.dtype, jnp.floating))
+
+    kernels = [a for a in arrays if a[1] == "pallas_call"]
+    assert len(kernels) == 6 and all(in_loop for in_loop, *_ in kernels)
+    for _, _, aval, operands in kernels:
+        assert aval.shape[0] in (chunk, g)
+        assert not [o for o in operands if o.shape and o.shape[0] == n]
+    outside = [name for in_loop, name, aval, _ in arrays
+               if not in_loop and whole(aval)]
+    assert sorted(outside) == ["empty", "empty", "while"]  # H, either way
+    inside = [name for in_loop, name, aval, _ in arrays
+              if in_loop and whole(aval)]
+    # (the broadcast is the select's scalar zero)
+    assert set(inside) == {"dynamic_update_slice", "gather",
+                           "convert_element_type", "select_n",
+                           "broadcast_in_dim"}
+    assert inside.count("dynamic_update_slice") == 1  # H
+    # the forward's and the backward's
+    assert inside.count("gather") == inside.count("select_n") == 2
 
 
 # (e) ------------------------------------------------------------------------
@@ -576,8 +864,8 @@ def test_device_counters_ride_the_flight_event_only_while_tracing():
     from paddle_tpu.monitor import flight
 
     prog, startup, loss, _ = _build(with_optimizer=True)
-    assert set(prog._device_counters) == {"moe_local_pairs",
-                                          "moe_max_over_mean"}
+    assert set(prog._device_counters) == {
+        "moe_local_pairs", "moe_max_over_mean", "moe_rows_walked"}
     scope, exe = pt.Scope(), pt.Executor()
     exe.run(startup, scope=scope)
     feed = {k: np.stack([v] * 2) for k, v in _feed(CFG).items()}
@@ -604,6 +892,10 @@ def test_device_counters_ride_the_flight_event_only_while_tracing():
     assert abs(counters["moe_local_pairs"] - 3 * tokens * k / 4) \
         < 3 * tokens * k / 8
     assert 1.0 <= counters["moe_max_over_mean"] < 4.0
+    # the op's own chunk rule: 64 x 4 pairs a layer are one chunk at this
+    # size (a quarter is no whole row tile), so each layer walks it once
+    assert llm_ops.chunk_rows(tokens * k) == tokens * k
+    assert counters["moe_rows_walked"] == 3 * tokens * k
 
 
 def test_kernel_named_rule_reads_a_conditional_between_two_names():
