@@ -4,7 +4,14 @@ control's (the reference in fp8, put in the program's place) and the
 planted faults'.  Not part of a benchmark run.
 
     python3 perfbench/calibrate.py --workload W --seeds 1,2,3 \
-        [--control-seeds 1,2,3] [--fault-seeds 1,2,3] [--out FILE]
+        [--control-seeds 1,2,3] [--fault-seeds 1,2,3] [--free-weights]
+        [--out FILE]
+
+A configuration that states a `weights_seed` gives every seed the same
+weights, and the seeds then differ in their feeds alone; with
+`--free-weights` the key is set aside and the weights follow each seed,
+which is how a limit is read across draws of the weights.  A traffic
+mix's `data_seed` is not read here: the feeds follow each seed always.
 """
 
 import argparse
@@ -16,12 +23,20 @@ import compare
 import registry
 
 
+def load_cell(workload, free_weights):
+    cell = registry.load_cell(workload)
+    if free_weights:
+        cell.cfg.pop("weights_seed", None)
+    return cell
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", default="")
     ap.add_argument("--control-seeds", default="")
     ap.add_argument("--fault-seeds", default="")
+    ap.add_argument("--free-weights", action="store_true")
     ap.add_argument("--out", default="")
     ap.add_argument("--allow-cpu", action="store_true")
     args = ap.parse_args()
@@ -33,7 +48,7 @@ def main():
     import traffic_gen
     from paddle_tpu.inference import enable_compile_cache
 
-    cell = registry.load_cell(args.workload)
+    cell = load_cell(args.workload, args.free_weights)
     if report.describe_device(cell.chips) is None and not args.allow_cpu:
         print("calibrate: no accelerator", file=sys.stderr)
         return 3

@@ -19,6 +19,21 @@ import traffic_gen
 from registry import load_module
 
 
+def weights_seed(cfg, seed):
+    """The seed a run's weights are drawn from: its `--seed`, unless the
+    configuration states one draw for every run (`weights_seed`; PERF.md
+    section 7 (10) says why a cell would).  The program's own key follows
+    `--seed` either way."""
+    return cfg.get("weights_seed", seed)
+
+
+def data_seed(traffic, seed):
+    """The seed a run's feeds are drawn from: its `--seed`, unless the
+    traffic mix states one set of batches for every run (`data_seed`).
+    perfbench/calibrate.py does not read the key: it goes on sweeping."""
+    return traffic.get("data_seed", seed)
+
+
 class TrainCell:
     def __init__(self, cell):
         import jax
@@ -68,7 +83,7 @@ class TrainCell:
         self.lr_names = sorted({op.input("LearningRate")[0] for op in adam})
 
     def init_params(self, seed):
-        return self._init(self.blocks.seed_key(seed))
+        return self._init(self.blocks.seed_key(weights_seed(self.cfg, seed)))
 
     def _listed(self, tree):
         return [tree[n] for n in self.trainable]
@@ -230,7 +245,8 @@ def run(cell, seed, seconds, trace, compile_cache=True, cell_class=TrainCell):
         enable_compile_cache()
     tc = cell_class(cell)
     traffic = cell.traffic
-    feeds = traffic_gen.train_feeds(traffic, cell.cfg, seed)
+    feeds = traffic_gen.train_feeds(traffic, cell.cfg,
+                                    data_seed(traffic, seed))
 
     steps = traffic["steps_per_call"]
     tokens_call = steps * traffic_gen.tokens_per_step(traffic, cell.cfg)
@@ -300,4 +316,6 @@ def run(cell, seed, seconds, trace, compile_cache=True, cell_class=TrainCell):
         "memory_peak_bytes": sum(parts.values()), "memory_parts": parts,
         "traced": traced,
         "compared": compared, "where": where,
+        "seeds": {"weights": weights_seed(cell.cfg, seed),
+                  "data": data_seed(traffic, seed)},
     }

@@ -47,6 +47,8 @@ def main():
     line = report.result_line(cell, result, device, bool(args.trace))
     if result.get("where"):  # which leaf each worst-leaf number was read at
         print(f"where {json.dumps(result['where'])}", file=sys.stderr)
+    if result.get("seeds"):  # what the weights and the feeds were drawn from
+        print(f"seeds {json.dumps(result['seeds'])}", file=sys.stderr)
     report.print_compared(result["compared"], sys.stderr)
     sys.stderr.flush()
     print(json.dumps(line), flush=True)

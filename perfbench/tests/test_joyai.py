@@ -135,7 +135,8 @@ def _ctx(ops_s, events, monkeypatch):
 @pytest.mark.parametrize("metric", NEW_METRICS)
 def test_readers_find_nothing_on_a_program_that_records_nothing(
         metric, monkeypatch):
-    ctx = _ctx({"fusion.1 f32[8]": 1.0, "fused_qkv_fwd.2 bf16[8] mosaic": 1.0},
+    ctx = _ctx({"fusion.1 f32[8]": 1.0,
+                "flash_bthd_fwd.2 bf16[8] mosaic": 1.0},  # T's kernel, not J's
                [{"phases": [["feed", 0, 1]]}], monkeypatch)
     assert registry.load_reader(metric).read(ctx) is None
 

@@ -2,7 +2,9 @@
 program at a tiny preset in float32, the FLOPs, pairs and bytes functions
 against hand reckoning, the six readers on hand-made contexts (and on a
 parent commit's, which records nothing), the configuration file against
-the published config, and the two new traffic files."""
+the published config, the two new traffic files, and what a stated
+`weights_seed`, a stated `data_seed` and a longer `feed_pool` change and
+leave (PR 34)."""
 
 import os
 
@@ -28,21 +30,31 @@ PUBLISHED = {
 }
 
 
-def tiny_cell(amp=False, router_trained=True):
+def tiny_cell(amp=False, router_trained=True, pinned=True):
     cfg = registry.load_json(os.path.join(HERE, "data/sdar_tiny.json"))
     cfg["amp"] = amp
     cfg["router_trained"] = router_trained
+    if not pinned:
+        del cfg["weights_seed"]
     return registry.Cell(
         "sdar_tiny", 1, cfg,
         registry.load_json(os.path.join(HERE, "data/train_tiny_sdar.json")),
         {})
 
 
-@pytest.mark.parametrize("router_trained", [True, False])
-def test_reference_follows_the_program_in_float32(router_trained):
-    cell = tiny_cell(router_trained=router_trained)
+@pytest.mark.parametrize("router_trained, pinned",
+                         [(True, False), (False, True)])
+def test_reference_follows_the_program_in_float32(router_trained, pinned):
+    cell = tiny_cell(router_trained=router_trained, pinned=pinned)
     train = registry.load_driver("train")
     tc = train.TrainCell(cell)
+    drawn, init = [], tc._init
+
+    def spy(key):
+        drawn.append(tc.jax.random.key_data(key).tolist())
+        return init(key)
+
+    tc._init = spy
     # 2 layers' routers are trained leaves, or none is
     assert sum("router_w" in n for n in tc.trainable) == (
         2 if router_trained else 0)
@@ -60,6 +72,103 @@ def test_reference_follows_the_program_in_float32(router_trained):
     assert numbers["frozen_moved"] == 0.0, numbers
     assert numbers["grad_gap"] < 1e-4, (numbers, where)
     assert numbers["step_gap"] < 1e-3, (numbers, where)
+    # program and reference were handed the same weights: every draw, the
+    # program's resets' and the reference's, came from one key, the stated
+    # seed's where the configuration states one and the run's where not
+    want = tc.blocks.seed_key(cell.cfg["weights_seed"] if pinned else seed)
+    assert len(drawn) >= 4
+    assert all(k == tc.jax.random.key_data(want).tolist() for k in drawn)
+
+
+@pytest.mark.parametrize("pinned", [True, False])
+def test_a_stated_weights_seed_gives_every_run_the_same_weights(pinned):
+    """With the key two `--seed`s start from equal parameters and train on
+    different feeds; without it they differ in both, as on the parent."""
+    cell = tiny_cell(pinned=pinned)
+    train = registry.load_driver("train")
+    tc = train.TrainCell(cell)
+    a, b = (tc.init_params(s) for s in (5, 2 ** 31 + 6))
+    random = [k for k in a if a[k].ndim > 1]  # norm scales start at one
+    assert len(random) > 8
+    assert {bool((a[k] == b[k]).all()) for k in random} == {pinned}
+    assert train.weights_seed(cell.cfg, 5) == (3400001 if pinned else 5)
+    fa, fb = (traffic_gen.train_feeds(cell.traffic, cell.cfg, s)
+              for s in (5, 2 ** 31 + 6))
+    assert not (fa[0]["ids"] == fb[0]["ids"]).all()
+    assert not (fa[0]["noise"] == fb[0]["noise"]).all()
+
+
+@pytest.mark.parametrize("stated", [77, None])
+def test_run_draws_its_feeds_from_a_stated_data_seed(stated, monkeypatch):
+    """`data_seed` in a traffic mix gives every run the same batches; a mix
+    without the key draws them from `--seed`, as on the parent."""
+    cell = tiny_cell()
+    if stated is not None:
+        cell.traffic["data_seed"] = stated
+    train = registry.load_driver("train")
+    assert train.data_seed(cell.traffic, 5) == (stated or 5)
+    seen = []
+
+    class Stop(Exception):
+        pass
+
+    def record(traffic, cfg, seed):
+        seen.append(seed)
+        raise Stop
+
+    monkeypatch.setattr(train.traffic_gen, "train_feeds", record)
+    with pytest.raises(Stop):
+        train.run(cell, 5, 1.0, False, compile_cache=False)
+    assert seen == [stated or 5]
+
+
+@pytest.mark.parametrize("real", [False, True])
+def test_a_longer_pool_leaves_a_seeds_first_feeds_as_they_were(real):
+    """`train_feeds` draws the feeds from one generator in order: the
+    checked calls' feeds, and with them `correct`, do not move with
+    `feed_pool` (PR 34 measured a pool of 12 against the 4 that stands)."""
+    cell = registry.load_cell(CELL) if real else tiny_cell()
+    seed = 2 ** 31 + 12
+    four, twelve = (traffic_gen.train_feeds(
+        dict(cell.traffic, feed_pool=n), cell.cfg, seed) for n in (4, 12))
+    assert (len(four), len(twelve)) == (4, 12)
+    for a, b in zip(four, twelve):
+        assert sorted(a) == sorted(b)
+        assert all((a[k] == b[k]).all() for k in a)
+    # every feed of a pool is a batch of its own
+    assert len({f["ids"].tobytes() for f in twelve}) == 12
+
+
+def test_calibrate_free_weights_sets_the_stated_seed_aside():
+    import calibrate
+
+    assert "weights_seed" in calibrate.load_cell(CELL, False).cfg
+    free = calibrate.load_cell(CELL, True)
+    assert "weights_seed" not in free.cfg
+    assert registry.load_driver("train").weights_seed(free.cfg, 7) == 7
+
+
+@pytest.mark.parametrize("workload", [
+    "transformer_base_train", "bert_base_train", "bert_base_train_seq512",
+    "joyai_flash_ep16_train", CELL])
+def test_only_this_cell_states_its_seeds(workload):
+    """The other cells' weights and feeds follow `--seed`, as on the
+    parent; this one states both and says so among its departures."""
+    cell = registry.load_cell(workload)
+    train = registry.load_driver("train")
+    seed = 2 ** 31 + 5
+    got = (train.weights_seed(cell.cfg, seed),
+           train.data_seed(cell.traffic, seed))
+    if workload == CELL:
+        assert got == (cell.cfg["weights_seed"], cell.traffic["data_seed"])
+        assert seed not in got
+        assert "weights_seed" in cell.cfg["assumed"]
+        assert "same_batches_every_run" in cell.cfg["departures"]
+    else:
+        assert got == (seed, seed)
+        assert "weights_seed" not in cell.cfg
+        assert "data_seed" not in cell.traffic
+    assert cell.traffic["feed_pool"] == 4
 
 
 def test_half_batch_fault_zeroes_whole_rows():

@@ -8,7 +8,10 @@ A `len` or `high` given as a string is looked up in the traffic file and
 then in the configuration.  Every seed gives the same sizes; only the
 values differ, and every row of every step is drawn anew.  `feed_pool`
 feeds are made and cycled through the window; set-up's frozen look at the
-losses (drivers/train.py) goes through the first `frozen_calls` of them."""
+losses (drivers/train.py) goes through the first `frozen_calls` of them.
+A mix that states a `data_seed` has its run's feeds drawn from that seed
+and not from `--seed` (drivers/train.py `data_seed`): every run the same
+batches."""
 
 import numpy as np
 
