@@ -293,12 +293,12 @@ def moe_leg(sizes=None, scan_steps=2, calls=3, interpret=False) -> dict:
     sizes = dict(sizes or MOE_SMALL)
     layers_ = sizes["n_moe"] + sizes["n_mtp"]
     pairs = sizes["batch"] * sizes["seq_len"] * sizes["top_k"]
-    chunk = chunk_rows(pairs)
     fails, rep = [], {}
     for tag, over in (("share", {}), ("all_held", dict(
             n_held=sizes["n_experts"], expert_offset=0))):
-        losses, hlo, counters = _moe_run(dict(sizes, **over), scan_steps,
-                                         calls)
+        run = dict(sizes, **over)
+        chunk = chunk_rows(pairs, run["n_held"], run["n_experts"])
+        losses, hlo, counters = _moe_run(run, scan_steps, calls)
         if not all(np.all(np.isfinite(x)) for x in losses):
             fails.append(f"{tag}: non-finite loss: {losses}")
         elif not losses[-1][-1] < losses[0][0]:
@@ -828,8 +828,32 @@ def _embedding_row(cfg, interpret, rng):
     return kernel, ref, (tables, m1s, m2s, ids, rows), _tol(dt)
 
 
+def _short_conv_row(cfg, interpret, rng):
+    import jax.numpy as jnp
+
+    from paddle_tpu.kernels import short_conv as sc
+
+    dt = cfg["dtype"]
+    x = _randn(rng, (cfg["b"], cfg["t"], 3 * cfg["d"]), dt)
+    g = _randn(rng, (cfg["b"], cfg["t"], cfg["d"]), dt)
+    w = _randn(rng, (cfg["d"], cfg["taps"]), "float32", 0.5)
+
+    def both(fwd, bwd):
+        def run(x, w, g):
+            dx, dw = bwd(x, w, g)
+            # dW sums 8192 rows: compared on its own scale
+            return fwd(x, w), dx, dw / jnp.max(jnp.abs(dw))
+        return run
+
+    kernel = both(lambda x, w: sc.short_conv(x, w, interpret=interpret),
+                  lambda x, w, g: sc.short_conv_bwd(x, w, g,
+                                                    interpret=interpret))
+    ref = both(sc.reference_short_conv, sc.reference_short_conv_bwd)
+    return kernel, ref, (x, w, g), _tol(dt)
+
+
 def kernel_matrix():
-    """(family, lint matrix, row builder) for the nine canonical matrices
+    """(family, lint matrix, row builder) for the ten canonical matrices
     of analysis/kernel_lint.py."""
     from paddle_tpu.analysis import kernel_lint as kl
 
@@ -844,6 +868,7 @@ def kernel_matrix():
         ("paged_decode_step", kl._PAGED_MEGASTEP_MATRIX,
          _paged_megastep_row),
         ("embedding", kl._EMBEDDING_MATRIX, _embedding_row),
+        ("short_conv", kl._SHORT_CONV_MATRIX, _short_conv_row),
     ]
 
 

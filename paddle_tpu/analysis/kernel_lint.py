@@ -313,6 +313,38 @@ def check_dropout_plan(cfg: dict, ok, rows, ncols, block_r, interpret,
             f"VMEM", fam, label))
 
 
+def check_short_conv_plan(cfg: dict, ok, rows, chunk, interpret,
+                          findings: List[Finding]):
+    """Gated short convolution plan (kernels/short_conv.py _plan): time
+    blocks of whole rows [rows, 3d] with 16-row halos, worked through in
+    channel chunks; the backward holds X, dOut and dX blocks at once."""
+    fam, label = "short_conv", cfg["label"]
+    t, d = cfg["t"], cfg["d"]
+    if cfg.get("must_accept", True) and not ok:
+        findings.append(_finding(
+            "kernel-plan-reject",
+            f"plan gate rejects the canonical shape t={t} d={d} "
+            f"{cfg['dtype']} — the model would silently run the XLA "
+            f"composition", fam, label))
+    if not ok:
+        return
+    if t % rows or rows % 16 or d % chunk or chunk % _LANE:
+        findings.append(_finding(
+            "kernel-grid-divisibility",
+            f"rows={rows} / chunk={chunk} do not tile t={t}, d={d} in "
+            f"(16,{_LANE}) tiles", fam, label))
+    dt = cfg["dtype"]
+    used = _vmem_use(
+        blocked=[((rows, 3 * d), dt)] * 2 + [((rows, d), dt)]
+        + [((16, d), dt)] * 4 + [((8, d), "float32")] * 2)
+    if used > _SCOPED_VMEM_DEFAULT:
+        findings.append(_finding(
+            "kernel-vmem-budget",
+            f"the backward's X, dX [{rows},{3 * d}] and dOut [{rows},{d}] "
+            f"blocks with their halos, double-buffered, = {used} bytes "
+            f"exceed the default scoped VMEM", fam, label))
+
+
 def check_decode_plan(cfg: dict, ok, block_t, interpret,
                       findings: List[Finding]):
     """Flash-decode plan (kernels/decode_attention.py _decode_plan):
@@ -661,6 +693,11 @@ _ATTENTION_MATRIX = [
     # rows [noisy ; clean] of 2 x 2048 in blocks of 4, masked by position
     dict(label="block-diffusion-gqa-bf16", b=1, h=32, h_kv=4, t=4096, d=128,
          dtype="bfloat16", fmt="bhtd", mask=(4, 2048)),
+    # the hybrid convolution / attention stack's one attention kind
+    # (models/hybrid_conv_decoder.py at the LFM2-8B-A1B widths): causal, 32
+    # query heads over 8 key/value heads of 64, rows of 4096
+    dict(label="causal-gqa-d64-bf16", b=1, h=32, h_kv=8, t=4096, d=64,
+         dtype="bfloat16", fmt="bhtd"),
     # h*d*esize > 2048: even a 128-block kv tile busts the 256 KB bound —
     # compiled mode must REJECT to XLA (the cap-floor regression class);
     # if the gate ever re-accepts this, the kv-tile check fires
@@ -725,6 +762,18 @@ _DROPOUT_MATRIX = [
 # flash-decode: the generation-tier cache shapes bench.py --model decode
 # actually launches (transformer-base geometry; max_t is the ring-buffer
 # row count, rounded to the 128-row block quantum by the model builders)
+_SHORT_CONV_MATRIX = [
+    # LFM2-8B-A1B's convolution blocks under amp (models/
+    # hybrid_conv_decoder.py): rows of 4096, 2048 channels, 3 taps
+    dict(label="lfm2-conv-bf16", b=2, t=4096, d=2048, taps=3,
+         dtype="bfloat16"),
+    dict(label="lfm2-conv-f32-s2048", b=2, t=2048, d=2048, taps=3,
+         dtype="float32"),
+    # rows that are no whole 16-row tiles: the XLA composition
+    dict(label="conv-ragged-rows", b=2, t=1000, d=2048, taps=3,
+         dtype="bfloat16", must_accept=False),
+]
+
 _DECODE_MATRIX = [
     # the ROADMAP metric pair: tokens/sec decode at batch 1 and 64
     dict(label="decode-base-b1", b=1, h=8, dh=64, max_t=128,
@@ -870,6 +919,7 @@ def lint_kernel_plans() -> Tuple[List[Finding], Dict[str, Any]]:
     from ..kernels import decode_attention as kda
     from ..kernels import dropout_epilogue as de
     from ..kernels import embedding as emb
+    from ..kernels import short_conv as sc
 
     findings: List[Finding] = []
     report: Dict[str, Any] = {}
@@ -926,6 +976,17 @@ def lint_kernel_plans() -> Tuple[List[Finding], Dict[str, Any]]:
         rows.append(dict(label=cfg["label"], accepted=bool(ok),
                          block_r=int(br), hw_prng=bool(hw)))
     report["dropout_epilogue"] = rows
+
+    rows = []
+    for cfg in _SHORT_CONV_MATRIX:
+        with _pretend_tpu():
+            ok, r, chunk, interp = sc._plan(
+                _spec((cfg["b"], cfg["t"], 3 * cfg["d"]), cfg["dtype"]),
+                _spec((cfg["d"], cfg["taps"]), "float32"), None)
+        check_short_conv_plan(cfg, ok, r, chunk, interp, findings)
+        rows.append(dict(label=cfg["label"], accepted=bool(ok),
+                         rows=int(r), chunk=int(chunk)))
+    report["short_conv"] = rows
 
     rows = []
     for cfg in _EMBEDDING_MATRIX:

@@ -225,13 +225,34 @@ def swiglu(x, name=None):
     return out
 
 
+def short_conv(x, taps=3, param_attr=None, name=None):
+    """Gated short convolution (ops/llm_ops.py short_conv) of x [b, t, 3d]
+    = [B | C | x]: C * causal_depthwise(B * x) -> [b, t, d], with one
+    filter of `taps` taps a channel, a parameter [d, taps] drawn at
+    normal(0, taps ** -0.5), a depthwise filter's fan-in scale, unless
+    `param_attr` brings an initializer; no bias."""
+    from ..initializer import NormalInitializer
+
+    d = x.shape[-1] // 3
+    helper = LayerHelper("short_conv", param_attr=param_attr, name=name)
+    w = helper.create_parameter(
+        helper.param_attr(), shape=[d, taps], dtype="float32",
+        default_initializer=NormalInitializer(0.0, taps ** -0.5))
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op("short_conv", inputs={"X": [x], "Filter": [w]},
+                     outputs={"Out": [out]})
+    out.shape = tuple(x.shape[:-1]) + (d,)
+    return out
+
+
 def moe_router(x, n_experts, top_k, scale=1.0, bias_std=0.0,
                param_attr=None, bias_attr=None, scoring="sigmoid",
-               name=None):
+               norm_eps=None, name=None):
     """Top-k router over `n_experts` (ops/llm_ops.py moe_router), scoring
     each expert by a "sigmoid" of its own logit or by a "softmax" over the
     experts: returns (TopkIdx [T, k] int32, TopkWeight [T, k] float32, the
-    chosen scores normalised over the chosen, times `scale`).  The
+    chosen scores normalised over the chosen, their sum plus `norm_eps`
+    where a family states one, times `scale`).  The
     score-correction bias is a buffer that enters the choice alone: a
     parameter that is not trained, drawn once at `bias_std` (zeros at 0);
     `bias_attr=False` leaves it out."""
@@ -257,6 +278,8 @@ def moe_router(x, n_experts, top_k, scale=1.0, bias_std=0.0,
     attrs = {"top_k": int(top_k), "scale": float(scale)}
     if scoring != "sigmoid":
         attrs["scoring"] = scoring
+    if norm_eps is not None:
+        attrs["norm_eps"] = float(norm_eps)
     idx = _residual(helper, None, "int32")
     scores = _residual(helper, None, "float32")
     weight = helper.create_variable_for_type_inference("float32")
@@ -268,13 +291,15 @@ def moe_router(x, n_experts, top_k, scale=1.0, bias_std=0.0,
     return idx, weight
 
 
-def moe_experts(x, topk_idx, topk_weight, n_held, d_ff, expert_offset=0,
-                gate_up_attr=None, down_attr=None, name=None):
+def moe_experts(x, topk_idx, topk_weight, n_held, d_ff, n_experts,
+                expert_offset=0, gate_up_attr=None, down_attr=None, name=None):
     """The routed experts this chip holds (ops/llm_ops.py moe_experts):
     experts expert_offset .. expert_offset + n_held - 1 of the layer, each
     a SwiGLU of width d_ff, as two stacked parameters [n_held, d, 2*d_ff]
-    (gate | up) and [n_held, d_ff, d].  Returns (out shaped like x, load
-    [n_held] int32: the pairs each held expert computed this step)."""
+    (gate | up) and [n_held, d_ff, d]; `n_experts`, the router's width,
+    lets the op size its walk's chunk by the share held.  Returns (out
+    shaped like x, load [n_held] int32: the pairs each held expert
+    computed this step)."""
     d = x.shape[-1]
     gu_helper = LayerHelper("moe_experts", param_attr=gate_up_attr)
     w_gu = gu_helper.create_parameter(
@@ -292,6 +317,7 @@ def moe_experts(x, topk_idx, topk_weight, n_held, d_ff, expert_offset=0,
         inputs={"X": [x], "TopkIdx": [topk_idx], "TopkWeight": [topk_weight],
                 "WGateUp": [w_gu], "WDown": [w_down]},
         outputs={"Out": [out], "H": [h], "Load": [load], "Order": [order]},
-        attrs={"expert_offset": int(expert_offset)})
+        attrs={"expert_offset": int(expert_offset),
+               "n_experts": int(n_experts)})
     out.shape = x.shape
     return out, load

@@ -31,12 +31,13 @@ from .mla_moe_decoder import (_Net, _embedding, _linear, _publish_load,
                               _shaped, decoder_block)
 
 
-def gqa_attention(net, x, name):
-    """x [b, 2L, d]: `n_head` query heads over `n_kv_head` key/value heads
+def grouped_query_attention(net, x, name, period=0, **masking):
+    """x [b, t, d]: `n_head` query heads over `n_kv_head` key/value heads
     of `head_dim`, each head of q and k RMS-normed (one learned scale a
     layer, shared by the heads) and then turned (half-split pairs,
-    positions restarting at L); the bhtd flash kernels read key/value head
-    i // group and mask by position."""
+    positions restarting every `period` rows where one is given); the bhtd
+    flash kernels read key/value head i // group under `masking`
+    (fused_attention's `causal` or `mask` arguments)."""
     b, t = x.shape[0], x.shape[1]
     h, hk, dh = net.n_head, net.n_kv_head, net.head_dim
 
@@ -50,7 +51,7 @@ def gqa_attention(net, x, name):
     def turned(a, norm_name):
         return contrib.rope(net.norm(a, name + norm_name),
                             theta=net.rope_theta, pairing="half",
-                            period=net.seq_len)
+                            period=period)
 
     q, k = turned(q, ".q_norm"), turned(k, ".k_norm")
     to_bhtd = [0, 2, 1, 3]
@@ -58,11 +59,18 @@ def gqa_attention(net, x, name):
         _shaped(layers.transpose(q, to_bhtd), (b, h, t, dh)),
         _shaped(layers.transpose(k, to_bhtd), (b, hk, t, dh)),
         _shaped(layers.transpose(v, to_bhtd), (b, hk, t, dh)),
-        scale=dh ** -0.5, fmt="bhtd", mask="block_diffusion",
-        block_length=net.block_length, clean_offset=net.seq_len)
+        scale=dh ** -0.5, fmt="bhtd", **masking)
     ctx = layers.reshape(layers.transpose(ctx, to_bhtd), [b, t, h * dh])
     w_o = net.weight(name + ".o_w", (h * dh, net.d_model))
     return _linear(_shaped(ctx, (b, t, h * dh)), w_o, net.d_model)
+
+
+def gqa_attention(net, x, name):
+    """x [b, 2L, d] = [noisy ; clean]: grouped-query attention whose
+    positions restart at L, masked by position (block diffusion)."""
+    return grouped_query_attention(
+        net, x, name, period=net.seq_len, mask="block_diffusion",
+        block_length=net.block_length, clean_offset=net.seq_len)
 
 
 def build_train_net(vocab_size, seq_len, batch, block_length=4,

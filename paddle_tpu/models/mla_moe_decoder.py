@@ -34,11 +34,13 @@ from ..param_attr import ParamAttr
 class _Net:
     """The sizes of one stack, and the parameters it creates by name.
     A stack that says nothing else routes by sigmoid scores with a
-    score-correction bias (`scoring`, `router_bias`) and trains its
-    router (`train_router`)."""
+    score-correction bias (`scoring`, `router_bias`), normalises the
+    chosen scores by their bare sum (`router_norm_eps`: a family's own
+    addend) and trains its router (`train_router`)."""
 
     scoring = "sigmoid"
     router_bias = True
+    router_norm_eps = None
     train_router = True
 
     def __init__(self, **sizes):
@@ -150,11 +152,12 @@ def moe_ffn(net, x, name):
         x, net.n_experts, net.top_k, scale=net.routed_scale,
         bias_std=net.bias_std, param_attr=router_attr,
         bias_attr=ParamAttr(name=name + ".router_bias")
-        if net.router_bias else False, scoring=net.scoring)
+        if net.router_bias else False, scoring=net.scoring,
+        norm_eps=net.router_norm_eps)
     if not net.train_router:
         weight.stop_gradient = True
     routed, load = contrib.moe_experts(
-        x, idx, weight, net.n_held, net.d_ff_expert,
+        x, idx, weight, net.n_held, net.d_ff_expert, net.n_experts,
         expert_offset=net.expert_offset,
         gate_up_attr=net.attr(name + ".experts_gate_up_w"),
         down_attr=net.attr(name + ".experts_down_w"))
@@ -204,7 +207,7 @@ def _publish_load(net, program):
         layers.elementwise_max(layers.reduce_mean(load),
                                layers.fill_constant([1], "float32", 1e-9)))
     walked = layers.reduce_sum(rows_walked(
-        layers.reduce_sum(load, dim=1), routed,
+        layers.reduce_sum(load, dim=1), routed, net.n_held, net.n_experts,
         lambda live, rows: layers.ceil(live / rows)))
     for name, var in (("moe_local_pairs", pairs),
                       ("moe_max_over_mean", worst),

@@ -405,9 +405,12 @@ _COMPILE_COUNTS = {
 # projection dots, nothing recomputed; and the (query tile, key tile)
 # pairs a head and sequence that the masked flash forward walks visit, of
 # the pairs of the whole square, summed over the sites traced
-# (kernels/attention.py `bd_tiles_visited`)
+# (kernels/attention.py `bd_tiles_visited`); and the short_conv sites
+# lowered to the Pallas kernel pair / to the XLA composition
+# (kernels/short_conv.py)
 _TRACE_COUNTS = ("grad_direct", "grad_generic", "qkv_bwd_composed",
-                 "attn_tiles_visited", "attn_tiles_total")
+                 "attn_tiles_visited", "attn_tiles_total",
+                 "short_conv_sites_kernel", "short_conv_sites_xla")
 _compile_totals: Dict[str, float] = dict.fromkeys(
     list(_COMPILE_DURATIONS.values()) + list(_COMPILE_COUNTS.values())
     + list(_TRACE_COUNTS), 0)
@@ -493,7 +496,9 @@ def compile_phases() -> Dict[str, float]:
     `qkv_bwd_composed` of fused_qkv_attention grad ops fed q, k, v from
     their forward, and `attn_tiles_visited` / `attn_tiles_total` of the
     masked attention walks (their ratio is the share of the score square
-    the kernels visit)."""
+    the kernels visit), and `short_conv_sites_kernel` /
+    `short_conv_sites_xla` of the short_conv ops lowered to the Pallas
+    kernels / to the XLA composition."""
     with _compile_lock:
         return dict(_compile_totals)
 

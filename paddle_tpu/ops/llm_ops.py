@@ -1,8 +1,9 @@
 """Ops of a pre-norm decoder block with a sparse expert layer: RMS norm,
 rotary positions (interleaved or half-split pairs, positions that may
-restart along a row), SwiGLU, a sigmoid or softmax top-k router and the
-experts a chip holds (grouped matmuls over the routed pairs sorted by
-expert, kernels/grouped_matmul.py).
+restart along a row), SwiGLU, a gated short convolution (kernels/
+short_conv.py), a sigmoid or softmax top-k router and the experts a chip
+holds (grouped matmuls over the routed pairs sorted by expert, kernels/
+grouped_matmul.py).
 
 Each has a forward lowering and a registered grad op (registry.
 residual_grad): the grad op reads what the forward wrote (`InvRms`,
@@ -158,6 +159,35 @@ def lower_swiglu_grad(ctx, ins):
 
 
 # ---------------------------------------------------------------------------
+# short_conv
+# ---------------------------------------------------------------------------
+
+
+@register("short_conv")
+def lower_short_conv(ctx, ins):
+    """Out [b, t, d] = C * causal_depthwise(B * x) of X [b, t, 3d] = [B |
+    C | x] and Filter [d, L]: c[t] = sum_j Filter[:, j] * (B * x)[t - (L-1)
+    + j] within a row, zeros before its start (LFM2's gated short
+    convolution between its in- and out-projection).  The Pallas pair of
+    kernels/short_conv.py on the TPU, its XLA composition elsewhere."""
+    from ..kernels.short_conv import short_conv
+
+    return {"Out": [short_conv(ins["X"][0], ins["Filter"][0])]}
+
+
+@residual_grad("short_conv")
+def lower_short_conv_grad(ctx, ins):
+    """dX and dFilter (summed over batch and time in float32) from X, the
+    filter and Out@GRAD: B * x and the convolution are made again from X,
+    which is cheaper than keeping them."""
+    from ..kernels.short_conv import short_conv_bwd
+
+    x, w = ins["X"][0], ins["Filter"][0]
+    dx, dw = short_conv_bwd(x, w, ins["Out@GRAD"][0])
+    return {"X@GRAD": [dx], "Filter@GRAD": [dw.astype(w.dtype)]}
+
+
+# ---------------------------------------------------------------------------
 # moe_router
 # ---------------------------------------------------------------------------
 
@@ -183,8 +213,8 @@ def lower_moe_router(ctx, ins):
     "softmax" (over the experts; the Qwen3-MoE lineage).  The top_k of
     (scores + Bias) are chosen (`noaux_tc` with one group: the correction
     bias enters the choice only; Bias may be absent) and weighted by their
-    own scores, normalised over the chosen (`norm_topk_prob`) and times
-    `scale`.
+    own scores, normalised over the chosen (`norm_topk_prob`: their sum
+    plus `norm_eps`, 1e-20 where a family states none) and times `scale`.
 
     X [.., d], W [d, E], Bias [E] or none -> TopkIdx [T, k] int32,
     TopkWeight [T, k] float32, Scores [T, E] float32 (T = the leading dims
@@ -203,7 +233,8 @@ def lower_moe_router(ctx, ins):
     idx = idx.astype(jnp.int32)
     chosen = jnp.take_along_axis(scores, idx, axis=1)
     weight = chosen * (ctx.attr("scale", 1.0)
-                       / (jnp.sum(chosen, axis=1, keepdims=True) + 1e-20))
+                       / (jnp.sum(chosen, axis=1, keepdims=True)
+                          + ctx.attr("norm_eps", 1e-20)))
     return {"TopkIdx": [idx], "TopkWeight": [weight], "Scores": [scores]}
 
 
@@ -217,7 +248,8 @@ def lower_moe_router_grad(ctx, ins):
     scores, idx = ins["Scores"][0], ins["TopkIdx"][0]
     dw_pair = _f32(ins["TopkWeight@GRAD"][0]).reshape(idx.shape)
     chosen = jnp.take_along_axis(scores, idx, axis=1)
-    total = jnp.sum(chosen, axis=1, keepdims=True) + 1e-20
+    total = jnp.sum(chosen, axis=1, keepdims=True) + ctx.attr(
+        "norm_eps", 1e-20)
     d_chosen = ctx.attr("scale", 1.0) * (
         dw_pair / total
         - jnp.sum(dw_pair * chosen, axis=1, keepdims=True) / (total * total))
@@ -260,28 +292,36 @@ def _dispatch(idx, n_held, offset):
     return order, load
 
 
-def chunk_rows(pairs):
+def chunk_rows(pairs, held, routed):
     """Rows of one chunk of the walk over `pairs` sorted (token, choice)
-    pairs: a quarter of them, whole row tiles of the grouped matmuls (a
-    share that holds an eighth or a sixteenth of the experts then takes
-    one trip at most loads, and a layer that holds every expert four); all
-    of them, one chunk, where a quarter is not whole tiles.  Fixed by the
-    shapes: no caller chooses it."""
+    pairs, for a chip that holds `held` of the layer's `routed` experts:
+    the smallest of a quarter, a half and all of the pairs that holds
+    twice the held experts' mean load, pairs x held / routed, in whole row
+    tiles of the grouped matmuls.  A share of an eighth or a sixteenth of
+    the experts walks a quarter and a share of a quarter a half, one trip
+    at all but rare loads (a quarter share at a quarter chunk takes a
+    second trip in every other layer-step: +2.9 % of the step in
+    lfm2_8b_a1b_ep4_train, PERF.md section 6).  A layer that holds every
+    expert walks all its pairs whatever the chunk and needs no such room:
+    a quarter, four trips.  All of them, one chunk, where that part is not
+    whole tiles.  Fixed by the shapes: no caller chooses it."""
     from ..kernels.grouped_matmul import ROW_TILE
 
-    return pairs // 4 if pairs % (4 * ROW_TILE) == 0 else pairs
+    parts = 4 if held == routed else next(
+        (n for n in (4, 2) if n * 2 * held <= routed), 1)
+    return pairs // parts if pairs % (parts * ROW_TILE) == 0 else pairs
 
 
-def rows_walked(live, pairs, ceil_div=lambda a, b: -(-a // b)):
+def rows_walked(live, pairs, held, routed, ceil_div=lambda a, b: -(-a // b)):
     """Rows the walk visits for `live` held pairs of `pairs`: whole chunks,
     trips x R.  THE rule, for the op's trip count and for the counter
     `moe_rows_walked` (models/mla_moe_decoder.py), which hands in a
     `ceil_div` over a program's variables."""
-    rows = chunk_rows(pairs)
+    rows = chunk_rows(pairs, held, routed)
     return ceil_div(live, rows) * rows
 
 
-def _walk(load, order):
+def _walk(load, order, routed):
     """How the op walks its sorted pairs: in chunks of R = chunk_rows rows,
     as many as hold a live row.  Returns (R, the held experts' offsets
     [G+1] in the sorted order, live = the held experts' pairs, trips =
@@ -289,12 +329,12 @@ def _walk(load, order):
     order)."""
     import jax.numpy as jnp
 
-    pairs = order.shape[0]
-    rows = chunk_rows(pairs)
+    pairs, held = order.shape[0], load.shape[0]
+    rows = chunk_rows(pairs, held, routed)
     offsets = jnp.concatenate([jnp.zeros(1, jnp.int32),
                                jnp.cumsum(load, dtype=jnp.int32)])
     return (rows, offsets, offsets[-1],
-            rows_walked(offsets[-1], pairs) // rows,
+            rows_walked(offsets[-1], pairs, held, routed) // rows,
             jnp.argsort(order).astype(order.dtype))
 
 
@@ -351,14 +391,16 @@ def lower_moe_experts(ctx, ins):
     exchange on one chip).
 
     X [.., d], TopkIdx / TopkWeight [T, k], WGateUp [G, d, 2f] (packed
-    gate | up), WDown [G, f, d]; attr `expert_offset`: the first held
-    expert's id.  The residuals: H [T*k, 2f] (the pairs' gate | up
-    pre-activations, sorted by expert; rows past the held experts' are
-    never written: `unfilled`), Load [G] (pairs an expert), Order [T*k]
-    (the sort).  Dropless: every pair of a held expert is computed.  The
-    sorted pairs are walked in chunks (`_walk`) under a traced trip count,
-    so apart from H every array made here has a chunk's rows or a token's,
-    and the work follows the pairs the held experts really got."""
+    gate | up), WDown [G, f, d]; attrs `expert_offset`: the first held
+    expert's id, and `n_experts`: the router's width, which with G sets
+    the walk's chunk (`chunk_rows`).  The residuals: H [T*k, 2f] (the
+    pairs' gate | up pre-activations, sorted by expert; rows past the held
+    experts' are never written: `unfilled`), Load [G] (pairs an expert),
+    Order [T*k] (the sort).  Dropless: every pair of a held expert is
+    computed.  The sorted pairs are walked in chunks (`_walk`) under a
+    traced trip count, so apart from H every array made here has a chunk's
+    rows or a token's, and the work follows the pairs the held experts
+    really got."""
     import jax
     import jax.numpy as jnp
 
@@ -370,7 +412,8 @@ def lower_moe_experts(ctx, ins):
     w_gu, w_down = ins["WGateUp"][0].astype(dt), ins["WDown"][0].astype(dt)
     x2 = x.reshape(t, -1)
     order, load = _dispatch(idx, w_gu.shape[0], ctx.attr("expert_offset", 0))
-    rows, offsets, live, trips, back = _walk(load, order)
+    rows, offsets, live, trips, back = _walk(
+        load, order, ctx.attr("n_experts"))
 
     def chunk(c, carry):
         h_all, out = carry
@@ -418,7 +461,8 @@ def lower_moe_experts_grad(ctx, ins):
     g2 = ins["Out@GRAD"][0].astype(dt).reshape(t, -1)
     weight = _f32(ins["TopkWeight"][0]).reshape(-1)
     wants_weight = any(ctx.op.output("TopkWeight@GRAD"))
-    rows, offsets, live, trips, back = _walk(load, order)
+    rows, offsets, live, trips, back = _walk(
+        load, order, ctx.attr("n_experts"))
 
     def chunk(c, carry):
         dx, dwt_all, dw_gu, dw_down = carry

@@ -230,7 +230,8 @@ def test_compile_phases_count_inside_executor_calls_only(clean_ring):
     assert set(hit0) == {"trace_s", "lower_s", "backend_s", "cache_load_s",
                          "cache_hits", "cache_misses", "grad_direct",
                          "grad_generic", "qkv_bwd_composed",
-                         "attn_tiles_visited", "attn_tiles_total"}
+                         "attn_tiles_visited", "attn_tiles_total",
+                         "short_conv_sites_kernel", "short_conv_sites_xla"}
     # the net's grad ops have no lowering of their own: all went through
     # the generic vjp, once each, in the miss call's trace and nowhere else
     assert miss["grad_generic"] - before["grad_generic"] > 0

@@ -330,9 +330,10 @@ def test_grouped_matmul_plan_rejects_what_mosaic_cannot_tile(monkeypatch):
 
 # the walk over the sorted pairs (ops/llm_ops.py moe_experts) -------------------
 
-#: 64 tokens x 4 choices over 16 experts of which 4..7 are held; with a row
-#: tile of 16 a chunk is 64 of the 256 sorted pairs
-WT, WK, WD, WF, WHELD, WOFF = 64, 4, 32, 16, 4, 4
+#: 64 tokens x 4 choices among experts 0..15 of a router 32 wide, of which
+#: 4..7 are held (an eighth); with a row tile of 16 a chunk is 64 of the 256
+#: sorted pairs
+WT, WK, WD, WF, WHELD, WOFF, WROUTED = 64, 4, 32, 16, 4, 4, 32
 
 
 def _walk_idx(case):
@@ -402,7 +403,7 @@ def _run_walk(case, weight_grad=True, executor=pt.Executor, prepare=None,
                          append_batch_size=False)
         xv.stop_gradient, wv.stop_gradient = False, not weight_grad
         out, load = contrib.moe_experts(
-            xv, iv, wv, WHELD, WF, expert_offset=WOFF,
+            xv, iv, wv, WHELD, WF, WROUTED, expert_offset=WOFF,
             gate_up_attr=pt.ParamAttr(name="wgu"),
             down_attr=pt.ParamAttr(name="wd"))
         backward.append_backward(layers.reduce_sum(
@@ -440,7 +441,7 @@ def test_expert_walk_follows_the_loop_over_experts(case, monkeypatch):
     monkeypatch.setattr(G, "ROW_TILE", 16)
     monkeypatch.setattr(jax.lax, "empty", lambda shape, dtype: jnp.full(
         shape, jnp.nan, dtype))
-    assert llm_ops.chunk_rows(WT * WK) == 64
+    assert llm_ops.chunk_rows(WT * WK, WHELD, WROUTED) == 64
     _, got, ref_out, ref = _run_walk(case)
     live = int(got["load"].sum())
     assert -(-live // 64) == WALKS[case]
@@ -475,14 +476,15 @@ def test_one_rule_says_how_many_rows_a_load_walks(live, trips):
     """`rows_walked` is the op's trip count and the model's counter: on
     whole numbers, on the op's traced load, and through a ceiling over a
     program's float variables (what `_publish_load` hands in)."""
-    pairs = 8192
-    assert llm_ops.chunk_rows(pairs) == 2048
-    assert llm_ops.rows_walked(live, pairs) == trips * 2048
+    pairs, held, routed = 8192, 2, 16
+    assert llm_ops.chunk_rows(pairs, held, routed) == 2048
+    assert llm_ops.rows_walked(live, pairs, held, routed) == trips * 2048
     load = jnp.asarray([live // 2, live - live // 2], jnp.int32)
-    assert int(llm_ops._walk(load, jnp.arange(pairs, dtype=jnp.int32))[3]) \
-        == trips
+    assert int(llm_ops._walk(load, jnp.arange(pairs, dtype=jnp.int32),
+                             routed)[3]) == trips
     assert llm_ops.rows_walked(
-        np.float32(live), pairs, lambda a, b: np.ceil(a / b)) == trips * 2048
+        np.float32(live), pairs, held, routed,
+        lambda a, b: np.ceil(a / b)) == trips * 2048
 
 
 @pytest.mark.parametrize("check", ["check_nan_inf", "locate"])
@@ -564,7 +566,7 @@ def test_expert_walk_makes_no_array_of_all_the_pairs_outside_its_loop():
     discards the pairs of other chunks, summed over a token's choices at
     once."""
     t, k, d, f, g = 256, 8, 128, 64, 4
-    n, chunk = t * k, llm_ops.chunk_rows(t * k)
+    n, chunk = t * k, llm_ops.chunk_rows(t * k, g, 8 * g)
     assert chunk == n // 4
 
     class Ctx:
@@ -572,7 +574,7 @@ def test_expert_walk_makes_no_array_of_all_the_pairs_outside_its_loop():
 
         @staticmethod
         def attr(name, default=None):
-            return default
+            return {"n_experts": 8 * g}.get(name, default)
 
     def layer(x, idx, w, wgu, wd, dout):
         ins = {"X": [x], "TopkIdx": [idx], "TopkWeight": [w],
@@ -894,7 +896,8 @@ def test_device_counters_ride_the_flight_event_only_while_tracing():
     assert 1.0 <= counters["moe_max_over_mean"] < 4.0
     # the op's own chunk rule: 64 x 4 pairs a layer are one chunk at this
     # size (a quarter is no whole row tile), so each layer walks it once
-    assert llm_ops.chunk_rows(tokens * k) == tokens * k
+    assert llm_ops.chunk_rows(tokens * k, CFG["n_routed_experts"],
+                              CFG["router_experts"]) == tokens * k
     assert counters["moe_rows_walked"] == 3 * tokens * k
 
 

@@ -356,9 +356,16 @@ class TestNoFalsePositives:
             "attention", "conv_bn", "dropout_epilogue",
             "embedding", "ring_attention", "decode_attention",
             "decode_step", "paged_decode_attention", "paged_decode_step",
+            "short_conv",
         }
         for fam, rows in report.items():
             assert rows, fam
+        # the convolution blocks' rows take the kernels in whole 16-row
+        # tiles; rows that are none fall to the XLA composition
+        conv = {r["label"]: r for r in report["short_conv"]}
+        assert conv["lfm2-conv-bf16"]["accepted"]
+        assert 4096 % conv["lfm2-conv-bf16"]["rows"] == 0
+        assert not conv["conv-ragged-rows"]["accepted"]
         # paged matrix contract: the capacity pair accepts, the
         # misaligned-pool and oversized-table rows reject (block_t is
         # pool geometry — never snapped)

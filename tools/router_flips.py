@@ -13,6 +13,11 @@ the flips are the cause, the second reading of the expert leaves falls to
 what the other leaves read.
 
     python3 tools/router_flips.py --workload W --seed N [--out FILE]
+        [--free-weights]
+
+The feeds follow `--seed` always; a configuration that states a
+`weights_seed` keeps its weights unless `--free-weights` sets the key
+aside (as perfbench/calibrate.py's does).
 """
 
 import argparse
@@ -102,6 +107,35 @@ def block_diffusion_choices(ref_mod, dots, cfg, params, ids, noise):
     return out
 
 
+def hybrid_conv_choices(ref_mod, dots, cfg, params, ids):
+    """`reference_choices` for a stack whose operator follows a table
+    (perfbench/configs/lfm2_8b_a1b_ep4_reference.py): `num_dense_layers`
+    dense blocks, then expert layers under a sigmoid router whose bias
+    enters the choice only; the stack sees all but the row's last id.
+    [(idx [b, seq, top_k], margin [b, seq])]."""
+    import jax
+
+    R, P, eps = ref_mod, params, cfg["norm_eps"]
+    k = cfg["num_experts_per_tok"]
+    out = []
+    x = P["embed_w"][ids[:, :-1]]
+    for i, kind in enumerate(R.layer_types(cfg)):
+        if i < cfg["num_dense_layers"]:
+            x = R.block(dots, cfg, x, P, i)
+            continue
+        p = f"layer{i}"
+        op = R.short_conv if kind == "conv" else R.attention
+        x = x + op(dots, cfg, R.rms_norm(x, P[p + ".attn_norm.scale"], eps),
+                   P, p)
+        y = R.rms_norm(x, P[p + ".ffn_norm.scale"], eps)
+        top, idx = jax.lax.top_k(
+            jax.nn.sigmoid(dots.mm(y, P[p + ".router_w"]))
+            + P[p + ".router_bias"], k + 1)
+        out.append((idx[..., :k], top[..., k - 1] - top[..., k]))
+        x = x + R.moe(dots, cfg, y, P, p)
+    return out
+
+
 def count_flips(mine, theirs, margin, n_experts, offset, held):
     """`mine`, `theirs` [tokens, top_k] expert ids, `margin` [tokens]:
     how far the two choices differ, over all experts and over those held
@@ -169,10 +203,13 @@ def diagnose(cell, seed):
                      for x in seen[0]], axis=2)
 
     dots = tc.blocks.Dots("f32")
-    # a traffic with a `noise` field trains by block diffusion
+    # a traffic with a `noise` field trains by block diffusion; a
+    # configuration with a table of operators is of the hybrid kind
     fields, choices = ("ids",), reference_choices
     if "noise" in feeds[0]:
         fields, choices = ("ids", "noise"), block_diffusion_choices
+    elif "layer_types" in cfg:
+        choices = hybrid_conv_choices
     look = jax.jit(lambda P, *rows: choices(tc.ref_mod, dots, cfg, P, *rows))
     params = tc.init_params(seed)
     layers = [[] for _ in idx_vars]
@@ -227,6 +264,7 @@ def main():
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--out", default="")
     ap.add_argument("--allow-cpu", action="store_true")
+    ap.add_argument("--free-weights", action="store_true")
     args = ap.parse_args()
 
     import registry
@@ -234,6 +272,8 @@ def main():
     from paddle_tpu.inference import enable_compile_cache
 
     cell = registry.load_cell(args.workload)
+    if args.free_weights:
+        cell.cfg.pop("weights_seed", None)
     if report.describe_device(cell.chips) is None and not args.allow_cpu:
         print("router_flips: no accelerator", file=sys.stderr)
         return 3
