@@ -496,6 +496,12 @@ _KEY_PARTS = (
 _COMPILE_BUCKETS = (0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0,
                     10.0, 30.0, 60.0, 120.0, 300.0)
 
+# how many calls of each Executor write their flight event whatever
+# `monitor.spans_on()` says: a training process's set-up (start-up, the
+# step's miss, the checked and warm calls) is 5-8 calls and runs before
+# any profiler session exists
+FIRST_CALLS = 16
+
 
 class _CallSpans:
     """The host phases of ONE executor call, told twice from the same
@@ -508,21 +514,26 @@ class _CallSpans:
     session), so ANY profiler session has the host's side of the call
     on the device planes' clock.
 
-    To the flight ring, when `monitor.spans_on()` held at entry: the
-    same boundaries as `phases`, [name, start offset, seconds] off one
-    `perf_counter_ns` sequence that starts at entry (a boundary is ONE
-    stamp: the end of a phase is the start of the next), in ONE event
-    written at exit, whose `dur` runs to that exit: what lies between
-    the end of `fetch` and it is the call's frame coming down (some
-    hundreds of donated arrays are freed there) and this bookkeeping.
-    With tracing off no stamp is taken and nothing is written."""
+    To the flight ring, when `monitor.spans_on()` held at entry or the
+    call is one of its Executor's FIRST_CALLS (`first`, which the event
+    says as `first_calls: 1`): the same boundaries as `phases`, [name,
+    start offset, seconds] off one `perf_counter_ns` sequence that starts
+    at entry (a boundary is ONE stamp: the end of a phase is the start of
+    the next), in ONE event written at exit, whose `dur` runs to that
+    exit: what lies between the end of `fetch` and it is the call's frame
+    coming down (some hundreds of donated arrays are freed there) and
+    this bookkeeping.  Any other call that misses starts its sequence at
+    `compiling()`: its `executor.compile` event covers the call from the
+    miss on.  A later hit with tracing off takes no stamp and writes
+    nothing."""
 
-    __slots__ = ("mode", "call", "mon", "on", "phases", "compile0",
-                 "outcome", "counters", "entry_ns", "_annotation", "_parent",
-                 "_child", "_in_call", "_open")
+    __slots__ = ("mode", "call", "first", "mon", "on", "stamp", "phases",
+                 "compile0", "jax_by_phase", "outcome", "counters",
+                 "entry_ns", "_annotation", "_parent", "_child", "_in_call",
+                 "_open", "_jax_at")
 
-    def __init__(self, mode: str, call: int):
-        self.mode, self.call = mode, call
+    def __init__(self, mode: str, call: int, first: bool = False):
+        self.mode, self.call, self.first = mode, call, first
         self.phases: List[list] = []
         self.compile0 = self.outcome = self.counters = None
         self._child = self._open = None
@@ -535,13 +546,14 @@ class _CallSpans:
 
         self.on = spans_on()
         self.mon = self.on and enabled()
+        self.stamp = self.on or self.first
         self._in_call = _flight.executor_call()
         self._in_call.__enter__()
         self._annotation = TraceAnnotation
         self._parent = TraceAnnotation(f"executor.{self.mode}",
                                        call=self.call)
         self._parent.__enter__()
-        if self.on:
+        if self.stamp:
             import time as _time
 
             self.entry_ns = _time.perf_counter_ns()
@@ -550,7 +562,7 @@ class _CallSpans:
     def phase(self, name: Optional[str]):
         """The boundary between the open phase and `name` (None: the
         last phase ends here)."""
-        if self.on:
+        if self.stamp:
             import time as _time
 
             now = _time.perf_counter_ns()
@@ -558,6 +570,8 @@ class _CallSpans:
                 opened, t_open = self._open
                 self.phases.append([opened, (t_open - self.entry_ns) / 1e9,
                                     (now - t_open) / 1e9])
+                if self.compile0 is not None:
+                    self._note_jax(opened)
             self._open = None if name is None else (name, now)
         if self._child is not None:
             self._child.__exit__(None, None, None)
@@ -570,12 +584,32 @@ class _CallSpans:
     def compiling(self):
         """A miss: open the `compile` phase, and note where jax's own
         compile-phase totals stand (its work comes with the first
-        dispatch), so that the call's flight event carries its share."""
-        self.phase("compile")
-        if self.on:
-            from ..monitor import flight as _flight
+        dispatch), so that the call's flight event carries its share.
+        Every miss is recorded: an unstamped call starts here."""
+        from ..monitor import flight as _flight
 
-            self.compile0 = _flight.compile_phases()
+        if not self.stamp:
+            import time as _time
+
+            self.stamp = True
+            self.entry_ns = _time.perf_counter_ns()
+        self.phase("compile")
+        self.compile0 = self._jax_at = _flight.compile_phases()
+        self.jax_by_phase = {}
+
+    def _note_jax(self, phase: str):
+        """A miss: jax's trace, lower and backend seconds that fell inside
+        `phase` (the first call of a process compiles the step key's
+        eager ops in `gather`; the program's own jit compiles in
+        `dispatch`)."""
+        from ..monitor import flight as _flight
+
+        now = _flight.compile_phases()
+        spent = {k: round(now[k] - self._jax_at[k], 6)
+                 for k in ("trace_s", "lower_s", "backend_s")}
+        if any(spent.values()):
+            self.jax_by_phase[phase] = spent
+        self._jax_at = now
 
     def finished(self, compiled_now, steps, waited, feed_vals, np_outs):
         """The call went through: what its record will say.  `waited`:
@@ -589,14 +623,14 @@ class _CallSpans:
 
     def __exit__(self, *exc):
         self.phase(None)
-        if self.on and self.outcome is not None and exc[0] is None:
+        if self.stamp and self.outcome is not None and exc[0] is None:
             self._record()
         self._parent.__exit__(None, None, None)
         self._in_call.__exit__(None, None, None)
         return False
 
     def _record(self):
-        """One finished executor call, tracing on: ONE flight event with
+        """One finished executor call, stamped: ONE flight event with
         the call's phases (`executor.compile` when this call traced and
         compiled — jax.jit compiles lazily, so the miss call's duration
         IS the compile cost, and jax's own phases of it ride along —
@@ -620,6 +654,8 @@ class _CallSpans:
         fields = {"t0": t0_epoch, "dur": round(dt, 6), "call": self.call,
                   "phases": [[n, round(a, 6), round(d, 6)]
                              for n, a, d in self.phases]}
+        if self.first:
+            fields["first_calls"] = 1
         meta = {"compiled": int(compiled_now)}
         if steps is not None:
             fields["steps"] = meta["steps"] = steps
@@ -639,10 +675,10 @@ class _CallSpans:
                 monitor.histogram(
                     "executor.compile_seconds",
                     buckets=_COMPILE_BUCKETS).observe(dt)
-            if self.compile0 is not None:
-                now = _flight.compile_phases()
-                fields.update({k: round(now[k] - v, 6)
-                               for k, v in self.compile0.items()})
+            now = _flight.compile_phases()
+            fields.update({k: round(now[k] - v, 6)
+                           for k, v in self.compile0.items()})
+            fields["jax_by_phase"] = self.jax_by_phase
             _flight.default_recorder().record(
                 "executor.compile", mode=mode, **fields)
         else:
@@ -1088,6 +1124,8 @@ class Executor:
         self._cache: Dict[Any, _CompiledEntry] = {}
         self._ref_names_cache: Dict[Any, tuple] = {}
         self._run_counter = 0
+        # calls left of the first-calls record (FIRST_CALLS, _CallSpans)
+        self._first_left = FIRST_CALLS
         # numerics failing-step replay (monitor/numerics.py): when set,
         # the next _next_run_id() returns THIS value once, without
         # advancing the counter — the replayed step folds the SAME id
@@ -1272,7 +1310,13 @@ class Executor:
               return_numpy):
         """Every call of every mode and layout: `feed`, `key`, [`compile`],
         `gather`, `dispatch`, `writeback`, `fetch`."""
-        with _CallSpans(mode.name, self._next_run_id()) as spans:
+        first = self._first_left > 0
+        if first:
+            with self._counter_lock:
+                first = self._first_left > 0
+                if first:
+                    self._first_left -= 1
+        with _CallSpans(mode.name, self._next_run_id(), first) as spans:
             call = self._prepare(spans, mode, count, program, feed,
                                  fetch_list, scope)
             fetches = self._execute(spans, call)
@@ -1636,7 +1680,7 @@ class Executor:
         user_outs = self._publish_numerics(c.program, fetch_names,
                                            c.user_fetch_n, outs)
         spans.phase(None)
-        if spans.on:
+        if spans.stamp:
             spans.finished(c.compiled_now, c.count, return_numpy,
                            c.feed_vals, outs if return_numpy else None)
         return user_outs

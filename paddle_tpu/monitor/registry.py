@@ -29,15 +29,26 @@ def enabled() -> bool:
     return FLAGS.monitor
 
 
+# between profiler.start_profiler and stop_profiler: a session with no jax
+# trace turns the executor's spans on too (its table reads them)
+_host_session = False
+
+
+def set_host_session(on: bool) -> None:
+    global _host_session
+    _host_session = bool(on)
+
+
 def spans_on() -> bool:
     """Whether the executor should stamp its host phases into the flight
-    ring: FLAGS.monitor, or ANY active jax.profiler session (the
-    benchmark's, `profiler.start_profiler(trace_dir=...)`, an operator's
-    xprof capture).  Narrower than `enabled()` on purpose: a profiler
-    session switches the spans on, not every histogram."""
+    ring: FLAGS.monitor, a `profiler.start_profiler` session, or ANY
+    active jax.profiler session (the benchmark's,
+    `profiler.start_profiler(trace_dir=...)`, an operator's xprof
+    capture).  Narrower than `enabled()` on purpose: a profiler session
+    switches the spans on, not every histogram."""
     from ..flags import FLAGS
 
-    if FLAGS.monitor:
+    if FLAGS.monitor or _host_session:
         return True
     from jax.profiler import TraceAnnotation
 

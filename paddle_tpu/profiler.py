@@ -3,97 +3,14 @@ host event tables + CUPTI device tracer → chrome trace).
 
 TPU equivalent: jax.profiler captures XPlane traces viewable in
 TensorBoard/Perfetto (the reference's tools/timeline.py chrome-trace role),
-plus a lightweight host-side step timer table for the per-op summary role."""
+plus a host table of the executor's calls and their phases, read from the
+flight events a session wrote, for the per-op summary role."""
 
 from __future__ import annotations
 
 import contextlib
 import time
-from collections import defaultdict
 from typing import Optional
-
-
-class _HostEvents:
-    def __init__(self):
-        import threading
-
-        self.totals = defaultdict(float)
-        self.counts = defaultdict(int)
-        self.maxes = defaultdict(float)
-        # per-thread range stack: concurrent record_event() ranges on
-        # different threads must not pop each other's (name, t0)
-        self._local = threading.local()
-        # add() is reached from serving/executor threads via
-        # profiler.add_event; unlocked += would drop increments
-        self._lock = threading.Lock()
-
-    @property
-    def _stack(self):
-        st = getattr(self._local, "stack", None)
-        if st is None:
-            st = self._local.stack = []
-        return st
-
-    def push(self, name):
-        self._stack.append((name, time.perf_counter()))
-
-    def pop(self):
-        name, t0 = self._stack.pop()
-        self.add(name, time.perf_counter() - t0)
-
-    def add(self, name, dt):
-        with self._lock:
-            self.totals[name] += dt
-            self.counts[name] += 1
-            self.maxes[name] = max(self.maxes[name], dt)
-
-    def summary(self, sorted_key="total"):
-        rows = []
-        with self._lock:  # add() on worker threads may insert new names
-            names = list(self.totals)
-            for name in names:
-                total = self.totals[name]
-                cnt = self.counts[name]
-                rows.append(
-                    (name, cnt, total, total / cnt, self.maxes[name]))
-        key_idx = {"total": 2, "calls": 1, "ave": 3, "max": 4}.get(sorted_key, 2)
-        rows.sort(key=lambda r: r[key_idx], reverse=True)
-        return rows
-
-    def reset(self):
-        with self._lock:  # don't interleave with a worker thread's add()
-            self.totals.clear()
-            self.counts.clear()
-            self.maxes.clear()
-
-
-_events = _HostEvents()
-_profiling = False
-
-
-@contextlib.contextmanager
-def record_event(name):
-    """RAII range (reference: platform/profiler.h:72 RecordEvent)."""
-    _events.push(name)
-    try:
-        yield
-    finally:
-        _events.pop()
-
-
-def add_event(name, seconds: float):
-    """Record an already-measured host range into the event table — used
-    by instrumentation that owns its timer (the executor's monitored
-    run/compile paths), so the profiler summary covers the runtime hot
-    paths without nesting context managers through their control flow."""
-    _events.add(name, seconds)
-
-
-def host_events(sorted_key="total"):
-    """Rows of (name, calls, total_s, avg_s, max_s) from the host event
-    table, without printing (stop_profiler's table, accessor form)."""
-    return _events.summary(sorted_key)
-
 
 # clock bridge for the unified timeline: xplane event timestamps are
 # relative to the trace-session start, flight-recorder events are epoch
@@ -101,12 +18,20 @@ def host_events(sorted_key="total"):
 # on one axis (skew = the microseconds start_trace takes to return)
 _trace_start_epoch: Optional[float] = None
 _trace_dir: Optional[str] = None
+# the flight ring's last sequence number when the session started: the
+# table reads the events written after it
+_session_seq = 0
 
 
 def start_profiler(state="All", trace_dir: Optional[str] = None):
-    global _profiling, _trace_start_epoch, _trace_dir
-    _profiling = True
-    _events.reset()
+    """Turn the executor's spans on (`monitor.spans_on()`) until
+    stop_profiler, and with `trace_dir` capture a jax trace there."""
+    global _trace_start_epoch, _trace_dir, _session_seq
+    from .monitor import flight, registry
+
+    last = flight.default_recorder().events(1)
+    _session_seq = last[0]["seq"] if last else 0
+    registry.set_host_session(True)
     if trace_dir:
         import jax
 
@@ -115,15 +40,42 @@ def start_profiler(state="All", trace_dir: Optional[str] = None):
         jax.profiler.start_trace(trace_dir)
 
 
+def _executor_table(events, sorted_key="total"):
+    """Rows (name, calls, total_s, ave_s, max_s) of executor calls
+    (`executor.<mode>`, a miss under its mode) and of their host phases
+    (`executor.<phase>`, the span names), from flight events that carry
+    `phases`."""
+    times = {}
+    for ev in events:
+        kind = ev.get("kind", "")
+        if not kind.startswith("executor.") or "phases" not in ev:
+            continue
+        mode = ev["mode"] if kind == "executor.compile" else \
+            kind[len("executor."):]
+        times.setdefault(f"executor.{mode}", []).append(ev["dur"])
+        for name, _, dur in ev["phases"]:
+            times.setdefault(f"executor.{name}", []).append(dur)
+    rows = [(name, len(ts), sum(ts), sum(ts) / len(ts), max(ts))
+            for name, ts in times.items()]
+    key_idx = {"total": 2, "calls": 1, "ave": 3, "max": 4}.get(sorted_key, 2)
+    rows.sort(key=lambda r: r[key_idx], reverse=True)
+    return rows
+
+
 def stop_profiler(sorted_key="total", profile_path: Optional[str] = None,
                   tracing: bool = False):
-    global _profiling
-    _profiling = False
+    """End the session and print the table of the executor calls it ran
+    (`_executor_table` over the flight events written since
+    start_profiler); returns its rows."""
+    from .monitor import flight, registry
+
+    registry.set_host_session(False)
     if tracing:
         import jax
 
         jax.profiler.stop_trace()
-    rows = _events.summary(sorted_key)
+    rows = _executor_table([e for e in flight.default_recorder().events()
+                           if e["seq"] > _session_seq], sorted_key)
     lines = ["Event                          Calls     Total(s)    Ave(s)      Max(s)"]
     for name, cnt, total, ave, mx in rows:
         lines.append(f"{name:<30} {cnt:>6} {total:>12.6f} {ave:>10.6f} {mx:>10.6f}")
@@ -343,19 +295,6 @@ def _xplane_chrome_events(trace_dir: str, max_events: int,
                     })
                     n_slices += 1
     return events
-
-
-def export_chrome_trace(trace_dir: str, out_path: str, max_events=50000):
-    """Convert a captured xplane trace to chrome://tracing JSON (the
-    reference's tools/timeline.py role over its protobuf profile).  Each
-    plane becomes a pid, each line a tid; op events carry their XLA
-    names.  Decoded natively — no TensorFlow proto dependency."""
-    import json as _json
-
-    events = _xplane_chrome_events(trace_dir, max_events)
-    with open(out_path, "w") as f:
-        _json.dump({"traceEvents": events}, f)
-    return len(events)
 
 
 # ---------------------------------------------------------------------------

@@ -16,6 +16,7 @@ import pytest
 
 import paddle_tpu as pt
 from paddle_tpu import layers, monitor
+from paddle_tpu.core.executor import FIRST_CALLS
 from paddle_tpu.flags import FLAGS
 from paddle_tpu.monitor import flight
 
@@ -36,10 +37,11 @@ def _train_net():
         pt.optimizer.Adam(1e-3).minimize(loss)
     exe, scope = pt.Executor(pt.CPUPlace()), pt.Scope()
     exe.run(startup, scope=scope)
-    feed = {"x": np.ones((STEPS, 4, 8), "float32"),
-            "y": np.ones((STEPS, 4, 1), "float32")}
 
-    def call():
+    def call(batch=4):
+        """One `run_steps` call; another `batch` is another signature."""
+        feed = {"x": np.ones((STEPS, batch, 8), "float32"),
+                "y": np.ones((STEPS, batch, 1), "float32")}
         return exe.run_steps(prog, feed=feed, fetch_list=[loss], scope=scope)
 
     return exe, call
@@ -59,12 +61,23 @@ def _tiles(ev):
 # (a) ------------------------------------------------------------------------
 
 
+def _past_the_first_calls(call):
+    """Calls 2..FIRST_CALLS of `_train_net`'s executor (start-up was the
+    first): after them, tracing off records nothing."""
+    for _ in range(FIRST_CALLS - 1):
+        call()
+
+
 def test_profiler_session_turns_the_spans_on(clean_ring, tmp_path):
     from jax.profiler import ProfileData
 
     _, call = _train_net()
-    call()  # the miss, outside any session: nothing recorded
-    assert clean_ring.events() == []
+    # the miss and the hits after it, outside any session: the first-calls
+    # record holds them and nothing else
+    _past_the_first_calls(call)
+    assert {e.get("first_calls") for e in clean_ring.events()} == {1}
+    assert len(clean_ring.events()) == FIRST_CALLS
+    clean_ring.clear()
     jax.profiler.start_trace(str(tmp_path))
     try:
         call()
@@ -73,7 +86,7 @@ def test_profiler_session_turns_the_spans_on(clean_ring, tmp_path):
     call()  # the session is over: off again
     (ev,) = clean_ring.events()
     assert ev["kind"] == "executor.run_steps" and ev["steps"] == STEPS
-    assert isinstance(ev["call"], int) and ev["call"] > 0
+    assert ev["call"] == FIRST_CALLS + 1 and "first_calls" not in ev
     assert [p[0] for p in ev["phases"]] == HIT_PHASES
     _tiles(ev)
     assert ev["dispatch_s"] + ev["device_wait_s"] == pytest.approx(
@@ -179,12 +192,10 @@ def test_a_wrapped_programs_calls_are_recorded_like_any(clean_ring, layout,
 # (b) ------------------------------------------------------------------------
 
 
-def test_tracing_off_records_nothing_and_reads_no_clock(clean_ring,
-                                                        monkeypatch):
+def _counting_clocks(monkeypatch):
+    """The clock reads made from core/executor.py from here on."""
     from paddle_tpu.core import executor as ex
 
-    _, call = _train_net()
-    call()
     reads = []
 
     def counting(real):
@@ -196,13 +207,127 @@ def test_tracing_off_records_nothing_and_reads_no_clock(clean_ring,
 
     for name in ("perf_counter", "perf_counter_ns", "time", "monotonic"):
         monkeypatch.setattr(time, name, counting(getattr(time, name)))
-    call()
+    return reads
+
+
+def test_tracing_off_records_the_first_calls_then_nothing(clean_ring,
+                                                          monkeypatch):
+    _, call = _train_net()
+    _past_the_first_calls(call)
+    evs = clean_ring.events()
+    # start-up, the step's miss, then hits: each with its phases, tracing
+    # off, on the epoch clock
+    assert [e["kind"] for e in evs] == (
+        ["executor.compile"] * 2 + ["executor.run_steps"] * (FIRST_CALLS - 2))
+    assert [e["call"] for e in evs] == list(range(1, FIRST_CALLS + 1))
+    assert [e["mode"] for e in evs[:2]] == ["run", "run_steps"]
+    for ev in evs:
+        assert ev["first_calls"] == 1 and "counters" not in ev
+        assert [p[0] for p in ev["phases"]] == (
+            MISS_PHASES if ev["kind"] == "executor.compile" else HIT_PHASES)
+        _tiles(ev)
+        assert abs(ev["t0"] - time.time()) < 60
+    assert evs[1]["trace_s"] > 0 and evs[1]["backend_s"] > 0
+    clean_ring.clear()
+    reads = _counting_clocks(monkeypatch)
+    call()  # the 17th, a hit
     assert reads == []
     assert clean_ring.events() == []
     assert monitor.default_registry().names() == []
     FLAGS.monitor = True
     call()
     assert "perf_counter_ns" in reads  # the probe does see the clock
+
+
+def test_each_executor_keeps_its_own_first_calls(clean_ring):
+    _, call = _train_net()
+    _past_the_first_calls(call)
+    call()
+    assert len(clean_ring.events()) == FIRST_CALLS
+    _, other = _train_net()  # start-up and its first step are recorded
+    other()
+    evs = clean_ring.events()[FIRST_CALLS:]
+    assert [(e["kind"], e["call"], e["first_calls"]) for e in evs] == [
+        ("executor.compile", 1, 1), ("executor.compile", 2, 1)]
+
+
+def test_a_miss_after_the_first_calls_is_recorded_from_the_miss_on(
+        clean_ring, monkeypatch):
+    _, call = _train_net()
+    _past_the_first_calls(call)
+    clean_ring.clear()
+    before = monitor.compile_phases()
+    call(5)
+    after = monitor.compile_phases()
+    (ev,) = clean_ring.events()
+    assert ev["kind"] == "executor.compile" and "first_calls" not in ev
+    assert ev["call"] == FIRST_CALLS + 1
+    assert [p[0] for p in ev["phases"]] == MISS_PHASES[2:]
+    _tiles(ev)
+    for k in before:
+        assert ev[k] == pytest.approx(after[k] - before[k], abs=2e-6), k
+    assert ev["jax_by_phase"]["dispatch"]["backend_s"] == pytest.approx(
+        ev["backend_s"], abs=2e-6)
+    # and the hit after it is silent again
+    reads = _counting_clocks(monkeypatch)
+    call(5)
+    assert reads == [] and len(clean_ring.events()) == 1
+
+
+def test_the_recorded_misses_sum_to_the_process_totals(clean_ring):
+    before = monitor.compile_phases()
+    _, call = _train_net()
+    _past_the_first_calls(call)
+    call(5)  # two misses past the first calls
+    call(6)
+    after = monitor.compile_phases()
+    misses = clean_ring.events(kind="executor.compile")
+    assert len(misses) == 4
+    for k in ("trace_s", "lower_s", "backend_s"):
+        total = after[k] - before[k]
+        # a hit re-traces the step key's fold_in (tens of microseconds)
+        assert sum(e[k] for e in misses) == pytest.approx(total, rel=0.01), k
+        assert sum(e[k] for e in misses) <= total + 1e-5
+
+
+def test_a_first_call_with_tracing_off_reads_no_device_counter(clean_ring,
+                                                               monkeypatch):
+    from paddle_tpu.core import executor as ex
+
+    prog, startup = pt.Program(), pt.Program()
+    with pt.program_guard(prog, startup):
+        x = layers.data(name="x", shape=[8], dtype="float32")
+        loss = layers.reduce_mean(layers.fc(x, size=1))
+        seen = layers.reduce_sum(x)
+        pt.optimizer.SGD(1e-2).minimize(loss)
+    monitor.device_counter(prog, "feed_sum", seen)
+    exe, scope = pt.Executor(pt.CPUPlace()), pt.Scope()
+    exe.run(startup, scope=scope)
+
+    class Numpy:
+        """core/executor.py's numpy, counting what it turns from a device
+        array into a host one."""
+        reads = 0
+
+        def __getattr__(self, name):
+            return getattr(np, name)
+
+        def asarray(self, v, *args, **kwargs):
+            Numpy.reads += isinstance(v, jax.Array)
+            return np.asarray(v, *args, **kwargs)
+
+    monkeypatch.setattr(ex, "np", Numpy())
+    feed = {"x": np.ones((STEPS, 4, 8), "float32")}
+    for _ in range(2):  # the miss and a hit, both in the first-calls record
+        assert exe.run_steps(prog, feed=feed, fetch_list=[],
+                             scope=scope) == []
+    assert Numpy.reads == 0
+    evs = clean_ring.events()
+    assert len(evs) == 3 and not any("counters" in e for e in evs)
+    FLAGS.monitor = True
+    exe.run_steps(prog, feed=feed, fetch_list=[], scope=scope)
+    assert Numpy.reads == 1
+    assert clean_ring.events()[-1]["counters"] == {"feed_sum": 32.0}
 
 
 # (c) ------------------------------------------------------------------------
@@ -422,6 +547,37 @@ def test_op_table_groups_by_scope_and_by_kernel(tmp_path, capsys):
     assert "Op type (scope)" in out and "31.6%" in out
     with pytest.raises(ValueError):
         profiler.xplane_op_table(str(tmp_path), by="nothing")
+
+
+def test_profiler_prints_the_executor_calls_it_ran(clean_ring, capsys):
+    from paddle_tpu import profiler
+
+    _, call = _train_net()
+    _past_the_first_calls(call)  # before the session: not in its table
+    profiler.start_profiler()
+    assert monitor.spans_on()  # a session with no jax trace turns them on
+    call(5)  # a miss
+    call(5)
+    call(5)
+    rows = profiler.stop_profiler(sorted_key="calls")
+    assert not monitor.spans_on()
+    table = {name: rest for name, *rest in rows}
+    assert sorted(table) == sorted(
+        ["executor.run_steps"] + ["executor." + p for p in MISS_PHASES])
+    assert table["executor.run_steps"][0] == 3
+    assert table["executor.compile"][0] == 1  # the miss's phase
+    assert [table["executor." + p][0] for p in HIT_PHASES] == [3] * 6
+    evs = clean_ring.events()[-3:]
+    calls, total, ave, mx = table["executor.dispatch"]
+    spent = [d for e in evs for n, _, d in e["phases"] if n == "dispatch"]
+    assert total == pytest.approx(sum(spent)) and mx == max(spent)
+    assert ave == pytest.approx(total / 3)
+    assert table["executor.run_steps"][1] == pytest.approx(
+        sum(e["dur"] for e in evs))
+    out = capsys.readouterr().out
+    assert out.splitlines()[0].startswith("Event") and len(
+        out.splitlines()) == 1 + len(rows)
+    assert "executor.run_steps" in out
 
 
 def test_trace_report_attribution_reads_the_phases():

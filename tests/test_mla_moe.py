@@ -881,7 +881,9 @@ def test_device_counters_ride_the_flight_event_only_while_tracing():
             kind="executor.run_steps")[n:]
 
     call()  # the miss
-    assert call() == []  # tracing off: no event, nothing read back
+    # tracing off: the first-calls record has the call, nothing read back
+    (event,) = call()
+    assert event["first_calls"] == 1 and "counters" not in event
     FLAGS.monitor = True
     try:
         (event,) = call()
