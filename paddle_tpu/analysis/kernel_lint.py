@@ -189,23 +189,27 @@ def check_attention_plan(cfg: dict, ok, block_q, block_k, interpret,
             label))
     dt = cfg["dtype"]
     if cfg["fmt"] == "bthd":
-        # whole-head kv tiles [block, h, d]: the plan gate caps blocks so
-        # the bwd working set fits; re-check with its own arithmetic
-        kv_tile = block_k * h * d * esize
-        if kv_tile > 256 * 1024:
+        # lane-dense operands [b, t, h*d] taken by head groups of 128
+        # lanes or a multiple: heads of 64 in pairs, or heads of 128*n
+        from ..kernels import attention as att
+
+        if att._bthd_group(h, d) is None:
             findings.append(_finding(
-                "kernel-vmem-budget",
-                f"bthd kv tile block_k*h*d = {kv_tile} bytes exceeds the "
-                f"256 KB per-tile bound the bwd kernel compiles under",
-                fam, label))
-        seq, kv, plane = (t, h, d), (block_k, h, d), (h, block_q, block_k)
-        stat = (h, t)
+                "kernel-misaligned-block",
+                f"bthd heads {h} x {d} make no group of 128 lanes (pairs "
+                f"of 64, or multiples of 128)", fam, label))
+            return
+        heads, lanes = att._bthd_group(h, d)
+        groups = att._bthd_step_groups(h, d, block_k, t, esize)
+        seq, kv = (t, groups * lanes), (block_k, groups * lanes)
+        plane, stat = (block_q, block_k), (groups * heads, 8, t)
     else:
         seq, kv, plane = (t, d), (block_k, d), (block_q, block_k)
         stat = (8, t)  # lse/delta ride 8 replicated sublanes
     # worst launch (the dkv walk): q + do pinned per grid row, lse +
     # delta rows, k/v in + dk/dv out tiles — every window moves with
-    # the grid — plus the s / p / dp score planes of the body
+    # the grid — plus the s / p / dp score planes of the body (one head
+    # at a time in a bthd group)
     used = _vmem_use(
         blocked=[(seq, dt)] * 2 + [(stat, "float32")] * 2 + [(kv, dt)] * 4,
         held=[(plane, "float32")] * 3)
@@ -679,12 +683,24 @@ _ATTENTION_MATRIX = [
     dict(label="ring-cp-chunk-bthd", b=2, h=8, t=128, d=64,
          dtype="float32", fmt="bthd"),
     # BERT-base's fused_qkv_attention sites under amp (their projections
-    # are XLA dots round these kernels since PR 30).  No such row for
-    # transformer-base (8 heads x 256 rows, bf16): the chip compiles its
-    # non-causal dkv walk at the default scope (PERF.md PR 28 (2)) where
-    # this file's model counts 18.9 MB
+    # are XLA dots round these kernels), at the cells' two lengths, and
+    # transformer-base's under amp: lane-dense group tiles put the dkv
+    # walk at 2.8-4.9 MB of the default scope by this file's model
     dict(label="bert-base-bf16-bthd", b=4, h=12, t=128, d=64,
          dtype="bfloat16", fmt="bthd"),
+    dict(label="bert-seq512-bf16-bthd", b=4, h=12, t=512, d=64,
+         dtype="bfloat16", fmt="bthd"),
+    dict(label="transformer-base-bf16-bthd", b=4, h=8, t=256, d=64,
+         dtype="bfloat16", fmt="bthd"),
+    # a group is one head of 128 lanes
+    dict(label="d128-bf16-bthd", b=2, h=8, t=512, d=128,
+         dtype="bfloat16", fmt="bthd"),
+    # no group of 128 lanes: an odd head count at 64, a head of 192 ->
+    # the XLA reference by design
+    dict(label="odd-heads-d64-bthd", b=2, h=3, t=256, d=64,
+         dtype="bfloat16", fmt="bthd", must_accept=False),
+    dict(label="d192-bthd", b=2, h=4, t=256, d=192,
+         dtype="float32", fmt="bthd", must_accept=False),
     # long-sequence flash leg (BENCH flash-attn workload)
     dict(label="flash-longseq", b=1, h=8, t=4096, d=64,
          dtype="float32", fmt="bhtd"),
@@ -698,11 +714,10 @@ _ATTENTION_MATRIX = [
     # query heads over 8 key/value heads of 64, rows of 4096
     dict(label="causal-gqa-d64-bf16", b=1, h=32, h_kv=8, t=4096, d=64,
          dtype="bfloat16", fmt="bhtd"),
-    # h*d*esize > 2048: even a 128-block kv tile busts the 256 KB bound —
-    # compiled mode must REJECT to XLA (the cap-floor regression class);
-    # if the gate ever re-accepts this, the kv-tile check fires
+    # 16 heads in f32: the whole-head tile's byte cap rejected it; a grid
+    # step of four groups holds 512 KB whole-sequence blocks
     dict(label="transformer-big-f32-bthd", b=2, h=16, t=256, d=64,
-         dtype="float32", fmt="bthd", must_accept=False),
+         dtype="float32", fmt="bthd"),
     # pipeline micro-batch shapes (parallel/pipeline): transformer-base
     # under pp splits runs the SAME flash kernels per stage on the
     # micro-batch slice — batch 32 / K=16 -> b=2, K=8 -> b=4 (covered by

@@ -20,6 +20,9 @@ Design (per pallas_guide.md):
   * additive bias is indexed per-block with broadcast-aware index maps
     ([B,1,1,Tk] padding masks and [B,1,Tq,Tk] causal+padding masks are read
     as-is — never broadcast-materialized to [B,H,Tq,Tk] in HBM).
+  * the [B,T,H,D] ("bthd") kernels take lane-dense [B,T,H*D] operands and
+    walk them a 128-lane head group at a time (two heads of 64, or one of
+    a multiple of 128), several groups a grid step where T is short.
 
 Falls back to a pure-XLA implementation off-TPU or for unaligned shapes.
 The bias gradient (trainable-bias case, e.g. relative-position biases) is
@@ -180,10 +183,10 @@ def _keep_tile_prng(seed_ref, shape, pid0, q_blk, k_blk, rate):
 
 
 def _keep_tile(seed, shape, head_base, tq, tk, q_lo, k_lo, rate):
-    """In-kernel dropout keep-mask for an attention-weights tile.
+    """In-kernel dropout keep-mask for a [bq, bk] attention-weights tile
+    of head `head_base` (b*H + h: the bhtd grid row, or the head a
+    lane-dense kernel walks).
 
-    shape [h, bq, bk] (whole-head bthd kernels; head_base = b*H) or
-    [bq, bk] (bhtd kernels; head_base = the grid's combined b*H + h index).
     The mask bit for logical element (b, h, q, k) is a pure function of
     (seed, b*H + h, q*Tk + k): the head coordinate folds into the seed
     (hash_rng.attn_head_seed — a flat index over [b*h, Tq, Tk] would wrap
@@ -203,20 +206,27 @@ def _keep_tile(seed, shape, head_base, tq, tk, q_lo, k_lo, rate):
     u32 = jnp.uint32
     q_lo = jnp.asarray(q_lo).astype(u32)
     k_lo = jnp.asarray(k_lo).astype(u32)
-    head_base = jnp.asarray(head_base).astype(u32)
-    if len(shape) == 3:
-        gh = head_base + jax.lax.broadcasted_iota(u32, shape, 0)
-        q_idx = q_lo + jax.lax.broadcasted_iota(u32, shape, 1)
-        k_idx = k_lo + jax.lax.broadcasted_iota(u32, shape, 2)
-    else:
-        gh = head_base
-        q_idx = q_lo + jax.lax.broadcasted_iota(u32, shape, 0)
-        k_idx = k_lo + jax.lax.broadcasted_iota(u32, shape, 1)
+    gh = jnp.asarray(head_base).astype(u32)
+    q_idx = q_lo + jax.lax.broadcasted_iota(u32, shape, 0)
+    k_idx = k_lo + jax.lax.broadcasted_iota(u32, shape, 1)
     # np.uint32 constants inline as jaxpr literals (jax Arrays would be
     # constvars, which a pallas_call refuses to lower)
     hseed = hash_rng.attn_head_seed(seed, gh)
     return hash_rng.keep_mask_tile(hseed, q_idx * np.uint32(tk) + k_idx,
                                    rate, fast=True)
+
+
+def _keep(seed_ref, head, q_blk, k_blk, block_q, block_k, seq_q, seq_k,
+          rate, hw_prng):
+    """The dropout keep-mask of one head's [block_q, block_k] tile at
+    (q block, k block), from the hardware PRNG or the hash; `head` is
+    b * H + the head's index (the bhtd grid row), so every kernel of
+    either family and the reference draw the same bits of a tile."""
+    if hw_prng:
+        return _keep_tile_prng(seed_ref, (block_q, block_k), head, q_blk,
+                               k_blk, rate)
+    return _keep_tile(seed_ref[0], (block_q, block_k), head, seq_q, seq_k,
+                      q_blk * block_q, k_blk * block_k, rate)
 
 
 # ---------------------------------------------------------------------------
@@ -232,18 +242,20 @@ def _keep_tile(seed, shape, head_base, tq, tk, q_lo, k_lo, rate):
 LSE_SUBLANES = 8
 
 
-def _read_bias(bias_ref, q_lo, block_q, k_lo, block_k, bias_q1):
+def _read_bias(bias_ref, q_lo, block_q, k_lo, block_k, bias_q1, head=None):
     """Slice a [block_q, block_k] (or [1, block_k]) bias tile from the
     kernel-local bias block (leading broadcast dims squeezed by the
-    BlockSpec).  `q_lo`/`k_lo` are offsets into the local block (already 0
-    when the BlockSpec pinned that dim)."""
+    BlockSpec; a block that keeps a head axis is read at `head`).
+    `q_lo`/`k_lo` are offsets into the local block (already 0 when the
+    BlockSpec pinned that dim)."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
+    at = () if head is None else (head,)
     if bias_q1:
-        b = bias_ref[:, pl.ds(k_lo, block_k)]  # [1, block_k]
+        b = bias_ref[(*at, slice(None), pl.ds(k_lo, block_k))]  # [1, bk]
     else:
-        b = bias_ref[pl.ds(q_lo, block_q), pl.ds(k_lo, block_k)]
+        b = bias_ref[(*at, pl.ds(q_lo, block_q), pl.ds(k_lo, block_k))]
     return b.astype(jnp.float32)
 
 
@@ -378,13 +390,8 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
         if drop_rate:
             # weights-dropout: l (the softmax normalizer) accumulates the
             # UNdropped p; only the value-accumulator sees the mask
-            if hw_prng:
-                keep = _keep_tile_prng(seed_ref, (block_q, block_k),
-                                       pid0, qi, j, drop_rate)
-            else:
-                keep = _keep_tile(seed_ref[0], (block_q, block_k),
-                                  pid0, seq_q, seq_k,
-                                  qi * block_q, j * block_k, drop_rate)
+            keep = _keep(seed_ref, pid0, qi, j, block_q, block_k, seq_q,
+                         seq_k, drop_rate, hw_prng)
             p = jnp.where(keep, p, 0.0)
         acc_new = acc * alpha[:, None] + p @ v
         return m_new, l_new, acc_new
@@ -445,13 +452,8 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                                      block_k, causal_offset, mask), p, 0.0)
         dp = do @ v.T  # [block_q, block_k]
         if drop_rate:
-            if hw_prng:
-                keep = _keep_tile_prng(seed_ref, (block_q, block_k),
-                                       pid0, qi, j, drop_rate)
-            else:
-                keep = _keep_tile(seed_ref[0], (block_q, block_k),
-                                  pid0, seq_q, seq_k,
-                                  qi * block_q, j * block_k, drop_rate)
+            keep = _keep(seed_ref, pid0, qi, j, block_q, block_k, seq_q,
+                         seq_k, drop_rate, hw_prng)
             dp = jnp.where(keep, dp * inv_keep, 0.0)
         ds = p * (dp - delta[:, None]) * scale
         return acc + ds @ k
@@ -504,13 +506,8 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                                      block_k, causal_offset, mask), p, 0.0)
         dp = do @ v.T
         if drop_rate:
-            if hw_prng:
-                keep = _keep_tile_prng(seed_ref, (block_q, block_k),
-                                       pid0, i, ki, drop_rate)
-            else:
-                keep = _keep_tile(seed_ref[0], (block_q, block_k),
-                                  pid0, seq_q, seq_k,
-                                  i * block_q, ki * block_k, drop_rate)
+            keep = _keep(seed_ref, pid0, i, ki, block_q, block_k, seq_q,
+                         seq_k, drop_rate, hw_prng)
             dv = dv + jnp.where(keep, p * inv_keep, 0.0).T @ do
             dp = jnp.where(keep, dp * inv_keep, 0.0)
         else:
@@ -550,43 +547,23 @@ def _plan(q, k, block_q, block_k, interpret, fmt="bhtd", v=None, mask=None):
     `mask` = (block_length, clean_offset) asks for the block-diffusion
     mask over [noisy ; clean]: bhtd, tq == tk == 2 * clean_offset, blocks
     that divide the half and a block length that divides it too, so that
-    a tile lies in one half.  The whole-head bthd kernels keep one head
-    size, one head count and no such mask."""
+    a tile lies in one half.  The lane-dense bthd kernels keep one head
+    size, one head count and no such mask, and take a head group of 128
+    lanes or a multiple (`_bthd_group`): heads of 64 in pairs, or heads of
+    a multiple of 128."""
     from .placement import resolve
 
     b, h, tq, d = _dims(q, fmt)
     hk, tk = _dims(k, fmt)[1:3]
     dv = d if v is None else _dims(v, fmt)[3]
-    if fmt == "bthd" and (dv != d or hk != h or mask is not None):
+    if fmt == "bthd" and (dv != d or hk != h or mask is not None
+                          or _bthd_group(h, d) is None):
         return False, 0, 0, resolve(interpret)[1]
     if h % hk:
         return False, 0, 0, resolve(interpret)[1]
     compiled, interpret = resolve(interpret)
     block_q = min(block_q, tq)
     block_k = min(block_k, tk)
-    if fmt == "bthd":
-        # whole-head blocks: each kv tile is [block, h, d] — cap the block
-        # so the bwd kernel's working set fits vmem (block=512 with
-        # h*d=512 bf16 fails to compile; 256 is the measured safe bound:
-        # 256 KB per kv tile).  The bound is in BYTES, so the cap scales
-        # with the dtype: the original hardcoded 2-byte element size let
-        # f32 tiles reach 512 KB (caught by the kernel plan linter,
-        # analysis/kernel_lint.py).  When even the smallest Mosaic-
-        # alignable block (128 lanes) busts the bound, compiled TPU mode
-        # must REJECT to the XLA fallback — flooring to 128 would re-admit
-        # the exact oversized-tile compile failure the cap exists for
-        # (interpret mode has no tile bound; keep the floor there so CPU
-        # tests still exercise the kernels).
-        import numpy as np
-
-        esize = np.dtype(q.dtype).itemsize
-        cap = (256 * 1024) // max(h * d * esize, 1)
-        if cap < 128:
-            if compiled:
-                return False, 0, 0, interpret
-            cap = 128
-        block_q = min(block_q, cap)
-        block_k = min(block_k, cap)
     if compiled:
         # Mosaic: lane-dim (last-dim) dynamic-slice offsets must be
         # 128-aligned; sublane offsets 8-aligned.  The backward kernels
@@ -649,35 +626,19 @@ def _bias_spec_and_arg(bias, b, h, tq, tk, block_q, block_k, for_dkv):
     return spec, bias, bias_q1
 
 
-def _qkv_specs(fmt, h, seq_mode_q, seq_mode_k, block_q, block_k, tq, tk, d,
+def _qkv_specs(seq_mode_q, seq_mode_k, block_q, block_k, tq, tk, d,
                group=1):
-    """BlockSpecs for q-like and k-like operands.
-
-    fmt "bhtd": arrays are pre-reshaped to [b*h, t, d]; grid axis 0 is bh.
-    With `group` > 1 the k-like operand is [b*h/group, t, d] and grid row
-    i reads its row i // group (b*h + head -> b*h_kv + head // group):
-    grouped-query attention without K or V at the query's head count.
-    fmt "bthd": arrays stay [b, t, h, d] — the layout the qkv projection
-    produces for free (reshape of [b, t, h*d] is a bitcast), so NO
-    transpose/relayout copy ever materializes at the custom-call boundary
-    (the round-3 profile showed ~5.5 GB/step of such copies).  Grid axis 0
-    is b; blocks cover ALL heads (Mosaic's (8,128) tiling forbids slicing
-    the second-minor h dim), and the whole-head kernels batch the matmuls
-    over h in-register.
+    """BlockSpecs for q-like and k-like operands of the bhtd kernels:
+    arrays pre-reshaped to [b*h, t, d], grid axis 0 is bh.  With `group` >
+    1 the k-like operand is [b*h/group, t, d] and grid row i reads its row
+    i // group (b*h + head -> b*h_kv + head // group): grouped-query
+    attention without K or V at the query's head count.
 
     seq_mode_*: "block" (one seq block, indexed by grid axis 1) or "full"
     (whole sequence pinned)."""
     from jax.experimental import pallas as pl
 
     def spec(seq_mode, block, t, group=1):
-        if fmt == "bthd":
-            if seq_mode == "block":
-                return pl.BlockSpec(
-                    (None, block, h, d), lambda i, j: (i, j, 0, 0)
-                )
-            return pl.BlockSpec(
-                (None, t, h, d), lambda i, j: (i, 0, 0, 0)
-            )
         row = (lambda i: i // group) if group > 1 else (lambda i: i)
         if seq_mode == "block":
             return pl.BlockSpec((None, block, d),
@@ -691,272 +652,466 @@ def _qkv_specs(fmt, h, seq_mode_q, seq_mode_k, block_q, block_k, tq, tk, d,
 
 
 # ---------------------------------------------------------------------------
-# Whole-head ("bthd") kernels: operands [b, t, h, d] with blocks covering
-# all heads; matmuls batch over h (Mosaic batched dot_general, batch dim 0)
-# after an in-register [t, h, d] -> [h, t, d] relayout — the relayout that
-# the bhtd path pays as an HBM transpose happens here for free in VMEM.
-# lse/delta ride as [b, h, tq] f32 (h fills the sublane tile exactly).
+# Lane-dense ("bthd") kernels.  q, k, v, the context and their cotangents
+# cross the kernels' boundary as [b, t, h*d], the free reshape of what the
+# projections write: no head axis to pad, in HBM or in VMEM (a [t, h, 64]
+# tile stores its heads in (16, 128) tiles, 2.67 x their bytes at 12
+# heads).  The grid is (batch row, run of `groups` head groups, sequence
+# block); a step walks its groups one after another in a fori_loop.  A
+# group is one 128-aligned lane slice: two heads of 64, or one head of a
+# multiple of 128.  The two heads of a group are told apart by lanes, not
+# by slices: q.k^T and dO.v^T contract all the group's lanes with the
+# other head's lanes of one operand zeroed, and p.v, ds.k, p^T.dO and
+# ds^T.q make one product a head over all the lanes and keep that head's
+# own — on a 128-wide MXU the passes of two dots of 64.  lse / delta ride
+# as [b, h, 8, t] f32 (q on the lanes, 8 replicated sublanes, as in the
+# bhtd family).
 # ---------------------------------------------------------------------------
 
+_GROUP_LANES = 128
+# a grid step holds the fewest head groups whose walks cover this many
+# (query, key) pairs, so that short sequences do not pay a grid step a
+# group ...
+_STEP_PAIRS = 512 * 512
+# ... and no more than keep a whole-sequence block within this many bytes
+_FULL_BLOCK_BYTES = 1 << 20
 
-def _bdot(a, b_, contract_a, contract_b):
-    """Batched-over-dim-0 dot: a [h, m, x], b_ [h, n, y] -> [h, m, n]."""
-    import jax
 
-    return jax.lax.dot_general(
-        a, b_, ((contract_a, contract_b), ((0,), (0,)))
-    )
+def _bthd_group(h, d):
+    """(heads, lanes) of a head group: two heads of 64 (h even) or one
+    head of a multiple of 128 lanes; None for any other shape, which the
+    plan sends to the XLA reference."""
+    if d == 64 and h % 2 == 0:
+        return 2, _GROUP_LANES
+    if d % _GROUP_LANES == 0:
+        return 1, d
+    return None
 
 
-def _bias_tile_f32(bias_ref, n_head, bias_h, bias_q1, block_q, q_lo,
-                   block_k, k_lo):
-    """Read the bias tile as f32 [h|1, q, k].  q-collapsed tiles are
-    expanded to [q, k] via an outer product with a ones column — Mosaic
-    miscompiles a sublane-extent-1 broadcast next to the batched matmuls
-    (`Check failed: limits[i] <= dim(i)`), while a dot lowers cleanly."""
-    import jax
-    import jax.numpy as jnp
+def _bthd_step_groups(h, d, block, full, esize):
+    """Head groups a grid step walks: the fewest (a divisor of all of
+    them) whose walks of `block` rows against `full` rows cover
+    _STEP_PAIRS pairs, while a whole-sequence block of `full` rows stays
+    within _FULL_BLOCK_BYTES."""
+    heads, lanes = _bthd_group(h, d)
+    n = h // heads
+    groups = 1
+    for g in range(2, n + 1):
+        if (groups * block * full >= _STEP_PAIRS
+                or g * lanes * full * esize > _FULL_BLOCK_BYTES):
+            break
+        if n % g == 0:
+            groups = g
+    return groups
+
+
+def _group_lanes(g, lanes):
+    """The lanes of head group g (traced) within a step's block."""
     from jax.experimental import pallas as pl
 
-    if bias_h:
-        if bias_q1:
-            t = bias_ref[:, :, pl.ds(k_lo, block_k)].astype(jnp.float32)
-            ones = jnp.ones((n_head, block_q, 1), jnp.float32)
-            return _bdot(ones, t, (2,), (1,))  # [h, q, k]
-        t = bias_ref[:, pl.ds(q_lo, block_q), pl.ds(k_lo, block_k)]
-        return t.astype(jnp.float32)
-    t = _read_bias(bias_ref, q_lo, block_q, k_lo, block_k, bias_q1)
-    if bias_q1:
-        ones = jnp.ones((block_q, 1), jnp.float32)
-        t = jax.lax.dot_general(ones, t, (((1,), (0,)), ((), ())))
-    return t[None]  # [1, q, k] broadcasts over heads (vreg replication)
+    return pl.ds(pl.multiple_of(g * lanes, _GROUP_LANES), lanes)
+
+
+def _upper_head(heads, lanes):
+    """[1, lanes] bool: the lanes of a group's second head (None for a
+    group of one head)."""
+    import jax
+    import jax.numpy as jnp
+
+    if heads == 1:
+        return None
+    return jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1) >= lanes // 2
+
+
+def _by_head(x, upper):
+    """A group's [rows, lanes] f32 tile as one operand a head: the other
+    head's lanes zeroed."""
+    import jax.numpy as jnp
+
+    if upper is None:
+        return (x,)
+    return jnp.where(upper, 0.0, x), jnp.where(upper, x, 0.0)
+
+
+def _join_heads(parts, upper):
+    """One [rows, lanes] tile of a value a head — a [rows, lanes] product
+    or a [rows] row statistic — each head's lanes from its own."""
+    import jax.numpy as jnp
+
+    parts = [p[:, None] if p.ndim == 1 else p for p in parts]
+    if upper is None:
+        return parts[0]
+    return jnp.where(upper, parts[1], parts[0])
 
 
 def _fwd_kernel_bthd(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
-                     lse_ref, *, scale, n_head, block_q, block_k, causal,
-                     seq_q, seq_k, causal_offset, bias_q1, bias_h,
-                     drop_rate, inv_keep, hw_prng=False):
+                     lse_ref, *, scale, n_head, head_dim, groups, block_q,
+                     block_k, causal, seq_q, seq_k, causal_offset, bias_q1,
+                     bias_h, drop_rate, inv_keep, hw_prng=False):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    qi = pl.program_id(1)
-    h = n_head
-    pid0h = pl.program_id(0) * h
-
-    q = q_ref[...].astype(jnp.float32).transpose(1, 0, 2) * scale  # [h,q,d]
-    d = q.shape[-1]
-    m = jnp.full((h, block_q), -jnp.inf, jnp.float32)
-    l = jnp.zeros((h, block_q), jnp.float32)
-    acc = jnp.zeros((h, block_q, d), jnp.float32)
-
+    qi = pl.program_id(2)
+    heads, lanes = _bthd_group(n_head, head_dim)
+    first = pl.program_id(0) * n_head + pl.program_id(1) * groups * heads
+    upper = _upper_head(heads, lanes)
     n_kv = seq_k // block_k
     if causal:
         hi = qi * block_q + block_q - 1 + causal_offset
         n_kv = jnp.minimum(n_kv, (hi // block_k) + 1)
 
-    def body(j, carry):
-        m, l, acc = carry
-        k = k_ref[pl.ds(j * block_k, block_k), :, :].astype(
-            jnp.float32).transpose(1, 0, 2)  # [h, k, d]
-        v = v_ref[pl.ds(j * block_k, block_k), :, :].astype(
-            jnp.float32).transpose(1, 0, 2)
-        s = _bdot(q, k, (2,), (2,))  # [h, q, k]
-        if bias_ref is not None:
-            s = s + _bias_tile_f32(bias_ref, h, bias_h, bias_q1,
-                                   block_q, 0, block_k, j * block_k)
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (h, block_q, block_k), 1
-            )
-            k_pos = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (h, block_q, block_k), 2
-            )
-            s = jnp.where(q_pos + causal_offset >= k_pos, s, -1e30)
-        m_new = jnp.maximum(m, s.max(axis=2))
-        p = jnp.exp(s - m_new[:, :, None])
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + p.sum(axis=2)
-        if drop_rate:
-            # weights-dropout: the normalizer l sees UNdropped p
-            if hw_prng:
-                keep = _keep_tile_prng(seed_ref, (h, block_q, block_k),
-                                       pid0h, qi, j, drop_rate)
-            else:
-                keep = _keep_tile(seed_ref[0], (h, block_q, block_k),
-                                  pid0h, seq_q, seq_k,
-                                  qi * block_q, j * block_k, drop_rate)
-            p = jnp.where(keep, p, 0.0)
-        acc_new = acc * alpha[:, :, None] + _bdot(p, v, (2,), (1,))
-        return m_new, l_new, acc_new
+    def group(g, carry):
+        cols = _group_lanes(g, lanes)
+        qs = _by_head(q_ref[:, cols].astype(jnp.float32) * scale, upper)
 
-    m, l, acc = jax.lax.fori_loop(0, n_kv, body, (m, l, acc))
-    masked = (l == 0.0) | (m <= -1e29)
-    l_safe = jnp.where(masked, 1.0, l)
-    if drop_rate:
-        acc = acc * inv_keep
-    o = jnp.where(masked[:, :, None], 0.0, acc / l_safe[:, :, None])
-    o_ref[...] = o.transpose(1, 0, 2).astype(o_ref.dtype)
-    lse_ref[...] = jnp.where(masked, jnp.inf, m + jnp.log(l_safe))
+        def body(j, carry):
+            m, l, acc = carry
+            k = k_ref[pl.ds(j * block_k, block_k), cols].astype(jnp.float32)
+            v = v_ref[pl.ds(j * block_k, block_k), cols].astype(jnp.float32)
+            if bias_ref is not None and not bias_h:
+                shared = _read_bias(bias_ref, 0, block_q, j * block_k,
+                                    block_k, bias_q1)
+            if causal:
+                visible = _tile_mask(qi * block_q, j * block_k, block_q,
+                                     block_k, causal_offset, None)
+            m_new, l_new, alpha, pv = [], [], [], []
+            for e in range(heads):
+                s = qs[e] @ k.T  # [block_q, block_k]
+                if bias_ref is not None:
+                    s = s + (_read_bias(bias_ref, 0, block_q, j * block_k,
+                                        block_k, bias_q1, g * heads + e)
+                             if bias_h else shared)
+                if causal:
+                    s = jnp.where(visible, s, -1e30)
+                m_new.append(jnp.maximum(m[e], s.max(axis=1)))
+                p = jnp.exp(s - m_new[e][:, None])
+                alpha.append(jnp.exp(m[e] - m_new[e]))
+                l_new.append(l[e] * alpha[e] + p.sum(axis=1))
+                if drop_rate:
+                    # weights-dropout: the normalizer l sees UNdropped p
+                    p = jnp.where(_keep(
+                        seed_ref, first + g * heads + e, qi, j, block_q,
+                        block_k, seq_q, seq_k, drop_rate, hw_prng), p, 0.0)
+                pv.append(p @ v)
+            acc = acc * _join_heads(alpha, upper) + _join_heads(pv, upper)
+            return tuple(m_new), tuple(l_new), acc
+
+        m, l, acc = jax.lax.fori_loop(0, n_kv, body, (
+            (jnp.full((block_q,), -jnp.inf, jnp.float32),) * heads,
+            (jnp.zeros((block_q,), jnp.float32),) * heads,
+            jnp.zeros((block_q, lanes), jnp.float32)))
+        # rows with no visible key: output 0 and lse = +inf (_fwd_kernel)
+        masked = [(l[e] == 0.0) | (m[e] <= -1e29) for e in range(heads)]
+        l_safe = [jnp.where(x, 1.0, y) for x, y in zip(masked, l)]
+        if drop_rate:
+            acc = acc * inv_keep
+        live = _join_heads([jnp.where(x, 0.0, 1.0) for x in masked], upper)
+        o_ref[:, cols] = jnp.where(
+            live > 0.0, acc / _join_heads(l_safe, upper), 0.0
+        ).astype(o_ref.dtype)
+        for e in range(heads):
+            lse = jnp.where(masked[e], jnp.inf, m[e] + jnp.log(l_safe[e]))
+            lse_ref[g * heads + e] = jnp.broadcast_to(
+                lse[None, :], (LSE_SUBLANES, block_q))
+        return carry
+
+    jax.lax.fori_loop(0, groups, group, 0)
 
 
 def _bwd_dq_kernel_bthd(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
                         lse_ref, delta_ref, dq_ref, *, scale, n_head,
-                        block_q, block_k, causal, seq_q, seq_k,
-                        causal_offset, bias_q1, bias_h, drop_rate, inv_keep,
-                        hw_prng=False):
+                        head_dim, groups, block_q, block_k, causal, seq_q,
+                        seq_k, causal_offset, bias_q1, bias_h, drop_rate,
+                        inv_keep, hw_prng=False):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    qi = pl.program_id(1)
-    h = n_head
-    pid0h = pl.program_id(0) * h
-
-    q = q_ref[...].astype(jnp.float32).transpose(1, 0, 2)   # [h, q, d]
-    do = do_ref[...].astype(jnp.float32).transpose(1, 0, 2)
-    lse = lse_ref[...]      # [h, block_q] f32
-    delta = delta_ref[...]
-    d = q.shape[-1]
-    acc = jnp.zeros((h, block_q, d), jnp.float32)
-
+    qi = pl.program_id(2)
+    heads, lanes = _bthd_group(n_head, head_dim)
+    first = pl.program_id(0) * n_head + pl.program_id(1) * groups * heads
+    upper = _upper_head(heads, lanes)
     n_kv = seq_k // block_k
     if causal:
         hi = qi * block_q + block_q - 1 + causal_offset
         n_kv = jnp.minimum(n_kv, (hi // block_k) + 1)
 
-    def body(j, acc):
-        k = k_ref[pl.ds(j * block_k, block_k), :, :].astype(
-            jnp.float32).transpose(1, 0, 2)
-        v = v_ref[pl.ds(j * block_k, block_k), :, :].astype(
-            jnp.float32).transpose(1, 0, 2)
-        s = _bdot(q, k, (2,), (2,)) * scale
-        if bias_ref is not None:
-            s = s + _bias_tile_f32(bias_ref, h, bias_h, bias_q1,
-                                   block_q, 0, block_k, j * block_k)
-        p = jnp.exp(s - lse[:, :, None])
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (h, block_q, block_k), 1
-            )
-            k_pos = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (h, block_q, block_k), 2
-            )
-            p = jnp.where(q_pos + causal_offset >= k_pos, p, 0.0)
-        dp = _bdot(do, v, (2,), (2,))  # [h, q, k]
-        if drop_rate:
-            if hw_prng:
-                keep = _keep_tile_prng(seed_ref, (h, block_q, block_k),
-                                       pid0h, qi, j, drop_rate)
-            else:
-                keep = _keep_tile(seed_ref[0], (h, block_q, block_k),
-                                  pid0h, seq_q, seq_k,
-                                  qi * block_q, j * block_k, drop_rate)
-            dp = jnp.where(keep, dp * inv_keep, 0.0)
-        ds = p * (dp - delta[:, :, None]) * scale
-        return acc + _bdot(ds, k, (2,), (1,))
+    def group(g, carry):
+        cols = _group_lanes(g, lanes)
+        qs = _by_head(q_ref[:, cols].astype(jnp.float32), upper)
+        dos = _by_head(do_ref[:, cols].astype(jnp.float32), upper)
+        lse = [lse_ref[g * heads + e, 0, :] for e in range(heads)]
+        delta = [delta_ref[g * heads + e, 0, :] for e in range(heads)]
 
-    acc = jax.lax.fori_loop(0, n_kv, body, acc)
-    dq_ref[...] = acc.transpose(1, 0, 2).astype(dq_ref.dtype)
+        def body(j, acc):
+            k = k_ref[pl.ds(j * block_k, block_k), cols].astype(jnp.float32)
+            v = v_ref[pl.ds(j * block_k, block_k), cols].astype(jnp.float32)
+            if bias_ref is not None and not bias_h:
+                shared = _read_bias(bias_ref, 0, block_q, j * block_k,
+                                    block_k, bias_q1)
+            if causal:
+                visible = _tile_mask(qi * block_q, j * block_k, block_q,
+                                     block_k, causal_offset, None)
+            parts = []
+            for e in range(heads):
+                s = (qs[e] @ k.T) * scale
+                if bias_ref is not None:
+                    s = s + (_read_bias(bias_ref, 0, block_q, j * block_k,
+                                        block_k, bias_q1, g * heads + e)
+                             if bias_h else shared)
+                p = jnp.exp(s - lse[e][:, None])
+                if causal:
+                    p = jnp.where(visible, p, 0.0)
+                dp = dos[e] @ v.T  # [block_q, block_k]
+                if drop_rate:
+                    keep = _keep(seed_ref, first + g * heads + e, qi, j,
+                                      block_q, block_k, seq_q, seq_k,
+                                      drop_rate, hw_prng)
+                    dp = jnp.where(keep, dp * inv_keep, 0.0)
+                ds = p * (dp - delta[e][:, None]) * scale
+                parts.append(ds @ k)
+            return acc + _join_heads(parts, upper)
+
+        acc = jax.lax.fori_loop(0, n_kv, body,
+                                jnp.zeros((block_q, lanes), jnp.float32))
+        dq_ref[:, cols] = acc.astype(dq_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, groups, group, 0)
 
 
 def _bwd_dkv_kernel_bthd(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
                          lse_ref, delta_ref, dk_ref, dv_ref, *, scale,
-                         n_head, block_q, block_k, causal, seq_q, seq_k,
-                         causal_offset, bias_q1, bias_h, drop_rate,
-                         inv_keep, hw_prng=False):
+                         n_head, head_dim, groups, block_q, block_k, causal,
+                         seq_q, seq_k, causal_offset, bias_q1, bias_h,
+                         drop_rate, inv_keep, hw_prng=False):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    ki = pl.program_id(1)
-    h = n_head
-    pid0h = pl.program_id(0) * h
-
-    k = k_ref[...].astype(jnp.float32).transpose(1, 0, 2)  # [h, k, d]
-    v = v_ref[...].astype(jnp.float32).transpose(1, 0, 2)
-    d = k.shape[-1]
-    dk = jnp.zeros((h, block_k, d), jnp.float32)
-    dv = jnp.zeros((h, block_k, d), jnp.float32)
-
+    ki = pl.program_id(2)
+    heads, lanes = _bthd_group(n_head, head_dim)
+    first = pl.program_id(0) * n_head + pl.program_id(1) * groups * heads
+    upper = _upper_head(heads, lanes)
     n_q = seq_q // block_q
     lo = 0
     if causal:
-        lo_pos = ki * block_k - causal_offset
-        lo = jnp.maximum(lo_pos // block_q, 0)
+        lo = jnp.maximum((ki * block_k - causal_offset) // block_q, 0)
 
-    def body(i, carry):
-        dk, dv = carry
-        q = q_ref[pl.ds(i * block_q, block_q), :, :].astype(
-            jnp.float32).transpose(1, 0, 2)  # [h, q, d]
-        do = do_ref[pl.ds(i * block_q, block_q), :, :].astype(
-            jnp.float32).transpose(1, 0, 2)
-        lse = lse_ref[:, pl.ds(i * block_q, block_q)]    # [h, q]
-        delta = delta_ref[:, pl.ds(i * block_q, block_q)]
-        s = _bdot(q, k, (2,), (2,)) * scale  # [h, q, k]
-        if bias_ref is not None:
-            s = s + _bias_tile_f32(bias_ref, h, bias_h, bias_q1,
-                                   block_q, i * block_q, block_k, 0)
-        p = jnp.exp(s - lse[:, :, None])
-        if causal:
-            q_pos = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (h, block_q, block_k), 1
-            )
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (h, block_q, block_k), 2
-            )
-            p = jnp.where(q_pos + causal_offset >= k_pos, p, 0.0)
-        dp = _bdot(do, v, (2,), (2,))        # [h, q, k]
-        if drop_rate:
-            if hw_prng:
-                keep = _keep_tile_prng(seed_ref, (h, block_q, block_k),
-                                       pid0h, i, ki, drop_rate)
-            else:
-                keep = _keep_tile(seed_ref[0], (h, block_q, block_k),
-                                  pid0h, seq_q, seq_k,
-                                  i * block_q, ki * block_k, drop_rate)
-            dv = dv + _bdot(jnp.where(keep, p * inv_keep, 0.0), do,
-                            (1,), (1,))      # [h, k, d]
-            dp = jnp.where(keep, dp * inv_keep, 0.0)
-        else:
-            dv = dv + _bdot(p, do, (1,), (1,))   # [h, k, d]
-        ds = p * (dp - delta[:, :, None]) * scale
-        dk = dk + _bdot(ds, q, (1,), (1,))   # [h, k, d]
-        return dk, dv
+    def group(g, carry):
+        cols = _group_lanes(g, lanes)
+        ks = _by_head(k_ref[:, cols].astype(jnp.float32), upper)
+        vs = _by_head(v_ref[:, cols].astype(jnp.float32), upper)
 
-    dk, dv = jax.lax.fori_loop(lo, n_q, body, (dk, dv))
-    dk_ref[...] = dk.transpose(1, 0, 2).astype(dk_ref.dtype)
-    dv_ref[...] = dv.transpose(1, 0, 2).astype(dv_ref.dtype)
+        def body(i, carry):
+            dk, dv = carry
+            rows = pl.ds(i * block_q, block_q)
+            q = q_ref[rows, cols].astype(jnp.float32)
+            do = do_ref[rows, cols].astype(jnp.float32)
+            if bias_ref is not None and not bias_h:
+                shared = _read_bias(bias_ref, i * block_q, block_q, 0,
+                                    block_k, bias_q1)
+            if causal:
+                visible = _tile_mask(i * block_q, ki * block_k, block_q,
+                                     block_k, causal_offset, None)
+            dks, dvs = [], []
+            for e in range(heads):
+                lse = lse_ref[g * heads + e, 0, rows]
+                delta = delta_ref[g * heads + e, 0, rows]
+                s = (q @ ks[e].T) * scale
+                if bias_ref is not None:
+                    s = s + (_read_bias(bias_ref, i * block_q, block_q, 0,
+                                        block_k, bias_q1, g * heads + e)
+                             if bias_h else shared)
+                p = jnp.exp(s - lse[:, None])
+                if causal:
+                    p = jnp.where(visible, p, 0.0)
+                dp = do @ vs[e].T  # [block_q, block_k]
+                if drop_rate:
+                    keep = _keep(seed_ref, first + g * heads + e, i, ki,
+                                      block_q, block_k, seq_q, seq_k,
+                                      drop_rate, hw_prng)
+                    dvs.append(jnp.where(keep, p * inv_keep, 0.0).T @ do)
+                    dp = jnp.where(keep, dp * inv_keep, 0.0)
+                else:
+                    dvs.append(p.T @ do)
+                ds = p * (dp - delta[:, None]) * scale
+                dks.append(ds.T @ q)
+            return (dk + _join_heads(dks, upper),
+                    dv + _join_heads(dvs, upper))
+
+        zeros = jnp.zeros((block_k, lanes), jnp.float32)
+        dk, dv = jax.lax.fori_loop(lo, n_q, body, (zeros, zeros))
+        dk_ref[:, cols] = dk.astype(dk_ref.dtype)
+        dv_ref[:, cols] = dv.astype(dv_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, groups, group, 0)
 
 
-def _bias_spec_bthd(bias, b, h, block_q, block_k, for_dkv):
-    """BlockSpec for the bias on the whole-head grid (axis 0 = batch).
-    Returns (spec, bias_q1, bias_h): bias_h marks a per-head bias (kernel
-    tile [h, q, k]); otherwise leading dims squeeze to a [q, k] tile."""
+def _bthd_specs(n_head, head_dim, groups):
+    """(rows, stats): BlockSpecs on the lane-dense grid (batch row, run of
+    `groups` head groups, sequence block).  rows(n, full) is n rows of a
+    [b, t, h*d] operand — at the step's sequence block, or from row 0
+    where `full` — in the run's lanes; stats(n, full) the same rows of a
+    [b, h, 8, t] statistic, the run's heads."""
+    from jax.experimental import pallas as pl
+
+    heads, lanes = _bthd_group(n_head, head_dim)
+
+    def rows(n, full=False):
+        return pl.BlockSpec((None, n, groups * lanes),
+                            lambda i, g, j: (i, 0 if full else j, g))
+
+    def stats(n, full=False):
+        return pl.BlockSpec((None, groups * heads, LSE_SUBLANES, n),
+                            lambda i, g, j: (i, g, 0, 0 if full else j))
+
+    return rows, stats
+
+
+def _bias_spec_bthd(bias, heads_a_step, block_q, block_k, for_dkv):
+    """BlockSpec of the [Bb, Hb, Tqb, Tk] bias on the lane-dense grid: the
+    step's batch row (or 0), the run's heads of a per-head bias (else the
+    axis squeezes away), and the step's q block against all keys (one row
+    where Tqb is 1), or (for_dkv) all rows against the step's key block.
+    Returns (spec, bias_q1, bias_h)."""
     from jax.experimental import pallas as pl
 
     bb, hb, tqb, tkb = bias.shape
-    bias_q1 = tqb == 1
-    bias_h = hb > 1
+    bias_q1, bias_h = tqb == 1, hb > 1
 
-    def ib(i):
-        return i if bb > 1 else 0
+    def at(i, g, q, k):
+        return (i if bb > 1 else 0, g if bias_h else 0, q, k)
 
-    hdim = hb if bias_h else None
+    hdim = heads_a_step if bias_h else None
     if for_dkv:
-        qdim = 1 if bias_q1 else tqb
-        spec = pl.BlockSpec(
-            (None, hdim, qdim, block_k), lambda i, j: (ib(i), 0, 0, j)
-        )
+        spec = pl.BlockSpec((None, hdim, tqb, block_k),
+                            lambda i, g, j: at(i, g, 0, j))
     elif bias_q1:
-        spec = pl.BlockSpec(
-            (None, hdim, 1, tkb), lambda i, j: (ib(i), 0, 0, 0)
-        )
+        spec = pl.BlockSpec((None, hdim, 1, tkb),
+                            lambda i, g, j: at(i, g, 0, 0))
     else:
-        spec = pl.BlockSpec(
-            (None, hdim, block_q, tkb), lambda i, j: (ib(i), 0, j, 0)
-        )
+        spec = pl.BlockSpec((None, hdim, block_q, tkb),
+                            lambda i, g, j: at(i, g, j, 0))
     return spec, bias_q1, bias_h
+
+
+def _bias_slot(kern, bias):
+    """The kernel as pallas_call calls it: `kern`, or without a bias
+    operand `kern` with None in its bias slot (the fifth ref)."""
+    if bias is not None:
+        return kern
+
+    def kernel(*refs):
+        return kern(*refs[:4], None, *refs[4:])
+
+    return kernel
+
+
+def _bthd_forward(q, k, v, bias, seed, scale, causal, block_q, block_k,
+                  interpret, drop_rate, inv_keep, hw_prng):
+    """(out [b, tq, h, d], lse [b, h, tq] f32) by flash_bthd_fwd on the
+    lane-dense operands."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    b, tq, h, d = q.shape
+    tk = k.shape[1]
+    heads, _ = _bthd_group(h, d)
+    groups = _bthd_step_groups(h, d, block_q, tk, q.dtype.itemsize)
+    rows, stats = _bthd_specs(h, d, groups)
+    in_specs = [_seed_spec(), rows(block_q), rows(tk, True), rows(tk, True)]
+    args = [seed] + [a.reshape(b, a.shape[1], h * d) for a in (q, k, v)]
+    bias_q1 = bias_h = False
+    if bias is not None:
+        spec, bias_q1, bias_h = _bias_spec_bthd(bias, groups * heads,
+                                                block_q, block_k, False)
+        in_specs.append(spec)
+        args.append(bias)
+    kernel = _bias_slot(functools.partial(
+        _fwd_kernel_bthd, scale=scale, n_head=h, head_dim=d, groups=groups,
+        block_q=block_q, block_k=block_k, causal=causal, seq_q=tq,
+        seq_k=tk, causal_offset=tk - tq, bias_q1=bias_q1, bias_h=bias_h,
+        drop_rate=drop_rate, inv_keep=inv_keep, hw_prng=hw_prng), bias)
+    out, lse = pl.pallas_call(
+        kernel,
+        name="flash_bthd_fwd",
+        grid=(b, h // heads // groups, tq // block_q),
+        in_specs=in_specs,
+        out_specs=[rows(block_q), stats(block_q)],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, tq, h * d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, LSE_SUBLANES, tq), jnp.float32),
+        ],
+        interpret=interpret,
+    )(*args)
+    return out.reshape(q.shape), lse[:, :, 0, :]
+
+
+def _bthd_backward(q, k, v, bias, seed, o, lse, g, scale, causal, block_q,
+                   block_k, interpret, drop_rate, inv_keep, hw_prng):
+    """(dq, dk, dv) in [b, t, h, d] by flash_bthd_bwd_dq (grid over q
+    blocks) and flash_bthd_bwd_dkv (grid over key blocks) on the
+    lane-dense operands."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    b, tq, h, d = q.shape
+    tk = k.shape[1]
+    heads, _ = _bthd_group(h, d)
+    # delta = rowsum(dO * O) a head: [b, tq, h] -> [b, h, tq] (tiny f32)
+    delta = jnp.sum(
+        g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
+    ).transpose(0, 2, 1)
+    operands = [a.reshape(b, a.shape[1], h * d) for a in (q, k, v, g)]
+    stat_args = [jnp.broadcast_to(s[:, :, None, :], (b, h, LSE_SUBLANES, tq))
+                 for s in (lse, delta)]
+
+    def call(for_dkv, outs):
+        block, full = (block_k, tq) if for_dkv else (block_q, tk)
+        groups = _bthd_step_groups(h, d, block, full, q.dtype.itemsize)
+        rows, stats = _bthd_specs(h, d, groups)
+        if for_dkv:
+            q_spec, k_spec, stat = rows(tq, True), rows(block_k), stats(
+                tq, True)
+        else:
+            q_spec, k_spec, stat = rows(block_q), rows(tk, True), stats(
+                block_q)
+        in_specs = [_seed_spec(), q_spec, k_spec, k_spec, q_spec, stat, stat]
+        args = [seed, *operands, *stat_args]
+        bias_q1 = bias_h = False
+        if bias is not None:
+            spec, bias_q1, bias_h = _bias_spec_bthd(
+                bias, groups * heads, block_q, block_k, for_dkv)
+            in_specs.insert(4, spec)
+            args.insert(4, bias)
+        kernel = _bias_slot(functools.partial(
+            _bwd_dkv_kernel_bthd if for_dkv else _bwd_dq_kernel_bthd,
+            scale=scale, n_head=h, head_dim=d, groups=groups,
+            block_q=block_q, block_k=block_k, causal=causal, seq_q=tq,
+            seq_k=tk, causal_offset=tk - tq, bias_q1=bias_q1,
+            bias_h=bias_h, drop_rate=drop_rate, inv_keep=inv_keep,
+            hw_prng=hw_prng), bias)
+        out_spec = k_spec if for_dkv else q_spec
+        return pl.pallas_call(
+            kernel,
+            name="flash_bthd_bwd_dkv" if for_dkv else "flash_bthd_bwd_dq",
+            grid=(b, h // heads // groups,
+                  tk // block_k if for_dkv else tq // block_q),
+            in_specs=in_specs,
+            out_specs=[out_spec] * len(outs),
+            out_shape=[jax.ShapeDtypeStruct(
+                (b, a.shape[1], h * d), a.dtype) for a in outs],
+            interpret=interpret,
+        )(*args)
+
+    dq, = call(False, [q])
+    dk, dv = call(True, [k, v])
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 def _drop_params(dropout_rate):
@@ -981,27 +1136,6 @@ def _use_hw_prng(drop_rate, interpret):
     from .placement import resolve
 
     return resolve(interpret)[0] and FLAGS.tpu_prng_dropout
-
-
-def _bthd_bwd_params(t, h, d, esize, causal):
-    """Mosaic parameters of the whole-head backward kernels: 32 MiB of
-    scoped VMEM (v5e: 128 MiB physical, 16 MiB default scope; the decode
-    kernels ask the same) where the default has been seen refused, the
-    default elsewhere.  A walk's working set stands near the default (q
-    and dO, or k and v, whole beside the score planes of all heads), and
-    XLA parks operands of its own in the same scope: at 16 MiB the dkv
-    walk was refused at 8 heads x 256 rows with causal=True (17.49 MB:
-    two position planes more; chip) and at BERT-base 32 x 512 (18.50 MB;
-    compile-only client).  So: causal, or more than 512 KiB held whole
-    (transformer-base's 256 x 8 x 64 in bf16 is at it).  Not for every
-    call: the scope a kernel reserves is VMEM that XLA's fusions round
-    it lose, 3.0 ms of transformer-base's 78.4 ms step with all 18 sites
-    raised (PERF.md, PR 28)."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    if causal or 2 * t * h * d * esize > 512 * 1024:
-        return pltpu.CompilerParams(vmem_limit_bytes=32 * 1024 * 1024)
-    return None
 
 
 def _seed_spec():
@@ -1029,49 +1163,15 @@ def _flash_forward(q, k, v, bias, seed, scale, causal, block_q, block_k,
     bh, group = b * h, h // hk
     drop_rate, inv_keep = _drop_params(dropout_rate)
     hw_prng = allow_hw_prng and _use_hw_prng(drop_rate, interpret)
-    q_spec, kv_spec = _qkv_specs(fmt, h, "block", "full", block_q, block_k,
-                                 tq, tk, d, group)
     if fmt == "bthd":
-        args = [seed, q, k, v]
-        in_specs = [_seed_spec(), q_spec, kv_spec, kv_spec]
-        bias_q1 = bias_h = False
-        if bias is not None:
-            spec, bias_q1, bias_h = _bias_spec_bthd(
-                bias, b, h, block_q, block_k, for_dkv=False)
-            in_specs.append(spec)
-            args.append(bias)
-        kern = functools.partial(
-            _fwd_kernel_bthd, scale=scale, n_head=h, block_q=block_q,
-            block_k=block_k, causal=causal, seq_q=tq, seq_k=tk,
-            causal_offset=tk - tq, bias_q1=bias_q1, bias_h=bias_h,
-            drop_rate=drop_rate, inv_keep=inv_keep, hw_prng=hw_prng,
-        )
-        if bias is None:
-            def kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref):
-                return kern(seed_ref, q_ref, k_ref, v_ref, None, o_ref,
-                            lse_ref)
-        else:
-            kernel = kern
-        out, lse = pl.pallas_call(
-            kernel,
-            name="flash_bthd_fwd",
-            grid=(b, tq // block_q),
-            in_specs=in_specs,
-            out_specs=[
-                q_spec,
-                pl.BlockSpec((None, h, block_q), lambda i, j: (i, 0, j)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((b, tq, h, d), q.dtype),
-                jax.ShapeDtypeStruct((b, h, tq), jnp.float32),
-            ],
-            interpret=interpret,
-        )(*args)
-        return out, lse
-
+        return _bthd_forward(q, k, v, bias, seed, scale, causal, block_q,
+                             block_k, interpret, drop_rate, inv_keep,
+                             hw_prng)
+    q_spec, kv_spec = _qkv_specs("block", "full", block_q, block_k, tq, tk,
+                                 d, group)
     dv = v.shape[-1]
-    o_spec, v_spec = _qkv_specs(fmt, h, "block", "full", block_q, block_k,
-                                tq, tk, dv, group)
+    o_spec, v_spec = _qkv_specs("block", "full", block_q, block_k, tq, tk,
+                                dv, group)
     args = [seed, q.reshape(bh, tq, d), k.reshape(bh // group, tk, d),
             v.reshape(bh // group, tk, dv)]
     in_specs = [_seed_spec(), q_spec, kv_spec, v_spec]
@@ -1095,14 +1195,8 @@ def _flash_forward(q, k, v, bias, seed, scale, causal, block_q, block_k,
         bias_q1=bias_q1, drop_rate=drop_rate, inv_keep=inv_keep,
         hw_prng=hw_prng, mask=mask,
     )
-    if bias is None:
-        def kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref):
-            return kern(seed_ref, q_ref, k_ref, v_ref, None, o_ref, lse_ref)
-    else:
-        kernel = kern
-
     out, lse = pl.pallas_call(
-        kernel,
+        _bias_slot(kern, bias),
         name="flash_bhtd_fwd",
         grid=(bh, tq // block_q),
         in_specs=in_specs,
@@ -1145,89 +1239,9 @@ def _flash_backward(q, k, v, bias, seed, o, lse, g, scale, causal, block_q,
     hw_prng = allow_hw_prng and _use_hw_prng(drop_rate, interpret)
 
     if fmt == "bthd":
-        bwd_params = _bthd_bwd_params(max(tq, tk), h, d, q.dtype.itemsize,
-                                      causal)
-        # delta[i] = rowsum(dO * O) -> [b, tq, h] -> [b, h, tq] (tiny f32)
-        delta = jnp.sum(
-            g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
-        ).transpose(0, 2, 1)
-        lse_spec_q = pl.BlockSpec((None, h, block_q), lambda i, j: (i, 0, j))
-        lse_spec_full = pl.BlockSpec((None, h, tq), lambda i, j: (i, 0, 0))
-
-        q_spec, kv_spec = _qkv_specs(fmt, h, "block", "full", block_q,
-                                     block_k, tq, tk, d)
-        in_specs = [_seed_spec(), q_spec, kv_spec, kv_spec, q_spec,
-                    lse_spec_q, lse_spec_q]
-        args = [seed, q, k, v, g, lse, delta]
-        bias_q1 = bias_h = False
-        if bias is not None:
-            spec, bias_q1, bias_h = _bias_spec_bthd(
-                bias, b, h, block_q, block_k, for_dkv=False)
-            in_specs.insert(4, spec)
-            args.insert(4, bias)
-        dq_kern = functools.partial(
-            _bwd_dq_kernel_bthd, scale=scale, n_head=h, block_q=block_q,
-            block_k=block_k, causal=causal, seq_q=tq, seq_k=tk,
-            causal_offset=causal_offset, bias_q1=bias_q1, bias_h=bias_h,
-            drop_rate=drop_rate, inv_keep=inv_keep, hw_prng=hw_prng,
-        )
-        if bias is None:
-            def dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                          delta_ref, dq_ref):
-                return dq_kern(seed_ref, q_ref, k_ref, v_ref, None, do_ref,
-                               lse_ref, delta_ref, dq_ref)
-        else:
-            dq_kernel = dq_kern
-        dq = pl.pallas_call(
-            dq_kernel,
-            name="flash_bthd_bwd_dq",
-            grid=(b, tq // block_q),
-            in_specs=in_specs,
-            out_specs=q_spec,
-            out_shape=jax.ShapeDtypeStruct((b, tq, h, d), q.dtype),
-            compiler_params=bwd_params,
-            interpret=interpret,
-        )(*args)
-
-        qfull_spec, kblock_spec = _qkv_specs(fmt, h, "full", "block",
-                                             block_q, block_k, tq, tk, d)
-        in_specs = [_seed_spec(), qfull_spec, kblock_spec, kblock_spec,
-                    qfull_spec, lse_spec_full, lse_spec_full]
-        args = [seed, q, k, v, g, lse, delta]
-        bias_q1 = bias_h = False
-        if bias is not None:
-            spec, bias_q1, bias_h = _bias_spec_bthd(
-                bias, b, h, block_q, block_k, for_dkv=True)
-            in_specs.insert(4, spec)
-            args.insert(4, bias)
-        dkv_kern = functools.partial(
-            _bwd_dkv_kernel_bthd, scale=scale, n_head=h, block_q=block_q,
-            block_k=block_k, causal=causal, seq_q=tq, seq_k=tk,
-            causal_offset=causal_offset, bias_q1=bias_q1, bias_h=bias_h,
-            drop_rate=drop_rate, inv_keep=inv_keep, hw_prng=hw_prng,
-        )
-        if bias is None:
-            def dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                           delta_ref, dk_ref, dv_ref):
-                return dkv_kern(seed_ref, q_ref, k_ref, v_ref, None, do_ref,
-                                lse_ref, delta_ref, dk_ref, dv_ref)
-        else:
-            dkv_kernel = dkv_kern
-        dk, dv = pl.pallas_call(
-            dkv_kernel,
-            name="flash_bthd_bwd_dkv",
-            grid=(b, tk // block_k),
-            in_specs=in_specs,
-            out_specs=[kblock_spec, kblock_spec],
-            out_shape=[
-                jax.ShapeDtypeStruct((b, tk, h, d), k.dtype),
-                jax.ShapeDtypeStruct((b, tk, h, d), v.dtype),
-            ],
-            compiler_params=bwd_params,
-            interpret=interpret,
-        )(*args)
-        return dq, dk, dv
-
+        return _bthd_backward(q, k, v, bias, seed, o, lse, g, scale, causal,
+                              block_q, block_k, interpret, drop_rate,
+                              inv_keep, hw_prng)
     dv_ = v.shape[-1]  # the value head size: v, o, g and dv carry it
     hk = k.shape[1]
     group = h // hk
@@ -1249,10 +1263,10 @@ def _flash_backward(q, k, v, bias, seed, o, lse, g, scale, causal, block_q,
         (None, LSE_SUBLANES, tq), lambda i, j: (i, 0, 0)
     )
     # ---- dQ: grid over q blocks -----------------------------------------
-    q_spec, kv_spec = _qkv_specs(fmt, h, "block", "full", block_q, block_k,
-                                 tq, tk, d, group)
-    g_spec, v_spec = _qkv_specs(fmt, h, "block", "full", block_q, block_k,
-                                tq, tk, dv_, group)
+    q_spec, kv_spec = _qkv_specs("block", "full", block_q, block_k, tq, tk,
+                                 d, group)
+    g_spec, v_spec = _qkv_specs("block", "full", block_q, block_k, tq, tk,
+                                dv_, group)
     in_specs = [_seed_spec(), q_spec, kv_spec, v_spec, g_spec,
                 _lse_spec_q, _lse_spec_q]
     args = [seed, args3[0], args3[1], args3[2], args3[3], lse3, delta3]
@@ -1270,16 +1284,8 @@ def _flash_backward(q, k, v, bias, seed, o, lse, g, scale, causal, block_q,
         bias_q1=bias_q1, drop_rate=drop_rate, inv_keep=inv_keep,
         hw_prng=hw_prng, mask=mask,
     )
-    if bias is None:
-        def dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                      delta_ref, dq_ref):
-            return dq_kern(seed_ref, q_ref, k_ref, v_ref, None, do_ref,
-                           lse_ref, delta_ref, dq_ref)
-    else:
-        dq_kernel = dq_kern
-
     dq = pl.pallas_call(
-        dq_kernel,
+        _bias_slot(dq_kern, bias),
         name="flash_bhtd_bwd_dq",
         grid=(bh, tq // block_q),
         in_specs=in_specs,
@@ -1289,15 +1295,14 @@ def _flash_backward(q, k, v, bias, seed, o, lse, g, scale, causal, block_q,
     )(*args)
 
     # ---- dK/dV: grid over kv blocks -------------------------------------
-    qfull_spec, kblock_spec = _qkv_specs(fmt, h, "full", "block", block_q,
-                                         block_k, tq, tk, d, group)
-    gfull_spec, vblock_spec = _qkv_specs(fmt, h, "full", "block", block_q,
-                                         block_k, tq, tk, dv_, group)
+    qfull_spec, kblock_spec = _qkv_specs("full", "block", block_q, block_k,
+                                         tq, tk, d, group)
+    gfull_spec, vblock_spec = _qkv_specs("full", "block", block_q, block_k,
+                                         tq, tk, dv_, group)
     # dk, dv: one row a QUERY head (the group's parts are summed below)
-    _, dk_spec = _qkv_specs(fmt, h, "full", "block", block_q, block_k, tq,
-                            tk, d)
-    _, dv_spec = _qkv_specs(fmt, h, "full", "block", block_q, block_k, tq,
-                            tk, dv_)
+    _, dk_spec = _qkv_specs("full", "block", block_q, block_k, tq, tk, d)
+    _, dv_spec = _qkv_specs("full", "block", block_q, block_k, tq, tk,
+                            dv_)
     in_specs = [_seed_spec(), qfull_spec, kblock_spec, vblock_spec,
                 gfull_spec, _lse_spec_full, _lse_spec_full]
     args = [seed, args3[0], args3[1], args3[2], args3[3], lse3, delta3]
@@ -1315,16 +1320,8 @@ def _flash_backward(q, k, v, bias, seed, o, lse, g, scale, causal, block_q,
         bias_q1=bias_q1, drop_rate=drop_rate, inv_keep=inv_keep,
         hw_prng=hw_prng, mask=mask,
     )
-    if bias is None:
-        def dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                       delta_ref, dk_ref, dv_ref):
-            return dkv_kern(seed_ref, q_ref, k_ref, v_ref, None, do_ref,
-                            lse_ref, delta_ref, dk_ref, dv_ref)
-    else:
-        dkv_kernel = dkv_kern
-
     dk, dv = pl.pallas_call(
-        dkv_kernel,
+        _bias_slot(dkv_kern, bias),
         name="flash_bhtd_bwd_dkv",
         grid=(bh, tk // block_k),
         in_specs=in_specs,
@@ -1547,6 +1544,16 @@ def _vjp_of_pair(fwd, bwd, kept):
     return attn
 
 
+def _note_site(fmt, route):
+    """A bthd site's forward, as it is traced inside an Executor call,
+    counts among the compile totals under its route: `bthd_sites_kernel`
+    or `bthd_sites_xla` (monitor.compile_phases)."""
+    if fmt == "bthd":
+        from ..monitor import flight
+
+        flight.note_compile_count(route)
+
+
 def _flash_kernels(q, k, v, bias, scale=1.0, causal=False, block_q=512,
                    block_k=512, interpret=None, fmt="bhtd",
                    dropout_rate=0.0, dropout_seed=None, trainable_bias=True,
@@ -1571,15 +1578,20 @@ def _flash_kernels(q, k, v, bias, scale=1.0, causal=False, block_q=512,
     norm = _bias_norm(bias, b, h, tq, tk) if ok else None
     if norm is None:
         ref = _reference_bthd if fmt == "bthd" else reference_attention
-        return seed, (lambda q, k, v, bias, seed: ref(
-            q, k, v, bias, scale, causal, dropout_rate, seed,
-            mask)), None, None
+
+        def fwd(q, k, v, bias, seed):
+            _note_site(fmt, "bthd_sites_xla")
+            return ref(q, k, v, bias, scale, causal, dropout_rate, seed,
+                       mask)
+
+        return seed, fwd, None, None
     # dropout + consumed bias gradient: the dbias recompute hashes its
     # mask, so the kernels must hash too (see trainable_bias docstring);
     # without a bias the hardware-PRNG path is fully enabled
     allow_hw = not (dropout_rate and trainable_bias and bias is not None)
 
     def fwd(q, k, v, bias, seed):
+        _note_site(fmt, "bthd_sites_kernel")
         return _flash_forward(q, k, v, bias, seed, scale, causal, bq, bk,
                               interp, fmt, dropout_rate,
                               allow_hw_prng=allow_hw, mask=mask)
@@ -1614,13 +1626,14 @@ def _flash_kernels(q, k, v, bias, scale=1.0, causal=False, block_q=512,
 #                                      checkpoints interop bit-for-bit)
 #   w_out   [h*dh, d_model]          — the output projection
 #   y       [b, t, d_model]
-# Every dot reads or writes [b, t, h, dh], the layout the bthd kernels take,
-# through free reshapes of the weights ([dm, 3, h, dh] / [h, dh, dm]): no
-# [b, t, 3*h*dh] array is made, sliced or concatenated in either direction
-# (that glue was 6-14 % of a site's backward, PERF.md PR 28 (1)).  q, k, v,
-# the context and the logsumexp are the forward's residuals: the backward
-# runs the bthd backward kernels on them between the projections' backward
-# dots and recomputes nothing.
+# Every dot reads or writes [b, t, h*dh], the lane-dense layout the bthd
+# kernels take, against a column block of the packed weight ([dm, 3, h*dh])
+# or the output weight as it is: no [b, t, 3*h*dh] array is made, sliced or
+# concatenated in either direction, and no [b, t, h, dh] array is stored
+# (the [b, t, h, dh] the functions hand over are reshapes that fold away).
+# q, k, v, the context and the logsumexp are the forward's residuals: the
+# backward runs the bthd backward kernels on them between the projections'
+# backward dots and recomputes nothing.
 #
 # Until PR 30 a Pallas kernel (`fused_qkv_fwd`) computed the projections tile
 # by tile inside the attention walk, so that q, k, v never reached HBM.  It
@@ -1631,17 +1644,14 @@ def _flash_kernels(q, k, v, bias, scale=1.0, causal=False, block_q=512,
 # ---------------------------------------------------------------------------
 
 
-def _qkv_weight_views(w_qkv, w_out, n_head):
-    """The packed weights as the per-head operands of the projection
-    einsums: w_qkv as [dm, 3, h, dh], w_out as [h, dh, dm_out]."""
-    dm = w_qkv.shape[0]
+def _qkv_weight_view(w_qkv, n_head):
+    """The packed weight as the operand of the projection einsums:
+    [dm, 3, h*dh], q | k | v."""
     if w_qkv.shape[1] % (3 * n_head):
         raise ValueError(
             f"flash_qkv_attention: packed dim {w_qkv.shape[1]} not "
             f"divisible by 3*n_head={3 * n_head}")
-    dh = w_qkv.shape[1] // (3 * n_head)
-    return (w_qkv.reshape(dm, 3, n_head, dh),
-            w_out.reshape(n_head, dh, w_out.shape[1]))
+    return w_qkv.reshape(w_qkv.shape[0], 3, w_qkv.shape[1] // 3)
 
 
 def flash_qkv_attention(x, w_qkv, w_out, bias=None, n_head=1, **options):
@@ -1664,11 +1674,13 @@ def flash_qkv_attention_fwd(x, w_qkv, w_out, bias=None, n_head=1,
     (ops/fused_ops.py)."""
     import jax.numpy as jnp
 
-    w4, wo3 = _qkv_weight_views(w_qkv, w_out, n_head)
-    q, k, v = (jnp.einsum("btm,mhd->bthd", x, w4[:, i]).astype(x.dtype)
-               for i in range(3))
+    w3 = _qkv_weight_view(w_qkv, n_head)
+    heads = x.shape[:2] + (n_head, w3.shape[2] // n_head)
+    q, k, v = (jnp.einsum("btm,mn->btn", x, w3[:, i]).astype(x.dtype)
+               .reshape(heads) for i in range(3))
     ctx, lse = flash_attention_fwd(q, k, v, bias, fmt="bthd", **options)
-    y = jnp.einsum("bthd,hdm->btm", ctx, wo3).astype(x.dtype)
+    y = jnp.einsum("btn,nm->btm", ctx.reshape(x.shape[:2] + (-1,)),
+                   w_out).astype(x.dtype)
     return y, q, k, v, ctx, lse
 
 
@@ -1682,19 +1694,23 @@ def flash_qkv_attention_bwd(x, w_qkv, w_out, bias, q, k, v, ctx, lse, g,
     rejects the operands (the forward then gave no lse either)."""
     import jax.numpy as jnp
 
-    w4, wo3 = _qkv_weight_views(w_qkv, w_out, n_head)
-    dctx = jnp.einsum("btm,hdm->bthd", g, wo3).astype(x.dtype)
-    grads = flash_attention_bwd(q, k, v, bias, ctx, lse, dctx, fmt="bthd",
+    w3 = _qkv_weight_view(w_qkv, n_head)
+
+    def flat(a):  # [b, t, h, dh] -> [b, t, h*dh]: a bitcast
+        return a.reshape(x.shape[:2] + (-1,))
+
+    dctx = jnp.einsum("btm,nm->btn", g, w_out).astype(x.dtype)
+    grads = flash_attention_bwd(q, k, v, bias, ctx, lse,
+                                dctx.reshape(q.shape), fmt="bthd",
                                 **options)
     if grads is None:
         return None
     *dqkv, dbias = grads
-    dw_out = jnp.einsum("bthd,btm->hdm", ctx, g).reshape(
-        w_out.shape).astype(w_out.dtype)
-    dx = sum(jnp.einsum("bthd,mhd->btm", d, w4[:, i],
+    dw_out = jnp.einsum("btn,btm->nm", flat(ctx), g).astype(w_out.dtype)
+    dx = sum(jnp.einsum("btn,mn->btm", flat(d), w3[:, i],
                         preferred_element_type=jnp.float32)
              for i, d in enumerate(dqkv)).astype(x.dtype)
     dw_qkv = jnp.stack(
-        [jnp.einsum("btm,bthd->mhd", x, d) for d in dqkv],
+        [jnp.einsum("btm,btn->mn", x, flat(d)) for d in dqkv],
         axis=1).reshape(w_qkv.shape).astype(w_qkv.dtype)
     return dx, dw_qkv, dw_out, dbias

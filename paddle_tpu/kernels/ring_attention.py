@@ -114,9 +114,9 @@ def _chunk_fwd(q, k, v, kbias, scale, causal, block_q, block_k,
                fmt="bhtd"):
     """One ring step's partial attention: Pallas flash kernel when the
     plan allows, XLA chunk otherwise.  Returns (o, lse') with the -inf
-    empty-row convention.  fmt "bthd" runs the whole-head kernels on the
-    SAME block specs as the single-device path (attention.py _qkv_specs)
-    — the per-device shards stay [b, t_local, h, d] and no split-head
+    empty-row convention.  fmt "bthd" runs the lane-dense kernels of the
+    single-device path (attention.py `_bthd_forward`) — the per-device
+    shards stay [b, t_local, h, d] and no split-head
     transpose exists anywhere on the ring (the relayout-copy class the
     bthd kernels were built to kill); only the XLA fallback transposes."""
     from .attention import _flash_forward
@@ -331,7 +331,7 @@ def ring_attention(q, k, v, axis_name, scale=1.0, causal=False, kbias=None,
     """Runs INSIDE shard_map: q,k,v are the per-device sequence shards
     [b, h, t_local, d] (fmt "bhtd") or [b, t_local, h, d] (fmt "bthd" —
     the transpose-free convention: the ring path reuses the single-device
-    bthd whole-head block specs, so context parallelism does not
+    lane-dense bthd kernels, so context parallelism does not
     re-introduce the split/merge-head transposes the bthd kernels
     deleted); optional kbias [b|1, 1, 1, t_local] is an additive key bias
     (padding mask) that travels the ring with its K/V chunk.  Exact

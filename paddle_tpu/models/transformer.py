@@ -124,8 +124,8 @@ def multi_head_attention(
 
     if use_ring:
         # context-parallel path on the same transpose-free convention:
-        # the ring chunks reuse the single-device bthd whole-head block
-        # specs (kernels/ring_attention.py) — CP re-introduces NO
+        # the ring chunks reuse the single-device lane-dense bthd kernels
+        # (kernels/ring_attention.py) — CP re-introduces NO
         # split/merge-head transposes
         from ..layers.contrib import ring_attention
 

@@ -407,10 +407,13 @@ _COMPILE_COUNTS = {
 # the pairs of the whole square, summed over the sites traced
 # (kernels/attention.py `bd_tiles_visited`); and the short_conv sites
 # lowered to the Pallas kernel pair / to the XLA composition
-# (kernels/short_conv.py)
+# (kernels/short_conv.py); and the bthd flash_attention sites whose forward
+# was traced on the lane-dense kernels / on the XLA reference
+# (kernels/attention.py `_note_site`)
 _TRACE_COUNTS = ("grad_direct", "grad_generic", "qkv_bwd_composed",
                  "attn_tiles_visited", "attn_tiles_total",
-                 "short_conv_sites_kernel", "short_conv_sites_xla")
+                 "short_conv_sites_kernel", "short_conv_sites_xla",
+                 "bthd_sites_kernel", "bthd_sites_xla")
 _compile_totals: Dict[str, float] = dict.fromkeys(
     list(_COMPILE_DURATIONS.values()) + list(_COMPILE_COUNTS.values())
     + list(_TRACE_COUNTS), 0)
@@ -498,7 +501,9 @@ def compile_phases() -> Dict[str, float]:
     masked attention walks (their ratio is the share of the score square
     the kernels visit), and `short_conv_sites_kernel` /
     `short_conv_sites_xla` of the short_conv ops lowered to the Pallas
-    kernels / to the XLA composition."""
+    kernels / to the XLA composition, and `bthd_sites_kernel` /
+    `bthd_sites_xla` of the bthd flash_attention sites traced on the
+    lane-dense kernels / on the XLA reference."""
     with _compile_lock:
         return dict(_compile_totals)
 

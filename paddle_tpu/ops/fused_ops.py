@@ -150,9 +150,9 @@ def lower_fused_qkv_attention(ctx, ins):
     (kernels/attention.py flash_qkv_attention): X [b, t, d_model], WQkv
     [d_model, 3*n_head*d_head] (the layers.fc packed layout), WOut
     [n_head*d_head, d_model], optional additive Bias.  The q, k, v and
-    output projections are XLA dots that read and write [b, t, h, dh]
-    straight, round the bthd flash forward kernel; the fc + split +
-    fused_attention + reshape + fc chain it stands for slices a
+    output projections are XLA dots that read and write the lane-dense
+    [b, t, h*dh] straight, round the bthd flash forward kernel; the fc +
+    split + fused_attention + reshape + fc chain it stands for slices a
     [b, t, 3*h*dh] array and concatenates its gradient.  (Until PR 30 a
     Pallas kernel ran the projections inside the attention walk, at a
     third of the peak: PERF.md PR 28 (3), PR 30.)  Dropout

@@ -252,10 +252,16 @@ def test_flash_kernels_with_a_value_head_size_of_their_own(dqk, dv):
 
 
 def test_whole_head_flash_plan_keeps_one_head_size():
-    q = jax.ShapeDtypeStruct((2, 256, 4, 192), jnp.float32)
-    v = jax.ShapeDtypeStruct((2, 256, 4, 128), jnp.float32)
+    # the lane-dense bthd plan keeps one head size and takes 128-lane
+    # groups: d_qk 128 with d_v 64 is refused, 128 with 128 taken; MLA's
+    # d_qk 192 makes no group and runs the reference (the bhtd plan takes
+    # it, with d_v 128)
+    q = jax.ShapeDtypeStruct((2, 256, 4, 128), jnp.float32)
+    v = jax.ShapeDtypeStruct((2, 256, 4, 64), jnp.float32)
     assert not A._plan(q, q, 128, 128, True, "bthd", v)[0]
     assert A._plan(q, q, 128, 128, True, "bthd", q)[0]
+    q192 = jax.ShapeDtypeStruct((2, 256, 4, 192), jnp.float32)
+    assert not A._plan(q192, q192, 128, 128, True, "bthd", q192)[0]
     qh = jax.ShapeDtypeStruct((2, 4, 256, 192), jnp.float32)
     vh = jax.ShapeDtypeStruct((2, 4, 256, 128), jnp.float32)
     assert A._plan(qh, qh, 128, 128, True, "bhtd", vh)[0]

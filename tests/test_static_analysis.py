@@ -185,9 +185,10 @@ class TestRedGate:
         assert any("128-lane" in f.message for f in findings)
 
     def test_kernel_vmem_budget_named(self):
-        # a bthd plan whose whole-head kv tile exceeds the 256 KB bound
-        # the backward kernels compile under
-        cfg = dict(label="seeded-vmem", b=1, h=16, t=2048, d=128,
+        # a bthd plan whose dkv walk holds q and dO whole past the default
+        # scope: two [8192, 128] f32 blocks, double-buffered, are 16 MiB
+        # alone (the whole-head tile's 256 KB bound went with that tile)
+        cfg = dict(label="seeded-vmem", b=1, h=16, t=8192, d=128,
                    dtype="float32", fmt="bthd")
         findings = []
         kernel_lint.check_attention_plan(cfg, True, 128, 128, False,
@@ -414,25 +415,25 @@ class TestNoFalsePositives:
         acc = {r["label"]: r.get("accepted") for r in report["attention"]}
         assert acc["transformer-base-f32"] and acc["bert-base-bf16"]
         assert acc["transformer-base-bthd"]
-        # BERT-base's fused_qkv_attention sites: bthd under amp
-        assert acc["bert-base-bf16-bthd"]
+        # BERT-base's fused_qkv_attention sites at both cell lengths and
+        # transformer-base's: bthd under amp
+        assert acc["bert-base-bf16-bthd"] and acc["bert-seq512-bf16-bthd"]
+        assert acc["transformer-base-bf16-bthd"]
 
     def test_attention_bthd_f32_cap_is_dtype_aware(self):
-        """Regression for the linter's first real catch: the bthd kv-tile
-        cap must scale with dtype (f32 tiles at the bf16 cap reached
-        512 KB)."""
-        import jax
-
+        """Regression for the linter's first real catch, a byte bound that
+        must scale with the dtype.  The bthd plan's bound is now on the
+        whole-sequence block of a grid step's head groups (the kv-tile cap
+        went with the whole-head tile): f32 takes fewer groups a step than
+        bf16 where the bound binds, and neither passes it."""
         from paddle_tpu.kernels import attention as att
 
-        q32 = jax.ShapeDtypeStruct((2, 256, 8, 64), np.float32)
-        q16 = jax.ShapeDtypeStruct((2, 256, 8, 64), np.dtype("float16"))
-        with kernel_lint._pretend_tpu():
-            _, bq32, bk32, _ = att._plan(q32, q32, 512, 512, False, "bthd")
-            _, bq16, bk16, _ = att._plan(q16, q16, 512, 512, False, "bthd")
-        assert bk32 * 8 * 64 * 4 <= 256 * 1024
-        assert bk16 * 8 * 64 * 2 <= 256 * 1024
-        assert bk16 >= bk32  # wider dtype -> tighter cap
+        t, block = 1536, 128
+        g32 = att._bthd_step_groups(16, 64, block, t, 4)
+        g16 = att._bthd_step_groups(16, 64, block, t, 2)
+        assert g32 * 128 * t * 4 <= att._FULL_BLOCK_BYTES
+        assert g16 * 128 * t * 2 <= att._FULL_BLOCK_BYTES
+        assert g16 > g32  # wider dtype -> fewer groups a step
 
 
 # ---------------------------------------------------------------------------

@@ -188,20 +188,35 @@ def test_causal_tile_masks_change_no_bit(tq, tk, bq, bk, monkeypatch):
         np.testing.assert_array_equal(mine, before)
 
 
-@pytest.mark.parametrize(
-    "name,tq,tk,bias_shape,causal",
-    [
-        ("plain", 128, 128, None, False),
-        ("causal", 128, 128, None, True),
-        ("full_bias", 128, 128, (2, 2, 128, 128), False),
-        ("pad_mask_bias", 128, 128, (2, 1, 1, 128), False),
-        ("cross", 64, 128, None, False),
-    ],
-)
-def test_flash_attention_bthd_format(name, tq, tk, bias_shape, causal):
-    """The transpose-free [B,T,H,D] calling convention must match the
-    [B,H,T,D] reference in outputs AND gradients (it is the layout the
-    bench transformer runs)."""
+#: name -> (tq, tk, heads, head size, bias shape, causal, dropout rate):
+#: the lane-dense kernels' two group layouts (pairs of heads of 64, one
+#: head of 128), walks of one to four blocks, the cells' bias kinds (a
+#: [b, 1, 1, t] padding row, a [b, 1, t, t] plane, a per-head bias), tq != tk
+#: both ways under causal (rows that see no key), and hash dropout
+_BTHD_CASES = {
+    "plain": (128, 128, 2, 64, None, False, 0.0),
+    "causal": (128, 128, 2, 64, None, True, 0.0),
+    "full_bias": (128, 128, 2, 64, (2, 2, 128, 128), False, 0.0),
+    "pad_mask_bias": (128, 128, 2, 64, (2, 1, 1, 128), False, 0.0),
+    "cross": (64, 128, 2, 64, None, False, 0.0),
+    "h8_t256_plane": (256, 256, 8, 64, (2, 1, 256, 256), False, 0.0),
+    "h12_t512_pad": (512, 512, 12, 64, (2, 1, 1, 512), False, 0.0),
+    "h12_t128_head_bias": (128, 128, 12, 64, (1, 12, 1, 128), False, 0.0),
+    "h8_d128_causal_cross": (128, 256, 8, 128, None, True, 0.0),
+    "h12_d128_t512_pad": (512, 512, 12, 128, (2, 1, 1, 512), False, 0.0),
+    "h8_causal_empty_rows": (256, 128, 8, 64, None, True, 0.0),
+    "h8_t256_hash_dropout": (256, 256, 8, 64, (2, 1, 1, 256), False, 0.2),
+    "h12_d128_causal_dropout": (128, 128, 12, 128, None, True, 0.1),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_BTHD_CASES))
+def test_flash_attention_bthd_format(name):
+    """The transpose-free [B,T,H,D] calling convention (the lane-dense
+    kernels, interpret mode) must match the [B,H,T,D] reference in
+    outputs AND gradients — dQ, dK, dV and the bias's — at the head
+    counts and sizes the cells run (it is the layout the bench transformer
+    and BERT run)."""
     import jax
     import jax.numpy as jnp
 
@@ -210,11 +225,13 @@ def test_flash_attention_bthd_format(name, tq, tk, bias_shape, causal):
         reference_attention,
     )
 
-    d = 64
+    tq, tk, h, d, bias_shape, causal, rate = _BTHD_CASES[name]
+    block = 64 if max(tq, tk) <= 128 else 128
+    seed = jnp.asarray([41], jnp.uint32)
     rng = np.random.RandomState(3)
-    q = jnp.asarray(rng.randn(2, 2, tq, d).astype("float32"))
-    k = jnp.asarray(rng.randn(2, 2, tk, d).astype("float32"))
-    v = jnp.asarray(rng.randn(2, 2, tk, d).astype("float32"))
+    q = jnp.asarray(rng.randn(2, h, tq, d).astype("float32"))
+    k = jnp.asarray(rng.randn(2, h, tk, d).astype("float32"))
+    v = jnp.asarray(rng.randn(2, h, tk, d).astype("float32"))
     args = (q, k, v)
     if bias_shape is not None:
         args = args + (jnp.asarray(
@@ -224,27 +241,29 @@ def test_flash_attention_bthd_format(name, tq, tk, bias_shape, causal):
     def loss_ref(*a):
         bias = a[3] if len(a) > 3 else None
         out = reference_attention(a[0], a[1], a[2], bias, scale=scale,
-                                  causal=causal)
+                                  causal=causal, dropout_rate=rate,
+                                  dropout_seed=seed)
         return jnp.sum(out * jnp.cos(out))
 
-    def loss_bthd(*a):
+    def bthd(*a):
         bias = a[3] if len(a) > 3 else None
-        out = flash_attention(
+        return flash_attention(
             a[0].transpose(0, 2, 1, 3), a[1].transpose(0, 2, 1, 3),
             a[2].transpose(0, 2, 1, 3), bias, scale=scale, causal=causal,
-            block_q=64, block_k=64, fmt="bthd")
+            block_q=block, block_k=block, fmt="bthd", dropout_rate=rate,
+            dropout_seed=seed)
+
+    def loss_bthd(*a):
+        out = bthd(*a)
         return jnp.sum(out * jnp.cos(out))
 
     argnums = tuple(range(len(args)))
     with jax.default_matmul_precision("highest"):
-        out_b = flash_attention(
-            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3),
-            args[3] if len(args) > 3 else None,
-            scale=scale, causal=causal, block_q=64, block_k=64, fmt="bthd")
+        out_b = bthd(*args)
         out_r = reference_attention(q, k, v,
                                     args[3] if len(args) > 3 else None,
-                                    scale=scale, causal=causal)
+                                    scale=scale, causal=causal,
+                                    dropout_rate=rate, dropout_seed=seed)
         np.testing.assert_allclose(
             np.asarray(out_b.transpose(0, 2, 1, 3)), np.asarray(out_r),
             atol=1e-5, err_msg=name)
@@ -255,6 +274,40 @@ def test_flash_attention_bthd_format(name, tq, tk, bias_shape, causal):
         np.testing.assert_allclose(
             np.asarray(gb), np.asarray(gr), atol=2e-4, rtol=1e-3,
             err_msg=name)
+
+
+@pytest.mark.parametrize("h,d,route", [
+    (8, 64, "kernel"),   # four groups of two heads of 64
+    (2, 128, "kernel"),  # a group is one head of 128
+    (3, 64, "xla"),      # an odd head count at 64 makes no pairs
+    (2, 192, "xla"),     # neither 64 nor a multiple of 128 lanes
+])
+def test_bthd_sites_counted_by_route(h, d, route):
+    """A bthd site counts among the compile totals as its forward is
+    traced inside an Executor call: `bthd_sites_kernel` where the plan
+    takes its head group, `bthd_sites_xla` where it rejects the shape,
+    and the rejected site computes the reference."""
+    import jax.numpy as jnp
+
+    from paddle_tpu import monitor
+    from paddle_tpu.kernels.attention import _reference_bthd, flash_attention
+    from paddle_tpu.monitor import flight
+
+    rng = np.random.RandomState(h + d)
+    q, k, v = (jnp.asarray(rng.randn(2, 128, h, d).astype("float32"))
+               for _ in range(3))
+    before = monitor.compile_phases()
+    with flight.executor_call():
+        out = flash_attention(q, k, v, None, scale=d ** -0.5, block_q=64,
+                              block_k=64, fmt="bthd")
+    after = monitor.compile_phases()
+    counted = tuple(after[f"bthd_sites_{r}"] - before[f"bthd_sites_{r}"]
+                    for r in ("kernel", "xla"))
+    assert counted == ((1, 0) if route == "kernel" else (0, 1))
+    np.testing.assert_allclose(
+        np.asarray(out),
+        np.asarray(_reference_bthd(q, k, v, None, d ** -0.5, False)),
+        atol=1e-5)
 
 
 @pytest.mark.parametrize(
