@@ -351,12 +351,32 @@ def check_short_conv_plan(cfg: dict, ok, rows, chunk, interpret,
             f"{used} bytes exceed the default scoped VMEM", fam, label))
 
 
+def kda_blocks(cfg: dict) -> Dict[str, tuple]:
+    """{kernel: (blocked, held)} of the KDA pair (kernels/kda.py) at one
+    grid step (row, head, chunk): [chunk, dk] blocks of q, k, v and o (the
+    forward), of q, k, v, dO, dq, dk, dv (the backward), g and dg float32,
+    beta and dbeta as [1, chunk] lane rows, the chunk's first state [dv,
+    dk] and its factors [chunk, chunk] float32 (written by the forward,
+    read by the backward); held: the [dv, dk] carry in VMEM scratch and
+    the backward's float32 temporaries ([chunk, chunk] matrices, [chunk,
+    dk] operands), which bound the forward's."""
+    chunk, dk, dt = cfg["chunk"], cfg["dk"], cfg["dtype"]
+    rows, rows32 = ((chunk, dk), dt), ((chunk, dk), "float32")
+    lane, state = ((1, chunk), "float32"), ((dk, dk), "float32")
+    factors = ((chunk, chunk), "float32")
+    held = ([((dk, dk), "float32")] * 4 + [((chunk, chunk), "float32")] * 8
+            + [((chunk, dk), "float32")] * 16)
+    return {
+        "kda_fwd": ([rows] * 4 + [rows32, lane, state, factors], held),
+        "kda_bwd": ([rows] * 7 + [rows32] * 2 + [lane] * 2
+                    + [state, factors], held),
+    }
+
+
 def check_kda_plan(cfg: dict, ok, interpret, findings: List[Finding]):
     """Kimi Delta Attention plan (kernels/kda.py _plan): grid (row, head,
-    chunk), a [chunk, 128] block of q, k, v, g (float32), dO and the four
-    gradients a grid step, beta as a [1, chunk] lane row, the [dv, dk]
-    float32 state in VMEM scratch, and the chunk's algebra as float32
-    temporaries ([chunk, chunk] matrices, [chunk, 128] operands)."""
+    chunk), each kernel's blocks and temporaries as `kda_blocks` lists
+    them, within the default scoped VMEM.  Returns {kernel: bytes}."""
     fam, label = "kda", cfg["label"]
     t, chunk, dk = cfg["t"], cfg["chunk"], cfg["dk"]
     if cfg.get("must_accept", True) and not ok:
@@ -366,24 +386,22 @@ def check_kda_plan(cfg: dict, ok, interpret, findings: List[Finding]):
             f"heads of {dk} — the model would silently run the XLA "
             f"composition", fam, label))
     if not ok:
-        return
+        return {}
     if chunk % 16 or dk % _LANE:
         findings.append(_finding(
             "kernel-grid-divisibility",
             f"chunk={chunk} / head {dk} do not tile in (16,{_LANE}) tiles",
             fam, label))
-    dt = cfg["dtype"]
-    used = _vmem_use(
-        blocked=[((chunk, dk), dt)] * 7 + [((chunk, dk), "float32")] * 2
-        + [((1, chunk), "float32")] * 2 + [((dk, dk), "float32")],
-        held=[((dk, dk), "float32")] * 4 + [((chunk, chunk), "float32")] * 8
-        + [((chunk, dk), "float32")] * 16)
-    if used > _SCOPED_VMEM_DEFAULT:
-        findings.append(_finding(
-            "kernel-vmem-budget",
-            f"the backward's [{chunk},{dk}] blocks, state and the chunk's "
-            f"float32 temporaries = {used} bytes exceed the default scoped "
-            f"VMEM", fam, label))
+    used = {name: _vmem_use(blocked=blocked, held=held)
+            for name, (blocked, held) in kda_blocks(cfg).items()}
+    for name, n in used.items():
+        if n > _SCOPED_VMEM_DEFAULT:
+            findings.append(_finding(
+                "kernel-vmem-budget",
+                f"{name}'s [{chunk},{dk}] blocks, state, [{chunk},{chunk}] "
+                f"factors and the chunk's float32 temporaries = {n} bytes "
+                f"exceed the default scoped VMEM", fam, label))
+    return used
 
 
 def check_decode_plan(cfg: dict, ok, block_t, interpret,
@@ -1068,9 +1086,9 @@ def lint_kernel_plans() -> Tuple[List[Finding], Dict[str, Any]]:
         beta = _spec((cfg["b"], cfg["t"], cfg["h"]), "float32")
         with _pretend_tpu():
             ok, interp = kd._plan(q, q, beta, cfg["chunk"], None)
-        check_kda_plan(cfg, ok, interp, findings)
+        vmem = check_kda_plan(cfg, ok, interp, findings)
         rows.append(dict(label=cfg["label"], accepted=bool(ok),
-                         chunk=int(cfg["chunk"])))
+                         chunk=int(cfg["chunk"]), vmem=vmem))
     report["kda"] = rows
 
     rows = []

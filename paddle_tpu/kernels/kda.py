@@ -31,18 +31,24 @@ triangular inverse is a product of (I + N^(2^m)) factors on the diagonal
 sub-blocks, merged by the same product over the sub-blocks.
 
 The state is kept transposed, H = S^T [dv, dk]: a decay by channel is
-then a multiply by a row.  The forward writes each chunk's first state
-(float32, b h t dk dv / C x 4 bytes: 134 MB a layer of Kimi-Linear's cell
-at C = 128) beside O; the backward walks the chunks in reverse from them,
-carrying dH and recomputing the chunk's algebra.  Walking the chunks again
-in the backward instead cost as much as the forward itself on the chip,
-for no less of the step's peak memory (PERF.md, section 6).  One algebra
-(`chunk_fwd`, `chunk_bwd`, functions of 2-D float32 arrays) serves the
-kernels' bodies and the XLA composition, which scans over the chunks.
+then a multiply by a row.  Beside O the forward writes, for each chunk,
+its first state and its factors: T and Aqk packed into one [C, C] (T
+strictly below the diagonal, its unit diagonal implied, Aqk^T on and
+above it; `_pack`), both float32, b h t (dk dv / C + C) x 4 bytes: 268
+MB a layer of Kimi-Linear's cell at C = 128.  The backward walks the
+chunks in reverse from them, carrying dH.  It makes again only the L2
+norms, G and Akk (dbeta's row sums read Akk, off the walk's critical
+path); the inverse and Aqk, at the head of that path, it reads.  Walking
+the chunks again in the backward instead of keeping the states cost as
+much as the forward itself on the chip, for no less of the step's peak
+memory (PERF.md, section 6).  One algebra (`chunk_fwd`, `chunk_bwd`,
+functions of 2-D float32 arrays) serves the kernels' bodies and the XLA
+composition, which scans over the chunks.
 
   kda(q, k, v [b, t, h*dk|dv], g [b, t, h*dk] f32, beta [b, t, h])
-      -> (o [b, t, h*dv], states [b, h, n, dv, dk] f32)
-  kda_bwd(q, k, v, g, beta, states, do)
+      -> (o [b, t, h*dv], states [b, h, n, dv, dk] f32,
+          factors [b, h, n, C, C] f32)
+  kda_bwd(q, k, v, g, beta, states, factors, do)
       -> (dq, dk, dv in the operands' dtype, dg f32, dbeta f32)
 """
 
@@ -86,6 +92,11 @@ def _iota(shape, axis):
 
 def _eye(n):
     return _iota((n, n), 0) == _iota((n, n), 1)
+
+
+def _below(n):
+    """[n, n]: True strictly below the diagonal."""
+    return _iota((n, n), 1) < _iota((n, n), 0)
 
 
 def _col(row):
@@ -316,24 +327,33 @@ def _unit_lower_inverse(n):
     return _dot(product(e, c // SUB), diag_inv, "nn")
 
 
-def _prepare_k(k, g, beta, vector):
-    """What every walk makes from a chunk's k, g, beta [C, 1]: normalised
-    k, its 1 / norm, G, Akk and T.  `vector`: the sub-blocks' own pairs
-    at once (the composition), else pair by pair (the kernels)."""
+def _prepare(q, k, g, scale, vector):
+    """What the forward and the backward both make from a chunk's q, k, g:
+    normalised q and k with their 1 / norms, q scaled, G and Akk.
+    `vector`: the sub-blocks' own pairs at once (the composition), else
+    pair by pair (the kernels)."""
+    qn, rq = _l2norm(q)
     kn, rk = _l2norm(k)
     gam = _cumsum(g)
     akk = gram(kn, kn, gam, strict=True, vector=vector)
-    return kn, rk, gam, akk, _unit_lower_inverse(beta * akk)
+    return qn, rq, kn, rk, qn * scale, gam, akk
 
 
-def _prepare(q, k, g, beta, scale, vector):
-    """What the forward and the backward both make from a chunk's q, k, g,
-    beta: normalised q (scaled) and k, G, Akk, Aqk, T."""
-    kn, rk, gam, akk, t = _prepare_k(k, g, beta, vector)
-    qn, rq = _l2norm(q)
-    qs = qn * scale
-    aqk = gram(qs, kn, gam, strict=False, vector=vector)
-    return qn, rq, kn, rk, qs, gam, akk, aqk, t
+def _pack(aqk, t):
+    """[C, C]: T below the diagonal (its unit diagonal implied) and Aqk^T
+    on and above it, the forward's one transpose."""
+    import jax.numpy as jnp
+
+    return jnp.where(_below(t.shape[0]), t, aqk.T)
+
+
+def _unpack(packed):
+    """(T, Aqk^T) from `_pack`'s [C, C]."""
+    import jax.numpy as jnp
+
+    c = packed.shape[0]
+    return (jnp.where(_below(c), packed, _eye(c).astype(jnp.float32)),
+            jnp.where(_below(c), 0.0, packed))
 
 
 def _advance(kn, gam, h0, u):
@@ -345,25 +365,27 @@ def _advance(kn, gam, h0, u):
 
 
 def chunk_fwd(q, k, v, g, beta, h0, scale, vector=False):
-    """One chunk: (o [C, dv], the next state H_C [dv, dk]) from q, k [C,
-    dk], v [C, dv], g [C, dk], beta [C, 1] and H_0 = S_0^T [dv, dk], all
-    float32."""
+    """One chunk: (o [C, dv], the next state H_C [dv, dk], T and Aqk
+    packed [C, C]) from q, k [C, dk], v [C, dv], g [C, dk], beta [C, 1]
+    and H_0 = S_0^T [dv, dk], all float32."""
     import jax.numpy as jnp
 
-    _, _, kn, _, qs, gam, _, aqk, t = _prepare(q, k, g, beta, scale, vector)
+    _, _, kn, _, qs, gam, akk = _prepare(q, k, g, scale, vector)
+    aqk = gram(qs, kn, gam, strict=False, vector=vector)
+    t = _unit_lower_inverse(beta * akk)
     eg = jnp.exp(gam)
     u = _dot(t, beta * (v - _dot(kn * eg, h0, "nt")), "nn")
     o = _dot(qs * eg, h0, "nt") + _dot(aqk, u, "nn")
-    return o, _advance(kn, gam, h0, u)
+    return o, _advance(kn, gam, h0, u), _pack(aqk, t)
 
 
-def chunk_bwd(q, k, v, g, beta, h0, do, dh1, scale, vector=False):
+def chunk_bwd(q, k, v, g, beta, h0, packed, do, dh1, scale, vector=False):
     """chunk_fwd's gradients: (dq, dk, dv, dg, dbeta [C, 1], dH_0) from
-    its inputs and the cotangents of o and of H_C."""
+    its inputs, its packed T and Aqk and the cotangents of o and of H_C."""
     import jax.numpy as jnp
 
-    qn, rq, kn, rk, qs, gam, akk, aqk, t = _prepare(q, k, g, beta, scale,
-                                                    vector)
+    qn, rq, kn, rk, qs, gam, akk = _prepare(q, k, g, scale, vector)
+    t, aqk_t = _unpack(packed)
     c = q.shape[0]
     lower = _iota((c, c), 1) <= _iota((c, c), 0)
     eg = jnp.exp(gam)
@@ -373,13 +395,13 @@ def chunk_bwd(q, k, v, g, beta, h0, do, dh1, scale, vector=False):
     resid = v - _dot(kt, h0, "nt")
     u = _dot(t, beta * resid, "nn")
 
-    du = _dot(aqk, do, "tn") + _dot(kh, dh1, "nt")
+    du = _dot(aqk_t, do, "nn") + _dot(kh, dh1, "nt")
     dkh = _dot(u, dh1, "nn")
     dqt = _dot(do, h0, "nn")
     daqk = jnp.where(lower, _dot(do, u, "nt"), 0.0)
     dx = _dot(t, du, "tn")
     dm = -_dot(dx, u, "nt")
-    dakk = jnp.where(_iota((c, c), 1) < _iota((c, c), 0), beta * dm, 0.0)
+    dakk = jnp.where(_below(c), beta * dm, 0.0)
     dbeta = (jnp.sum(dm * akk, axis=1, keepdims=True)
              + jnp.sum(dx * resid, axis=1, keepdims=True))
     dv = beta * dx
@@ -459,34 +481,35 @@ def _dims(q, v, beta):
 
 
 def reference_kda(q, k, v, g, beta, chunk=CHUNK):
-    """(o, states) of the composition: the lowering off the TPU and where
-    the plan rejects."""
+    """(o, states, factors) of the composition: the lowering off the TPU
+    and where the plan rejects."""
     heads, dk, dv = _dims(q, v, beta)
     args = [_by_chunk(x, heads, chunk) for x in (q, k, v, g)]
     args.append(_beta_chunks(beta, chunk))
 
     def step(h, xs):
-        o, h1 = chunk_fwd(*xs, h, dk ** -0.5, vector=True)
-        return h1, (o, h)
+        o, h1, packed = chunk_fwd(*xs, h, dk ** -0.5, vector=True)
+        return h1, (o, h, packed)
 
-    o, states = _walk(step, args, dv, dk)
-    return _from_chunks(o, q.shape[1], q.dtype), states
+    o, states, factors = _walk(step, args, dv, dk)
+    return _from_chunks(o, q.shape[1], q.dtype), states, factors
 
 
-def reference_kda_bwd(q, k, v, g, beta, states, do, chunk=CHUNK):
+def reference_kda_bwd(q, k, v, g, beta, states, factors, do, chunk=CHUNK):
     """(dq, dk, dv, dg f32, dbeta f32) of the composition, from the
-    forward's chunk states: a reverse scan that carries dH."""
+    forward's chunk states and factors: a reverse scan that carries dH."""
     import jax.numpy as jnp
 
     heads, dk, dv = _dims(q, v, beta)
     args = [_by_chunk(x, heads, chunk) for x in (q, k, v, g)]
-    args += [_beta_chunks(beta, chunk), states,
+    args += [_beta_chunks(beta, chunk), states, factors,
              _by_chunk(do.reshape(v.shape), heads, chunk)]
 
     def step(dh, xs):
-        q_, k_, v_, g_, b_, h0, do_ = xs
-        dq, dk_, dv_, dg, db, dh0 = chunk_bwd(q_, k_, v_, g_, b_, h0, do_,
-                                              dh, dk ** -0.5, vector=True)
+        q_, k_, v_, g_, b_, h0, packed, do_ = xs
+        dq, dk_, dv_, dg, db, dh0 = chunk_bwd(
+            q_, k_, v_, g_, b_, h0, packed, do_, dh, dk ** -0.5,
+            vector=True)
         return dh0, (dq, dk_, dv_, dg, db)
 
     dq, dk_, dv_, dg, db = _walk(step, args, dv, dk, reverse=True)
@@ -528,23 +551,24 @@ def _zero_at_first_chunk(ref):
         ref[...] = jnp.zeros(ref.shape, jnp.float32)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s_ref, h_ref, *,
-                scale):
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s_ref, f_ref,
+                h_ref, *, scale):
     _zero_at_first_chunk(h_ref)
     h0 = h_ref[...]
     s_ref[...] = h0
-    o, h_ref[...] = chunk_fwd(
+    o, h_ref[...], f_ref[...] = chunk_fwd(
         _f32(q_ref[...]), _f32(k_ref[...]), _f32(v_ref[...]), g_ref[...],
         _col(b_ref[...]), h0, scale)
     o_ref[...] = o.astype(o_ref.dtype)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, do_ref, dq_ref,
-                dk_ref, dv_ref, dg_ref, db_ref, dh_ref, *, scale):
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, f_ref, do_ref,
+                dq_ref, dk_ref, dv_ref, dg_ref, db_ref, dh_ref, *, scale):
     _zero_at_first_chunk(dh_ref)
     dq, dk, dv, dg, db, dh_ref[...] = chunk_bwd(
         _f32(q_ref[...]), _f32(k_ref[...]), _f32(v_ref[...]), g_ref[...],
-        _col(b_ref[...]), s_ref[...], _f32(do_ref[...]), dh_ref[...], scale)
+        _col(b_ref[...]), s_ref[...], f_ref[...], _f32(do_ref[...]),
+        dh_ref[...], scale)
     dq_ref[...] = dq.astype(dq_ref.dtype)
     dk_ref[...] = dk.astype(dk_ref.dtype)
     dv_ref[...] = dv.astype(dv_ref.dtype)
@@ -564,11 +588,12 @@ def _specs(t, dk, dv, chunk, reverse):
     def rows(d):
         return pl.BlockSpec((None, chunk, d), lambda i, h, c: (i, at(c), h))
 
-    beta = pl.BlockSpec((None, None, None, 1, chunk),
-                        lambda i, h, c: (i, h, at(c), 0, 0))
-    state = pl.BlockSpec((None, None, None, dv, dk),
-                         lambda i, h, c: (i, h, at(c), 0, 0))
-    return rows, beta, state
+    def per_chunk(shape):
+        return pl.BlockSpec((None, None, None) + shape,
+                            lambda i, h, c: (i, h, at(c), 0, 0))
+
+    return rows, per_chunk((1, chunk)), per_chunk((dv, dk)), per_chunk(
+        (chunk, chunk))
 
 
 def _beta_rows(beta, chunk):
@@ -611,8 +636,9 @@ def _walk_params(dims, chunk, interpret):
 
 def kda(q, k, v, g, beta, chunk=CHUNK, interpret=None):
     """(o [b, t, h*dv] in q's dtype, states [b, h, n, dv, dk] float32: each
-    chunk's first state, transposed) of KDA over q, k [b, t, h*dk], v [b,
-    t, h*dv], the log-decay g [b, t, h*dk] and beta [b, t, h]."""
+    chunk's first state, transposed, factors [b, h, n, C, C] float32: each
+    chunk's T and Aqk packed, `_pack`) of KDA over q, k [b, t, h*dk], v
+    [b, t, h*dv], the log-decay g [b, t, h*dk] and beta [b, t, h]."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -625,38 +651,43 @@ def kda(q, k, v, g, beta, chunk=CHUNK, interpret=None):
     t = q.shape[1]
     args, dims = _padded(q, k, v, g, beta, chunk)
     b, tp, heads, dk, dv = dims
-    rows, beta_spec, state = _specs(tp, dk, dv, chunk, False)
-    o, states = pl.pallas_call(
+    rows, beta_spec, state, factor = _specs(tp, dk, dv, chunk, False)
+    per_chunk = (b, heads, tp // chunk)
+    o, states, packed = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=dk ** -0.5), name="kda_fwd",
         in_specs=[rows(dk), rows(dk), rows(dv), rows(dk), beta_spec],
-        out_specs=[rows(dv), state],
+        out_specs=[rows(dv), state, factor],
         out_shape=[jax.ShapeDtypeStruct((b, tp, heads * dv), q.dtype),
-                   jax.ShapeDtypeStruct((b, heads, tp // chunk, dv, dk),
+                   jax.ShapeDtypeStruct(per_chunk + (dv, dk), jnp.float32),
+                   jax.ShapeDtypeStruct(per_chunk + (chunk, chunk),
                                         jnp.float32)],
         **_walk_params(dims, chunk, interpret))(*args)
-    return o[:, :t], states
+    return o[:, :t], states, packed
 
 
-def kda_bwd(q, k, v, g, beta, states, do, chunk=CHUNK, interpret=None):
+def kda_bwd(q, k, v, g, beta, states, factors, do, chunk=CHUNK,
+            interpret=None):
     """(dq, dk, dv in the operands' dtypes, dg [b, t, h*dk] float32, dbeta
     [b, t, h] float32) from the forward's operands, its chunk states and
-    the cotangent of o: the chunks walked in reverse."""
+    factors and the cotangent of o: the chunks walked in reverse."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
+    _note("kda_bwd_sites_kept")
     ok, interpret = _plan(q, v, beta, chunk, interpret)
     if not ok:
-        return reference_kda_bwd(q, k, v, g, beta, states, do, chunk)
+        return reference_kda_bwd(q, k, v, g, beta, states, factors, do,
+                                 chunk)
     t = q.shape[1]
     (q, k, v, g, beta_rows, do), dims = _padded(
         q, k, v, g, beta, chunk, do.astype(v.dtype).reshape(v.shape))
     b, tp, heads, dk, dv = dims
-    rows, beta_spec, state = _specs(tp, dk, dv, chunk, True)
+    rows, beta_spec, state, factor = _specs(tp, dk, dv, chunk, True)
     dq, dk_, dv_, dg, db = pl.pallas_call(
         functools.partial(_bwd_kernel, scale=dk ** -0.5), name="kda_bwd",
         in_specs=[rows(dk), rows(dk), rows(dv), rows(dk), beta_spec, state,
-                  rows(dv)],
+                  factor, rows(dv)],
         out_specs=[rows(dk), rows(dk), rows(dv), rows(dk), beta_spec],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -664,6 +695,6 @@ def kda_bwd(q, k, v, g, beta, states, do, chunk=CHUNK, interpret=None):
                    jax.ShapeDtypeStruct(g.shape, jnp.float32),
                    jax.ShapeDtypeStruct(beta_rows.shape, jnp.float32)],
         **_walk_params(dims, chunk, interpret))(
-            q, k, v, g, beta_rows, states, do)
+            q, k, v, g, beta_rows, states, factors, do)
     return (dq[:, :t], dk_[:, :t], dv_[:, :t], dg[:, :t],
             db.reshape(b, heads, tp)[:, :, :t].transpose(0, 2, 1))

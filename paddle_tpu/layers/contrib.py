@@ -285,10 +285,12 @@ def kda(q, k, v, g, beta, name=None):
     helper = LayerHelper("kda", name=name)
     out = helper.create_variable_for_type_inference(v.dtype)
     states = _residual(helper, None, "float32")
+    factors = _residual(helper, None, "float32")
     helper.append_op("kda",
                      inputs={"Q": [q], "K": [k], "V": [v], "G": [g],
                              "Beta": [beta]},
-                     outputs={"Out": [out], "States": [states]})
+                     outputs={"Out": [out], "States": [states],
+                              "Factors": [factors]})
     out.shape = v.shape
     return out
 

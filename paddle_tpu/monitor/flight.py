@@ -410,12 +410,13 @@ _COMPILE_COUNTS = {
 # (kernels/short_conv.py); and the bthd flash_attention sites whose forward
 # was traced on the lane-dense kernels / on the XLA reference
 # (kernels/attention.py `_note_site`); and the kda sites lowered to the
-# Pallas kernel pair / to the XLA composition (kernels/kda.py)
+# Pallas kernel pair / to the XLA composition, and the kda sites whose
+# backward read the forward's kept factors (kernels/kda.py)
 _TRACE_COUNTS = ("grad_direct", "grad_generic", "qkv_bwd_composed",
                  "attn_tiles_visited", "attn_tiles_total",
                  "short_conv_sites_kernel", "short_conv_sites_xla",
                  "bthd_sites_kernel", "bthd_sites_xla",
-                 "kda_sites_kernel", "kda_sites_xla")
+                 "kda_sites_kernel", "kda_sites_xla", "kda_bwd_sites_kept")
 _compile_totals: Dict[str, float] = dict.fromkeys(
     list(_COMPILE_DURATIONS.values()) + list(_COMPILE_COUNTS.values())
     + list(_TRACE_COUNTS), 0)
@@ -507,7 +508,8 @@ def compile_phases() -> Dict[str, float]:
     `bthd_sites_xla` of the bthd flash_attention sites traced on the
     lane-dense kernels / on the XLA reference, and `kda_sites_kernel` /
     `kda_sites_xla` of the kda ops lowered to the Pallas kernels / to the
-    XLA composition."""
+    XLA composition, and `kda_bwd_sites_kept` of the kda grad ops whose
+    backward read each chunk's inverse and Aqk from the forward."""
     with _compile_lock:
         return dict(_compile_totals)
 

@@ -223,31 +223,33 @@ def lower_kda_gate(ctx, ins):
             "Beta": [jax.nn.sigmoid(_f32(ins["B"][0]))]}
 
 
-@register("kda", residuals=("States",))
+@register("kda", residuals=("States", "Factors"))
 def lower_kda(ctx, ins):
     """Kimi Delta Attention (kernels/kda.py) of Q, K [b, t, h*dk], V [b, t,
     h*dv], the log-decay G [b, t, h*dk] float32 and Beta [b, t, h]: q and
     k L2-normalised inside, the state chunk by chunk (kernels/kda.py
     CHUNK rows).  States, each chunk's first state [b, h, n, dv, dk]
-    float32, is the residual kda_grad reads."""
+    float32, and Factors, each chunk's inverse T and Aqk packed [b, h, n,
+    C, C] float32, are the residuals kda_grad reads."""
     from ..kernels.kda import kda
 
-    out, states = kda(ins["Q"][0], ins["K"][0], ins["V"][0], ins["G"][0],
-                      ins["Beta"][0])
-    return {"Out": [out], "States": [states]}
+    out, states, factors = kda(ins["Q"][0], ins["K"][0], ins["V"][0],
+                               ins["G"][0], ins["Beta"][0])
+    return {"Out": [out], "States": [states], "Factors": [factors]}
 
 
 @residual_grad("kda")
 def lower_kda_grad(ctx, ins):
-    """dQ, dK, dV, dG, dBeta from the forward's operands, its States and
-    Out@GRAD: the chunks walked in reverse from the states, each chunk's
-    algebra made again (the L2 norms' gradient included)."""
+    """dQ, dK, dV, dG, dBeta from the forward's operands, its States,
+    Factors and Out@GRAD: the chunks walked in reverse from the states,
+    each chunk's inverse and Aqk read from Factors, its L2 norms, G and
+    Akk made again (the L2 norms' gradient included)."""
     from ..kernels.kda import kda_bwd
 
     g, beta = ins["G"][0], ins["Beta"][0]
     dq, dk, dv, dg, db = kda_bwd(
         ins["Q"][0], ins["K"][0], ins["V"][0], g, beta, ins["States"][0],
-        ins["Out@GRAD"][0])
+        ins["Factors"][0], ins["Out@GRAD"][0])
     return {"Q@GRAD": [dq], "K@GRAD": [dk], "V@GRAD": [dv],
             "G@GRAD": [dg.astype(g.dtype)],
             "Beta@GRAD": [db.astype(beta.dtype)]}

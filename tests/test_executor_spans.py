@@ -358,7 +358,8 @@ def test_compile_phases_count_inside_executor_calls_only(clean_ring):
                          "attn_tiles_visited", "attn_tiles_total",
                          "short_conv_sites_kernel", "short_conv_sites_xla",
                          "bthd_sites_kernel", "bthd_sites_xla",
-                         "kda_sites_kernel", "kda_sites_xla"}
+                         "kda_sites_kernel", "kda_sites_xla",
+                         "kda_bwd_sites_kept"}
     # the net's grad ops have no lowering of their own: all went through
     # the generic vjp, once each, in the miss call's trace and nowhere else
     assert miss["grad_generic"] - before["grad_generic"] > 0

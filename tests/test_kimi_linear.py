@@ -16,6 +16,8 @@ over every channel and token with cancellation);
 interpreted kernels against the XLA composition 1e-5 absolute on O(1)
 results."""
 
+import functools
+import json
 import os
 import sys
 
@@ -120,14 +122,15 @@ CASES = [(80, 64, 0.3), (150, 128, 0.3), (45, 32, 0.3), (80, 64, 8.0)]
 def test_chunked_composition_follows_the_recurrence(t, chunk, gate_scale):
     """Forward and all five gradients, at t no whole number of chunks, at
     chunks of 64, 128 and 32, and at a gate whose log-decay within a chunk
-    passes 88 (gate_scale 8)."""
+    passes 88 (gate_scale 8); the backward reads the forward's factors."""
     args = _kda_operands(2, t, 2, 32, 16, gate_scale)
     want, vjp = jax.vjp(_recurrence, *args)
-    out, states = K.reference_kda(*args, chunk=chunk)
+    out, states, factors = K.reference_kda(*args, chunk=chunk)
     _close(out, want)
     assert states.shape == (2, 2, -(-t // chunk), 16, 32)
+    assert factors.shape == (2, 2, -(-t // chunk), chunk, chunk)
     do = jax.random.normal(jax.random.key(9), want.shape)
-    got = K.reference_kda_bwd(*args, states, do, chunk=chunk)
+    got = K.reference_kda_bwd(*args, states, factors, do, chunk=chunk)
     for name, a, r in zip(("dq", "dk", "dv", "dg", "dbeta"), got, vjp(do)):
         assert a.shape == r.shape, name
         _close(a, r, rel=5e-6)
@@ -138,24 +141,88 @@ def test_decay_past_88_in_a_chunk_would_overflow_the_factorised_form():
     cum = jnp.cumsum(g[0], axis=0)  # one chunk of 64
     assert float(jnp.min(cum)) < -88.0
     assert not bool(jnp.all(jnp.isfinite(jnp.exp(-cum))))  # exp(-G) overflows
-    out, _ = K.reference_kda(q, k, v, g, beta, chunk=64)
+    out, _, _ = K.reference_kda(q, k, v, g, beta, chunk=64)
     assert bool(jnp.all(jnp.isfinite(out)))
     _close(out, _recurrence(q, k, v, g, beta))
 
 
 @pytest.mark.parametrize("chunk", [64, 128])
 def test_kernels_follow_the_composition(chunk):
-    """The Pallas pair (interpreted) on 128-lane heads, t no whole chunk."""
+    """The Pallas pair (interpreted) on 128-lane heads, t no whole chunk:
+    the backward reads the factors the forward kernel wrote."""
     args = _kda_operands(1, 80, 2, 128, 128, 0.5, seed=1)
-    out, states = K.kda(*args, chunk=chunk, interpret=True)
-    want_out, want_states = K.reference_kda(*args, chunk=chunk)
+    out, states, factors = K.kda(*args, chunk=chunk, interpret=True)
+    want_out, want_states, want_factors = K.reference_kda(*args, chunk=chunk)
     _close(out, want_out, rel=1e-5)
     _close(states, want_states, rel=1e-5)
+    _close(factors, want_factors, rel=1e-5)
     do = jax.random.normal(jax.random.key(3), (1, 80, 256))
-    got = K.kda_bwd(*args, states, do, chunk=chunk, interpret=True)
-    want = K.reference_kda_bwd(*args, want_states, do, chunk=chunk)
+    got = K.kda_bwd(*args, states, factors, do, chunk=chunk, interpret=True)
+    want = K.reference_kda_bwd(*args, want_states, want_factors, do,
+                               chunk=chunk)
     for a, r in zip(got, want):
         _close(a, r, rel=1e-5)
+
+
+def _one_chunk(c, dk, dv, gate_scale, seed):
+    """q, k, v, g [c, d] and beta [c, 1] of one chunk, float32."""
+    q, k, v, g, beta = _kda_operands(1, c, 1, dk, dv, gate_scale, seed)
+    return q[0], k[0], v[0], g[0], beta[0]
+
+
+@pytest.mark.parametrize("vector", [False, True])
+def test_factors_are_the_inverse_below_and_aqk_transposed_above(vector):
+    """The forward's packed [C, C]: strictly below the diagonal T = (I +
+    Diag(b) Akk)^-1 (its unit diagonal implied), on and above it Aqk^T,
+    both against a plain dense form; unpacked, T's diagonal is one."""
+    c, dk, dv = 32, 16, 8
+    q, k, v, g, beta = _one_chunk(c, dk, dv, 0.3, seed=6)
+    _, _, packed = K.chunk_fwd(q, k, v, g, beta, jnp.zeros((dv, dk)),
+                               dk ** -0.5, vector=vector)
+    qn = q / jnp.sqrt(jnp.sum(q * q, -1, keepdims=True) + K.L2_EPS)
+    kn = k / jnp.sqrt(jnp.sum(k * k, -1, keepdims=True) + K.L2_EPS)
+    gam = np.cumsum(np.asarray(g, np.float64), axis=0)
+    decay = np.exp(np.minimum(gam[:, None, :] - gam[None, :, :], 0.0))
+    akk = np.tril(np.einsum("ic,jc,ijc->ij", kn, kn, decay), -1)
+    aqk = np.tril(np.einsum("ic,jc,ijc->ij", qn * dk ** -0.5, kn, decay))
+    t = np.linalg.inv(np.eye(c) + np.asarray(beta) * akk)
+    packed = np.asarray(packed)
+    np.testing.assert_allclose(np.tril(packed, -1), np.tril(t, -1),
+                               atol=2e-6)
+    np.testing.assert_allclose(np.triu(packed), aqk.T, atol=2e-6)
+    t_read, aqk_t = K._unpack(jnp.asarray(packed))
+    assert np.all(np.diag(np.asarray(t_read)) == 1.0)
+    assert np.all(np.triu(np.asarray(t_read), 1) == 0.0)
+    np.testing.assert_array_equal(np.asarray(aqk_t), np.triu(packed))
+
+
+@pytest.mark.parametrize("vector", [False, True])
+def test_backward_makes_no_inverse_and_akk_alone(monkeypatch, vector):
+    """chunk_bwd traced with `_unit_lower_inverse` and `gram` counted: no
+    inverse and one Gram, Akk's (strict); the forward makes both of its
+    Gram matrices and the inverse."""
+    made = []
+
+    def counted(name, fn):
+        def wrapped(*a, **kw):
+            made.append((name, kw.get("strict")))
+            return fn(*a, **kw)
+        return wrapped
+
+    monkeypatch.setattr(K, "gram", counted("gram", K.gram))
+    monkeypatch.setattr(K, "_unit_lower_inverse",
+                        counted("inverse", K._unit_lower_inverse))
+    c, dk, dv = 32, 16, 8
+    q, k, v, g, beta = _one_chunk(c, dk, dv, 0.3, seed=7)
+    h0 = jnp.zeros((dv, dk))
+    jax.make_jaxpr(functools.partial(K.chunk_fwd, vector=vector))(
+        q, k, v, g, beta, h0, dk ** -0.5)
+    assert sorted(made) == [("gram", False), ("gram", True),
+                            ("inverse", None)]
+    made.clear()
+    jax.make_jaxpr(functools.partial(K.chunk_bwd, vector=vector))(
+        q, k, v, g, beta, h0, jnp.zeros((c, c)), v, h0, dk ** -0.5)
+    assert made == [("gram", True)]
 
 
 def test_plan_takes_the_kernels_on_whole_lane_heads_alone():
@@ -170,9 +237,9 @@ def test_plan_takes_the_kernels_on_whole_lane_heads_alone():
 
 def test_kda_is_causal_and_keeps_rows_apart():
     q, k, v, g, beta = _kda_operands(2, 70, 2, 16, 16, 0.5)
-    base, _ = K.reference_kda(q, k, v, g, beta, chunk=32)
+    base, _, _ = K.reference_kda(q, k, v, g, beta, chunk=32)
     later = v.at[:, 40:].add(1.0).at[1].add(1.0)
-    moved, _ = K.reference_kda(q, k, later, g, beta, chunk=32)
+    moved, _, _ = K.reference_kda(q, k, later, g, beta, chunk=32)
     np.testing.assert_allclose(moved[0, :40], base[0, :40], atol=1e-7)
     assert float(jnp.max(jnp.abs(moved[0, 40:] - base[0, 40:]))) > 1e-3
 
@@ -220,6 +287,7 @@ def test_kda_op_and_its_grad_op_through_a_program():
     # two are the loss's product and sum); the XLA composition
     assert added["grad_direct"] == 1 and added["grad_generic"] == 2
     assert (added["kda_sites_xla"], added["kda_sites_kernel"]) == (1, 0)
+    assert added["kda_bwd_sites_kept"] == 1
 
 
 def test_kda_gate_is_the_published_decay_and_beta():
@@ -490,6 +558,7 @@ def test_new_ops_take_the_direct_grad_route_and_the_sites_are_counted():
     exe.run_steps(prog, feed=feed, fetch_list=[loss], scope=scope)
     added = {k: v - before[k] for k, v in monitor.compile_phases().items()}
     assert added["kda_sites_xla"] == 4 and added["kda_sites_kernel"] == 0
+    assert added["kda_bwd_sites_kept"] == 4  # every backward read its factors
     # every grad op registered with its forward's residuals took them (MLA's
     # heads of 12 take the attention's XLA reference here, which keeps no
     # Lse: its grad op is the generic one)
@@ -581,3 +650,27 @@ def test_reference_routed_as_it_chose_itself_is_unchanged():
                if op.type == "moe_router"]
     assert [op.input("W")[0] for op in routers] == [
         f"layer{i}.router_w" for i in range(1, 5)]
+
+
+# the KDA kernel bench (tools/kda_bench.py) ---------------------------------
+
+
+def test_kda_bench_reads_both_chunks_against_the_composition(tmp_path):
+    """Interpreted on the CPU: one line a chunk, no device time (the trace
+    has no TPU plane), the kernels' results beside the composition's
+    (o and the three bf16 gradients to a bf16 step, dg and dbeta f32)."""
+    sys.path.insert(0, os.path.join(ROOT, "tools"))
+    import kda_bench
+
+    out = tmp_path / "kb.jsonl"
+    assert kda_bench.main(["--batch", "1", "--seq", "128", "--heads", "1",
+                           "--reps", "1", "--check", "--out",
+                           str(out)]) == 0
+    lines = [json.loads(x) for x in out.read_text().splitlines()]
+    assert [x["chunk"] for x in lines] == [64, 128]
+    for x in lines:
+        assert x["kda_fwd_ms"] is None and x["kda_bwd_ms"] is None
+        assert x["fwd_ms"] > 0 and x["bwd_ms"] > 0
+        diff = x["max_diff"]
+        assert max(diff[n] for n in ("o", "dq", "dk", "dv")) <= 2 ** -6
+        assert max(diff["dg"], diff["dbeta"]) <= 1e-5

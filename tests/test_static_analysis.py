@@ -229,6 +229,27 @@ class TestRedGate:
                    and "vmem_limit_bytes" in f.message
                    for f in findings), findings
 
+    @pytest.mark.parametrize("chunk", [64, 128])
+    def test_kda_vmem_counts_the_kept_factors_in_both_kernels(self, chunk):
+        # the forward writes and the backward reads one [chunk, chunk]
+        # float32 block of T and Aqk a grid step, double-buffered both
+        # ways (at 64 its lanes pad to 128; heads of 256 keep every other
+        # block another shape); K's pair fits with it
+        cfg = next(c for c in kernel_lint._KDA_MATRIX
+                   if c["label"] == f"kimi-kda-c{chunk}-bf16")
+        factors = ((chunk, chunk), "float32")
+        for name, (blocked, held) in kernel_lint.kda_blocks(
+                dict(cfg, dk=256)).items():
+            assert blocked.count(factors) == 1, name
+            rest = [b for b in blocked if b != factors]
+            assert kernel_lint._vmem_use(blocked=blocked, held=held) \
+                - kernel_lint._vmem_use(blocked=rest, held=held) \
+                == 2 * chunk * 128 * 4, name
+        findings = []
+        used = kernel_lint.check_kda_plan(cfg, True, False, findings)
+        assert findings == [] and set(used) == {"kda_fwd", "kda_bwd"}
+        assert max(used.values()) <= kernel_lint._SCOPED_VMEM_DEFAULT
+
     def test_kernel_alias_mismatch_named(self):
         cfg = dict(label="seeded-alias",
                    tables=[((100, 8), "float32"), ((100, 8), "bfloat16")],
