@@ -358,13 +358,14 @@ def kda_blocks(cfg: dict) -> Dict[str, tuple]:
     beta and dbeta as [1, chunk] lane rows, the chunk's first state [dv,
     dk] and its factors [chunk, chunk] float32 (written by the forward,
     read by the backward); held: the [dv, dk] carry in VMEM scratch and
-    the backward's float32 temporaries ([chunk, chunk] matrices, [chunk,
-    dk] operands), which bound the forward's."""
+    the backward's float32 temporaries ([chunk, chunk] matrices, the
+    transpose `decay_mm_t` takes of its product among them, [chunk, dk]
+    operands), which bound the forward's."""
     chunk, dk, dt = cfg["chunk"], cfg["dk"], cfg["dtype"]
     rows, rows32 = ((chunk, dk), dt), ((chunk, dk), "float32")
     lane, state = ((1, chunk), "float32"), ((dk, dk), "float32")
     factors = ((chunk, chunk), "float32")
-    held = ([((dk, dk), "float32")] * 4 + [((chunk, chunk), "float32")] * 8
+    held = ([((dk, dk), "float32")] * 4 + [((chunk, chunk), "float32")] * 9
             + [((chunk, dk), "float32")] * 16)
     return {
         "kda_fwd": ([rows] * 4 + [rows32, lane, state, factors], held),

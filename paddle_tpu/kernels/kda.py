@@ -23,12 +23,15 @@ state S_0:
 Every factor stays at or under one: `exp(G_i - G_j)` is never split into
 `exp(G_i) exp(-G_j)`, which overflows float32 once a channel's log-decay
 in a chunk passes about 88.  Pairs in different sub-blocks of SUB rows
-meet through the reference G at the row before the later sub-block (FLA's
-secondary chunking), both factors exp of a non-positive number; pairs
-inside one sub-block are computed pair by pair (a column a step in the
-kernels, all at once over [SUB, SUB, dk] in the composition).  The
-triangular inverse is a product of (I + N^(2^m)) factors on the diagonal
-sub-blocks, merged by the same product over the sub-blocks.
+meet through a reference G (FLA's secondary chunking), both factors exp
+of a non-positive number: the row before the later sub-block where the
+result's rows are the later sub-block's (`gram`, `decay_mm`), the
+earlier sub-block's last row where they are the earlier one's
+(`decay_mm_t`); either way one [SUB, C] x [C, d] product a sub-block.
+Pairs inside one sub-block are computed pair by pair (a column a step
+in the kernels, all at once over [SUB, SUB, dk] in the composition).
+The triangular inverse is a product of (I + N^(2^m)) factors on the
+diagonal sub-blocks, merged by the same product over the sub-blocks.
 
 The state is kept transposed, H = S^T [dv, dk]: a decay by channel is
 then a multiply by a row.  Beside O the forward writes, for each chunk,
@@ -145,6 +148,13 @@ def _refs(gam):
     """For each sub-block I > 0 of the chunk: (its first row, the reference
     G [1, dk] at the row before it)."""
     return [(lo, gam[lo - 1:lo]) for lo in range(SUB, gam.shape[0], SUB)]
+
+
+def _refs_after(gam):
+    """For each sub-block J with rows after it: (its first row, the
+    reference G [1, dk] at its last row)."""
+    return [(lo, gam[lo + SUB - 1:lo + SUB])
+            for lo in range(0, gam.shape[0] - SUB, SUB)]
 
 
 def _column(p, j):
@@ -284,21 +294,24 @@ def decay_mm(p, y, gam, vector=False):
 
 def decay_mm_t(p, x, gam, vector=False):
     """[C, d]: z_j = sum_{i >= j} p_ij exp(G_i - G_j) * x_i for p [C, C]
-    zero above its diagonal (decay_mm's transpose)."""
+    zero above its diagonal (decay_mm's transpose), by the output's row
+    blocks as decay_mm: one [SUB, C] x [C, d] product a sub-block, p
+    transposed once."""
     import jax.numpy as jnp
 
-    rows = _iota(x.shape, 0)
-    out = jnp.zeros(x.shape, jnp.float32)
-    for lo, ref in _refs(gam):
-        # pairs (i in the sub-block from lo, j before lo) meet through ref
-        left = jnp.where((rows >= lo) & (rows < lo + SUB),
-                         x * jnp.exp(jnp.minimum(gam - ref, 0.0)), 0.0)
-        out = out + jnp.where(
-            rows < lo, jnp.exp(jnp.minimum(ref - gam, 0.0))
-            * _dot(p, left, "tn"), 0.0)
-    return out + jnp.concatenate(
-        [_diag_mm_t(p[lo:lo + SUB], x[lo:lo + SUB], g_blk, lo, vector)
-         for lo, g_blk in _sub_blocks(gam)], axis=0)
+    p_t = p.T
+    after = dict(_refs_after(gam))
+    row_blocks = []
+    for lo, g_blk in _sub_blocks(gam):
+        out = _diag_mm_t(p[lo:lo + SUB], x[lo:lo + SUB], g_blk, lo, vector)
+        if lo in after:
+            ref = after[lo]
+            right = jnp.where(_iota(x.shape, 0) >= lo + SUB,
+                              x * jnp.exp(jnp.minimum(gam - ref, 0.0)), 0.0)
+            out = out + jnp.exp(jnp.minimum(ref - g_blk, 0.0)) * _dot(
+                p_t[lo:lo + SUB], right, "nn")
+        row_blocks.append(out)
+    return jnp.concatenate(row_blocks, axis=0)
 
 
 def _unit_lower_inverse(n):

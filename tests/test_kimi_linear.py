@@ -225,6 +225,52 @@ def test_backward_makes_no_inverse_and_akk_alone(monkeypatch, vector):
     assert made == [("gram", True)]
 
 
+@pytest.mark.parametrize("vector", [False, True])
+def test_backward_streams_no_full_product_per_sub_block(vector):
+    """chunk_bwd at C = 128: the transposed decay products walk the
+    output's row blocks (one [16, C] x [C, d] product a sub-block), so no
+    more than 16 of its products have a 128-row result."""
+    c = dk = dv = 128
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32)
+    jaxpr = jax.make_jaxpr(functools.partial(K.chunk_bwd, vector=vector))(
+        f32((c, dk)), f32((c, dk)), f32((c, dv)), f32((c, dk)), f32((c, 1)),
+        f32((dv, dk)), f32((c, c)), f32((c, dv)), f32((dv, dk)), dk ** -0.5)
+    rows = [e.outvars[0].aval.shape[0] for e in jaxpr.jaxpr.eqns
+            if e.primitive.name == "dot_general"]
+    assert 0 < rows.count(c) <= 16
+    assert set(rows) == {K.SUB, c}
+
+
+@pytest.mark.parametrize("vector", [False, True])
+@pytest.mark.parametrize("gate_scale", [0.3, 8.0])
+@pytest.mark.parametrize("c", [32, 64, 128])
+def test_transposed_decay_product_is_the_sum_over_all_pairs(c, gate_scale,
+                                                            vector):
+    """decay_mm_t against a dense float64 sum over every pair i >= j of
+    p_ij exp(G_i - G_j) x_i, on the same float32 G; at gate_scale 8 the
+    log-decay passes 88 in the chunk and the result stays finite."""
+    key = jax.random.key(c)
+
+    def normal(i, shape):
+        return np.asarray(jax.random.normal(jax.random.fold_in(key, i),
+                                            shape), np.float64)
+
+    d = 32
+    p, x = np.tril(normal(0, (c, c))), normal(1, (c, d))
+    g = -gate_scale * np.logaddexp(0.0, normal(2, (c, d)))
+    gam = np.cumsum(g, axis=0).astype(np.float32)
+    assert (float(gam.min()) < -88.0) == (gate_scale == 8.0)
+    g64 = gam.astype(np.float64)
+    decay = np.exp(np.minimum(g64[:, None, :] - g64[None, :, :], 0.0))
+    want = np.einsum("ij,ijc,ic->jc", p, decay, x)
+    got = np.asarray(K.decay_mm_t(jnp.asarray(p, jnp.float32),
+                                  jnp.asarray(x, jnp.float32),
+                                  jnp.asarray(gam), vector))
+    assert np.all(np.isfinite(got))
+    assert float(np.max(np.abs(got - want))) <= 1e-6 * float(
+        np.max(np.abs(want)))
+
+
 def test_plan_takes_the_kernels_on_whole_lane_heads_alone():
     q = jax.ShapeDtypeStruct((2, 4096, 4096), jnp.bfloat16)
     beta = jax.ShapeDtypeStruct((2, 4096, 32), jnp.float32)
