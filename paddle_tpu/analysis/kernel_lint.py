@@ -358,19 +358,28 @@ def kda_blocks(cfg: dict) -> Dict[str, tuple]:
     beta and dbeta as [1, chunk] lane rows, the chunk's first state [dv,
     dk] and its factors [chunk, chunk] float32 (written by the forward,
     read by the backward); held: the [dv, dk] carry in VMEM scratch and
-    the backward's float32 temporaries ([chunk, chunk] matrices, the
-    transpose `decay_mm_t` takes of its product among them, [chunk, dk]
-    operands), which bound the forward's."""
+    each kernel's float32 temporaries.  The forward's: [chunk, chunk] Akk,
+    Aqk, the diagonal sub-blocks' inverse and the terms of the product
+    that makes the inverse below them, [chunk, dk] operands, and the
+    one walk's per-sub-block pieces: its three [16, chunk] strips (its
+    rows of Akk, Aqk and of the inverse) and its stacked [32, dk] operand
+    with the [32, chunk] product.  The backward's: [chunk, chunk]
+    matrices, the transpose `decay_mm_t` takes of its product among
+    them, and [chunk, dk] operands."""
+    from ..kernels.kda import SUB
+
     chunk, dk, dt = cfg["chunk"], cfg["dk"], cfg["dtype"]
     rows, rows32 = ((chunk, dk), dt), ((chunk, dk), "float32")
     lane, state = ((1, chunk), "float32"), ((dk, dk), "float32")
     factors = ((chunk, chunk), "float32")
-    held = ([((dk, dk), "float32")] * 4 + [((chunk, chunk), "float32")] * 9
-            + [((chunk, dk), "float32")] * 16)
+    held_fwd = ([state] * 3 + [factors] * 7 + [rows32] * 14
+                + [((SUB, chunk), "float32")] * 3
+                + [((2 * SUB, dk), "float32"), ((2 * SUB, chunk), "float32")])
+    held_bwd = [state] * 4 + [factors] * 9 + [rows32] * 16
     return {
-        "kda_fwd": ([rows] * 4 + [rows32, lane, state, factors], held),
+        "kda_fwd": ([rows] * 4 + [rows32, lane, state, factors], held_fwd),
         "kda_bwd": ([rows] * 7 + [rows32] * 2 + [lane] * 2
-                    + [state, factors], held),
+                    + [state, factors], held_bwd),
     }
 
 

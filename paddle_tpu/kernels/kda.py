@@ -25,13 +25,19 @@ Every factor stays at or under one: `exp(G_i - G_j)` is never split into
 in a chunk passes about 88.  Pairs in different sub-blocks of SUB rows
 meet through a reference G (FLA's secondary chunking), both factors exp
 of a non-positive number: the row before the later sub-block where the
-result's rows are the later sub-block's (`gram`, `decay_mm`), the
-earlier sub-block's last row where they are the earlier one's
-(`decay_mm_t`); either way one [SUB, C] x [C, d] product a sub-block.
+result's rows are the later sub-block's (`gram`, `_factors`,
+`decay_mm`), the earlier sub-block's last row where they are the
+earlier one's (`decay_mm_t`); either way one [SUB, C] x [C, d] product
+a sub-block (two such stacked in `_factors`).
 Pairs inside one sub-block are computed pair by pair (a column a step
 in the kernels, all at once over [SUB, SUB, dk] in the composition).
-The triangular inverse is a product of (I + N^(2^m)) factors on the
-diagonal sub-blocks, merged by the same product over the sub-blocks.
+The forward makes Akk, Aqk and the inverse of I + Diag(b) Akk on the
+diagonal sub-blocks in one walk of the sub-blocks (`_factors`): a decay
+tile exp(G_i - G_j) k_j serves both Grams, their rows across sub-blocks
+meet the shared decayed k in one stacked product, and each column of Akk
+takes one step of forward substitution for its sub-block's inverse as it
+comes.  The inverse below those blocks is a product of (I + E^(2^m))
+factors over the sub-blocks.
 
 The state is kept transposed, H = S^T [dv, dk]: a decay by channel is
 then a multiply by a row.  Beside O the forward writes, for each chunk,
@@ -314,37 +320,103 @@ def decay_mm_t(p, x, gam, vector=False):
     return jnp.concatenate(row_blocks, axis=0)
 
 
-def _unit_lower_inverse(n):
-    """(I + n)^-1 for n [C, C] strictly lower triangular: on the diagonal
-    sub-blocks the product (I - n)(I + n^2)(I + n^4).. (n^SUB = 0 there),
-    then the same product over the sub-blocks for what lies below them."""
+def _own_columns(kn_blk, qs_blk, g_blk, vector):
+    """A sub-block's own pairs of both Grams, yielded column by column, so
+    that each is used as it is made: for each j (Akk's column j [SUB, 1],
+    zero on and above row j, Aqk's, zero above it), both from one decay
+    tile exp(G_i - G_j) k_j: made a column a step (the kernels' form) or
+    all at once over [SUB, SUB, dk] (`vector`, the composition's)."""
+    import jax.numpy as jnp
+
+    i = _iota((SUB, 1), 0)
+    if vector:
+        keep, e = _pairs(g_blk, False)
+        w = e * kn_blk[None, :, :]
+        strict = (_iota((SUB, SUB), 0) > _iota((SUB, SUB), 1))[:, :, None]
+        akk = jnp.sum(jnp.where(strict, kn_blk[:, None, :] * w, 0.0), -1)
+        aqk = jnp.sum(jnp.where(keep, qs_blk[:, None, :] * w, 0.0), -1)
+        for j in range(SUB):
+            yield akk[:, j:j + 1], aqk[:, j:j + 1]
+        return
+    for j in range(SUB):
+        w = _pair_decay(g_blk, g_blk[j:j + 1], i >= j) * kn_blk[j:j + 1]
+        yield (
+            jnp.where(i > j, jnp.sum(kn_blk * w, axis=1, keepdims=True), 0.0),
+            jnp.where(i >= j, jnp.sum(qs_blk * w, axis=1, keepdims=True),
+                      0.0))
+
+
+def _diag_factors(kn_blk, qs_blk, b_blk, g_blk, lo, c, vector):
+    """[SUB, C] each, in columns lo .. lo + SUB: a sub-block's own pairs of
+    Akk and of Aqk, and its rows of the inverse of its diagonal block of I
+    + Diag(b) Akk, by forward substitution as Akk's columns come (row j of
+    the inverse is whole once the columns before j are applied)."""
+    import jax.numpy as jnp
+
+    lanes = _iota((SUB, c), 1)
+    akk = aqk = jnp.zeros((SUB, c), jnp.float32)
+    inv = (lanes == lo + _iota((SUB, 1), 0)).astype(jnp.float32)
+    for j, (kcol, qcol) in enumerate(
+            _own_columns(kn_blk, qs_blk, g_blk, vector)):
+        at = lanes == lo + j
+        akk = akk + jnp.where(at, kcol, 0.0)
+        aqk = aqk + jnp.where(at, qcol, 0.0)
+        inv = inv - b_blk * kcol * inv[j:j + 1]
+    return akk, aqk, inv
+
+
+def _factors(kn, qs, beta, gam, vector):
+    """(Akk, Aqk, D^-1) [C, C] of a chunk in one walk over its sub-blocks,
+    D = I + Diag(b) Akk on its diagonal sub-blocks alone: the two Grams
+    share each decay tile, and their pairs across sub-blocks one [2 SUB,
+    dk] x [C, dk] product a sub-block (Akk's rows over Aqk's, both against
+    the same decayed k)."""
+    import jax.numpy as jnp
+
+    c = kn.shape[0]
+    below = dict(_refs(gam))
+    blocks = []
+    for lo, g_blk in _sub_blocks(gam):
+        kn_blk, qs_blk = kn[lo:lo + SUB], qs[lo:lo + SUB]
+        akk, aqk, inv = _diag_factors(kn_blk, qs_blk, beta[lo:lo + SUB],
+                                      g_blk, lo, c, vector)
+        if lo in below:
+            ref = below[lo]
+            right = jnp.where(_iota(kn.shape, 0) < lo,
+                              kn * jnp.exp(jnp.minimum(ref - gam, 0.0)), 0.0)
+            d = jnp.exp(g_blk - ref)
+            both = _dot(jnp.concatenate([kn_blk * d, qs_blk * d], axis=0),
+                        right, "nt")
+            akk, aqk = akk + both[:SUB], aqk + both[SUB:]
+        blocks.append((akk, aqk, inv))
+    return [jnp.concatenate(x, axis=0) for x in zip(*blocks)]
+
+
+def _unit_lower_inverse(n, diag_inv):
+    """(I + n)^-1 for n [C, C] strictly lower triangular, from diag_inv,
+    the inverse of I + n on its diagonal sub-blocks: (I + E)^-1 diag_inv,
+    E = diag_inv times n below those blocks, by the product (I - E)(I +
+    E^2)(I + E^4).. (E^(C / SUB) = 0)."""
     import jax.numpy as jnp
 
     c = n.shape[0]
     blk_r, blk_c = _iota((c, c), 0) // SUB, _iota((c, c), 1) // SUB
     eye = _eye(c).astype(jnp.float32)
-
-    def product(m, order):
-        inv, power = eye - m, m
-        span = 2
-        while span < order:
-            power = _dot(power, power, "nn")
-            inv = _dot(inv, eye + power, "nn")
-            span *= 2
-        return inv
-
-    diag_inv = product(jnp.where(blk_r == blk_c, n, 0.0), SUB)
-    if c == SUB:
-        return diag_inv
     e = _dot(diag_inv, jnp.where(blk_r > blk_c, n, 0.0), "nn")
-    return _dot(product(e, c // SUB), diag_inv, "nn")
+    inv, power = eye - e, e
+    span = 2
+    while span < c // SUB:
+        power = _dot(power, power, "nn")
+        inv = _dot(inv, eye + power, "nn")
+        span *= 2
+    return _dot(inv, diag_inv, "nn")
 
 
 def _prepare(q, k, g, scale, vector):
-    """What the forward and the backward both make from a chunk's q, k, g:
-    normalised q and k with their 1 / norms, q scaled, G and Akk.
-    `vector`: the sub-blocks' own pairs at once (the composition), else
-    pair by pair (the kernels)."""
+    """What the backward makes again from a chunk's q, k, g: normalised q
+    and k with their 1 / norms, q scaled, G and Akk.  `vector`: the
+    sub-blocks' own pairs at once (the composition), else pair by pair
+    (the kernels)."""
     qn, rq = _l2norm(q)
     kn, rk = _l2norm(k)
     gam = _cumsum(g)
@@ -383,9 +455,12 @@ def chunk_fwd(q, k, v, g, beta, h0, scale, vector=False):
     and H_0 = S_0^T [dv, dk], all float32."""
     import jax.numpy as jnp
 
-    _, _, kn, _, qs, gam, akk = _prepare(q, k, g, scale, vector)
-    aqk = gram(qs, kn, gam, strict=False, vector=vector)
-    t = _unit_lower_inverse(beta * akk)
+    qn, _ = _l2norm(q)
+    kn, _ = _l2norm(k)
+    gam = _cumsum(g)
+    qs = qn * scale
+    akk, aqk, diag_inv = _factors(kn, qs, beta, gam, vector)
+    t = _unit_lower_inverse(beta * akk, diag_inv)
     eg = jnp.exp(gam)
     u = _dot(t, beta * (v - _dot(kn * eg, h0, "nt")), "nn")
     o = _dot(qs * eg, h0, "nt") + _dot(aqk, u, "nn")

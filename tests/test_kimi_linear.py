@@ -198,9 +198,10 @@ def test_factors_are_the_inverse_below_and_aqk_transposed_above(vector):
 
 @pytest.mark.parametrize("vector", [False, True])
 def test_backward_makes_no_inverse_and_akk_alone(monkeypatch, vector):
-    """chunk_bwd traced with `_unit_lower_inverse` and `gram` counted: no
-    inverse and one Gram, Akk's (strict); the forward makes both of its
-    Gram matrices and the inverse."""
+    """chunk_bwd traced with `_unit_lower_inverse`, `_factors` and `gram`
+    counted: no inverse and one Gram, Akk's (strict); the forward makes
+    both of its Gram matrices and the diagonal sub-blocks' inverse in one
+    walk (`_factors`), then the inverse below them, and calls no `gram`."""
     made = []
 
     def counted(name, fn):
@@ -210,6 +211,7 @@ def test_backward_makes_no_inverse_and_akk_alone(monkeypatch, vector):
         return wrapped
 
     monkeypatch.setattr(K, "gram", counted("gram", K.gram))
+    monkeypatch.setattr(K, "_factors", counted("factors", K._factors))
     monkeypatch.setattr(K, "_unit_lower_inverse",
                         counted("inverse", K._unit_lower_inverse))
     c, dk, dv = 32, 16, 8
@@ -217,8 +219,7 @@ def test_backward_makes_no_inverse_and_akk_alone(monkeypatch, vector):
     h0 = jnp.zeros((dv, dk))
     jax.make_jaxpr(functools.partial(K.chunk_fwd, vector=vector))(
         q, k, v, g, beta, h0, dk ** -0.5)
-    assert sorted(made) == [("gram", False), ("gram", True),
-                            ("inverse", None)]
+    assert made == [("factors", None), ("inverse", None)]
     made.clear()
     jax.make_jaxpr(functools.partial(K.chunk_bwd, vector=vector))(
         q, k, v, g, beta, h0, jnp.zeros((c, c)), v, h0, dk ** -0.5)
@@ -239,6 +240,85 @@ def test_backward_streams_no_full_product_per_sub_block(vector):
             if e.primitive.name == "dot_general"]
     assert 0 < rows.count(c) <= 16
     assert set(rows) == {K.SUB, c}
+
+
+def _traced_counts(fn, *shapes):
+    """(rows of each product's result, number of `exp`s) of fn traced on
+    float32 operands of these shapes (the scale dk^-1/2 last)."""
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32)
+    jaxpr = jax.make_jaxpr(fn)(*map(f32, shapes[:-1]), shapes[-1])
+    eqns = jaxpr.jaxpr.eqns
+    return ([e.outvars[0].aval.shape[0] for e in eqns
+             if e.primitive.name == "dot_general"],
+            sum(e.primitive.name == "exp" for e in eqns))
+
+
+@pytest.mark.parametrize("vector", [False, True])
+def test_forward_makes_twelve_full_products_and_stacks_the_grams(vector):
+    """chunk_fwd at C = 128: the diagonal sub-blocks' inverse comes out of
+    the Grams' walk, so 12 products have a 128-row result (18 before); the
+    two Grams' rows of a sub-block meet the shared decayed k in one
+    32-row product (seven, where there were fourteen of 16 rows); the
+    kernels' form makes each decay tile once for both Grams."""
+    c = dk = dv = 128
+    rows, exps = _traced_counts(
+        functools.partial(K.chunk_fwd, vector=vector), (c, dk), (c, dk),
+        (c, dv), (c, dk), (c, 1), (dv, dk), dk ** -0.5)
+    assert 0 < rows.count(c) <= 12
+    assert rows.count(2 * K.SUB) == c // K.SUB - 1
+    assert set(rows) == {2 * K.SUB, c}
+    if not vector:  # a tile a column of each sub-block, 2 a stacked product
+        assert exps == c + 2 * (c // K.SUB - 1) + 3
+
+
+@pytest.mark.parametrize("vector", [False, True])
+def test_backward_products_and_exps_are_unchanged(vector):
+    """chunk_bwd at C = 128 keeps its products (14 full, 35 of 16 rows) and
+    its `exp`s: the forward's one walk leaves the backward as it was."""
+    c = dk = dv = 128
+    rows, exps = _traced_counts(
+        functools.partial(K.chunk_bwd, vector=vector), (c, dk), (c, dk),
+        (c, dv), (c, dk), (c, 1), (dv, dk), (c, c), (c, dv), (dv, dk),
+        dk ** -0.5)
+    assert (rows.count(c), rows.count(K.SUB), len(rows)) == (14, 35, 49)
+    assert exps == (115 if vector else 715)
+
+
+@pytest.mark.parametrize("vector", [False, True])
+@pytest.mark.parametrize("gate_scale", [0.3, 8.0])
+@pytest.mark.parametrize("c", [32, 64, 128])
+def test_one_walk_is_both_grams_and_the_diagonal_inverse(c, gate_scale,
+                                                         vector):
+    """`_factors` against dense float64 on the same float32 G: Akk and Aqk
+    as sums over every pair, D^-1 as np.linalg.inv of each diagonal
+    sub-block of I + Diag(b) Akk; at gate_scale 8 the log-decay passes 88
+    in the chunk and all three stay finite."""
+    dk = 32
+    q, k, _, g, beta = _one_chunk(c, dk, 8, gate_scale, seed=c)
+    kn = np.asarray(k, np.float64)
+    kn = kn / np.sqrt(np.sum(kn * kn, -1, keepdims=True) + K.L2_EPS)
+    qs = np.asarray(q, np.float64)
+    qs = qs / np.sqrt(np.sum(qs * qs, -1, keepdims=True) + K.L2_EPS)
+    qs = qs * dk ** -0.5
+    gam = np.cumsum(np.asarray(g), axis=0, dtype=np.float32)
+    assert (float(gam.min()) < -88.0) == (gate_scale == 8.0)
+    g64 = gam.astype(np.float64)
+    decay = np.exp(np.minimum(g64[:, None, :] - g64[None, :, :], 0.0))
+    akk = np.tril(np.einsum("ic,jc,ijc->ij", kn, kn, decay), -1)
+    aqk = np.tril(np.einsum("ic,jc,ijc->ij", qs, kn, decay))
+    d = np.eye(c) + np.asarray(beta, np.float64) * akk
+    dinv = np.zeros((c, c))
+    for lo in range(0, c, K.SUB):
+        blk = slice(lo, lo + K.SUB)
+        dinv[blk, blk] = np.linalg.inv(d[blk, blk])
+    got = jax.jit(K._factors, static_argnums=4)(
+        jnp.asarray(kn, jnp.float32), jnp.asarray(qs, jnp.float32),
+        jnp.asarray(beta), jnp.asarray(gam), vector)
+    for name, a, want in zip(("akk", "aqk", "dinv"), got, (akk, aqk, dinv)):
+        a = np.asarray(a)
+        assert np.all(np.isfinite(a)), name
+        assert float(np.max(np.abs(a - want))) <= 1e-6 * float(
+            np.max(np.abs(want))), name
 
 
 @pytest.mark.parametrize("vector", [False, True])
